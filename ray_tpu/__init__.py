@@ -41,11 +41,15 @@ __version__ = "0.5.0"
 
 
 def get_tpu_ids():
-    """Chip indices allocated to the current worker (reference role:
-    ray.get_gpu_ids, _private/worker.py:1170, for the TPU resource)."""
+    """Chip indices the raylet granted the current worker (reference role:
+    ray.get_gpu_ids, _private/worker.py:1170, for the TPU resource). The
+    worker pool starts a chip-owning worker with them in its environment;
+    every other process gets ``[]``."""
     import os
 
-    raw = os.environ.get("TPU_VISIBLE_CHIPS", "")
+    from ._internal.accelerators import GRANTED_CHIPS_ENV
+
+    raw = os.environ.get(GRANTED_CHIPS_ENV, "")
     return [int(x) for x in raw.split(",") if x.strip().isdigit()]
 
 

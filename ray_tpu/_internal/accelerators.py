@@ -201,24 +201,21 @@ class TpuAcceleratorManager(AcceleratorManager):
         return {}
 
     @staticmethod
-    def get_visibility_env(instance_ids) -> Dict[str, str]:
-        return set_visible_chips(instance_ids)
-
-    @staticmethod
     def detect_num_chips() -> int:
+        """Chips attached to this node. The device files are what is
+        attached; the runtime's ``TPU_CHIPS_PER_HOST_BOUNDS`` describes the
+        host the VM was cut from (a one-chip machine of a 2x2 host still
+        says "2,2,1") and only counts where no device file exists."""
+        chips = count_chip_devices()
+        if chips:
+            return chips
         env = os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS")
-        if env:
-            # "2,2,1" style bounds string
-            total = 1
-            for part in env.split(","):
-                total *= int(part)
-            return total
-        # numbered vfio devices only: /dev/vfio/vfio is the always-present
-        # control node, not a chip
-        chips = len(glob.glob("/dev/accel*")) or len(
-            glob.glob("/dev/vfio/[0-9]*")
-        )
-        return chips
+        if not env:
+            return 0
+        total = 1
+        for part in env.split(","):
+            total *= int(part)
+        return total
 
     @staticmethod
     def current_node_identity() -> Dict[str, str]:
@@ -245,14 +242,43 @@ class TpuAcceleratorManager(AcceleratorManager):
 
 
 
-def set_visible_chips(instance_ids) -> Dict[str, str]:
-    """Env vars restricting a worker process to specific chips (reference:
-    tpu.py TPU_VISIBLE_CHIPS handling :36-50)."""
-    ids = ",".join(str(i) for i in instance_ids)
-    return {
-        "TPU_VISIBLE_CHIPS": ids,
-        "TPU_CHIPS_PER_PROCESS_BOUNDS": f"1,{max(len(instance_ids), 1)},1",
-    }
+def count_chip_devices() -> int:
+    """TPU device files on this node: ``/dev/accel*``, or numbered vfio
+    groups (``/dev/vfio/vfio`` is the always-present control node, not a
+    chip)."""
+    return len(glob.glob("/dev/accel*")) or len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+# chip ids the raylet granted this worker; what get_tpu_ids() reads
+GRANTED_CHIPS_ENV = "RAY_TPU_GRANTED_CHIPS"
+
+
+def set_visible_chips(instance_ids, node_chips: int) -> Dict[str, str]:
+    """Env vars that make a worker process the owner of exactly the granted
+    chips of a ``node_chips``-chip node (reference: tpu.py
+    set_current_process_visible_accelerator_ids). A worker granted the
+    whole host keeps the host's own settings. One chip is carved out with
+    single-chip, single-host bounds, which is also what lets several such
+    processes load the TPU runtime side by side (four of them each own a
+    chip of a v5e 2x2 host). Both spellings of the bounds are set: libtpu
+    reads ``*_PROCESS_BOUNDS`` first, and the ``*_HOST_BOUNDS`` a TPU VM
+    image exports would otherwise still describe the whole host. Other
+    sizes only name their chips: the bounds that fit depend on where the
+    chips sit in the host's topology (a 2-chip carve-out with the
+    reference's "1,2,1" did not initialise on that host)."""
+    ids = ",".join(str(int(i)) for i in instance_ids)
+    env = {GRANTED_CHIPS_ENV: ids}
+    if len(instance_ids) >= node_chips:
+        return env
+    env["TPU_VISIBLE_CHIPS"] = ids
+    if len(instance_ids) == 1:
+        env.update({
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_CHIPS_PER_HOST_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_HOST_BOUNDS": "1,1,1",
+        })
+    return env
 
 
 @register_accelerator_manager

@@ -90,7 +90,12 @@ class Config:
 
     # --- fault tolerance ---
     health_check_period_s: float = 1.0
-    health_check_timeout_s: float = 10.0
+    # How long a node may go unheard before it is declared dead (reference:
+    # period 3 s x failure threshold 5 + timeout 10 s). Not 10 s: on a TPU
+    # host, a worker bringing the TPU runtime up for four chips froze every
+    # other process of the machine for 10.9 s (v5e 2x2 host in a sandbox,
+    # PR 21), and the GCS buried the raylet it shares a process with.
+    health_check_timeout_s: float = 30.0
     # --- partition tolerance ---
     # A node whose resource reports stop arriving is actively probed
     # (raylet ping) once its report age exceeds this; a failed probe marks

@@ -303,7 +303,7 @@ def _weights_broadcast_bench(scale: float) -> Dict[str, float]:
     one subscriber per measured fan-out level. Same-node numbers here — the
     O(1)-in-subscribers publisher upload is asserted by the multi-node test
     (tests/test_weights_broadcast.py); MB/s vs subscriber count on a real
-    cluster lands in BENCH_LOG.md."""
+    cluster has not been measured."""
     import numpy as np
 
     from ray_tpu import weights
@@ -415,7 +415,7 @@ def print_results(results: Dict[str, float]) -> None:
 
 
 def json_results(results: Dict[str, float]) -> str:
-    """One machine-readable JSON line for BENCH_LOG.md appends: every metric
+    """One machine-readable JSON line: every metric
     with its unit, plus the per-method RPC latency histograms recorded by
     the run (the lease-reuse / v2-framing proof layer)."""
     import json

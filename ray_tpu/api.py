@@ -54,6 +54,13 @@ def init(
             return _worker_api.get_node()
         raise RuntimeError("ray_tpu.init() called twice; shutdown() first")
 
+    # a chip belongs to one process, and that process is the worker the
+    # raylet leases it to: the driver (with the GCS and raylet on its loop
+    # thread) stays on the CPU platform unless it already holds a backend
+    from ._internal.platform import pin_cpu_platform
+
+    pin_cpu_platform()
+
     if address is not None and address.startswith("ray://"):
         from .client import connect as _client_connect
 
@@ -367,6 +374,24 @@ def nodes() -> List[dict]:
         }
         for n in infos
     ]
+
+
+def require_chips(chips: float, what: str) -> None:
+    """Raise NoAcceleratorError unless some alive node has ``chips`` TPU
+    chips: ``what`` (a use_tpu trainer worker, a TPU replica) must land
+    whole on one node, and an infeasible lease waits instead of failing."""
+    most = max(
+        (n["Resources"].get("TPU", 0.0) for n in nodes() if n["Alive"]),
+        default=0.0,
+    )
+    if most < chips:
+        from .exceptions import NoAcceleratorError
+
+        raise NoAcceleratorError(
+            f"{what} needs {chips:g} TPU chip(s) on one node; the largest "
+            f"alive node has {most:g} (chip detection counts /dev/accel* "
+            "or /dev/vfio/<n>; pass num_tpus= to init() to override)"
+        )
 
 
 def cluster_resources() -> Dict[str, float]:

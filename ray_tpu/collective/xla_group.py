@@ -29,11 +29,11 @@ from typing import Any, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..runtime.gcs import keys as gcs_keys
 from .base import BaseGroup, ReduceOp, tensor_nbytes
-from .._internal.jax_compat import shard_map
 from .._internal.quantization import (
     dequantize_jax,
     quantize_jax,

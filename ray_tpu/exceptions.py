@@ -228,6 +228,13 @@ class MeshValidationError(RayTpuError, ValueError):
     error from deep inside the first sharded prefill."""
 
 
+class NoAcceleratorError(RayTpuError):
+    """A trainer worker or a serve replica asks for TPU chips and no alive
+    node of the cluster has that many — typically a node whose chip
+    detection found none. Raised when the job is submitted: the lease
+    would otherwise be infeasible and wait forever."""
+
+
 class RpcError(RayTpuError):
     """Transport-level RPC failure."""
 

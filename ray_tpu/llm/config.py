@@ -75,15 +75,19 @@ class LLMConfig:
     # fields (target_ttft_p99_ms/target_queue_per_replica/min_replicas/
     # max_replicas/...). Takes precedence over autoscaling_config.
     autoscale_policy: Optional[Dict[str, Any]] = None
-    resources_per_replica: Dict[str, float] = field(
-        default_factory=lambda: {"TPU": 0.0, "CPU": 1.0}
-    )
+    # what one replica leases. None (default) asks for the chips the
+    # replica's mesh needs — tp x sp, 1 without a mesh — when this node has
+    # chips, and for a CPU alone when it has none (tests, CPU clusters).
+    # A replica that leases chips runs in the worker process that owns them.
+    resources_per_replica: Optional[Dict[str, float]] = None
     # generation defaults
     max_new_tokens: int = 64
     temperature: float = 0.0  # 0 = greedy
     # sampling seed: None (default) = fresh per replica process, so
     # temperature>0 replicas don't emit identical streams; set an int for
     # reproducible sampling
+    # (a replica deployed without weights also draws its random-init
+    # parameters from this seed, 0 when None)
     seed: Optional[int] = None
     # paged KV cache (ray_tpu.kvcache): when kv_cache_blocks is set, each
     # replica runs a ContinuousBatchingEngine over a block pool of that
@@ -157,6 +161,12 @@ class LLMConfig:
                         f"LLMConfig.mesh[{axis!r}] must be a positive "
                         f"int, got {size!r}"
                     )
+        if self.resources_per_replica is None:
+            from .._internal.accelerators import TpuAcceleratorManager
+
+            tp, sp = self.effective_parallelism()
+            chips = tp * sp if TpuAcceleratorManager.detect_num_chips() else 0
+            self.resources_per_replica = {"TPU": float(chips), "CPU": 1.0}
         if self.kv_ship_codec not in ("raw", "int8"):
             raise ValueError(
                 f"LLMConfig.kv_ship_codec must be 'raw' or 'int8', got "

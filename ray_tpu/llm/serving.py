@@ -110,7 +110,9 @@ class _LLMReplica:
             from ..models.llama import init_params
 
             params = unbox_params(
-                init_params(model_config, jax.random.PRNGKey(0))
+                init_params(
+                    model_config, jax.random.PRNGKey(llm_config.seed or 0)
+                )
             )
         if role not in (None, "prefill", "decode"):
             raise ValueError(f"unknown replica role {role!r}")
@@ -245,36 +247,42 @@ class _LLMReplica:
         ) and self._weights_sub is not None:
             self.reload_weights(user_config["weights_version"])
 
-    def mesh_info(self) -> Dict[str, Any]:
-        """The replica's mesh ownership card — polled into the serve
-        controller's replica inventory (``ray_tpu list replicas``,
-        dashboard ``/api/serve``): mesh shape, device count, per-device
-        HBM in use where the backend reports it (CPU meshes report None),
-        and the per-device KV block-pool footprint."""
+    def _devices(self) -> list:
+        """The devices this replica spans: its mesh's, or the default one."""
         import jax
 
         if self._plan is None:
-            devices = jax.devices()[:1]
+            return jax.devices()[:1]
+        return list(self._plan.mesh.devices.flat)
+
+    def mesh_info(self) -> Dict[str, Any]:
+        """The replica's mesh ownership card — polled into the serve
+        controller's replica inventory (``ray_tpu list replicas``,
+        dashboard ``/api/serve``): mesh shape, the devices it spans
+        (platform, kind, ids), per-device HBM in use where the backend
+        reports it (CPU meshes report None), and the per-device KV
+        block-pool footprint."""
+        devices = self._devices()
+        if self._plan is None:
             info: Dict[str, Any] = {
                 "mesh": {}, "tag": "tp=1", "num_devices": 1,
             }
         else:
-            devices = list(self._plan.mesh.devices.flat)
             info = {
                 "mesh": self._plan.mesh_shape(),
                 "tag": self._plan.describe(),
                 "num_devices": self._plan.num_devices,
             }
+        info["platform"] = devices[0].platform
+        info["device_kind"] = devices[0].device_kind
+        info["device_ids"] = [d.id for d in devices]
         hbm = []
         for d in devices:
-            try:
-                stats = d.memory_stats()
-                hbm.append(
-                    int(stats["bytes_in_use"])
-                    if stats and "bytes_in_use" in stats else None
-                )
-            except Exception:
-                hbm.append(None)
+            stats = d.memory_stats()  # None on backends that keep none
+            hbm.append(
+                int(stats["bytes_in_use"])
+                if stats and "bytes_in_use" in stats else None
+            )
         info["per_device_hbm_bytes"] = hbm
         if self._kv_cache is not None:
             info["kv_pool_bytes_per_device"] = self._kv_cache.pool_accounting()[
@@ -286,6 +294,63 @@ class _LLMReplica:
                 self._weights_sub.wire_bytes_pulled
             )
         return info
+
+    def runtime_info(self) -> Dict[str, Any]:
+        """What this replica's process holds and compiled: its pid, the
+        chips the raylet granted it, peak device bytes, where its compile
+        cache lives and what it answered, and the mode each Pallas kernel
+        was traced in. Read by chip_smoke.py; the process that owns the
+        chip is the only one that can report these."""
+        import os
+
+        import jax
+
+        from .. import get_tpu_ids
+        from .._internal import compile_cache
+        from .._internal.platform import traced_kernel_modes
+
+        return {
+            "pid": os.getpid(),
+            "tpu_ids": get_tpu_ids(),
+            "peak_hbm_bytes": [
+                (d.memory_stats() or {}).get("peak_bytes_in_use")
+                for d in self._devices()
+            ],
+            "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+            "compile": compile_cache.stats(),
+            "kernels": traced_kernel_modes(),
+        }
+
+    def check_prefill_logits(self, token_ids) -> Dict[str, Any]:
+        """Parity self-check on this replica's own weights: the last
+        position's logits of ``token_ids`` from the engine's prefill
+        program (einsum attention over the cache) against a plain
+        full-sequence forward through ``Llama(cfg, None)`` (the flash
+        kernel). Returns both argmaxes, the largest absolute logit
+        difference, and the reference's margin between its best two
+        tokens — a difference above the margin can flip a greedy token
+        without either path being wrong."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..models.llama import Llama
+
+        cfg = self._engine._cfg
+        tokens = jnp.asarray([list(token_ids)], jnp.int32)
+        engine_logits, _ = self._engine._prefill(self._engine._params, tokens)
+        ref_logits = jax.jit(
+            lambda p, t: Llama(cfg, None).apply({"params": p}, t)[:, -1, :]
+        )(self._engine._params, tokens)
+        eng = engine_logits[0].astype(jnp.float32)
+        ref = ref_logits[0].astype(jnp.float32)
+        top2 = jax.lax.top_k(ref, 2)[0]
+        return {
+            "reference_argmax": int(jnp.argmax(ref)),
+            "engine_argmax": int(jnp.argmax(eng)),
+            "max_abs_logit_diff": float(jnp.max(jnp.abs(eng - ref))),
+            "reference_top2_margin": float(top2[0] - top2[1]),
+            "finite": bool(jnp.all(jnp.isfinite(eng)) & jnp.all(jnp.isfinite(ref))),
+        }
 
     def kvcache_stats(self) -> Optional[Dict[str, Any]]:
         """Replica-local KV-cache stats (None on the dense engine); routed
@@ -570,9 +635,16 @@ def build_llm_deployment(
     base_name = name or llm_config.model_id
 
     def _common_options() -> Dict[str, Any]:
-        return dict(
-            ray_actor_options=dict(llm_config.resources_per_replica),
-        )
+        # the replica actor leases exactly resources_per_replica; a TPU
+        # share makes its worker the owner of those chips
+        res = dict(llm_config.resources_per_replica)
+        actor = {"num_cpus": res.pop("CPU", 1.0)}
+        chips = res.pop("TPU", 0)
+        if chips:
+            actor["num_tpus"] = chips
+        if res:
+            actor["resources"] = res
+        return dict(ray_actor_options=actor)
 
     if llm_config.roles is not None:
         prefill_dep = serve.deployment(

@@ -25,6 +25,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.flash_attention import flash_attention
@@ -32,7 +33,6 @@ from ..ops.rmsnorm import rmsnorm
 from ..ops.rope import apply_rope, rope_table
 from ..parallel.ring_attention import ring_attention
 from ..parallel.sharding import logical_to_spec
-from .._internal.jax_compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -308,7 +308,8 @@ class Block(nn.Module):
             cfg.param_dtype,
         )
         h = x + Attention(cfg, self.mesh, self.decode, name="attn")(
-            rmsnorm(x, attn_norm_w.astype(x.dtype), cfg.norm_eps), cos, sin,
+            rmsnorm(x, attn_norm_w.astype(x.dtype), cfg.norm_eps, self.mesh),
+            cos, sin,
             (adapters or {}).get("attn"), adapter_slots,
         )
         mlp_norm_w = self.param(
@@ -318,7 +319,7 @@ class Block(nn.Module):
             cfg.param_dtype,
         )
         return h + MLP(cfg, name="mlp")(
-            rmsnorm(h, mlp_norm_w.astype(h.dtype), cfg.norm_eps)
+            rmsnorm(h, mlp_norm_w.astype(h.dtype), cfg.norm_eps, self.mesh)
         )
 
 
@@ -394,7 +395,7 @@ class Llama(nn.Module):
             (cfg.dim,),
             cfg.param_dtype,
         )
-        x = rmsnorm(x, final_norm_w.astype(x.dtype), cfg.norm_eps)
+        x = rmsnorm(x, final_norm_w.astype(x.dtype), cfg.norm_eps, self.mesh)
         head = self.param(
             "lm_head",
             nn.with_logical_partitioning(

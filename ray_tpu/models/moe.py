@@ -159,7 +159,8 @@ class MoEBlock(nn.Module):
             cfg.param_dtype,
         )
         h = x + Attention(attn_cfg, self.mesh, name="attn")(
-            rmsnorm(x, attn_norm_w.astype(x.dtype), cfg.norm_eps), cos, sin
+            rmsnorm(x, attn_norm_w.astype(x.dtype), cfg.norm_eps, self.mesh),
+            cos, sin,
         )
         ffn_norm_w = self.param(
             "ffn_norm",
@@ -168,7 +169,7 @@ class MoEBlock(nn.Module):
             cfg.param_dtype,
         )
         return h + MoEFFN(cfg, name="moe")(
-            rmsnorm(h, ffn_norm_w.astype(h.dtype), cfg.norm_eps)
+            rmsnorm(h, ffn_norm_w.astype(h.dtype), cfg.norm_eps, self.mesh)
         )
 
 
@@ -204,7 +205,7 @@ class MoETransformer(nn.Module):
             (cfg.dim,),
             cfg.param_dtype,
         )
-        x = rmsnorm(x, final_norm_w.astype(x.dtype), cfg.norm_eps)
+        x = rmsnorm(x, final_norm_w.astype(x.dtype), cfg.norm_eps, self.mesh)
         head = self.param(
             "lm_head",
             nn.with_logical_partitioning(

@@ -31,9 +31,9 @@ _NEG_INF = -1e30
 
 
 def _use_interpret() -> bool:
-    from ray_tpu._internal.platform import is_tpu_backend
+    from ray_tpu._internal.platform import pallas_interpret
 
-    return not is_tpu_backend()
+    return pallas_interpret("flash_attention")
 
 
 # ---------------------------------------------------------------------------

@@ -8,18 +8,19 @@ numerics LLaMA-family models expect.
 from __future__ import annotations
 
 import functools
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def _use_interpret() -> bool:
-    from ray_tpu._internal.platform import is_tpu_backend
+    from ray_tpu._internal.platform import pallas_interpret
 
-    return not is_tpu_backend()
+    return pallas_interpret("rmsnorm")
 
 
 def _fwd_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -45,68 +46,13 @@ def _rmsnorm_fwd_impl(x2, w, eps, block_rows):
     )(x2, w)
 
 
-# --- GSPMD partitioning rule -------------------------------------------------
-# A pallas_call is an opaque custom-call to the SPMD partitioner: without a
-# rule it REPLICATES the operand (all-gather of the full batch on every chip,
-# then dynamic-slice back — the "involuntary full rematerialization" path),
-# which turned per-chip collective bytes linear in the dp degree. Rows are
-# independent, so declare: x row-sharded / feature dim unsharded, w
-# replicated, out like x. Covers both partitioners: callbacks for GSPMD,
-# einsum-style sharding_rule for Shardy.
-
-
-def _row_sharding(mesh, x_sharding):
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    spec = getattr(x_sharding, "spec", None)
-    row = spec[0] if spec else None
-    return NamedSharding(mesh, P(row, None))
-
-
-def _rmsnorm_infer_sharding(eps, mesh, arg_infos, result_infos):
-    return _row_sharding(mesh, arg_infos[0].sharding)
-
-
-def _rmsnorm_partition(eps, mesh, arg_infos, result_infos):
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    x_sharding = _row_sharding(mesh, arg_infos[0].sharding)
-    w_sharding = NamedSharding(mesh, P())
-
-    def lower_fn(x2, w):
-        return _rmsnorm_fwd_impl(x2, w, eps, block_rows=256)
-
-    return mesh, lower_fn, x_sharding, (x_sharding, w_sharding)
-
-
-@functools.partial(custom_partitioning, static_argnums=(2,))
-def _rmsnorm_sharded(x2, w, eps):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rmsnorm(x2, w, eps):
     return _rmsnorm_fwd_impl(x2, w, eps, block_rows=256)
 
 
-try:
-    _rmsnorm_sharded.def_partition(
-        partition=_rmsnorm_partition,
-        infer_sharding_from_operands=_rmsnorm_infer_sharding,
-        sharding_rule="i j, j -> i j",
-    )
-except TypeError:
-    # older jax: custom_partitioning predates the Shardy sharding_rule
-    # kwarg — register the GSPMD callbacks alone rather than failing the
-    # import (which took the whole llama/llm stack down with it)
-    _rmsnorm_sharded.def_partition(
-        partition=_rmsnorm_partition,
-        infer_sharding_from_operands=_rmsnorm_infer_sharding,
-    )
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _rmsnorm(x2, w, eps):
-    return _rmsnorm_sharded(x2, w, eps)
-
-
 def _rmsnorm_fwd(x2, w, eps):
-    return _rmsnorm_sharded(x2, w, eps), (x2, w)
+    return _rmsnorm(x2, w, eps), (x2, w)
 
 
 def _rmsnorm_bwd(eps, res, g):
@@ -129,9 +75,51 @@ def _rmsnorm_bwd(eps, res, g):
 _rmsnorm.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
 
 
-def rmsnorm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
-    """RMSNorm over the last axis; any leading shape."""
+def _rmsnorm_any_shape(x, weight, eps):
     shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
-    out = _rmsnorm(x2, weight, eps)
+    out = _rmsnorm(x.reshape(-1, shape[-1]), weight, eps)
     return out.reshape(shape)
+
+
+def activation_spec(mesh: Mesh, shape) -> P:
+    """How the models lay an activation of ``shape`` out on ``mesh``
+    (parallel/sharding.py DEFAULT_RULES): batch over the data axes, the
+    sequence axis (second to last, when there is one) over sp, features
+    whole — so under tp every rank holds the full residual stream. An axis
+    its mesh axes do not divide (a decode batch of 1 on a dp mesh) stays
+    whole."""
+    def fit(axes, size):
+        axes = tuple(a for a in axes if a in mesh.axis_names)
+        extent = math.prod(mesh.shape[a] for a in axes)
+        return axes if extent > 1 and size % extent == 0 else None
+
+    if len(shape) < 2:
+        return P(None)
+    batch = fit(("dcn", "dp", "fsdp"), shape[0])
+    if len(shape) == 2:
+        return P(batch, None)
+    return P(batch, *([None] * (len(shape) - 3)), fit(("sp",), shape[-2]), None)
+
+
+def rmsnorm(
+    x: jax.Array, weight: jax.Array, eps: float = 1e-6,
+    mesh: Optional[Mesh] = None,
+) -> jax.Array:
+    """RMSNorm over the last axis; any leading shape.
+
+    A pallas_call is opaque to the SPMD partitioner: left alone under a
+    multi-device mesh it is replicated — every chip all-gathers the whole
+    batch, runs every row, and slices its share back. Rows are independent,
+    so with a ``mesh`` the kernel runs per shard under shard_map, on the
+    layout the models already keep their activations in
+    (``activation_spec``), weight replicated. (custom_partitioning, the
+    declarative way to say the same, never reaches libtpu's partitioner on
+    this installation: the TPU compiler rejects the program with "Custom
+    emitter for CustomSPMDPartitioning not found".)"""
+    if mesh is None or mesh.size == 1:
+        return _rmsnorm_any_shape(x, weight, eps)
+    spec = activation_spec(mesh, x.shape)
+    return jax.shard_map(
+        functools.partial(_rmsnorm_any_shape, eps=eps),
+        mesh=mesh, in_specs=(spec, P()), out_specs=spec, check_vma=False,
+    )(x, weight)

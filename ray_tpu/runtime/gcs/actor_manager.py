@@ -28,6 +28,15 @@ if TYPE_CHECKING:
 
 logger = logging.getLogger(__name__)
 
+# How long the creation push may take: the actor's constructor runs inside
+# it. A model replica initialises its accelerator, loads or generates
+# weights and builds its engine there — minutes, not the half minute a
+# control-plane call gets (which on a TPU host left the timed-out worker
+# alive, still constructing, holding the chip the retry then could not
+# open). A worker that dies meanwhile fails the push at once through its
+# dropped connection.
+_ACTOR_CREATION_TIMEOUT_S = 900.0
+
 
 class GcsActorManager:
     def __init__(self, gcs: "GcsServer"):
@@ -163,7 +172,9 @@ class GcsActorManager:
             try:
                 raylet = self._gcs.raylet_client(node_id)
                 worker_client = self._gcs.client_pool.get(*worker_addr)
-                await worker_client.call("create_actor", spec, timeout=30.0)
+                await worker_client.call(
+                    "create_actor", spec, timeout=_ACTOR_CREATION_TIMEOUT_S
+                )
             except Exception as e:
                 logger.warning("actor %s creation push failed: %s", info.actor_id, e)
                 try:

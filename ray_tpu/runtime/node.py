@@ -17,6 +17,7 @@ from typing import Dict, Optional, Tuple
 
 from .._internal.config import Config
 from .._internal.event_loop import LoopThread
+from .._internal.platform import pin_cpu_platform
 from .gcs.server import GcsServer
 from .raylet.raylet import Raylet
 
@@ -33,6 +34,8 @@ class Node:
         object_store_memory: Optional[int] = None,
         loop_thread: Optional[LoopThread] = None,
     ):
+        # a process that hosts a raylet hands its chips to workers
+        pin_cpu_platform()
         self.config = config
         self.head = head
         self.session_id = session_id or f"{os.getpid()}_{int(time.time() * 1000) % 10**8}"

@@ -215,6 +215,7 @@ class Raylet:
             auth_token=self.config.cluster_auth_token,
             log_dir=self.log_dir,
             log_sink=self._worker_log_sink,
+            node_chips=int(self.resources.total_float().get("TPU", 0)),
         )
         gcs = self.client_pool.get(*self.gcs_address)
         info = self._node_info()
@@ -629,7 +630,11 @@ class Raylet:
         if lease is None:
             return False
         self.resources.release(lease.allocation)
-        if not worker_failed:
+        if worker_failed:
+            # never serves again — and must not linger: whatever it was
+            # doing (a constructor past its deadline) it may hold a chip
+            self.worker_pool.discard(lease.worker)
+        else:
             self.worker_pool.push(lease.worker)
         self._dispatch_wakeup.set()
         return True
@@ -769,10 +774,13 @@ class Raylet:
             return None
         from ..._internal.runtime_env import env_key as _env_key
 
+        # a lease that was granted chips gets the worker that owns exactly
+        # those chips; every other worker is pinned to the CPU platform
         worker = await self.worker_pool.pop(
             timeout=60.0,
             env_key=_env_key(spec.runtime_env),
             runtime_env=spec.runtime_env,
+            chip_ids=allocation.instance_ids.get("TPU", ()),
         )
         if worker is None:
             self.resources.release(allocation)

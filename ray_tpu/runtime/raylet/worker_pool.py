@@ -10,6 +10,13 @@ monitor, _private/log_monitor.py): each worker's output is pumped by a reader
 thread into a per-worker file under the session log dir and, batched, into a
 ``log_sink`` callable that the raylet wires to the GCS "logs" pubsub channel
 so drivers can echo worker output (ray.init(log_to_driver=True) semantics).
+
+Chip ownership: a TPU chip belongs to one process at a time, so a lease
+that was granted TPU instances gets a worker dedicated to exactly those
+chips — started with its chip visibility set before any JAX backend can
+exist, keyed like a runtime-env worker so a later lease of the same chips
+reuses the process that already holds them. Every other worker is started
+pinned to the CPU platform and can never claim a chip.
 """
 
 from __future__ import annotations
@@ -24,9 +31,26 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+from ..._internal.accelerators import set_visible_chips
 from ..._internal.ids import NodeID, WorkerID
+from ..._internal.platform import chip_worker_platforms
 
 logger = logging.getLogger(__name__)
+
+_CHIP_KEY = "+tpu:"
+
+
+def _pool_key(env_key: str, chip_ids) -> str:
+    """Dedicated-worker key: the runtime-env fingerprint, plus the granted
+    chip ids for a chip-owning worker."""
+    if not chip_ids:
+        return env_key
+    return env_key + _CHIP_KEY + ",".join(str(int(i)) for i in chip_ids)
+
+
+def _key_chips(key: str) -> frozenset:
+    _, sep, ids = key.partition(_CHIP_KEY)
+    return frozenset(int(i) for i in ids.split(",")) if sep else frozenset()
 
 
 @dataclass
@@ -52,8 +76,12 @@ class WorkerPool:
         auth_token: str = "",
         log_dir: Optional[str] = None,
         log_sink: Optional[Callable[[dict], None]] = None,
+        node_chips: int = 0,
     ):
         self._node_id = node_id
+        # the node's TPU total: a grant of all of it keeps the host's own
+        # TPU runtime settings, a smaller one is carved out
+        self._node_chips = node_chips
         self._raylet_port_getter = raylet_port_getter
         self._gcs_address = gcs_address
         self._session_id = session_id
@@ -72,6 +100,9 @@ class WorkerPool:
         # lease waiters keyed by runtime-env fingerprint (reference:
         # WorkerPool pops workers matching the lease's runtime env)
         self._waiters: Dict[str, List[asyncio.Future]] = {}
+        # killed chip owners that may not have exited yet: their chips are
+        # free only once the process is gone
+        self._dying: List[WorkerHandle] = []
         self._stopped = False
 
     def _prune_dead_spawns(self):
@@ -88,20 +119,28 @@ class WorkerPool:
     def num_total(self) -> int:
         return len(self._registered) + len(self._pending_spawns)
 
-    def _spawn(self, env_overrides: Optional[dict] = None,
+    def _spawn(self, env_overrides: dict,
                runtime_env: Optional[dict] = None, env_key: str = ""):
-        """Start one worker subprocess; it will dial back and register."""
+        """Start one worker subprocess; it will dial back and register.
+        ``env_overrides`` (``_platform_env``) decides which JAX platform it
+        may initialise; a None value removes the variable."""
         env = dict(os.environ)
         env["RAY_TPU_NODE_ID"] = self._node_id.hex()
         if self._auth_token:
             # Config.__post_init__ picks this up (cluster_auth_token field)
             env["RAY_TPU_CLUSTER_AUTH_TOKEN"] = self._auth_token
-        env.update(env_overrides or {})
+        for key, value in env_overrides.items():
+            if value is None:
+                env.pop(key, None)
+            else:
+                env[key] = value
+        # what the worker registers under (never a value inherited from a
+        # raylet that is itself a dedicated worker's child)
+        env["RAY_TPU_ENV_KEY"] = env_key
         if runtime_env:
             import json as _json
 
             env["RAY_TPU_RUNTIME_ENV"] = _json.dumps(runtime_env)
-            env["RAY_TPU_ENV_KEY"] = env_key
             # env_vars also applied at process start so they are visible to
             # module-level imports (reference: dedicated-worker env vars)
             env.update(runtime_env.get("env_vars") or {})
@@ -228,14 +267,60 @@ class WorkerPool:
         self._idle = [w for w in self._idle if w.worker_id != worker_id]
         return handle
 
+    def _platform_env(self, chip_ids) -> dict:
+        """Which JAX platform the new worker may initialise: the granted
+        chips (visibility set before its backend exists), or the CPU."""
+        if not chip_ids:
+            return {"JAX_PLATFORMS": "cpu"}
+        env = set_visible_chips(chip_ids, self._node_chips)
+        env["JAX_PLATFORMS"] = chip_worker_platforms()
+        return env
+
+    def discard(self, handle: WorkerHandle):
+        """Kill a worker that will not serve again. A chip owner is
+        remembered until its process is gone (``_free_chips``)."""
+        self._kill(handle)
+        if _key_chips(handle.env_key):
+            self._dying.append(handle)
+
+    async def _free_chips(self, key: str):
+        """Before a worker for ``key`` may start, nothing else may hold its
+        chips: an idle owner of any of them under another key cannot serve
+        this lease and is killed, and every killed owner is waited for —
+        the chip is free only when the process is gone."""
+        wanted = _key_chips(key)
+        for handle in [
+            h for h in self._idle
+            if h.env_key != key and _key_chips(h.env_key) & wanted
+        ]:
+            self._idle.remove(handle)
+            self.discard(handle)
+        loop = asyncio.get_event_loop()
+        for handle in list(self._dying):
+            proc = self._spawned_procs.get(handle.pid)
+            if proc is not None and _key_chips(handle.env_key) & wanted:
+                try:
+                    await loop.run_in_executor(None, proc.wait, 10.0)
+                except subprocess.TimeoutExpired:
+                    proc.kill()
+                    await loop.run_in_executor(None, proc.wait)
+            if proc is None or proc.poll() is not None:
+                self._dying.remove(handle)
+                self._spawned_procs.pop(handle.pid, None)
+
     async def pop(self, timeout: float = 60.0, env_key: str = "",
-                  runtime_env: Optional[dict] = None) -> Optional[WorkerHandle]:
-        """Pop an idle worker whose runtime env matches, spawning a
-        dedicated one if needed (reference: WorkerPool::PopWorker matching
-        by runtime-env hash)."""
+                  runtime_env: Optional[dict] = None,
+                  chip_ids=()) -> Optional[WorkerHandle]:
+        """Pop an idle worker whose runtime env (and, for a lease that was
+        granted TPU instances, chip set) matches, spawning a dedicated one
+        if needed (reference: WorkerPool::PopWorker matching by
+        runtime-env hash)."""
+        env_key = _pool_key(env_key, chip_ids)
         for i, handle in enumerate(self._idle):
             if handle.env_key == env_key:
                 return self._idle.pop(i)
+        if chip_ids:
+            await self._free_chips(env_key)
         self._prune_dead_spawns()
         if self.num_total >= self._max_workers and self._idle:
             # pool full of other-env workers: evict the longest-idle one to
@@ -250,7 +335,10 @@ class WorkerPool:
             self.num_total < self._max_workers
             and self._num_starting(env_key) < pending_demand
         ):
-            self._spawn(runtime_env=runtime_env, env_key=env_key)
+            self._spawn(
+                self._platform_env(chip_ids),
+                runtime_env=runtime_env, env_key=env_key,
+            )
         fut: asyncio.Future = asyncio.get_event_loop().create_future()
         self._waiters.setdefault(env_key, []).append(fut)
         try:
@@ -274,7 +362,7 @@ class WorkerPool:
     def prestart(self, count: int):
         for _ in range(count):
             if self.num_total < self._max_workers:
-                self._spawn()
+                self._spawn(self._platform_env(()))
 
     def reap_idle(self, keep: int, idle_kill_s: float):
         """Kill workers idle beyond the timeout, keeping a floor."""

@@ -1071,7 +1071,7 @@ def main(argv=None):
         p.add_argument("--small", action="store_true")
         p.add_argument(
             "--json", action="store_true",
-            help="emit one machine-readable JSON line (BENCH_LOG.md appends)",
+            help="emit one machine-readable JSON line",
         )
         p.set_defaults(fn=cmd_microbenchmark)
 

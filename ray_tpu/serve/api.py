@@ -306,8 +306,17 @@ def run(
         from .local_mode import run_local
 
         return run_local(app, name)
-    controller = start(proxy=_proxy)
     nodes = app._collect()
+    for node in nodes:
+        # before anything starts: a replica that leases chips no node has
+        # would sit in an infeasible lease, and run() with it
+        options = node.deployment._config.ray_actor_options or {}
+        if options.get("num_tpus"):
+            ray_api.require_chips(
+                options["num_tpus"],
+                f"a replica of {node.deployment.name!r}",
+            )
+    controller = start(proxy=_proxy)
     ingress_name = app.root.deployment.name
     payload = []
     for node in nodes:
@@ -352,7 +361,13 @@ def run(
     _state["ingress"][name] = ingress_name
     handle = DeploymentHandle(controller, name, ingress_name)
     if _blocking:
-        _wait_healthy(name)
+        # as long as the controller itself gives the slowest deployment's
+        # replicas to start (a model replica loads and compiles for
+        # minutes); a fixed minute failed the run before the replica did
+        _wait_healthy(
+            name,
+            max(p["config"].startup_timeout_s for p in payload) + 10.0,
+        )
     return handle
 
 
@@ -370,7 +385,7 @@ def _replace_bound(obj, controller, app_name):
     return obj
 
 
-def _wait_healthy(app_name: str, timeout_s: float = 60.0):
+def _wait_healthy(app_name: str, timeout_s: float):
     import time
 
     controller = _state["controller"]

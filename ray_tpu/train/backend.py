@@ -82,12 +82,9 @@ def _jax_worker_setup(
     coordinator: Optional[str],
     num_processes: int,
     process_id: int,
-    use_tpu: bool,
 ):
-    import os
-
-    if use_tpu:
-        os.environ.setdefault("JAX_PLATFORMS", "tpu")
+    # which platform this worker's JAX may initialise was decided by the
+    # worker pool before the process started (chip grant, or the CPU)
     if coordinator is not None:
         import jax
 
@@ -144,7 +141,6 @@ class _JaxBackend(Backend):
                     coordinator,
                     n,
                     w.world_rank,
-                    self._config.use_tpu,
                 )
             )
         from .. import api as ray_api

@@ -14,12 +14,29 @@ and each worker runs the same pjit/GSPMD-sharded LoRA step:
 - params sharded by the logical-axis rule table (embed→fsdp, mlp/heads→tp)
   over a mesh built from however many devices the slice exposes
 
-``train_config`` keys: model ("tiny" | "7b"), epochs, steps_per_epoch,
-batch_per_worker, seq, lora_rank, mesh axes overrides. The tiny default
-runs on a CPU test cluster in seconds; "7b" is the v5e-64 flagship.
+``train_config`` keys: model ("tiny" | "7b"), n_layers, epochs,
+steps_per_epoch, batch_per_worker, seq, lora_rank, seed, mesh axes
+overrides. The tiny default runs on a CPU test cluster in seconds; "7b" is
+the v5e-64 flagship, and ``n_layers`` cuts its depth to what fewer chips
+hold (chip_smoke.py runs it on one).
+
+Every report carries ``losses`` (one per optimizer step of the epoch) and
+``base_bytes_per_device``: how the frozen base parameters' bytes are spread
+over this process's devices.
 """
 
 from __future__ import annotations
+
+
+def _bytes_per_device(tree) -> list:
+    """Bytes of ``tree``'s arrays resident on each local device, by id."""
+    import jax
+
+    held: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in leaf.addressable_shards:
+            held[shard.device.id] = held.get(shard.device.id, 0) + shard.data.nbytes
+    return [held[i] for i in sorted(held)]
 
 
 def train_loop_per_worker(config: dict):
@@ -40,7 +57,8 @@ def train_loop_per_worker(config: dict):
 
     if config.get("model") == "7b":
         cfg = LlamaConfig(
-            vocab_size=32000, dim=4096, n_layers=32, n_heads=32,
+            vocab_size=32000, dim=4096,
+            n_layers=config.get("n_layers", 32), n_heads=32,
             n_kv_heads=32, intermediate=11008,
             max_seq_len=config.get("seq", 2048),
             param_dtype=jnp.bfloat16, remat=True, scan_layers=True,
@@ -51,6 +69,7 @@ def train_loop_per_worker(config: dict):
             max_seq_len=config.get("seq", 128),
             lora_rank=config.get("lora_rank", 4),
             scan_layers=True, remat=True,
+            n_layers=config.get("n_layers", 2),
         )
 
     # mesh over every device jax.distributed exposes to this SPMD program;
@@ -64,11 +83,16 @@ def train_loop_per_worker(config: dict):
         shape.get("dcn", 1) * shape.get("dp", 1) * shape.get("fsdp", 1)
     )
 
-    boxed = init_params(cfg, jax.random.PRNGKey(0))
-    shardings = param_shardings(mesh, boxed)
-    params = jax.jit(lambda p: p, out_shardings=shardings)(
-        unbox_params(boxed)
+    # born sharded: each device generates its own shard of every leaf, so
+    # no device ever holds the whole model (the eager init-then-reshard
+    # form kept a full copy on device 0 beside the sharded one)
+    key = jax.random.PRNGKey(config.get("seed", 0))
+    shardings = param_shardings(
+        mesh, jax.eval_shape(lambda k: init_params(cfg, k), key)
     )
+    params = jax.jit(
+        lambda k: unbox_params(init_params(cfg, k)), out_shardings=shardings
+    )(key)
     base, lora = split_lora(params)
     del params
     optimizer = optax.adamw(config.get("lr", 1e-4))
@@ -91,12 +115,11 @@ def train_loop_per_worker(config: dict):
     batch = max(batch, local_shards)
     batch -= batch % local_shards
     seq = cfg.max_seq_len
-    # one checkpoint dir per run, epochs overwrite (no per-epoch /tmp leak)
-    ckpt_dir = None
     steps = config.get("steps_per_epoch", 4)
     rank = ctx.get_world_rank()
-    loss = None
+    base_bytes = _bytes_per_device(base)
     for epoch in range(config.get("epochs", 2)):
+        losses = []
         for step in range(steps):
             # each process contributes ITS shard of the global batch —
             # process_local_batch assembles the global sharded jax.Array
@@ -111,26 +134,28 @@ def train_loop_per_worker(config: dict):
             )
             tokens = process_local_batch(mesh, local)
             lora, opt_state, loss = train_step(base, lora, opt_state, tokens)
+            losses.append(loss)  # stays on the device until the report
         checkpoint = None
         if rank == 0:
             # LoRA-only checkpoint: adapters are the entire trainable state.
-            # Real runs point RunConfig at shared storage; this example
-            # keeps one reused node-local directory for the whole run.
+            # Staged under the run's storage path (one directory, epochs
+            # overwrite); report() files it as checkpoint_<index>.
             import os
             import pickle
-            import tempfile
 
             from ...train.checkpoint import Checkpoint
 
-            if ckpt_dir is None:
-                ckpt_dir = tempfile.mkdtemp(prefix="lora_ckpt_")
+            ckpt_dir = os.path.join(ctx.get_storage_path(), "lora_staging")
+            os.makedirs(ckpt_dir, exist_ok=True)
             with open(os.path.join(ckpt_dir, "lora.pkl"), "wb") as f:
                 pickle.dump(
                     {"lora": jax.device_get(lora), "epoch": epoch}, f
                 )
             checkpoint = Checkpoint.from_directory(ckpt_dir)
+        losses = [float(x) for x in losses]
         rt_train.report(
-            {"epoch": epoch, "loss": float(loss), "rank": rank},
+            {"epoch": epoch, "loss": losses[-1], "losses": losses,
+             "rank": rank, "base_bytes_per_device": base_bytes},
             checkpoint=checkpoint,
         )
 

@@ -51,6 +51,7 @@ class TrainWorker:
         import os
         import socket
 
+        from .. import get_tpu_ids
         from ..runtime_context import get_runtime_context
 
         rc = get_runtime_context()
@@ -58,7 +59,7 @@ class TrainWorker:
             "node_id": rc.get_node_id(),
             "hostname": socket.gethostname(),
             "pid": os.getpid(),
-            "tpu_chips": _visible_tpu_chips(),
+            "tpu_chips": len(get_tpu_ids()),
         }
 
     def init_context(self, ctx_fields: dict):
@@ -180,12 +181,6 @@ class TrainWorker:
         return True
 
 
-def _visible_tpu_chips() -> int:
-    import glob
-
-    return len(glob.glob("/dev/accel*"))
-
-
 @dataclass
 class WorkerInfo:
     actor: Any
@@ -215,6 +210,8 @@ class WorkerGroup:
     def create(self, pg_timeout: float = 60.0):
         n = self._scaling.num_workers
         res = self._scaling._resources_per_worker_not_none
+        if res.get("TPU"):
+            ray_api.require_chips(res["TPU"], "a train worker")
         if self._pg is None:
             selectors = (
                 [dict(self._label_selector) for _ in range(n)]

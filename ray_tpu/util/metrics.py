@@ -260,7 +260,7 @@ def tasks_submitted_total() -> float:
 def rpc_latency_summary() -> Dict[str, dict]:
     """Process-local per-method latency summary: count, mean ms, and the
     cumulative histogram buckets ({le: count}) — the machine-readable shape
-    the microbenchmark CLI emits for BENCH_LOG.md."""
+    the microbenchmark CLI emits."""
     latency, _, _ = _ensure_rpc_metrics()
     out: Dict[str, dict] = {}
     with latency._lock:
@@ -1225,10 +1225,16 @@ def _ensure_device_metrics() -> dict:
 def sample_device_memory() -> Dict[str, Dict[str, float]]:
     """Set the per-device HBM gauges from jax.local_devices() memory stats
     and return {device: {used, limit}}. Devices without memory stats (CPU
-    backend) report zeros so the series exist on every platform."""
-    import sys
+    backend) report zeros so the series exist on every platform.
 
-    if "jax" not in sys.modules:
+    Only a backend this process has ALREADY initialised is read. Imported
+    is not initialised: asking jax for its devices initialises the default
+    backend, and on a TPU host that claims the chip — the pusher thread of
+    a driver, controller or proxy would take it from the worker that was
+    leased it."""
+    from .._internal.platform import backend_initialized
+
+    if not backend_initialized():
         return {}
     import jax
 
@@ -2354,7 +2360,8 @@ def _ensure_pusher():
                 continue
             try:
                 # piggyback device telemetry on the push cadence; only when
-                # this process already uses jax (no forced import)
+                # this process already holds a jax backend (no forced
+                # import, no forced backend initialisation)
                 sample_device_memory()
             except Exception:
                 pass

@@ -693,6 +693,7 @@ _EXC_INSTANCES = [
     exceptions.ReplicaDrainingError("replica-2"),
     exceptions.NodeFencedError("node-3", "gcs unreachable"),
     exceptions.MeshValidationError("tp=3 does not divide 8 devices"),
+    exceptions.NoAcceleratorError("a train worker needs 1 TPU chip(s)"),
     exceptions.RpcError("connection reset"),
     exceptions.PendingCallsLimitExceeded("queue cap"),
 ]

@@ -1,0 +1,308 @@
+"""What the chip path needs, checked without the chip.
+
+1. The main path's kernels and step programs compile for a *described* TPU
+   v5e at Llama-2-7B widths (the TPU compiler is installed here; the chip
+   is not attached). A kernel that only ever ran in interpret mode can be
+   refused by the real compiler; these catch that at no chip time. Skipped
+   where the topology cannot be described.
+2. One process per chip: a TPU lease makes its worker the owner of exactly
+   the granted chips, every other process stays on the CPU platform, and
+   nothing that is not a chip run prints a result.
+"""
+
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import ray_tpu
+from ray_tpu._internal import accelerators, platform
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ---------------------------------------------------------------------------
+# 1. compiles for a described v5e chip
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """SingleDeviceSharding on the first device of a described v5e 2x2 host,
+    with the persistent compile cache off: such a compile would be written
+    to it and could never be read back without a chip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def native_kernels(monkeypatch):
+    """Trace the Pallas kernels as the chip would: this process's default
+    backend is the CPU, so the ops would otherwise pick interpret mode."""
+    from ray_tpu.ops import flash_attention, rmsnorm
+
+    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+    monkeypatch.setattr(rmsnorm, "_use_interpret", lambda: False)
+
+
+def _on(chip, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree
+    )
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def test_flash_attention_forward_and_backward_compile(v5e_chip, native_kernels):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    qkv = jax.ShapeDtypeStruct(
+        (1, 32, 2048, 128), jnp.bfloat16, sharding=v5e_chip
+    )
+
+    def forward(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def backward(q, k, v):
+        return jax.grad(
+            lambda *a: forward(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    assert "tpu_custom_call" in _compile(forward, qkv, qkv, qkv)
+    # dq and dk/dv are separate kernels, after the recomputed forward
+    assert _compile(backward, qkv, qkv, qkv).count("tpu_custom_call") >= 3
+
+
+def test_rmsnorm_compiles(v5e_chip, native_kernels):
+    from ray_tpu.ops.rmsnorm import rmsnorm
+
+    x = jax.ShapeDtypeStruct((2, 2048, 4096), jnp.bfloat16, sharding=v5e_chip)
+    w = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=v5e_chip)
+    assert "tpu_custom_call" in _compile(lambda a, b: rmsnorm(a, b, 1e-5), x, w)
+
+
+def test_decode_model_prefill_and_decode_compile(v5e_chip, native_kernels):
+    """The serving engine's two programs at 7B widths, two layers deep."""
+    from ray_tpu.llm.engine import _DecodeModelBase
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    from ray_tpu.parallel.sharding import unbox_params
+
+    cfg = LlamaConfig(
+        vocab_size=32000, dim=4096, n_layers=2, n_heads=32, n_kv_heads=32,
+        intermediate=11008, max_seq_len=2048, param_dtype=jnp.bfloat16,
+    )
+    params = jax.eval_shape(
+        lambda k: unbox_params(init_params(cfg, k)), jax.random.PRNGKey(0)
+    )
+    model = _DecodeModelBase(cfg, None)
+    prompt = jax.ShapeDtypeStruct((1, 512), jnp.int32)
+    row = jax.eval_shape(model._prefill_impl, params, prompt)[1]
+    pool = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((8,) + s.shape[1:], s.dtype), row
+    )
+    last = jax.ShapeDtypeStruct((8, 1), jnp.int32)
+
+    prefill = _compile(
+        model._prefill_impl, _on(v5e_chip, params), _on(v5e_chip, prompt)
+    )
+    decode = _compile(
+        model._decode_impl, _on(v5e_chip, params), _on(v5e_chip, pool),
+        _on(v5e_chip, last),
+    )
+    # the einsum attention path: rmsnorm is the model's only kernel here
+    assert "tpu_custom_call" in prefill
+    assert "tpu_custom_call" in decode
+
+
+# ---------------------------------------------------------------------------
+# 2. one process per chip, no fallback
+# ---------------------------------------------------------------------------
+
+
+def test_tpu_lease_owns_its_chips(ray_start_regular):
+    """A num_tpus=1 task runs in a worker granted exactly one chip id, two
+    concurrent ones get different chips, and a CPU task gets none."""
+
+    @ray_tpu.remote(num_cpus=0, num_tpus=1)
+    def chip_task(hold_s):
+        import os
+        import time
+
+        time.sleep(hold_s)  # overlap with the other lease
+        return ray_tpu.get_tpu_ids(), os.getpid(), os.environ["JAX_PLATFORMS"]
+
+    @ray_tpu.remote(num_cpus=1)
+    def cpu_task():
+        import os
+
+        return ray_tpu.get_tpu_ids(), os.environ["JAX_PLATFORMS"]
+
+    (ids_a, pid_a, plat_a), (ids_b, pid_b, _) = ray_tpu.get(
+        [chip_task.remote(1.0), chip_task.remote(1.0)], timeout=60
+    )
+    assert len(ids_a) == 1 and len(ids_b) == 1
+    assert ids_a != ids_b and pid_a != pid_b
+    # tests force the CPU platform, and a chip worker inherits that
+    assert plat_a == "cpu"
+    assert ray_tpu.get(cpu_task.remote(), timeout=60) == ([], "cpu")
+    assert ray_tpu.get_tpu_ids() == []  # the driver owns no chip
+
+
+def test_visible_chips_env():
+    whole = accelerators.set_visible_chips([0, 1, 2, 3], 4)
+    assert whole == {accelerators.GRANTED_CHIPS_ENV: "0,1,2,3"}
+    one = accelerators.set_visible_chips([2], 4)
+    assert one[accelerators.GRANTED_CHIPS_ENV] == "2"
+    assert one["TPU_VISIBLE_CHIPS"] == "2"
+    for name in ("TPU_CHIPS_PER_PROCESS_BOUNDS", "TPU_CHIPS_PER_HOST_BOUNDS",
+                 "TPU_PROCESS_BOUNDS", "TPU_HOST_BOUNDS"):
+        assert one[name] == "1,1,1"
+
+
+def test_device_files_win_over_host_bounds(monkeypatch):
+    """A one-chip machine cut from a 2x2 host still exports the host's
+    bounds; what is attached is what counts."""
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    monkeypatch.setattr(accelerators, "count_chip_devices", lambda: 1)
+    assert accelerators.TpuAcceleratorManager.detect_num_chips() == 1
+    monkeypatch.setattr(accelerators, "count_chip_devices", lambda: 0)
+    assert accelerators.TpuAcceleratorManager.detect_num_chips() == 4
+
+
+def _run_python(code, **env):
+    merged = {**os.environ, "PYTHONPATH": REPO, **env}
+    merged = {k: v for k, v in merged.items() if v is not None}
+    return subprocess.run(
+        [sys.executable, "-c", code], env=merged, capture_output=True,
+        text=True, timeout=120,
+    )
+
+
+def test_sampler_leaves_an_uninitialised_backend_alone():
+    """Imported is not initialised: the metrics pusher's device sampler
+    must not be what claims the chip in a driver, controller or proxy."""
+    out = _run_python(
+        "import jax\n"
+        "from ray_tpu.util.metrics import sample_device_memory\n"
+        "from ray_tpu._internal.platform import backend_initialized\n"
+        "print(sample_device_memory(), backend_initialized())\n"
+        "jax.devices()\n"
+        "print(sorted(sample_device_memory()), backend_initialized())\n"
+    )
+    assert out.returncode == 0, out.stderr
+    first, second = out.stdout.strip().splitlines()
+    assert first == "{} False"
+    assert second == "['cpu:0', 'cpu:1', 'cpu:2', 'cpu:3', 'cpu:4', 'cpu:5', " \
+                     "'cpu:6', 'cpu:7'] True"
+
+
+@pytest.mark.parametrize("cache_env", ["/some/dir", None])
+def test_compile_cache_is_placed_from_outside(cache_env):
+    """JAX_COMPILATION_CACHE_DIR set: nothing is set in code. Unset: one
+    fixed directory inside the checkout."""
+    out = _run_python(
+        "import jax\n"
+        "from ray_tpu._internal import compile_cache\n"
+        "before = jax.config.jax_compilation_cache_dir\n"
+        "print(before, compile_cache.configure(),"
+        " jax.config.jax_compilation_cache_dir)\n",
+        JAX_COMPILATION_CACHE_DIR=cache_env,
+    )
+    assert out.returncode == 0, out.stderr
+    before, returned, after = out.stdout.split()
+    if cache_env:
+        assert (before, returned, after) == (cache_env,) * 3
+    else:
+        assert before == "None"
+        assert returned == after == os.path.join(REPO, ".jax_cache")
+
+
+def test_pin_cpu_platform_keeps_the_inherited_value_for_chip_workers():
+    out = _run_python(
+        "import os\n"
+        "from ray_tpu._internal import platform\n"
+        "import jax\n"
+        "platform.pin_cpu_platform()\n"
+        "print(os.environ['JAX_PLATFORMS'], jax.config.jax_platforms,"
+        " platform.chip_worker_platforms())\n",
+        JAX_PLATFORMS="tpu,cpu",
+    )
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == ["cpu", "cpu", "tpu,cpu"]
+
+
+def test_kernels_interpret_on_cpu_only(monkeypatch):
+    assert platform.is_tpu_backend() is False
+    assert platform.pallas_interpret("probe") is True
+    assert platform.traced_kernel_modes()["probe"] == [True]
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no lowering for platform 'gpu'"):
+        platform.pallas_interpret("probe")
+
+
+@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+def test_no_chip_no_result(script):
+    """Without an accelerator the chip scripts fail in seconds and print
+    neither ``"ok": true`` nor a metric line."""
+    t0 = time.time()
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, script)],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=REPO,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode != 0
+    assert time.time() - t0 < 30
+    assert '"ok"' not in out.stdout and '"metric"' not in out.stdout
+
+
+def test_tpu_work_on_a_chipless_cluster_fails_typed(cluster):
+    """A use_tpu trainer or a TPU replica on a cluster without chips raises
+    instead of waiting on an infeasible lease."""
+    from ray_tpu import serve, train
+    from ray_tpu.exceptions import NoAcceleratorError
+
+    trainer = train.JaxTrainer(
+        lambda: None,
+        scaling_config=train.ScalingConfig(num_workers=1, use_tpu=True),
+    )
+    with pytest.raises(NoAcceleratorError, match="a train worker needs 1"):
+        trainer.fit()
+
+    @serve.deployment(ray_actor_options={"num_tpus": 1})
+    def on_chip(_):
+        return "never"
+
+    with pytest.raises(NoAcceleratorError, match="a replica of 'on_chip'"):
+        serve.run(on_chip.bind())
+
+
+def test_llm_replica_asks_for_the_chips_its_mesh_needs(monkeypatch):
+    from ray_tpu.llm import LLMConfig
+
+    assert LLMConfig().resources_per_replica == {"TPU": 0.0, "CPU": 1.0}
+    monkeypatch.setattr(accelerators, "count_chip_devices", lambda: 4)
+    assert LLMConfig().resources_per_replica["TPU"] == 1.0
+    assert LLMConfig(mesh={"tp": 4}).resources_per_replica["TPU"] == 4.0
+    explicit = LLMConfig(resources_per_replica={"CPU": 2.0})
+    assert explicit.resources_per_replica == {"CPU": 2.0}

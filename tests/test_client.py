@@ -92,7 +92,7 @@ def test_client_mode_end_to_end(shutdown_only):
     proc = subprocess.run(
         [sys.executable, "-c", script],
         capture_output=True, text=True, timeout=180,
-        env={**os.environ, "RAY_TPU_JAX_PLATFORM": "cpu"},
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert proc.returncode == 0, (proc.stdout, proc.stderr)
     assert "CLIENT_OK" in proc.stdout
@@ -119,7 +119,7 @@ def test_client_server_survives_client_exit(shutdown_only):
         proc = subprocess.run(
             [sys.executable, "-c", quick],
             capture_output=True, text=True, timeout=120,
-            env={**os.environ, "RAY_TPU_JAX_PLATFORM": "cpu"},
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         assert proc.returncode == 0, (proc.stdout, proc.stderr)
         assert "OK" in proc.stdout

@@ -4,11 +4,11 @@ device mesh (reference test model: util/collective tests)."""
 import jax
 import numpy as np
 import pytest
+from jax import shard_map
 
 import ray_tpu
 from ray_tpu.collective import ReduceOp
 from ray_tpu.collective.xla_group import XlaGroup
-from ray_tpu._internal.jax_compat import shard_map
 
 
 @pytest.fixture(scope="module")

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.models.llama import Llama, LlamaConfig, init_params, next_token_loss
@@ -12,7 +13,6 @@ from ray_tpu.ops.flash_attention import reference_attention
 from ray_tpu.parallel.mesh import MeshSpec, make_mesh, mesh_axis_size
 from ray_tpu.parallel.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import logical_to_spec, param_shardings, unbox_params
-from ray_tpu._internal.jax_compat import shard_map
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices"

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.models.moe import MoEConfig, next_token_loss
@@ -21,7 +22,6 @@ from ray_tpu.parallel.mesh import make_mesh
 from ray_tpu.parallel.pipeline import pipeline_apply, select_stage_params
 from ray_tpu.parallel.sharding import param_shardings, unbox_params
 from ray_tpu.parallel.ulysses import ulysses_attention
-from ray_tpu._internal.jax_compat import shard_map
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices"

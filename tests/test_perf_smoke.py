@@ -37,7 +37,7 @@ def test_microbenchmarks_produce_all_metrics(shutdown_only):
 
 
 def test_microbenchmark_json_output(shutdown_only):
-    """The CLI's machine-readable mode (BENCH_LOG.md appends): every metric
+    """The CLI's machine-readable mode: every metric
     carries a unit, and the per-method RPC latency histograms ride along."""
     import json
 
@@ -412,7 +412,7 @@ def test_scale_100_virtual_nodes(shutdown_only):
     """Scalability quantification (BASELINE.md's 2,000-node envelope,
     scaled to a 1-core CI box): a 100-raylet in-process cluster must
     register quickly, serve O(n) cluster views fast, and dispatch work
-    across the full node set. Prints timings for BENCH_LOG.md."""
+    across the full node set. Prints its timings."""
     import time
 
     from ray_tpu.cluster_utils import Cluster

@@ -349,7 +349,7 @@ def test_flight_recorder_crash_dump_retrievable(cluster):
             "--limit", "1000",
         ],
         capture_output=True, text=True, timeout=60,
-        env={**os.environ, "RAY_TPU_JAX_PLATFORM": "cpu"},
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert out.returncode == 0, out.stderr
     listed = json.loads(out.stdout)

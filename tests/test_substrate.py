@@ -202,6 +202,9 @@ def test_recv_loop_survives_non_exception_error_payload():
                 try:
                     req_id, method, args, kwargs = await _read_frame(reader)
                 except Exception:
+                    # Python 3.12's Server.wait_closed() waits for every
+                    # connection: leave none open behind the handler
+                    writer.close()
                     return
                 if req_id == -1:
                     continue
