@@ -100,7 +100,33 @@ def _sample_impl(logits, temps, key):
 
 
 _fused_sample = jax.jit(_sample_impl)
-_greedy_sample = jax.jit(lambda logits: jnp.argmax(logits, axis=-1))
+
+
+@jax.jit
+def _greedy_sample(logits):
+    # a def, not a lambda: its XLA module is named jit__greedy_sample
+    return jnp.argmax(logits, axis=-1)
+
+
+_span = _tracing.annotate_device_trace
+
+
+class _StepLock:
+    """The engine lock as the stepping entry points take it: the wait is
+    an ``engine.lock_wait`` region in the profiler's trace. One thread
+    holds the lock and steps; the others of a replica's pool sit here."""
+
+    __slots__ = ("_lock",)
+
+    def __init__(self, lock):
+        self._lock = lock
+
+    def __enter__(self):
+        with _span("engine.lock_wait"):
+            self._lock.acquire()
+
+    def __exit__(self, exc_type, exc, tb):
+        self._lock.release()
 
 
 @dataclasses.dataclass
@@ -454,10 +480,11 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # serve replicas call sync methods from a thread pool: every public
         # entry point serializes on this (reentrant: step() inside generate)
         self._lock = threading.RLock()
+        self._step_lock = _StepLock(self._lock)
         # results finished by another thread's step() land here until the
         # owning generate()/generate_stream() call collects them
         self._finished_buf: Dict[int, GenerationResult] = {}
-        self._enqueue_ts: Dict[int, float] = {}  # rid -> monotonic, for TTFT
+        self._enqueue_ts: Dict[int, float] = {}  # rid -> wall, for TTFT
         # rid -> {"ctx", "wall"}: populated only while the submitting
         # request is traced, so the untraced path never touches it
         self._req_trace: Dict[int, Any] = {}
@@ -568,14 +595,17 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 "prompt + max_new_tokens + spec_tokens exceeds max_seq_len "
                 "(speculative verification needs headroom)"
             )
+        # one stamp for the queue-wait span, TTFT and queue_wait_us, taken
+        # before the lock: waiting for a running step is queue wait too
+        now = time.time()
         tr = None
         if _tracing.is_tracing_enabled():
-            tr = {"ctx": _tracing.current_context(), "wall": time.time()}
+            tr = {"ctx": _tracing.current_context(), "wall": now}
         with self._lock:
             rid = self._next_id
             self._next_id += 1
             self._pending.append((rid, request, shipment))
-            self._enqueue_ts[rid] = time.monotonic()
+            self._enqueue_ts[rid] = now
             if tr is not None:
                 self._req_trace[rid] = tr
         return rid
@@ -588,50 +618,62 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         """One engine iteration: admit pending requests into free slots
         (prefill), decode one token for every occupied slot, retire finished
         requests. Returns [(request_id, GenerationResult)] finished now."""
-        with self._lock:
+        with self._step_lock:
             return self._step_locked()
 
     def _step_locked(self) -> List[tuple]:
-        self.last_step_prefill_tokens = 0
-        finished: List[tuple] = self._admit()
-        if self._prefilling:
-            self._advance_prefills(finished)
-        if not self._slots:
+        # wall_us lays the profiler's clock (which counts from its
+        # session's start) beside the request spans' and the clients'
+        with _span(
+            "engine.step", step=self._step_count,
+            pending=len(self._pending), prefilling=len(self._prefilling),
+            wall_us=time.time_ns() // 1000,
+        ):
+            self.last_step_prefill_tokens = 0
+            finished: List[tuple] = self._admit()
+            if self._prefilling:
+                self._advance_prefills(finished)
+            if not self._slots:
+                return finished
+            if self._spec_k and self._draft is not None:
+                self._spec_step(finished)
+            else:
+                self._dense_step(finished)
             return finished
-        if self._spec_k and self._draft is not None:
-            self._spec_step(finished)
-        else:
-            self._dense_step(finished)
-        return finished
 
     def _dense_step(self, finished: List[tuple]) -> None:
         # one decode step for the whole pool; free rows compute garbage at
         # their stale positions (static-shape trade) and are ignored
-        last = np.zeros((self._num_slots, 1), np.int32)
-        for si, slot in self._slots.items():
-            last[si, 0] = slot.last_token
-        logits, self._cache = self._decode(
-            self._params, self._cache, jnp.asarray(last),
-            *self._adapter_args(self._row_adapter_slots()),
-        )
+        with _span("engine.decode_dispatch", batch=len(self._slots)):
+            last = np.zeros((self._num_slots, 1), np.int32)
+            for si, slot in self._slots.items():
+                last[si, 0] = slot.last_token
+            logits, self._cache = self._decode(
+                self._params, self._cache, jnp.asarray(last),
+                *self._adapter_args(self._row_adapter_slots()),
+            )
         self._step_count += 1
-        tokens = self._sample_rows(logits)
-        now = time.monotonic()
-        for si in list(self._slots):
-            slot = self._slots[si]
-            tok = int(tokens[si])
-            slot.generated.append(tok)
-            slot.last_token = tok
-            if slot.last_emit_ts:
-                _record_itl(now - slot.last_emit_ts, mesh=self._mesh_tag)
-            slot.last_emit_ts = now
-            req = slot.request
-            done_eos = req.eos_token_id is not None and tok == req.eos_token_id
-            done_len = len(slot.generated) >= req.max_new_tokens
-            if done_eos or done_len:
-                self._finish_slot(
-                    si, slot, "eos" if done_eos else "length", finished
+        with _span("engine.sample_sync"):  # the host waits for the device
+            tokens = self._sample_rows(logits)
+        with _span("engine.emit"):
+            now = time.monotonic()
+            for si in list(self._slots):
+                slot = self._slots[si]
+                tok = int(tokens[si])
+                slot.generated.append(tok)
+                slot.last_token = tok
+                if slot.last_emit_ts:
+                    _record_itl(now - slot.last_emit_ts, mesh=self._mesh_tag)
+                slot.last_emit_ts = now
+                req = slot.request
+                done_eos = (
+                    req.eos_token_id is not None and tok == req.eos_token_id
                 )
+                done_len = len(slot.generated) >= req.max_new_tokens
+                if done_eos or done_len:
+                    self._finish_slot(
+                        si, slot, "eos" if done_eos else "length", finished
+                    )
 
     def _spec_step(self, finished: List[tuple]) -> None:
         """One speculative iteration for the whole pool: the draft model
@@ -641,69 +683,73 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         and the rolled-back cache index — two compiled programs and one
         host transfer of (tokens, counts) per step."""
         S, k = self._num_slots, self._spec_k
-        last = np.zeros((S, 1), np.int32)
-        temps = np.zeros(S, np.float32)
-        start = np.zeros(S, np.int32)
-        for si, slot in self._slots.items():
-            last[si, 0] = slot.last_token
-            temps[si] = max(slot.request.temperature, 0.0)
-            # cache invariant: K/V covers prompt + generated[:-1]
-            start[si] = (
-                len(slot.request.token_ids) + len(slot.generated) - 1
-            )
-        key = jax.random.fold_in(self._rng, 10_000 + self._step_count)
-        self._step_count += 1
-        temps_d = jnp.asarray(temps)
-        # proposal: the whole k-step draft loop is one fused program
-        chunk, draft_tok, draft_logits, self._draft_cache = self._propose(
-            self._draft._params, self._draft_cache, jnp.asarray(last),
-            temps_d, key,
-        )
-        # adapters apply to the TARGET verify pass only: the draft proposes
-        # base-model tokens (it has no per-tenant fine-tune), which costs
-        # acceptance rate on adapter-heavy rows but never correctness —
-        # verification is against the adapter-applied target distribution
-        emitted, counts, self._cache, new_idx = self._verify(
-            self._params, self._cache, chunk, draft_tok, draft_logits,
-            temps_d, jax.random.fold_in(key, 0), jnp.asarray(start),
-            *self._adapter_args(self._row_adapter_slots()),
-        )
-        # the draft pool rolls back to the same corrected position
-        self._draft_cache = self._set_index(self._draft_cache, new_idx)
-        em = host_sync(emitted)
-        cnt = host_sync(counts)
-        now = time.monotonic()
-        proposed = accepted = 0
-        for si in list(self._slots):
-            slot = self._slots[si]
-            req = slot.request
-            n = int(cnt[si])
-            proposed += k
-            accepted += n - 1  # the last emitted token is bonus/correction
-            done_reason = None
-            for j in range(n):
-                tok = int(em[si, j])
-                slot.generated.append(tok)
-                slot.last_token = tok
-                if req.eos_token_id is not None and tok == req.eos_token_id:
-                    done_reason = "eos"
-                    break
-                if len(slot.generated) >= req.max_new_tokens:
-                    done_reason = "length"
-                    break
-            if slot.last_emit_ts:
-                # n tokens landed in one step: each saw gap/n of latency
-                _record_itl(
-                    (now - slot.last_emit_ts) / max(n, 1), n=n,
-                    mesh=self._mesh_tag,
+        with _span("engine.decode_dispatch", batch=len(self._slots)):
+            last = np.zeros((S, 1), np.int32)
+            temps = np.zeros(S, np.float32)
+            start = np.zeros(S, np.int32)
+            for si, slot in self._slots.items():
+                last[si, 0] = slot.last_token
+                temps[si] = max(slot.request.temperature, 0.0)
+                # cache invariant: K/V covers prompt + generated[:-1]
+                start[si] = (
+                    len(slot.request.token_ids) + len(slot.generated) - 1
                 )
-            slot.last_emit_ts = now
-            if done_reason is not None:
-                self._finish_slot(si, slot, done_reason, finished)
-            else:
-                self._commit_decode_tail(si, slot)
-        if proposed:
-            _record_spec(proposed, accepted, mesh=self._mesh_tag)
+            key = jax.random.fold_in(self._rng, 10_000 + self._step_count)
+            self._step_count += 1
+            temps_d = jnp.asarray(temps)
+            # proposal: the whole k-step draft loop is one fused program
+            chunk, draft_tok, draft_logits, self._draft_cache = self._propose(
+                self._draft._params, self._draft_cache, jnp.asarray(last),
+                temps_d, key,
+            )
+            # adapters apply to the TARGET verify pass only: the draft
+            # proposes base-model tokens (it has no per-tenant fine-tune),
+            # which costs acceptance rate on adapter-heavy rows but never
+            # correctness — verification is against the adapter-applied
+            # target distribution
+            emitted, counts, self._cache, new_idx = self._verify(
+                self._params, self._cache, chunk, draft_tok, draft_logits,
+                temps_d, jax.random.fold_in(key, 0), jnp.asarray(start),
+                *self._adapter_args(self._row_adapter_slots()),
+            )
+            # the draft pool rolls back to the same corrected position
+            self._draft_cache = self._set_index(self._draft_cache, new_idx)
+        with _span("engine.sample_sync"):
+            em = host_sync(emitted)
+            cnt = host_sync(counts)
+        with _span("engine.emit"):
+            now = time.monotonic()
+            proposed = accepted = 0
+            for si in list(self._slots):
+                slot = self._slots[si]
+                req = slot.request
+                n = int(cnt[si])
+                proposed += k
+                accepted += n - 1  # the last emitted token is bonus/correction
+                done_reason = None
+                for j in range(n):
+                    tok = int(em[si, j])
+                    slot.generated.append(tok)
+                    slot.last_token = tok
+                    if req.eos_token_id is not None and tok == req.eos_token_id:
+                        done_reason = "eos"
+                        break
+                    if len(slot.generated) >= req.max_new_tokens:
+                        done_reason = "length"
+                        break
+                if slot.last_emit_ts:
+                    # n tokens landed in one step: each saw gap/n of latency
+                    _record_itl(
+                        (now - slot.last_emit_ts) / max(n, 1), n=n,
+                        mesh=self._mesh_tag,
+                    )
+                slot.last_emit_ts = now
+                if done_reason is not None:
+                    self._finish_slot(si, slot, done_reason, finished)
+                else:
+                    self._commit_decode_tail(si, slot)
+            if proposed:
+                _record_spec(proposed, accepted, mesh=self._mesh_tag)
 
     def _row_adapter_slots(self) -> np.ndarray:
         """Per-row adapter slot indices for the pooled decode batch; free
@@ -764,13 +810,29 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         if avail <= slot.committed_blocks:
             return
         self._kv.extend(slot.lease, avail - slot.committed_blocks)
-        row = self._extract_row(self._cache, jnp.asarray(si, jnp.int32))
-        self._kv.commit(
-            slot.lease,
-            self._kv_key_tokens(slot.request, tokens[: avail * bs]),
-            row, pin=False,
+        self._commit_row_tail(
+            si, slot, self._kv_key_tokens(slot.request, tokens[: avail * bs]),
+            avail - slot.committed_blocks, trace=None,
         )
         slot.committed_blocks = avail
+
+    def _commit_row_tail(self, si: int, slot: _Slot, key_tokens: List[int],
+                         blocks: int, trace) -> None:
+        """Read slot ``si``'s row back and commit its new full blocks: the
+        decode-tail commit both the retire path and the speculative path
+        make."""
+        with _tracing.step_span(
+            "kv.commit", trace, request_span="kvcache.commit",
+            category="kvcache",
+            attrs={"request_id": slot.request_id,
+                   "tokens": len(key_tokens), "tail": True},
+            tail=1, blocks=blocks,
+        ):
+            with _span("kv.extract_row", blocks=blocks):
+                row = self._extract_row(
+                    self._cache, jnp.asarray(si, jnp.int32)
+                )
+            self._kv.commit(slot.lease, key_tokens, row, pin=False)
 
     def _retire_slot(self, si: int) -> None:
         """Free the slot; with a KV manager, first commit the sequence's
@@ -787,19 +849,12 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             slot.committed_blocks,
             len(req.token_ids) // self._kv.block_size,
         )
-        if len(tokens) // self._kv.block_size > already:
-            cm_t0 = time.time() if slot.trace else 0.0
-            row = self._extract_row(self._cache, jnp.asarray(si, jnp.int32))
-            self._kv.commit(
-                slot.lease, self._kv_key_tokens(req, tokens), row, pin=False
+        full = len(tokens) // self._kv.block_size
+        if full > already:
+            self._commit_row_tail(
+                si, slot, self._kv_key_tokens(req, tokens), full - already,
+                trace=slot.trace,
             )
-            if slot.trace:
-                _tracing.emit_span(
-                    "kvcache.commit", slot.trace["ctx"], cm_t0,
-                    time.time() - cm_t0, category="kvcache",
-                    request_id=slot.request_id, tokens=len(tokens),
-                    tail=True,
-                )
         self._kv.release(slot.lease)
 
     def run_until_complete(self) -> Dict[int, GenerationResult]:
@@ -808,7 +863,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         the engine keeps NO finished-result state (a serving loop would leak
         otherwise)."""
         out: Dict[int, GenerationResult] = {}
-        with self._lock:
+        with self._step_lock:
             while self.num_active:
                 for rid, result in self._step_locked():
                     out[rid] = result
@@ -826,7 +881,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         want = set(rids)
         out: Dict[int, GenerationResult] = {}
         while len(out) < len(want):
-            with self._lock:
+            with self._step_lock:
                 for rid in want:
                     if rid in self._finished_buf:
                         out[rid] = self._finished_buf.pop(rid)
@@ -845,7 +900,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         shipment (see add_request) — the decode-role entry point."""
         rid = self.add_request(request, shipment=shipment)
         while True:
-            with self._lock:
+            with self._step_lock:
                 if rid in self._finished_buf:
                     return self._finished_buf.pop(rid)
                 for frid, res in self._step_locked():
@@ -862,7 +917,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         emitted = 0
         final: Optional[GenerationResult] = None
         while True:
-            with self._lock:
+            with self._step_lock:
                 if rid in self._finished_buf:
                     final = self._finished_buf.pop(rid)
                 if final is None:
@@ -919,26 +974,49 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         while free and self._pending:
             si = free.pop(0)
             rid, req, ship = self._pending.pop(0)
-            tr = self._req_trace.get(rid)
-            plen = len(req.token_ids)
-            pulled = None
-            # the cluster tier and directed shipments carry BASE-model KV;
-            # adapter requests stay out of both (their prefixes live in the
-            # adapter-salted local radix namespace instead)
-            if self._kv is not None and req.adapter_id is None:
-                if ship is not None:
-                    pulled = self._as_pulled(ship, req)
-                elif self._tier is not None:
-                    local = self._kv.cached_blocks(req.token_ids)
-                    if local < (plen - 1) // self._kv.block_size:
-                        pulled = self._tier.pull(
-                            req.token_ids, min_blocks=local
-                        )
-            fast = pulled is not None and pulled.exact
-            tier_src = "peer" if pulled is not None else None
-            lease = None
-            if self._kv is not None:
-                kv_t0 = time.time() if tr else 0.0
+            now = time.time()
+            with _span(
+                "engine.admit", request_id=rid,
+                queue_wait_us=int(
+                    (now - self._enqueue_ts.get(rid, now)) * 1e6
+                ),
+                prompt_tokens=len(req.token_ids),
+            ):
+                admitted = self._admit_one(si, rid, req, ship, finished)
+            if admitted is None:  # backpressure: wait for a release
+                break
+            if not admitted:
+                free.insert(0, si)
+        return finished
+
+    def _admit_one(self, si, rid, req, ship, finished) -> Optional[bool]:
+        """Admit one pending request into slot ``si``. True: the slot is
+        taken (decoding, or parked for chunked prefill). False: the request
+        finished at admission and the slot is free again. None: the pool
+        has no blocks for it; it is back at the head of the queue."""
+        tr = self._req_trace.get(rid)
+        plen = len(req.token_ids)
+        pulled = None
+        # the cluster tier and directed shipments carry BASE-model KV;
+        # adapter requests stay out of both (their prefixes live in the
+        # adapter-salted local radix namespace instead)
+        if self._kv is not None and req.adapter_id is None:
+            if ship is not None:
+                pulled = self._as_pulled(ship, req)
+            elif self._tier is not None:
+                local = self._kv.cached_blocks(req.token_ids)
+                if local < (plen - 1) // self._kv.block_size:
+                    pulled = self._tier.pull(
+                        req.token_ids, min_blocks=local
+                    )
+        fast = pulled is not None and pulled.exact
+        tier_src = "peer" if pulled is not None else None
+        lease = None
+        if self._kv is not None:
+            with _tracing.step_span(
+                "kv.acquire", tr, request_span="kvcache.acquire",
+                category="kvcache", attrs={"request_id": rid},
+            ) as acquiring:
                 if pulled is not None:
                     # shipped blocks land in the pool + radix BEFORE the
                     # acquire, so the lease pins them like any local hit
@@ -949,44 +1027,48 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                         else pulled.matched_blocks,
                     )
                 lease = self._kv.acquire(self._kv_key_tokens(req))
-                if lease is None:  # backpressure: wait for a release
-                    self._pending.insert(0, (rid, req, ship))
-                    if rid not in self._blocked_rids:
-                        self._blocked_rids.add(rid)
-                        _events.record_event(
-                            _events.ENGINE_ADMISSION_BLOCKED,
-                            request_id=rid,
-                            prompt_tokens=len(req.token_ids),
-                            pending=len(self._pending),
-                        )
-                    break
-                self._blocked_rids.discard(rid)
-                if tr:
-                    _tracing.emit_span(
-                        "kvcache.acquire", tr["ctx"], kv_t0,
-                        time.time() - kv_t0, category="kvcache",
+                if lease is None:
+                    acquiring.cancel()
+                else:
+                    acquiring.set(cached_tokens=lease.num_cached_tokens)
+            if lease is None:
+                self._pending.insert(0, (rid, req, ship))
+                if rid not in self._blocked_rids:
+                    self._blocked_rids.add(rid)
+                    _events.record_event(
+                        _events.ENGINE_ADMISSION_BLOCKED,
                         request_id=rid,
-                        cached_tokens=lease.num_cached_tokens,
+                        prompt_tokens=len(req.token_ids),
+                        pending=len(self._pending),
                     )
-            tr = self._req_trace.pop(rid, None)
-            if tr:
-                now = time.time()
-                _tracing.emit_span(
-                    "engine.queue_wait", tr["ctx"], tr["wall"],
-                    now - tr["wall"], category="engine", request_id=rid,
-                )
-            if self._prefill_chunk and not fast:
-                # budgeted prefill: the request keeps its slot reservation
-                # but computes nothing yet — _advance_prefills spreads the
-                # prompt over engine steps alongside in-flight decodes
-                self._prefilling[si] = {
-                    "rid": rid, "req": req, "lease": lease,
-                    "tier_src": tier_src, "tr": tr,
-                    "row": None, "pos": 0, "logits": None, "committed": 0,
-                    "pf_wall": time.time() if tr else 0.0,
-                }
-                continue
-            pf_wall = time.time() if tr else 0.0
+                return None
+            self._blocked_rids.discard(rid)
+        tr = self._req_trace.pop(rid, None)
+        if tr:
+            _tracing.emit_span(
+                "engine.queue_wait", tr["ctx"], tr["wall"],
+                time.time() - tr["wall"], category="engine", request_id=rid,
+            )
+        if self._prefill_chunk and not fast:
+            # budgeted prefill: the request keeps its slot reservation
+            # but computes nothing yet — _advance_prefills spreads the
+            # prompt over engine steps alongside in-flight decodes
+            self._prefilling[si] = {
+                "rid": rid, "req": req, "lease": lease,
+                "tier_src": tier_src, "tr": tr,
+                "row": None, "pos": 0, "logits": None, "committed": 0,
+                "pf_wall": time.time() if tr else 0.0,
+            }
+            return True
+        cached = (
+            plen if fast
+            else lease.num_cached_tokens if lease is not None else 0
+        )
+        with _tracing.step_span(
+            "engine.prefill", tr,
+            attrs=self._prefill_attrs(rid, cached, tier_src),
+            computed_tokens=plen - cached, cached_tokens=cached,
+        ):
             if fast:
                 # zero-prefill: the payload covers every prompt token and
                 # the first token was sampled by the shipping replica
@@ -996,52 +1078,44 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 logits, solo_cache = self._prefill_leased(
                     req, lease, trace=tr
                 )
-                self.last_step_prefill_tokens += plen - (
-                    lease.num_cached_tokens if lease is not None else 0
-                )
-                first = int(
-                    self._sample_tokens(
-                        logits,
-                        np.array([max(req.temperature, 0.0)], np.float32),
-                        jax.random.fold_in(self._rng, rid),
-                    )[0]
-                )
-            if not self._finish_admission(
-                si, rid, req, lease, solo_cache, first, fast, tier_src,
-                tr, pf_wall, finished,
-            ):
-                free.insert(0, si)
-        return finished
+                self.last_step_prefill_tokens += plen - cached
+                # the host waits for the prefill here
+                first = self._sample_first(logits, req, rid)
+        return self._finish_admission(
+            si, rid, req, lease, solo_cache, first, fast, tier_src,
+            tr, finished,
+        )
+
+    def _prefill_attrs(self, rid, cached: int, tier_src) -> dict:
+        return {
+            "request_id": rid, "hit": cached > 0,
+            "tier": tier_src or "local", "mesh": self._mesh_tag,
+        }
+
+    def _sample_first(self, logits, req: GenerationRequest, rid: int) -> int:
+        return int(
+            self._sample_tokens(
+                logits,
+                np.array([max(req.temperature, 0.0)], np.float32),
+                jax.random.fold_in(self._rng, rid),
+            )[0]
+        )
 
     def _finish_admission(self, si, rid, req, lease, solo_cache, first,
-                          fast, tier_src, tr, pf_wall, finished) -> bool:
+                          fast, tier_src, tr, finished) -> bool:
         """The admission tail every prefill path funnels through (inline,
         chunked, zero-prefill): TTFT + prefill metrics, prompt-block
         commit + tier export, pool row insert, slot creation. Returns
         False when the request finished AT admission (eos on the first
         token / max_new_tokens <= 1) — the caller returns the slot."""
         plen = len(req.token_ids)
-        if tr:
-            cached = (
-                plen if fast
-                else lease.num_cached_tokens if lease is not None
-                else 0
-            )
-            _tracing.emit_span(
-                "engine.prefill", tr["ctx"], pf_wall,
-                time.time() - pf_wall, category="engine",
-                request_id=rid, cached_tokens=cached,
-                computed_tokens=plen - cached,
-                hit=cached > 0, tier=tier_src or "local",
-                mesh=self._mesh_tag,
-            )
         ts = self._enqueue_ts.pop(rid, None)
         if self._kv is not None:
             cached = plen if fast else lease.num_cached_tokens
             self._kv.record_prefill(cached, plen - cached)
             if ts is not None:
                 _record_ttft(
-                    time.monotonic() - ts, hit=cached > 0,
+                    max(time.time() - ts, 0.0), hit=cached > 0,
                     mesh=self._mesh_tag,
                     tier=tier_src
                     or ("local" if cached > 0 else "miss"),
@@ -1050,13 +1124,15 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 # commit the prompt's full blocks while the prefilled
                 # row is at hand; reserved blocks are consumed here
                 # (the fast path adopted them instead)
-                cm_t0 = time.time() if tr else 0.0
-                self._kv.commit(lease, self._kv_key_tokens(req), solo_cache)
-                if tr:
-                    _tracing.emit_span(
-                        "kvcache.commit", tr["ctx"], cm_t0,
-                        time.time() - cm_t0, category="kvcache",
-                        request_id=rid, tokens=len(req.token_ids),
+                bs = self._kv.block_size
+                with _tracing.step_span(
+                    "kv.commit", tr, request_span="kvcache.commit",
+                    category="kvcache",
+                    attrs={"request_id": rid, "tokens": plen},
+                    blocks=plen // bs - cached // bs,
+                ):
+                    self._kv.commit(
+                        lease, self._kv_key_tokens(req), solo_cache
                     )
                 if (
                     self._tier is not None
@@ -1077,12 +1153,13 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                         plen // self._kv.block_size,
                         first_token=first,
                     )
-        if self._cache is None:
-            self._cache = self._empty_cache(solo_cache)
-        # insert the prefilled K/V row + its write position into slot si
-        self._cache = self._insert_row(
-            self._cache, solo_cache, jnp.asarray(si, jnp.int32)
-        )
+        with _span("kv.insert_row"):
+            if self._cache is None:
+                self._cache = self._empty_cache(solo_cache)
+            # insert the prefilled K/V row + its write position into slot si
+            self._cache = self._insert_row(
+                self._cache, solo_cache, jnp.asarray(si, jnp.int32)
+            )
         req_eos = req.eos_token_id is not None and first == req.eos_token_id
         if req_eos or req.max_new_tokens <= 1:
             result = GenerationResult(
@@ -1124,60 +1201,72 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             st = self._prefilling[si]
             req, lease, tr = st["req"], st["lease"], st["tr"]
             tokens = req.token_ids
-            if st["row"] is None:
-                if lease is not None and lease.num_cached_tokens:
-                    as_t0 = time.time() if tr else 0.0
-                    st["row"] = self._kv.assemble(lease)
-                    if tr:
-                        _tracing.emit_span(
-                            "kvcache.assemble", tr["ctx"], as_t0,
-                            time.time() - as_t0, category="kvcache",
-                            cached_tokens=lease.num_cached_tokens,
-                        )
-                    st["pos"] = lease.num_cached_tokens
-                    st["committed"] = (
-                        lease.num_cached_tokens // self._kv.block_size
+            cached = lease.num_cached_tokens if lease is not None else 0
+            with _span(
+                "engine.prefill",
+                computed_tokens=min(
+                    len(tokens) - (st["pos"] or cached), budget
+                ),
+                cached_tokens=cached,
+            ):
+                if st["row"] is None:
+                    if cached:
+                        with _tracing.step_span(
+                            "kv.assemble", tr,
+                            request_span="kvcache.assemble",
+                            category="kvcache", cached_tokens=cached,
+                        ):
+                            st["row"] = self._kv.assemble(lease)
+                        st["pos"] = cached
+                        st["committed"] = cached // self._kv.block_size
+                    else:
+                        st["row"] = self._empty_row()
+                pos = st["pos"]
+                while pos < len(tokens) and budget > 0:
+                    take = min(chunk_max, len(tokens) - pos, budget)
+                    chunk = jnp.asarray([tokens[pos:pos + take]], jnp.int32)
+                    st["logits"], st["row"] = self._decode(
+                        self._params, st["row"], chunk,
+                        *self._adapter_args([req.adapter_slot]),
                     )
-                else:
-                    st["row"] = self._empty_row()
-            pos = st["pos"]
-            while pos < len(tokens) and budget > 0:
-                take = min(chunk_max, len(tokens) - pos, budget)
-                chunk = jnp.asarray([tokens[pos:pos + take]], jnp.int32)
-                st["logits"], st["row"] = self._decode(
-                    self._params, st["row"], chunk,
-                    *self._adapter_args([req.adapter_slot]),
+                    pos += take
+                    budget -= take
+                    self.last_step_prefill_tokens += take
+                st["pos"] = pos
+                if pos < len(tokens):
+                    bs = self._kv.block_size if self._kv is not None else 0
+                    if (
+                        self._kv is not None and lease is not None
+                        and pos // bs > st["committed"]
+                    ):
+                        # partial commit: completed full blocks become
+                        # hittable for concurrent shared-prefix admissions
+                        # NOW, not when the whole prompt lands
+                        with _span(
+                            "kv.commit", blocks=pos // bs - st["committed"]
+                        ):
+                            self._kv.commit(
+                                lease,
+                                self._kv_key_tokens(req, tokens[:pos]),
+                                st["row"],
+                            )
+                        st["committed"] = pos // bs
+                    continue
+                del self._prefilling[si]
+                first = self._sample_first(st["logits"], req, st["rid"])
+            if tr:
+                # the request's prefill span runs from its parking to here,
+                # across steps: no one block brackets it
+                _tracing.emit_span(
+                    "engine.prefill", tr["ctx"], st["pf_wall"],
+                    time.time() - st["pf_wall"], category="engine",
+                    cached_tokens=cached,
+                    computed_tokens=len(tokens) - cached,
+                    **self._prefill_attrs(st["rid"], cached, st["tier_src"]),
                 )
-                pos += take
-                budget -= take
-                self.last_step_prefill_tokens += take
-            st["pos"] = pos
-            if pos < len(tokens):
-                bs = self._kv.block_size if self._kv is not None else 0
-                if (
-                    self._kv is not None and lease is not None
-                    and pos // bs > st["committed"]
-                ):
-                    # partial commit: completed full blocks become
-                    # hittable for concurrent shared-prefix admissions
-                    # NOW, not when the whole prompt lands
-                    self._kv.commit(
-                        lease, self._kv_key_tokens(req, tokens[:pos]),
-                        st["row"],
-                    )
-                    st["committed"] = pos // bs
-                continue
-            del self._prefilling[si]
-            first = int(
-                self._sample_tokens(
-                    st["logits"],
-                    np.array([max(req.temperature, 0.0)], np.float32),
-                    jax.random.fold_in(self._rng, st["rid"]),
-                )[0]
-            )
             self._finish_admission(
                 si, st["rid"], req, lease, st["row"], first, False,
-                st["tier_src"], tr, st["pf_wall"], finished,
+                st["tier_src"], tr, finished,
             )
 
     def _empty_row(self):
@@ -1389,15 +1478,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             self._next_id += 1
             try:
                 logits, solo_cache = self._prefill_leased(request, lease)
-                first = int(
-                    self._sample_tokens(
-                        logits,
-                        np.array(
-                            [max(request.temperature, 0.0)], np.float32
-                        ),
-                        jax.random.fold_in(self._rng, rid),
-                    )[0]
-                )
+                first = self._sample_first(logits, request, rid)
                 cached = lease.num_cached_tokens
                 self._kv.record_prefill(cached, plen - cached)
                 self._kv.commit(lease, request.token_ids, solo_cache)
@@ -1424,14 +1505,11 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 self._params, jnp.asarray([tokens], jnp.int32),
                 *self._adapter_args([req.adapter_slot]),
             )
-        as_t0 = time.time() if trace else 0.0
-        row = self._kv.assemble(lease)
-        if trace:
-            _tracing.emit_span(
-                "kvcache.assemble", trace["ctx"], as_t0,
-                time.time() - as_t0, category="kvcache",
-                cached_tokens=lease.num_cached_tokens,
-            )
+        with _tracing.step_span(
+            "kv.assemble", trace, request_span="kvcache.assemble",
+            category="kvcache", cached_tokens=lease.num_cached_tokens,
+        ):
+            row = self._kv.assemble(lease)
         logits = None
         pos = lease.num_cached_tokens
         while pos < len(tokens):

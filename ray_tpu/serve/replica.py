@@ -499,9 +499,20 @@ class Replica:
                 # Every step runs under the copied context — generator bodies
                 # see the context active at each next(), not at creation, so
                 # a bare next() would drop the multiplexed-model-id var.
+                def next_item(asked_ns: int):
+                    # one next() of the stream, in a pool thread; the count
+                    # is how long it waited for one of the pool's threads
+                    with _tracing.annotate_device_trace(
+                        "replica.stream_next",
+                        executor_wait_us=(
+                            time.perf_counter_ns() - asked_ns
+                        ) // 1000,
+                    ):
+                        return ctx.run(next, gen, _SENTINEL)
+
                 while True:
                     item = await loop.run_in_executor(
-                        self._pool, lambda: ctx.run(next, gen, _SENTINEL)
+                        self._pool, next_item, time.perf_counter_ns()
                     )
                     if item is _SENTINEL:
                         return

@@ -15,10 +15,11 @@ from __future__ import annotations
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 import uuid
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional
 
 _lock = threading.Lock()
@@ -277,7 +278,8 @@ def _record_span(name, category, wall, dur_s, trace_id, span_id, parent_id,
             drop = len(_spans) - _spans_cap
             del _spans[:drop]
             _flush_cursor = max(0, _flush_cursor - drop)
-    _ensure_span_pusher()
+    if not _span_pusher_started:
+        _ensure_span_pusher()
 
 
 def get_spans() -> List[dict]:
@@ -429,32 +431,8 @@ def timeline(filename: Optional[str] = None) -> List[dict]:
 # -- device profiling (TPU): jax.profiler passthrough -----------------------
 
 
-def start_device_trace(log_dir: str = "/tmp/ray_tpu_trace"):
-    """Start a jax.profiler trace capturing XLA/TPU activity (the
-    TPU-native role of the reference's NVTX/torch profiler flags)."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    return log_dir
-
-
-def stop_device_trace():
-    import jax
-
-    jax.profiler.stop_trace()
-
-
 @contextmanager
-def device_trace(log_dir: str = "/tmp/ray_tpu_trace"):
-    start_device_trace(log_dir)
-    try:
-        yield log_dir
-    finally:
-        stop_device_trace()
-
-
-@contextmanager
-def device_profile(logdir: str, *, host_tracer_level: int = 2):
+def device_profile(logdir: str):
     """Capture a device (TPU/XLA) profile around a block of jax work
     (SURVEY §5: 'jax.profiler traces + XPlane export' as the TPU analogue of
     the reference's NVTX/torch profiling flags). Writes an XPlane trace a
@@ -462,21 +440,86 @@ def device_profile(logdir: str, *, host_tracer_level: int = 2):
 
         with ray_tpu.util.tracing.device_profile("/tmp/prof"):
             train_step(...)
+
+    The Python tracer is off (it slows the host loop it would be
+    measuring); the host plane still carries every ``step_span`` /
+    ``annotate_device_trace`` region with its counts, on the device
+    planes' clock.
     """
     import jax
 
-    jax.profiler.start_trace(
-        logdir, create_perfetto_link=False, create_perfetto_trace=False
-    )
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(logdir, profiler_options=options)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
 
 
-def annotate_device_trace(name: str):
-    """Named region inside a device profile (jax.profiler.TraceAnnotation):
-    shows up in the XPlane timeline around the annotated host-side dispatch."""
-    import jax
+_TraceAnnotation = None
+_NO_REGION = nullcontext()
 
-    return jax.profiler.TraceAnnotation(name)
+
+def annotate_device_trace(name: str, **counts):
+    """Named region in the profiler's own trace (jax.profiler.TraceAnnotation):
+    lands in the ``/host:CPU`` plane of any running ``jax.profiler`` session,
+    on the clock the device planes use, with ``counts`` as the event's
+    stats. Counts are fixed when the region opens. With no session running
+    it is a no-op of about a microsecond, so hot paths call it
+    unconditionally. A process that has not imported jax has no session to
+    write to, and is not made to import it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return _NO_REGION
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **counts)
+
+
+class step_span:
+    """One boundary of a serving step, instrumented once: always a region
+    in the profiler's trace (``annotate_device_trace(name, **counts)``), and
+    on exit also the wall-clock request span ``request_span`` when the
+    request carries a trace context (``trace`` = ``{"ctx": ...}`` as the
+    engine keeps it per traced request, else None). The request span's
+    attributes are ``counts`` plus ``attrs`` plus whatever ``set()`` added
+    inside the block; ``cancel()`` drops it (the region stays)."""
+
+    __slots__ = ("_region", "_trace", "_name", "_category", "_attrs", "_wall")
+
+    def __init__(self, name: str, trace: Optional[dict] = None, *,
+                 request_span: Optional[str] = None, category: str = "engine",
+                 attrs: Optional[dict] = None, **counts):
+        self._region = annotate_device_trace(name, **counts)
+        self._trace = trace
+        if trace is not None:
+            self._name = request_span or name
+            self._category = category
+            self._attrs = {**counts, **(attrs or {})}
+
+    def set(self, **attrs) -> None:
+        if self._trace is not None:
+            self._attrs.update(attrs)
+
+    def cancel(self) -> None:
+        self._trace = None
+
+    def __enter__(self):
+        if self._trace is not None:
+            self._wall = time.time()
+        self._region.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._region.__exit__(exc_type, exc, tb)
+        if self._trace is not None and exc_type is None:
+            emit_span(
+                self._name, self._trace["ctx"], self._wall,
+                time.time() - self._wall, category=self._category,
+                **self._attrs,
+            )
+        return False

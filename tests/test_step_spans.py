@@ -1,0 +1,391 @@
+"""Step spans: the engine's and the replica's regions in the profiler's own
+trace (``tracing.annotate_device_trace`` / ``tracing.step_span``), read back
+with ``jax.profiler.ProfileData`` under the benchmark harness's profiler
+options (host tracer level 1, Python tracer off).
+
+One tiny engine and one profile session serve the whole file: a stepped
+scenario whose counts the test arranges, a scenario held back by a full
+block pool, and three threads driving ``generate_stream``.
+"""
+
+import asyncio
+import threading
+import time
+from collections import defaultdict
+
+import jax
+import pytest
+
+from ray_tpu.util import tracing
+
+BLOCK = 16
+SLOTS = 3
+NEW = 14  # 20 prompt tokens + 13 fed back: the tail crosses a block boundary
+
+
+def _prompt(i, n=20):
+    return [(97 * i + 13 * j + 5) % 250 + 3 for j in range(n)]
+
+
+def _request(i, n=20, new=NEW):
+    from ray_tpu.llm.engine import GenerationRequest
+
+    return GenerationRequest(
+        token_ids=_prompt(i, n), max_new_tokens=new, temperature=0.0)
+
+
+def _engine():
+    from ray_tpu.kvcache import KVCacheManager
+    from ray_tpu.llm.engine import ContinuousBatchingEngine
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    from ray_tpu.parallel.sharding import unbox_params
+
+    cfg = LlamaConfig.tiny(max_seq_len=128)
+    params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
+    return ContinuousBatchingEngine(
+        cfg, params, num_slots=SLOTS, seed=0,
+        kv_cache=KVCacheManager(num_blocks=8, block_size=BLOCK))
+
+
+def _drain(eng):
+    out = {}
+    while eng.num_active:
+        out.update(eng.step())
+    return out
+
+
+def _stepped(eng, base):
+    """Four requests on three slots, then the first prompt again."""
+    rids = [eng.add_request(_request(base + i)) for i in range(4)]
+    out = _drain(eng)
+    again = eng.add_request(_request(base))  # prefix hit: one cached block
+    out.update(_drain(eng))
+    return [out[r].token_ids for r in rids + [again]]
+
+
+def _blocked(eng, base, hold_s=0.05):
+    """Two 64-token prompts pin all 8 blocks; a third has a free slot and
+    no blocks until one of them retires."""
+    for i in range(2):
+        eng.add_request(_request(base + i, n=64, new=4))
+    eng.step()
+    held = eng.add_request(_request(base + 2, n=64, new=2))
+    asked = time.time()
+    eng.step()
+    time.sleep(hold_s)
+    eng.step()
+    time.sleep(hold_s)
+    waited_s = time.time() - asked
+    _drain(eng)
+    return held, waited_s
+
+
+def _threaded(eng, base, step_s=0.0):
+    """Three threads, a stream each. ``step_s`` lengthens every decode step
+    by a sleep, and each consumer then pauses a tenth of that over a token,
+    as one that writes to a socket would. Without both, the thread that
+    holds the engine lock takes it again microseconds after releasing it,
+    and runs its stream to the end while the others still wait, inside
+    ``add_request``, for the lock."""
+    out = [None] * 3
+    sample = eng._sample_rows
+
+    def slow_sample(logits):
+        time.sleep(step_s)
+        return sample(logits)
+
+    def stream(i):
+        for item in eng.generate_stream(_request(base + i)):
+            time.sleep(step_s / 10)
+        out[i] = item.token_ids
+
+    eng._sample_rows = slow_sample
+    try:
+        threads = [threading.Thread(target=stream, args=(i,)) for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        del eng._sample_rows
+    return out
+
+
+def _read(logdir):
+    """[{name, thread, start, end, stats}] of the host plane's step spans,
+    nanoseconds, in start order (outer before inner)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{logdir}/plugins/profile/*/*.xplane.pb")
+    (plane,) = [p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU"]
+    spans = []
+    for thread, line in enumerate(plane.lines):
+        for ev in line.events:
+            if ev.name.split(".")[0] in ("engine", "kv", "replica", "test"):
+                spans.append({
+                    "name": ev.name, "thread": thread, "start": ev.start_ns,
+                    "end": ev.start_ns + ev.duration_ns,
+                    "stats": dict(ev.stats)})
+    return sorted(spans, key=lambda s: (s["start"], -s["end"]))
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    eng = _engine()
+    # compile every program the scenarios use, outside the session
+    _stepped(eng, 100)
+    _blocked(eng, 110, hold_s=0.0)
+    logdir = str(tmp_path_factory.mktemp("step_spans"))
+    tracing.clear_spans()
+    with tracing.device_profile(logdir):
+        with tracing.annotate_device_trace("test.stepped"):
+            stepped = _stepped(eng, 0)
+        with tracing.annotate_device_trace("test.blocked"):
+            held, waited_s = _blocked(eng, 10)
+        with tracing.annotate_device_trace("test.threaded"):
+            threaded = _threaded(eng, 20, step_s=0.02)
+    spans = _read(logdir)
+
+    def scenario(name):
+        (mark,) = [s for s in spans if s["name"] == f"test.{name}"]
+        return [s for s in spans if mark["start"] <= s["start"]
+                and s["end"] <= mark["end"] and s is not mark]
+
+    return {
+        "engine": eng, "stepped": scenario("stepped"),
+        "blocked": scenario("blocked"), "threaded": scenario("threaded"),
+        "tokens": {"stepped": stepped, "threaded": threaded},
+        "held": held, "waited_s": waited_s,
+        "request_spans": tracing.get_spans(),
+    }
+
+
+def _parents(spans):
+    """id(span) -> the innermost span of its thread that contains it; also
+    checks that spans of one thread nest and never straddle."""
+    parent, stacks = {}, defaultdict(list)
+    for s in spans:
+        stack = stacks[s["thread"]]
+        while stack and stack[-1]["end"] <= s["start"]:
+            stack.pop()
+        if stack:
+            assert s["end"] <= stack[-1]["end"], (s, stack[-1])
+            parent[id(s)] = stack[-1]
+        stack.append(s)
+    return parent
+
+
+def _named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+def test_every_span_of_the_table_with_its_counts(recorded):
+    spans = recorded["stepped"]
+    parent = _parents(spans)
+    inside = {
+        "engine.admit": "engine.step", "engine.decode_dispatch": "engine.step",
+        "engine.sample_sync": "engine.step", "engine.emit": "engine.step",
+        "kv.acquire": "engine.admit", "engine.prefill": "engine.admit",
+        "kv.insert_row": "engine.admit", "kv.assemble": "engine.prefill",
+        "kv.extract_row": "kv.commit",
+    }
+    for name, outer in inside.items():
+        found = _named(spans, name)
+        assert found, f"no {name} span"
+        for s in found:
+            assert parent[id(s)]["name"] == outer, (s, parent[id(s)])
+    for s in _named(spans, "kv.commit"):
+        outer = parent[id(s)]["name"]
+        if s["stats"].get("tail"):
+            # a decoded tail is committed as its slot retires, inside emit
+            assert outer == "engine.emit" and s["stats"]["blocks"] == 1
+        else:
+            assert outer == "engine.admit" and "blocks" in s["stats"]
+    assert any(s["stats"].get("tail") == 1 for s in _named(spans, "kv.commit"))
+    steps = _named(spans, "engine.step")
+    assert all({"step", "pending", "prefilling", "wall_us"} <= set(s["stats"])
+               for s in steps)
+    # wall_us is the wall clock at entry: it advances as the trace's does
+    a, b = steps[0], steps[-1]
+    assert (b["stats"]["wall_us"] - a["stats"]["wall_us"]) * 1e3 == pytest.approx(
+        b["start"] - a["start"], abs=5e6)
+    admits = _named(spans, "engine.admit")
+    assert [s["stats"]["prompt_tokens"] for s in admits] == [20] * 5
+    assert len({s["stats"]["request_id"] for s in admits}) == 5
+    prefills = _named(spans, "engine.prefill")
+    assert [(s["stats"]["computed_tokens"], s["stats"]["cached_tokens"])
+            for s in prefills] == [(20, 0)] * 4 + [(4, BLOCK)]
+    assert len(_named(spans, "kv.assemble")) == 1
+    # the first committed prompt block of the repeated prompt is cached
+    assert [s["stats"]["blocks"] for s in _named(spans, "kv.commit")
+            if not s["stats"].get("tail")] == [1, 1, 1, 1, 0]
+    # no span a token or a slot: a step opens one of each, whatever it carries
+    for step in steps:
+        own = [s for s in spans if parent.get(id(s)) is step]
+        for name in ("engine.decode_dispatch", "engine.sample_sync", "engine.emit"):
+            assert len(_named(own, name)) <= 1
+
+
+def test_batch_and_pending_are_what_was_arranged(recorded):
+    spans = recorded["stepped"]
+    steps = _named(spans, "engine.step")
+    # four requests queued on three slots, then one, then the repeat alone
+    assert [s["stats"]["pending"] for s in steps[:3]] == [4, 1, 1]
+    assert steps[0]["stats"]["step"] + 1 == steps[1]["stats"]["step"]
+    batches = [s["stats"]["batch"] for s in _named(spans, "engine.decode_dispatch")]
+    # NEW - 1 decode steps a request: three together, the fourth alone (it
+    # is admitted in the step after the three retire), then the repeat
+    assert batches == [3] * (NEW - 1) + [1] * (NEW - 1) * 2
+    assert all(s["stats"]["prefilling"] == 0 for s in steps)
+
+
+def test_queue_wait_of_a_request_the_pool_held_back(recorded):
+    spans = recorded["blocked"]
+    parent = _parents(spans)
+    mine = [s for s in _named(spans, "engine.admit")
+            if s["stats"]["request_id"] == recorded["held"]]
+    # every attempt opens engine.admit; only the last one got its blocks
+    assert len(mine) >= 3
+    for attempt in mine[:-1]:
+        children = [s["name"] for s in spans if parent.get(id(s)) is attempt]
+        assert children == ["kv.acquire"]
+    admitted = mine[-1]
+    assert "engine.prefill" in [
+        s["name"] for s in spans if parent.get(id(s)) is admitted]
+    assert admitted["stats"]["queue_wait_us"] >= recorded["waited_s"] * 1e6
+    waits = [s["stats"]["queue_wait_us"] for s in mine]
+    assert waits == sorted(waits) and waits[0] < 50_000 <= waits[-1]
+    assert recorded["engine"]._kv.stats()["admission_blocked"] >= 2
+
+
+def test_lock_waits_are_the_other_threads_steps(recorded):
+    spans = recorded["threaded"]
+    _parents(spans)  # nesting holds on every thread
+    waits = _named(spans, "engine.lock_wait")
+    steps = _named(spans, "engine.step")
+    assert len({s["thread"] for s in steps}) >= 2
+    assert len({s["thread"] for s in waits}) == 3
+    # the lock admits one stepping thread at a time
+    ordered = sorted(steps, key=lambda s: s["start"])
+    for a, b in zip(ordered, ordered[1:]):
+        assert a["end"] <= b["start"]
+    waited = covered = 0.0
+    for w in waits:
+        waited += w["end"] - w["start"]
+        for s in steps:
+            overlap = min(w["end"], s["end"]) - max(w["start"], s["start"])
+            if overlap > 0:
+                # nobody waits for the lock while stepping
+                assert s["thread"] != w["thread"]
+                covered += overlap
+    # what a thread waits for is, for the most part, the holders' steps:
+    # the rest is the hand-over of the lock and of the interpreter
+    assert waited > 0 and covered / waited > 0.5
+    # and a step that carries all three streams has somebody waiting for it
+    parent = _parents(spans)
+    full = [parent[id(d)] for d in _named(spans, "engine.decode_dispatch")
+            if d["stats"]["batch"] == 3]
+    assert len(full) >= 5
+    for s in full:
+        assert any(w["start"] < s["end"] and s["start"] < w["end"] for w in waits)
+
+
+def test_without_a_session_the_same_tokens_and_no_request_span(recorded):
+    # nothing recorded a wall-clock request span while the profiler ran
+    assert recorded["request_spans"] == []
+    eng = recorded["engine"]
+    tracing.clear_spans()
+    assert _stepped(eng, 0) == recorded["tokens"]["stepped"]
+    assert _threaded(eng, 20) == recorded["tokens"]["threaded"]
+    assert tracing.get_spans() == []
+    # a fresh engine stepped alone agrees token for token
+    assert _stepped(_engine(), 0)[:1] == recorded["tokens"]["stepped"][:1]
+
+
+def test_step_span_records_the_request_span_only_for_a_traced_request(monkeypatch):
+    monkeypatch.setattr(tracing, "flush_spans", lambda: None)
+    tracing.clear_spans()
+    with tracing.step_span("kv.acquire", None, request_span="kvcache.acquire",
+                           category="kvcache", attrs={"request_id": 1}) as s:
+        s.set(cached_tokens=16)
+    assert tracing.get_spans() == []
+    ctx = tracing.new_trace_context()
+    parent = {"trace_id": ctx["trace_id"], "span_id": "abcd"}
+    trace = {"ctx": parent, "wall": time.time()}
+    with tracing.step_span("kv.acquire", trace, request_span="kvcache.acquire",
+                           category="kvcache", attrs={"request_id": 1}) as s:
+        s.set(cached_tokens=16)
+        time.sleep(0.01)
+    with tracing.step_span("kv.commit", trace, request_span="kvcache.commit",
+                           blocks=2) as s:
+        s.cancel()
+    with pytest.raises(RuntimeError):
+        with tracing.step_span("engine.prefill", trace, computed_tokens=4):
+            raise RuntimeError("prefill failed")
+    (span,) = tracing.get_spans()
+    assert span["name"] == "kvcache.acquire" and span["cat"] == "kvcache"
+    assert span["trace_id"] == ctx["trace_id"] and span["parent_id"] == "abcd"
+    assert span["args"]["request_id"] == 1 and span["args"]["cached_tokens"] == 16
+    assert span["dur"] >= 10_000
+    tracing.clear_spans()
+
+
+def test_replica_stream_next_counts_the_wait_for_a_pool_thread(tmp_path):
+    from ray_tpu._internal import serialization
+    from ray_tpu.serve.replica import Replica
+
+    class Slow:
+        def gen(self, n):
+            for i in range(n):
+                time.sleep(0.03)
+                yield i
+
+    async def streams(replica, count):
+        async def one():
+            return [item async for item in replica.handle_request_stream(
+                "gen", (3,), {}, None)]
+
+        return await asyncio.gather(*[one() for _ in range(count)])
+
+    replica = Replica("d", "r0", serialization.dumps(Slow), (), {}, None)
+    logdir = str(tmp_path / "prof")
+    with tracing.device_profile(logdir):
+        with tracing.annotate_device_trace("test.alone"):
+            assert asyncio.run(streams(replica, 1)) == [[0, 1, 2]]
+        with tracing.annotate_device_trace("test.crowded"):
+            # 12 streams on the pool's 8 threads: four wait a whole next()
+            assert asyncio.run(streams(replica, 12)) == [[0, 1, 2]] * 12
+    spans = _read(logdir)
+    (alone,) = _named(spans, "test.alone")
+    nexts = _named(spans, "replica.stream_next")
+    first = [s for s in nexts if s["end"] <= alone["end"]]
+    later = [s for s in nexts if s["start"] >= alone["end"]]
+    # three items and the end of the stream, each one next()
+    assert len(first) == 4 and len(later) == 12 * 4
+    assert min(s["stats"]["executor_wait_us"] for s in first) < 20_000
+    assert max(s["stats"]["executor_wait_us"] for s in later) >= 20_000
+    assert len({s["thread"] for s in later}) == 8
+
+
+def test_a_process_without_jax_is_not_made_to_import_it():
+    """A replica of a plain Python deployment opens ``replica.stream_next``
+    too. No jax there means no profiler session to write to: the region is
+    a no-op, and the process stays as light as it was."""
+    import subprocess
+    import sys
+
+    done = subprocess.run(
+        [sys.executable, "-c", (
+            "import sys\n"
+            "from ray_tpu.util import tracing\n"
+            "with tracing.annotate_device_trace('replica.stream_next', executor_wait_us=3):\n"
+            "    pass\n"
+            "with tracing.step_span('kv.acquire', None, request_span='kvcache.acquire'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n"
+            "assert tracing.get_spans() == []\n")],
+        capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
