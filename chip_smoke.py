@@ -289,7 +289,8 @@ def serve_phase(seed: int, sizes: dict = SERVE, widths: dict = WIDTHS) -> dict:
         check(
             "serve.kernels_native",
             info["kernels"].get("rmsnorm") == [False]
-            and info["kernels"].get("flash_attention") == [False],
+            and info["kernels"].get("flash_attention") == [False]
+            and info["kernels"].get("decode_attention") == [False],
             kernels=info["kernels"],
         )
         device = _device(mesh)
@@ -537,7 +538,8 @@ def tp4_against_tp1(seed: int, sizes: dict = SERVE, widths: dict = WIDTHS) -> di
             "tp4.one_worker_owns_four_chips",
             info4["tpu_ids"] == [0, 1, 2, 3] and mesh["num_devices"] == 4
             and info1["tpu_ids"] == [0]
-            and info4["kernels"].get("rmsnorm") == [False],
+            and info4["kernels"].get("rmsnorm") == [False]
+            and info4["kernels"].get("decode_attention") == [False],
             tp1_worker=info1["pid"], tp4_worker=info4["pid"],
             kernels=info4["kernels"],
         )

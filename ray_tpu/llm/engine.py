@@ -211,7 +211,18 @@ class _DecodeModelBase:
         return logits[:, -1, :], vars_out["cache"]
 
     def _decode_impl(self, params, cache, last_tokens, adapters=None,
-                     adapter_slots=None):
+                     adapter_slots=None, active=None):
+        if active is not None:
+            # a pool steps every row, live or free. A free row's position
+            # would otherwise run on, past the cache's end in time, and
+            # attention reads as far as a row's position says: an inactive
+            # row restarts at 0 each step, one key long, and whatever it
+            # writes there the next admission's row insert replaces
+            cache = jax.tree.map(
+                lambda leaf: jnp.where(active, leaf, 0)
+                if leaf.ndim == 1 else leaf,
+                cache,
+            )
         logits, vars_out = self._model.apply(
             {"params": params, "cache": cache}, last_tokens, adapters,
             adapter_slots, mutable=["cache"],
@@ -643,14 +654,20 @@ class ContinuousBatchingEngine(_DecodeModelBase):
 
     def _dense_step(self, finished: List[tuple]) -> None:
         # one decode step for the whole pool; free rows compute garbage at
-        # their stale positions (static-shape trade) and are ignored
-        with _span("engine.decode_dispatch", batch=len(self._slots)):
+        # position 0 (static-shape trade) and are ignored
+        with _span(
+            "engine.decode_dispatch", batch=len(self._slots),
+            live_tokens=self._live_tokens(),
+        ):
             last = np.zeros((self._num_slots, 1), np.int32)
+            active = np.zeros(self._num_slots, bool)
             for si, slot in self._slots.items():
                 last[si, 0] = slot.last_token
+                active[si] = True
             logits, self._cache = self._decode(
                 self._params, self._cache, jnp.asarray(last),
                 *self._adapter_args(self._row_adapter_slots()),
+                active=active,
             )
         self._step_count += 1
         with _span("engine.sample_sync"):  # the host waits for the device
@@ -683,7 +700,10 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         and the rolled-back cache index — two compiled programs and one
         host transfer of (tokens, counts) per step."""
         S, k = self._num_slots, self._spec_k
-        with _span("engine.decode_dispatch", batch=len(self._slots)):
+        with _span(
+            "engine.decode_dispatch", batch=len(self._slots),
+            live_tokens=self._live_tokens(),
+        ):
             last = np.zeros((S, 1), np.int32)
             temps = np.zeros(S, np.float32)
             start = np.zeros(S, np.int32)
@@ -750,6 +770,15 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     self._commit_decode_tail(si, slot)
             if proposed:
                 _record_spec(proposed, accepted, mesh=self._mesh_tag)
+
+    def _live_tokens(self) -> int:
+        """Key positions the coming decode step attends over all live rows
+        (prompt plus generated, the token being fed included): what a
+        length-aware attention reads, of num_slots x max_seq_len."""
+        return sum(
+            len(s.request.token_ids) + len(s.generated)
+            for s in self._slots.values()
+        )
 
     def _row_adapter_slots(self) -> np.ndarray:
         """Per-row adapter slot indices for the pooled decode batch; free

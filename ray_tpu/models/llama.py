@@ -8,7 +8,8 @@ No reference analogue: the reference serves models through vLLM/torch
   logical axes to mesh axes, XLA GSPMD inserts the collectives — TP/FSDP
   come from the sharding annotations, not model code changes
 - attention runs the Pallas flash kernel; with a sequence-parallel mesh axis
-  it runs ring attention under shard_map (parallel/ring_attention.py)
+  it runs ring attention under shard_map (parallel/ring_attention.py); a
+  decode step over the KV cache runs ops/decode_attention.py
 - bfloat16 activations, f32 params/optimizer by default; per-layer remat
   (jax.checkpoint) to trade FLOPs for HBM
 - LoRA (q/k/v/o + optional mlp) for the Llama-2-7B fine-tune north-star
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..ops.decode_attention import decode_attention
 from ..ops.flash_attention import flash_attention
 from ..ops.rmsnorm import rmsnorm
 from ..ops.rope import apply_rope, rope_table
@@ -229,22 +231,32 @@ class Attention(nn.Module):
                 cached_v.value, v.astype(cfg.dtype), idx
             )
             idx_var.value = idx + s
-            k_all = jnp.repeat(cached_k.value, h // hk, axis=1)
-            v_all = jnp.repeat(cached_v.value, h // hk, axis=1)
-            # row r's query i sits at absolute position idx[r]+i; key j is
-            # visible iff j <= idx[r]+i (and thus has been written)
-            scores = jnp.einsum(
-                "bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                k_all.astype(jnp.float32),
-            ) / math.sqrt(d)
-            q_pos = idx[:, None, None] + jnp.arange(s)[None, :, None]
-            k_pos = jnp.arange(cfg.max_seq_len)[None, None, :]
-            mask = k_pos <= q_pos  # (b, s, max_seq)
-            scores = jnp.where(mask[:, None], scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum(
-                "bhqk,bhkd->bhqd", probs, v_all.astype(jnp.float32)
-            ).astype(cfg.dtype)
+            if s == 1:
+                # a decode step: the kernel reads each row's keys and
+                # values once, as stored, up to the row's length (an index
+                # that ran past the cache still names at most all of it)
+                out = decode_attention(
+                    q[:, :, 0], cached_k.value, cached_v.value,
+                    jnp.minimum(idx + 1, cfg.max_seq_len), self.mesh,
+                )[:, :, None]
+            else:
+                # prefill, chunked prefill, speculative verify
+                k_all = jnp.repeat(cached_k.value, h // hk, axis=1)
+                v_all = jnp.repeat(cached_v.value, h // hk, axis=1)
+                # row r's query i sits at absolute position idx[r]+i; key
+                # j is visible iff j <= idx[r]+i (and thus has been written)
+                scores = jnp.einsum(
+                    "bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                    k_all.astype(jnp.float32),
+                ) / math.sqrt(d)
+                q_pos = idx[:, None, None] + jnp.arange(s)[None, :, None]
+                k_pos = jnp.arange(cfg.max_seq_len)[None, None, :]
+                mask = k_pos <= q_pos  # (b, s, max_seq)
+                scores = jnp.where(mask[:, None], scores, -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1)
+                out = jnp.einsum(
+                    "bhqk,bhkd->bhqd", probs, v_all.astype(jnp.float32)
+                ).astype(cfg.dtype)
         elif self.mesh is not None:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)

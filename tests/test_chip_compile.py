@@ -11,6 +11,7 @@
 """
 
 import os
+import re
 import subprocess
 import sys
 import time
@@ -18,6 +19,7 @@ import time
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import PartitionSpec as P
 
 import ray_tpu
 from ray_tpu._internal import accelerators, platform
@@ -31,14 +33,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """SingleDeviceSharding on the first device of a described v5e 2x2 host,
-    with the persistent compile cache off: such a compile would be written
-    to it and could never be read back without a chip."""
+def v5e_host():
+    """The four devices of a described v5e 2x2 host, with the persistent
+    compile cache off: such a compile would be written to it and could
+    never be read back without a chip."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(
@@ -49,19 +50,27 @@ def v5e_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_host):
+    """SingleDeviceSharding on the host's first device."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_host[0])
 
 
 @pytest.fixture
 def native_kernels(monkeypatch):
     """Trace the Pallas kernels as the chip would: this process's default
     backend is the CPU, so the ops would otherwise pick interpret mode."""
-    from ray_tpu.ops import flash_attention, rmsnorm
+    from ray_tpu.ops import decode_attention, flash_attention, rmsnorm
 
-    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
-    monkeypatch.setattr(rmsnorm, "_use_interpret", lambda: False)
+    for kernel in (decode_attention, flash_attention, rmsnorm):
+        monkeypatch.setattr(kernel, "_use_interpret", lambda: False)
 
 
 def _on(chip, tree):
@@ -102,15 +111,73 @@ def test_rmsnorm_compiles(v5e_chip, native_kernels):
     assert "tpu_custom_call" in _compile(lambda a, b: rmsnorm(a, b, 1e-5), x, w)
 
 
-def test_decode_model_prefill_and_decode_compile(v5e_chip, native_kernels):
+# (b, h, hk, max_seq_len): the chat cells' pool (Mistral-7B: 32 query heads
+# over 8 KV heads of 128, 16 slots x 4096) and chip_smoke.py's (Llama-2-7B)
+MISTRAL_POOL = (16, 32, 8, 4096)
+LLAMA2_POOL = (8, 32, 32, 2048)
+
+
+@pytest.mark.parametrize("pool", [MISTRAL_POOL, LLAMA2_POOL],
+                         ids=["mistral", "llama2"])
+def test_decode_attention_compiles(v5e_chip, native_kernels, pool):
+    from ray_tpu.ops.decode_attention import decode_attention
+
+    b, h, hk, max_seq_len = pool
+    q = jax.ShapeDtypeStruct((b, h, 128), jnp.bfloat16, sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct(
+        (b, hk, max_seq_len, 128), jnp.bfloat16, sharding=v5e_chip
+    )
+    lengths = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e_chip)
+    assert "tpu_custom_call" in _compile(decode_attention, q, kv, kv, lengths)
+
+
+def test_decode_attention_compiles_per_shard_under_tp4(v5e_host, native_kernels):
+    """The chat cells' pool with its KV heads over four chips, as
+    PartitionPlan lays the decode cache out: two KV heads a chip."""
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.ops.decode_attention import decode_attention
+    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.parallel.plan import KV_SPEC
+
+    b, h, hk, max_seq_len = MISTRAL_POOL
+    mesh = make_mesh(tp=4, fsdp=1, devices=v5e_host)
+
+    def on(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*spec))
+        )
+
+    q = on((b, h, 128), jnp.bfloat16, None, "tp", None)
+    kv = on((b, hk, max_seq_len, 128), jnp.bfloat16, *KV_SPEC)
+    text = _compile(
+        lambda *a: decode_attention(*a, mesh=mesh),
+        q, kv, kv, on((b,), jnp.int32),
+    )
+    # each chip's kernel sees its own 2 of the 8 KV heads, and nothing is
+    # gathered to run it
+    assert f"bf16[{b},{hk // 4},8,128]" in text
+    assert "all-gather" not in text and "all-reduce" not in text
+
+
+@pytest.mark.parametrize(
+    "pool,widths",
+    [(MISTRAL_POOL, dict(vocab_size=32768, intermediate=14336, rope_theta=1e6)),
+     (LLAMA2_POOL, dict(vocab_size=32000, intermediate=11008))],
+    ids=["mistral", "llama2"],
+)
+def test_decode_model_prefill_and_decode_compile(
+    v5e_chip, native_kernels, pool, widths
+):
     """The serving engine's two programs at 7B widths, two layers deep."""
     from ray_tpu.llm.engine import _DecodeModelBase
     from ray_tpu.models.llama import LlamaConfig, init_params
     from ray_tpu.parallel.sharding import unbox_params
 
+    slots, h, hk, max_seq_len = pool
     cfg = LlamaConfig(
-        vocab_size=32000, dim=4096, n_layers=2, n_heads=32, n_kv_heads=32,
-        intermediate=11008, max_seq_len=2048, param_dtype=jnp.bfloat16,
+        dim=4096, n_layers=2, n_heads=h, n_kv_heads=hk,
+        max_seq_len=max_seq_len, param_dtype=jnp.bfloat16, **widths,
     )
     params = jax.eval_shape(
         lambda k: unbox_params(init_params(cfg, k)), jax.random.PRNGKey(0)
@@ -119,9 +186,9 @@ def test_decode_model_prefill_and_decode_compile(v5e_chip, native_kernels):
     prompt = jax.ShapeDtypeStruct((1, 512), jnp.int32)
     row = jax.eval_shape(model._prefill_impl, params, prompt)[1]
     pool = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct((8,) + s.shape[1:], s.dtype), row
+        lambda s: jax.ShapeDtypeStruct((slots,) + s.shape[1:], s.dtype), row
     )
-    last = jax.ShapeDtypeStruct((8, 1), jnp.int32)
+    last = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
 
     prefill = _compile(
         model._prefill_impl, _on(v5e_chip, params), _on(v5e_chip, prompt)
@@ -130,9 +197,15 @@ def test_decode_model_prefill_and_decode_compile(v5e_chip, native_kernels):
         model._decode_impl, _on(v5e_chip, params), _on(v5e_chip, pool),
         _on(v5e_chip, last),
     )
-    # the einsum attention path: rmsnorm is the model's only kernel here
-    assert "tpu_custom_call" in prefill
-    assert "tpu_custom_call" in decode
+    # prefill attends by einsum: rmsnorm is its only kernel (two a layer
+    # and the final one); a decode step adds the attention kernel a layer
+    kernel = 'custom_call_target="tpu_custom_call"'
+    assert prefill.count(kernel) == 5
+    assert decode.count(kernel) == 7
+    # and holds no f32 copy of a cache, whole or expanded over the group
+    assert not re.search(
+        rf"f32\[{slots},{hk},(\d+,)?{max_seq_len},128\]", decode
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -253,9 +326,15 @@ def test_pin_cpu_platform_keeps_the_inherited_value_for_chip_workers():
 
 
 def test_kernels_interpret_on_cpu_only(monkeypatch):
+    from ray_tpu.ops import decode_attention, flash_attention, rmsnorm
+
     assert platform.is_tpu_backend() is False
     assert platform.pallas_interpret("probe") is True
     assert platform.traced_kernel_modes()["probe"] == [True]
+    for kernel in (decode_attention, flash_attention, rmsnorm):
+        assert kernel._use_interpret() is True
+        name = kernel.__name__.rsplit(".", 1)[1]
+        assert platform.traced_kernel_modes()[name] == [True]
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     with pytest.raises(RuntimeError, match="no lowering for platform 'gpu'"):
         platform.pallas_interpret("probe")

@@ -30,6 +30,14 @@ def tiny_engine():
     return cfg, params, LLMEngine(cfg, params, max_batch_size=4)
 
 
+@pytest.fixture(scope="module")
+def gqa_engine():
+    """Two query heads a KV head: the decode kernel's grouped path."""
+    cfg = LlamaConfig.tiny(n_heads=4, n_kv_heads=2, max_seq_len=64)
+    params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
+    return cfg, params, LLMEngine(cfg, params, max_batch_size=4)
+
+
 def _greedy_reference(cfg, params, prompt, n_new):
     """Greedy decoding via repeated FULL forward passes (no cache)."""
     model = Llama(cfg, None)
@@ -43,8 +51,9 @@ def _greedy_reference(cfg, params, prompt, n_new):
 
 
 @pytest.mark.slow
-def test_cache_decode_matches_full_forward(tiny_engine):
-    cfg, params, engine = tiny_engine
+@pytest.mark.parametrize("engine_fixture", ["tiny_engine", "gqa_engine"])
+def test_cache_decode_matches_full_forward(engine_fixture, request):
+    cfg, params, engine = request.getfixturevalue(engine_fixture)
     prompt = [3, 14, 15, 92, 65, 35]
     n_new = 8
     ref = _greedy_reference(cfg, params, prompt, n_new)
@@ -157,10 +166,11 @@ def test_llm_batch_stage(ray_start_regular):
 
 @pytest.mark.slow
 class TestContinuousBatching:
-    def test_matches_full_forward(self, tiny_engine):
+    @pytest.mark.parametrize("engine_fixture", ["tiny_engine", "gqa_engine"])
+    def test_matches_full_forward(self, engine_fixture, request):
         from ray_tpu.llm.engine import ContinuousBatchingEngine
 
-        cfg, params, _ = tiny_engine
+        cfg, params, _ = request.getfixturevalue(engine_fixture)
         engine = ContinuousBatchingEngine(cfg, params, num_slots=4)
         prompt = [3, 14, 15, 92, 65, 35]
         ref = _greedy_reference(cfg, params, prompt, 8)

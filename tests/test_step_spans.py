@@ -242,6 +242,15 @@ def test_batch_and_pending_are_what_was_arranged(recorded):
     assert all(s["stats"]["prefilling"] == 0 for s in steps)
 
 
+def test_live_tokens_is_the_sum_of_the_live_rows_lengths(recorded):
+    live = [s["stats"]["live_tokens"]
+            for s in _named(recorded["stepped"], "engine.decode_dispatch")]
+    # a row's keys at a decode step: its 20 prompt tokens, the token its
+    # prefill sampled, and one more with every step since
+    row = [20 + 1 + i for i in range(NEW - 1)]
+    assert live == [3 * n for n in row] + row * 2
+
+
 def test_queue_wait_of_a_request_the_pool_held_back(recorded):
     spans = recorded["blocked"]
     parent = _parents(spans)
