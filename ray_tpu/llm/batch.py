@@ -47,10 +47,10 @@ class LLMPredictor:
 
             _, params = weights.fetch(weights_name, timeout=60.0)
         else:
-            from ..models.llama import init_params
+            from .. import models
 
             params = unbox_params(
-                init_params(model_config, jax.random.PRNGKey(0))
+                models.init_params(model_config, jax.random.PRNGKey(0))
             )
         self._adapter_store = None
         if self._config.adapters is not None:

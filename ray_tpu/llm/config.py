@@ -145,6 +145,29 @@ class LLMConfig:
     adapters: Optional[AdapterConfig] = None
 
     def __post_init__(self):
+        from ..models import refusals
+
+        tp, sp = self.effective_parallelism()
+        using = {
+            "adapters": self.adapters is not None,
+            "draft_model": self.draft_model is not None,
+            "mesh": tp > 1 or sp > 1,
+        }
+        for feature, reason in refusals(self.model_family).items():
+            if using[feature]:
+                raise ValueError(
+                    f"LLMConfig: model_family {self.model_family!r} cannot "
+                    f"serve with {feature} yet: {reason}"
+                )
+        if self.model_family == "moe" and not self.model_kwargs.get(
+            "dropless", True
+        ):
+            # no knob: with a capacity, a row's answer would depend on the
+            # rows that share its batch, and the decode model will not build
+            raise ValueError(
+                "LLMConfig: model_kwargs['dropless']=False: serving has no "
+                "capacity path for routed experts"
+            )
         if self.mesh is not None:
             from ..exceptions import MeshValidationError
 
@@ -234,6 +257,9 @@ class LLMConfig:
 
             kwargs = dict(self.model_kwargs)
             kwargs.setdefault("max_seq_len", self.max_seq_len)
+            # serving never drops an assignment (__post_init__ refused
+            # the other value)
+            kwargs["dropless"] = True
             return MoEConfig.tiny(**kwargs) if self.model_id.endswith(
                 "tiny"
             ) else MoEConfig(**kwargs)

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import ROUTING as _ROUTING
 from ..util import events as _events
 from ..util import tracing as _tracing
 
@@ -111,6 +112,43 @@ def _greedy_sample(logits):
 _span = _tracing.annotate_device_trace
 
 
+def _new_expert_counts(model_config) -> Optional[dict]:
+    """Zeroed device-side counters for a model with routed experts, None
+    for one without: ``steps`` decode steps, ``assignments`` (layers,
+    experts) choices made by live rows, ``touched`` (layers,) the sum over
+    steps of distinct experts live rows chose. int32: at 8 choices a row
+    and 50 steps a second an expert's count lasts two months."""
+    n_experts = getattr(model_config, "n_experts", 0)
+    if not n_experts:
+        return None
+    layers = model_config.n_layers
+    return {
+        "steps": jnp.zeros((), jnp.int32),
+        "assignments": jnp.zeros((layers, n_experts), jnp.int32),
+        "touched": jnp.zeros((layers,), jnp.int32),
+    }
+
+
+def _count_experts(counts: dict, routing: dict, active) -> dict:
+    """``counts`` plus one decode step's choices (``routing``: the sown
+    collection, ``layer_<i>/moe/experts`` a tuple of one (rows, k) array),
+    free rows left out."""
+    n_experts = counts["assignments"].shape[1]
+    step = jnp.stack([
+        routing[f"layer_{i}"]["moe"]["experts"][0]
+        for i in range(counts["touched"].shape[0])
+    ])  # (layers, rows, k)
+    hits = jax.nn.one_hot(step, n_experts, dtype=jnp.int32)
+    if active is not None:
+        hits = hits * jnp.asarray(active, jnp.int32)[None, :, None, None]
+    per_expert = hits.sum(axis=(1, 2))  # (layers, experts)
+    return {
+        "steps": counts["steps"] + 1,
+        "assignments": counts["assignments"] + per_expert,
+        "touched": counts["touched"] + (per_expert > 0).sum(axis=1),
+    }
+
+
 class _StepLock:
     """The engine lock as the stepping entry points take it: the wait is
     an ``engine.lock_wait`` region in the profiler's trace. One thread
@@ -151,12 +189,14 @@ class GenerationResult:
 
 
 class _DecodeModelBase:
-    """Shared jitted prefill/decode programs over the cached Llama
-    (both engines compile the identical two programs)."""
+    """Shared jitted prefill/decode programs over the cached model of
+    whatever family ``model_config`` belongs to (``ray_tpu.models`` says
+    what a family has to offer; both engines compile the identical two
+    programs)."""
 
     def __init__(self, model_config, params, mesh=None, plan=None,
                  adapter_store=None):
-        from ..models.llama import Llama
+        from .. import models
 
         self._cfg = model_config
         self._mesh = mesh
@@ -173,7 +213,7 @@ class _DecodeModelBase:
             plan = PartitionPlan(mesh)
         self._plan = plan
         self._mesh_tag = plan.describe() if plan is not None else "tp=1"
-        self._model = Llama(model_config, mesh, decode=True)
+        self._model = models.build(model_config, mesh, decode=True)
         self._cache_shardings = None
         self._replicated = None
         if plan is not None:
@@ -211,7 +251,7 @@ class _DecodeModelBase:
         return logits[:, -1, :], vars_out["cache"]
 
     def _decode_impl(self, params, cache, last_tokens, adapters=None,
-                     adapter_slots=None, active=None):
+                     adapter_slots=None, active=None, expert_counts=None):
         if active is not None:
             # a pool steps every row, live or free. A free row's position
             # would otherwise run on, past the cache's end in time, and
@@ -223,10 +263,19 @@ class _DecodeModelBase:
                 if leaf.ndim == 1 else leaf,
                 cache,
             )
+        # a routed model's step also says which experts its rows chose: the
+        # running counts ride through the program, so counting costs the
+        # host nothing and the step no sync
+        counting = expert_counts is not None
         logits, vars_out = self._model.apply(
             {"params": params, "cache": cache}, last_tokens, adapters,
-            adapter_slots, mutable=["cache"],
+            adapter_slots,
+            mutable=["cache", _ROUTING] if counting else ["cache"],
         )
+        if counting:
+            return logits[:, -1, :], vars_out["cache"], _count_experts(
+                expert_counts, vars_out[_ROUTING], active
+            )
         return logits[:, -1, :], vars_out["cache"]
 
     def _adapter_args(self, slots) -> tuple:
@@ -472,6 +521,9 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         self._next_id = 0
         self._rng = jax.random.PRNGKey(_resolve_seed(seed))
         self._step_count = 0
+        # running expert counts of a routed model (None for a dense one),
+        # device-side; expert_stats() reads them
+        self._expert_counts = _new_expert_counts(model_config)
         self._cache = None  # pooled cache, allocated on first prefill
         # paged prefix cache (ray_tpu.kvcache.KVCacheManager) or None for
         # the dense per-slot pool; with a manager, _admit serves the
@@ -664,11 +716,17 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             for si, slot in self._slots.items():
                 last[si, 0] = slot.last_token
                 active[si] = True
-            logits, self._cache = self._decode(
+            counted = (
+                {} if self._expert_counts is None
+                else {"expert_counts": self._expert_counts}
+            )
+            logits, self._cache, *counts = self._decode(
                 self._params, self._cache, jnp.asarray(last),
                 *self._adapter_args(self._row_adapter_slots()),
-                active=active,
+                active=active, **counted,
             )
+            if counts:
+                (self._expert_counts,) = counts
         self._step_count += 1
         with _span("engine.sample_sync"):  # the host waits for the device
             tokens = self._sample_rows(logits)
@@ -770,6 +828,22 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     self._commit_decode_tail(si, slot)
             if proposed:
                 _record_spec(proposed, accepted, mesh=self._mesh_tag)
+
+    def expert_stats(self) -> Optional[dict]:
+        """The routed model's running counts as plain numbers (this read
+        waits for the device; the step never does): ``decode_steps``,
+        ``assignments`` [layer][expert] by live rows, ``touched`` [layer]
+        = sum over steps of distinct experts live rows chose. None for a
+        model without routed experts."""
+        if self._expert_counts is None:
+            return None
+        with self._lock:
+            counts = jax.tree.map(host_sync, self._expert_counts)
+        return {
+            "decode_steps": int(counts["steps"]),
+            "assignments": counts["assignments"].tolist(),
+            "touched": counts["touched"].tolist(),
+        }
 
     def _live_tokens(self) -> int:
         """Key positions the coming decode step attends over all live rows

@@ -107,10 +107,10 @@ class _LLMReplica:
 
             params = serialization.loads(params_blob)
         else:
-            from ..models.llama import init_params
+            from .. import models
 
             params = unbox_params(
-                init_params(
+                models.init_params(
                     model_config, jax.random.PRNGKey(llm_config.seed or 0)
                 )
             )
@@ -148,11 +148,11 @@ class _LLMReplica:
             if llm_config.draft_model is not None:
                 # speculative draft: initialized per replica (the draft is
                 # tiny — no weight plane, no sharded publish)
-                from ..models.llama import init_params as _init_draft
+                from .. import models
 
                 draft_cfg = llm_config.build_draft_model_config()
                 draft_params = unbox_params(
-                    _init_draft(draft_cfg, jax.random.PRNGKey(1))
+                    models.init_params(draft_cfg, jax.random.PRNGKey(1))
                 )
                 draft = (draft_cfg, draft_params)
             self._adapter_store = None
@@ -319,27 +319,30 @@ class _LLMReplica:
             "compile_cache_dir": jax.config.jax_compilation_cache_dir,
             "compile": compile_cache.stats(),
             "kernels": traced_kernel_modes(),
+            # a routed model's expert counters (engine.expert_stats());
+            # None for a dense model or the grouped-batch engine
+            "moe": getattr(self._engine, "expert_stats", lambda: None)(),
         }
 
     def check_prefill_logits(self, token_ids) -> Dict[str, Any]:
         """Parity self-check on this replica's own weights: the last
         position's logits of ``token_ids`` from the engine's prefill
         program (einsum attention over the cache) against a plain
-        full-sequence forward through ``Llama(cfg, None)`` (the flash
-        kernel). Returns both argmaxes, the largest absolute logit
+        full-sequence forward through the family's training-mode model
+        (the flash kernel). Returns both argmaxes, the largest absolute logit
         difference, and the reference's margin between its best two
         tokens — a difference above the margin can flip a greedy token
         without either path being wrong."""
         import jax
         import jax.numpy as jnp
 
-        from ..models.llama import Llama
+        from .. import models
 
         cfg = self._engine._cfg
         tokens = jnp.asarray([list(token_ids)], jnp.int32)
         engine_logits, _ = self._engine._prefill(self._engine._params, tokens)
         ref_logits = jax.jit(
-            lambda p, t: Llama(cfg, None).apply({"params": p}, t)[:, -1, :]
+            lambda p, t: models.build(cfg).apply({"params": p}, t)[:, -1, :]
         )(self._engine._params, tokens)
         eng = engine_logits[0].astype(jnp.float32)
         ref = ref_logits[0].astype(jnp.float32)

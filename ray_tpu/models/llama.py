@@ -59,6 +59,9 @@ class LlamaConfig:
     scan_layers: bool = False
     lora_rank: int = 0
     lora_alpha: float = 16.0
+    # RMSNorm over the whole q and k projections, before the split into
+    # heads and before RoPE (OLMoE's q_norm / k_norm)
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -193,9 +196,27 @@ class Attention(nn.Module):
         def run(mod, name):
             return mod(x, adapters.get(name), adapter_slots)
 
-        q = run(proj(h * d, "wq"), "wq").reshape(b, s, h, d).transpose(0, 2, 1, 3)
-        k = run(proj(hk * d, "wk"), "wk").reshape(b, s, hk, d).transpose(0, 2, 1, 3)
-        v = run(proj(hk * d, "wv"), "wv").reshape(b, s, hk, d).transpose(0, 2, 1, 3)
+        def normed(y, name):
+            # OLMoE's q_norm / k_norm: over the whole projection, before
+            # the split into heads
+            if not cfg.qk_norm:
+                return y
+            w = self.param(
+                name,
+                nn.with_logical_partitioning(
+                    nn.initializers.ones_init(), ("heads",)
+                ),
+                (y.shape[-1],),
+                cfg.param_dtype,
+            )
+            return rmsnorm(y, w.astype(y.dtype), cfg.norm_eps, self.mesh)
+
+        def heads(y, n):
+            return y.reshape(b, s, n, d).transpose(0, 2, 1, 3)
+
+        q = heads(normed(run(proj(h * d, "wq"), "wq"), "q_norm"), h)
+        k = heads(normed(run(proj(hk * d, "wk"), "wk"), "k_norm"), hk)
+        v = heads(run(proj(hk * d, "wv"), "wv"), hk)
 
         if self.decode:
             # KV-cache incremental path (serving; reference role: vLLM's
@@ -417,6 +438,11 @@ class Llama(nn.Module):
             cfg.param_dtype,
         )
         return x @ head.astype(x.dtype)
+
+
+def build(config: LlamaConfig, mesh: Optional[Mesh] = None, decode: bool = False):
+    """What ``ray_tpu.models.build`` returns for this family."""
+    return Llama(config, mesh, decode)
 
 
 def init_params(config: LlamaConfig, rng, mesh: Optional[Mesh] = None, seq: int = 8):

@@ -1,12 +1,22 @@
-"""Mixture-of-Experts transformer (Mixtral-style), TPU-first.
+"""Mixture-of-Experts transformer (Mixtral- and OLMoE-shaped), TPU-first.
 
 No reference analogue (the reference serves MoE through vLLM engine kwargs
 — SURVEY §2c "EP delegated"); here the framework owns the model layer.
-Mixtral-shape: LLaMA attention blocks with the dense FFN replaced by a
-top-k routed expert FFN. Expert weights carry the ``expert`` logical axis
-(sharded over the ``ep`` mesh axis by parallel/sharding.py rules); the
-dispatch/combine einsums (parallel/expert.py) lower to all_to_alls under
-GSPMD — no manual collectives in model code.
+LLaMA attention blocks (the shared ``models/llama.Attention``, decode cache
+included) with the dense FFN replaced by a top-k routed expert FFN. Two
+expert paths (parallel/expert.py):
+
+- capacity (``dropless=False``, the training default): GShard dispatch and
+  combine einsums with a static capacity; expert weights carry the
+  ``expert`` logical axis and GSPMD lowers the einsums to all_to_alls over
+  ``ep``. A token past an expert's capacity is dropped.
+- dropless (``dropless=True``; serving always): the assignments sorted by
+  expert through one grouped kernel (ops/moe_experts.py). Nothing is
+  dropped, so a row's answer does not depend on its batch.
+
+OLMoE (``modeling_olmoe.py``) is ``qk_norm=True, norm_topk_prob=False``:
+RMSNorm over the whole q and k projections, and top-k weights that are not
+renormalised.
 """
 
 from __future__ import annotations
@@ -21,7 +31,14 @@ from jax.sharding import Mesh
 
 from ..ops.rmsnorm import rmsnorm
 from ..ops.rope import rope_table
-from ..parallel.expert import expert_capacity, moe_apply_gspmd, top_k_gating
+from ..parallel.expert import (
+    expert_capacity,
+    moe_apply_dropless,
+    moe_apply_gspmd,
+    top_k_gating,
+    top_k_routing,
+)
+from . import ROUTING
 from .llama import Attention, LlamaConfig
 
 
@@ -43,6 +60,21 @@ class MoEConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
+    # no capacity: every assignment is computed (see the module docstring)
+    dropless: bool = False
+    # divide the kept top-k weights by their sum (Mixtral: yes, OLMoE: no)
+    norm_topk_prob: bool = True
+    # RMSNorm over the whole q and k projections (OLMoE)
+    qk_norm: bool = False
+
+    def __post_init__(self):
+        if not self.dropless and not self.norm_topk_prob:
+            raise ValueError(
+                "MoEConfig(norm_topk_prob=False) needs dropless=True: the "
+                "capacity path (parallel/expert.top_k_gating) always "
+                "divides the kept weights by their sum, so it would train "
+                "Mixtral's mathematics under OLMoE's setting"
+            )
 
     @property
     def head_dim(self) -> int:
@@ -62,6 +94,7 @@ class MoEConfig:
             dtype=self.dtype,
             param_dtype=self.param_dtype,
             remat=self.remat,
+            qk_norm=self.qk_norm,
         )
 
     @staticmethod
@@ -99,19 +132,38 @@ class MoEFFN(nn.Module):
             (cfg.dim, cfg.n_experts),
             cfg.param_dtype,
         )
-        logits = tokens.astype(jnp.float32) @ router_w.astype(jnp.float32)
-        capacity = expert_capacity(
-            b * s, cfg.n_experts, cfg.capacity_factor, cfg.experts_per_token
-        )
-        dispatch, combine, aux = top_k_gating(
-            logits, capacity, k=cfg.experts_per_token
-        )
+        with jax.named_scope("moe.route"):
+            # full f32 products: on a TPU a default-precision f32 matmul
+            # rounds its operands to bf16, which is enough to swap the
+            # k-th and (k+1)-th expert; the product is tiny
+            logits = jnp.dot(
+                tokens.astype(jnp.float32), router_w.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            if cfg.dropless:
+                weights, chosen, aux = top_k_routing(
+                    logits, cfg.experts_per_token, cfg.norm_topk_prob
+                )
+                self.sow(ROUTING, "experts", chosen)
+            else:
+                capacity = expert_capacity(
+                    b * s, cfg.n_experts, cfg.capacity_factor,
+                    cfg.experts_per_token,
+                )
+                dispatch, combine, aux = top_k_gating(
+                    logits, capacity, k=cfg.experts_per_token
+                )
         self.sow("losses", "router_aux", cfg.router_aux_weight * aux)
 
+        # fan-in of one expert's matrix, not of all of them together: with
+        # the expert axis counted in, every matrix is sqrt(n_experts) too
+        # small and a random model's logits do not depend on its experts
+        # (measured on the chip at OLMoE's widths, PERF.md finding 25.5)
+        per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
         w_gate = self.param(
             "w_gate",
             nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), ("expert", "embed", "mlp")
+                per_expert, ("expert", "embed", "mlp")
             ),
             (cfg.n_experts, cfg.dim, cfg.intermediate),
             cfg.param_dtype,
@@ -119,7 +171,7 @@ class MoEFFN(nn.Module):
         w_up = self.param(
             "w_up",
             nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), ("expert", "embed", "mlp")
+                per_expert, ("expert", "embed", "mlp")
             ),
             (cfg.n_experts, cfg.dim, cfg.intermediate),
             cfg.param_dtype,
@@ -127,7 +179,7 @@ class MoEFFN(nn.Module):
         w_down = self.param(
             "w_down",
             nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), ("expert", "mlp", "embed")
+                per_expert, ("expert", "mlp", "embed")
             ),
             (cfg.n_experts, cfg.intermediate, cfg.dim),
             cfg.param_dtype,
@@ -140,16 +192,24 @@ class MoEFFN(nn.Module):
                 "ecf,efd->ecd", nn.silu(gate) * up, w_down.astype(inp.dtype)
             )
 
-        out = moe_apply_gspmd(tokens, dispatch, combine, experts)
+        with jax.named_scope("moe.experts"):
+            if cfg.dropless:
+                out = moe_apply_dropless(
+                    tokens, weights, chosen, w_gate.astype(tokens.dtype),
+                    w_up.astype(tokens.dtype), w_down.astype(tokens.dtype),
+                )
+            else:
+                out = moe_apply_gspmd(tokens, dispatch, combine, experts)
         return out.reshape(b, s, d)
 
 
 class MoEBlock(nn.Module):
     config: MoEConfig
     mesh: Optional[Mesh] = None
+    decode: bool = False
 
     @nn.compact
-    def __call__(self, x, cos, sin):
+    def __call__(self, x, cos, sin, adapters=None, adapter_slots=None):
         cfg = self.config
         attn_cfg = cfg.attention_config()
         attn_norm_w = self.param(
@@ -158,9 +218,10 @@ class MoEBlock(nn.Module):
             (cfg.dim,),
             cfg.param_dtype,
         )
-        h = x + Attention(attn_cfg, self.mesh, name="attn")(
+        h = x + Attention(attn_cfg, self.mesh, self.decode, name="attn")(
             rmsnorm(x, attn_norm_w.astype(x.dtype), cfg.norm_eps, self.mesh),
             cos, sin,
+            (adapters or {}).get("attn"), adapter_slots,
         )
         ffn_norm_w = self.param(
             "ffn_norm",
@@ -176,10 +237,18 @@ class MoEBlock(nn.Module):
 class MoETransformer(nn.Module):
     config: MoEConfig
     mesh: Optional[Mesh] = None
+    decode: bool = False
 
     @nn.compact
-    def __call__(self, tokens):  # (batch, seq) int32
+    def __call__(self, tokens, adapters=None, adapter_slots=None):
+        # tokens: (batch, seq) int32; adapters / adapter_slots as in
+        # models/llama.Llama (attention projections only)
         cfg = self.config
+        if self.decode and not cfg.dropless:
+            raise ValueError(
+                "a decode cache needs MoEConfig.dropless: with a capacity, "
+                "what a row is answered depends on the rows beside it"
+            )
         embed = self.param(
             "embed",
             nn.with_logical_partitioning(
@@ -198,7 +267,10 @@ class MoETransformer(nn.Module):
                 prevent_cse=False,
             )
         for i in range(cfg.n_layers):
-            x = block(cfg, self.mesh, name=f"layer_{i}")(x, cos, sin)
+            x = block(cfg, self.mesh, self.decode, name=f"layer_{i}")(
+                x, cos, sin,
+                (adapters or {}).get(f"layer_{i}"), adapter_slots,
+            )
         final_norm_w = self.param(
             "final_norm",
             nn.with_logical_partitioning(nn.initializers.ones_init(), ("embed",)),
@@ -215,6 +287,11 @@ class MoETransformer(nn.Module):
             cfg.param_dtype,
         )
         return x @ head.astype(x.dtype)
+
+
+def build(config: MoEConfig, mesh: Optional[Mesh] = None, decode: bool = False):
+    """What ``ray_tpu.models.build`` returns for this family."""
+    return MoETransformer(config, mesh, decode)
 
 
 def init_params(config: MoEConfig, rng, mesh: Optional[Mesh] = None, seq: int = 8):

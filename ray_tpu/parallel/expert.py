@@ -14,6 +14,13 @@ static shapes — no gather/scatter of ragged expert batches):
   ``lax.all_to_all`` over the ``ep`` axis moves (expert, capacity, dim)
   slabs so each rank runs only its local experts — for hand-scheduled
   kernels and tests of the comm pattern itself
+
+The dropless path (serving, and any model with ``MoEConfig.dropless``) has
+no capacity: ``top_k_routing`` picks each token's experts and
+``moe_apply_dropless`` sorts the ``tokens x k`` assignments by expert and
+runs them as one grouped matmul (ops/moe_experts.py), so every assignment
+is computed whatever the imbalance and an answer does not depend on which
+other rows share the batch.
 """
 
 from __future__ import annotations
@@ -143,3 +150,69 @@ def moe_combine(y_local, combine, axis_name: str = "ep"):
     return jnp.einsum(
         "ecd,tec->td", slabs_home.astype(jnp.float32), combine
     ).astype(y_local.dtype)
+
+
+# -- dropless path -----------------------------------------------------------
+
+
+def top_k_routing(
+    router_logits: jax.Array,  # (tokens, experts)
+    k: int,
+    normalize: bool = True,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Each token's ``k`` experts: f32 softmax over all experts, the ``k``
+    largest probabilities and their experts; ``normalize`` divides the kept
+    weights by their sum (Mixtral), without it they sum to less than 1
+    (OLMoE's ``norm_topk_prob: false``).
+
+    Returns ``(weights (tokens, k) f32, experts (tokens, k) int32,
+    aux_loss)`` with the same Switch-style load-balance loss as
+    ``top_k_gating``."""
+    n_experts = router_logits.shape[-1]
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    weights, experts = lax.top_k(probs, k)
+    if normalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    chosen = jnp.zeros_like(probs).at[
+        jnp.arange(probs.shape[0])[:, None], experts
+    ].set(1.0)
+    aux_loss = n_experts * jnp.sum(
+        jnp.mean(chosen, axis=0) * jnp.mean(probs, axis=0)
+    )
+    return weights, experts.astype(jnp.int32), aux_loss
+
+
+def moe_apply_dropless(
+    x: jax.Array,  # (tokens, dim)
+    weights: jax.Array,  # (tokens, k) f32
+    experts: jax.Array,  # (tokens, k) int32
+    w_gate: jax.Array,  # (E, dim, f)
+    w_up: jax.Array,  # (E, dim, f)
+    w_down: jax.Array,  # (E, f, dim)
+) -> jax.Array:
+    """``sum_j weights[t, j] * swiglu_{experts[t, j]}(x[t])`` for every
+    token, no assignment dropped: the ``tokens x k`` assignments sorted by
+    expert, one grouped matmul over the sorted rows, then back in token
+    order and summed. Static shapes: the rows are padded to whole tiles,
+    and the padding rides with the last expert at weight zero."""
+    from ..ops.moe_experts import moe_experts, tile_rows
+
+    tokens, k = experts.shape
+    n_experts = w_gate.shape[0]
+    n = tokens * k
+    tm = tile_rows(n)
+    padded = -(-n // tm) * tm
+    flat = experts.reshape(n)
+    if padded != n:
+        flat = jnp.concatenate(
+            [flat, jnp.full((padded - n,), n_experts - 1, jnp.int32)]
+        )
+    order = jnp.argsort(flat, stable=True)
+    # an assignment's token: row i of ``flat`` belongs to token i // k
+    # (padding rows read the last token; nothing reads their result)
+    source = jnp.minimum(order // k, tokens - 1)
+    group_sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    y = moe_experts(x[source], w_gate, w_up, w_down, group_sizes)
+    back = jnp.argsort(order)[:n]  # sorted row of each assignment
+    y = y[back].reshape(tokens, k, -1)
+    return jnp.sum(y * weights[..., None], axis=1).astype(x.dtype)

@@ -67,9 +67,11 @@ def v5e_chip(v5e_host):
 def native_kernels(monkeypatch):
     """Trace the Pallas kernels as the chip would: this process's default
     backend is the CPU, so the ops would otherwise pick interpret mode."""
-    from ray_tpu.ops import decode_attention, flash_attention, rmsnorm
+    from ray_tpu.ops import (
+        decode_attention, flash_attention, moe_experts, rmsnorm,
+    )
 
-    for kernel in (decode_attention, flash_attention, rmsnorm):
+    for kernel in (decode_attention, flash_attention, moe_experts, rmsnorm):
         monkeypatch.setattr(kernel, "_use_interpret", lambda: False)
 
 
@@ -206,6 +208,70 @@ def test_decode_model_prefill_and_decode_compile(
     assert not re.search(
         rf"f32\[{slots},{hk},(\d+,)?{max_seq_len},128\]", decode
     )
+
+
+def test_olmoe_prefill_and_decode_compile(v5e_chip, native_kernels):
+    """The `olmoe-chat-backlog` cell's two programs at its shapes (8 slots
+    x 4096, 8 layers of 64 experts at the published widths), inside the
+    configuration file's budget, with no capacity tensor anywhere."""
+    from ray_tpu.llm.engine import _DecodeModelBase, _new_expert_counts
+    from ray_tpu.models import init_params
+    from ray_tpu.models.moe import MoEConfig
+    from ray_tpu.parallel.expert import expert_capacity
+    from ray_tpu.parallel.sharding import unbox_params
+
+    slots, layers, experts, k = 8, 8, 64, 8
+    cfg = MoEConfig(
+        vocab_size=50304, dim=2048, n_layers=layers, n_heads=16, n_kv_heads=16,
+        intermediate=1024, n_experts=experts, experts_per_token=k,
+        max_seq_len=4096, rope_theta=10000.0, param_dtype=jnp.bfloat16,
+        dropless=True, norm_topk_prob=False, qk_norm=True, remat=False,
+    )
+    params = jax.eval_shape(
+        lambda key: unbox_params(init_params(cfg, key)), jax.random.PRNGKey(0)
+    )
+    model = _DecodeModelBase(cfg, None)
+    prompt = jax.ShapeDtypeStruct((1, 512), jnp.int32)
+    row = jax.eval_shape(model._prefill_impl, params, prompt)[1]
+    pool = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((slots,) + s.shape[1:], s.dtype), row
+    )
+    last = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
+    counts = jax.eval_shape(lambda: _new_expert_counts(cfg))
+
+    prefill = jax.jit(model._prefill_impl).lower(
+        _on(v5e_chip, params), _on(v5e_chip, prompt)
+    ).compile()
+    decode = jax.jit(
+        lambda p, c, t, n: model._decode_impl(p, c, t, expert_counts=n)
+    ).lower(
+        _on(v5e_chip, params), _on(v5e_chip, pool), _on(v5e_chip, last),
+        _on(v5e_chip, counts),
+    ).compile()
+    kernel = 'custom_call_target="tpu_custom_call"'
+    # a layer: four rmsnorms (two of them q_norm and k_norm) and the
+    # grouped experts, in decode the attention kernel too; one final norm
+    assert prefill.as_text().count(kernel) == 5 * layers + 1
+    assert decode.as_text().count(kernel) == 6 * layers + 1
+    for program, tokens in ((prefill, 512), (decode, slots)):
+        capacity = expert_capacity(tokens, experts, cfg.capacity_factor, k)
+        assert not re.search(
+            rf"\[{tokens},{experts},{capacity}\]", program.as_text()
+        )
+    # the configuration file's budget: weights 7.12 GB and the slot cache
+    # of 2.15 GB twice (S1: the step does not donate it) = 11.42 GB, which
+    # with the pool's 1.61 GB is the 13.0 GB; the step's own temporaries
+    # and a 512-token prefill's must be small beside that
+    def size(tree):
+        return sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(tree))
+
+    assert 7.0e9 < size(params) < 7.2e9
+    assert 2.1e9 < size(pool) < 2.2e9
+    mem = decode.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < size(params) + 2 * size(pool) + 0.1e9
+    assert prefill.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
 # ---------------------------------------------------------------------------
