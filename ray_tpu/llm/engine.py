@@ -235,13 +235,19 @@ class _DecodeModelBase:
             self._prefill = jax.jit(
                 self._prefill_impl, out_shardings=(rep, cache_sh)
             )
+            # a program that takes a cache and returns its successor
+            # donates it: the step writes one position a row in place
+            # (the cache comes in under the sharding it leaves with, so
+            # the alias holds per shard). Every caller rebinds what it
+            # passed; a cache handed to _decode is gone afterwards
             self._decode = jax.jit(
-                self._decode_impl, out_shardings=(rep, cache_sh)
+                self._decode_impl, donate_argnums=(1,),
+                out_shardings=(rep, cache_sh),
             )
         else:
             self._params = params
             self._prefill = jax.jit(self._prefill_impl)
-            self._decode = jax.jit(self._decode_impl)
+            self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
 
     def _prefill_impl(self, params, tokens, adapters=None, adapter_slots=None):
         logits, vars_out = self._model.apply(
@@ -560,8 +566,10 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 lambda p: jax.lax.dynamic_slice_in_dim(p, si, 1, axis=0), pool
             )
         )
-        # donated in-place row insert: one compiled program for every slot
-        # (si is a traced scalar), no full-pool copy per admission
+        # donated in-place row insert, like every program that advances a
+        # cache (_decode, _verify, _propose, _set_index): one compiled
+        # program for every slot (si is a traced scalar), no full-pool copy
+        # per admission. The solo row is read, not donated
         self._insert_row = jax.jit(
             lambda pool, solo, si: jax.tree.map(
                 lambda p, s: jax.lax.dynamic_update_index_in_dim(
@@ -593,7 +601,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             )
             if self._draft._cache_shardings is not None:
                 self._propose = jax.jit(
-                    self._propose_impl,
+                    self._propose_impl, donate_argnums=(1,),
                     out_shardings=(
                         self._draft._replicated, self._draft._replicated,
                         self._draft._replicated,
@@ -601,17 +609,21 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     ),
                 )
             else:
-                self._propose = jax.jit(self._propose_impl)
+                self._propose = jax.jit(
+                    self._propose_impl, donate_argnums=(1,)
+                )
             if self._cache_shardings is not None:
                 self._verify = jax.jit(
-                    self._verify_impl,
+                    self._verify_impl, donate_argnums=(1,),
                     out_shardings=(
                         self._replicated, self._replicated,
                         self._cache_shardings, self._replicated,
                     ),
                 )
             else:
-                self._verify = jax.jit(self._verify_impl)
+                self._verify = jax.jit(
+                    self._verify_impl, donate_argnums=(1,)
+                )
             # rollback-as-index-reset for the draft pool: K/V past the
             # accepted prefix is garbage the causal mask never reads and
             # the next write overwrites — only the position moves back
@@ -631,7 +643,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # decodes keep stepping instead of stalling behind a long prompt.
         self._prefill_chunk = int(prefill_chunk_tokens or 0)
         self._prefilling: Dict[int, dict] = {}
-        self._empty_row_template = None
+        self._empty_row_shape = None
         # observability for the perf-smoke guard: prefill tokens actually
         # computed by the most recent step()
         self.last_step_prefill_tokens = 0
@@ -1373,26 +1385,24 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             )
 
     def _empty_row(self):
-        """An all-zero solo cache row with write position 0 — the chunked
-        prefill seed when no cached prefix exists (shaped via eval_shape:
-        structure only, no compute). Memoized: the eval_shape trace walks
-        the whole model (~hundreds of ms) and the template never changes;
-        handing out the same immutable arrays is safe because ``_decode``
-        does not donate its cache argument."""
-        if self._empty_row_template is not None:
-            return self._empty_row_template
-        cache_shape = jax.eval_shape(
-            self._prefill_impl, self._params,
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        )[1]
+        """A fresh all-zero solo cache row with write position 0 — the
+        chunked prefill seed when no cached prefix exists. The shapes are
+        memoized (the eval_shape trace walks the whole model, ~hundreds of
+        ms, and never changes); the zeros are built anew each call, because
+        ``_decode`` donates its cache argument: a row handed to it is
+        consumed, so no two prefills may share one."""
+        if self._empty_row_shape is None:
+            self._empty_row_shape = jax.eval_shape(
+                self._prefill_impl, self._params,
+                jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            )[1]
         row = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), cache_shape
+            lambda s: jnp.zeros(s.shape, s.dtype), self._empty_row_shape
         )
         if self._plan is not None:
             row = jax.tree.map(
                 jax.device_put, row, self._plan.cache_shardings(row)
             )
-        self._empty_row_template = row
         return row
 
     def _admit_draft_row(self, req: GenerationRequest, si: int) -> None:

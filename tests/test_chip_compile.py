@@ -85,6 +85,39 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _size(tree):
+    return sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(tree))
+
+
+def _assert_steps_in_place(decode, params, pool):
+    """The compiled decode step writes its cache where it was handed it
+    (S1): every cache leaf is an input aliased to the output that succeeds
+    it, and no instruction copies a whole K or V of the pool."""
+    text = decode.as_text()
+    header = text.split("\n", 1)[0]
+    aliases = {
+        int(param): int(out) for out, param in re.findall(
+            r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header
+        )
+    }
+    # flattened arguments: the params' leaves, then the cache's; outputs:
+    # the logits, then the cache's leaves in the same order
+    first, leaves = len(jax.tree.leaves(params)), jax.tree.leaves(pool)
+    assert aliases == {first + i: 1 + i for i in range(len(leaves))}
+    # (the device pads each small index leaf to 512 bytes)
+    aliased = decode.memory_analysis().alias_size_in_bytes
+    assert _size(pool) <= aliased <= _size(pool) + 512 * len(leaves)
+    kv = {",".join(map(str, s.shape)) for s in leaves if s.ndim == 4}
+    assert len(kv) == 1  # one K/V shape: [slots, kv_heads, max_seq_len, 128]
+    assert not re.search(rf"= \w+\[{kv.pop()}\]\S* copy\(", text)
+    # so the step holds the pool once: weights + pool + its temporaries
+    mem = decode.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+            ) < _size(params) + _size(pool) + 0.1e9
+
+
 def test_flash_attention_forward_and_backward_compile(v5e_chip, native_kernels):
     from ray_tpu.ops.flash_attention import flash_attention
 
@@ -162,25 +195,33 @@ def test_decode_attention_compiles_per_shard_under_tp4(v5e_host, native_kernels)
     assert "all-gather" not in text and "all-reduce" not in text
 
 
-@pytest.mark.parametrize(
+DENSE_7B = pytest.mark.parametrize(
     "pool,widths",
     [(MISTRAL_POOL, dict(vocab_size=32768, intermediate=14336, rope_theta=1e6)),
      (LLAMA2_POOL, dict(vocab_size=32000, intermediate=11008))],
     ids=["mistral", "llama2"],
 )
-def test_decode_model_prefill_and_decode_compile(
-    v5e_chip, native_kernels, pool, widths
-):
-    """The serving engine's two programs at 7B widths, two layers deep."""
-    from ray_tpu.llm.engine import _DecodeModelBase
-    from ray_tpu.models.llama import LlamaConfig, init_params
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    """What one test of a pair compiled, kept for its sibling."""
+    return {}
+
+
+def _serving_programs(compiled, chip, cfg, slots):
+    """The serving engine's two programs for ``cfg`` on a described chip,
+    compiled once: the prefill function over a 512-token prompt, and the
+    engine's own ``_decode`` (the jit object, as a pooled step calls it:
+    ``active`` rows, and a routed model's running expert counts). Returns
+    (prefill, decode, params, pool), the last two as shapes."""
+    from ray_tpu.llm.engine import _DecodeModelBase, _new_expert_counts
+    from ray_tpu.models import init_params
     from ray_tpu.parallel.sharding import unbox_params
 
-    slots, h, hk, max_seq_len = pool
-    cfg = LlamaConfig(
-        dim=4096, n_layers=2, n_heads=h, n_kv_heads=hk,
-        max_seq_len=max_seq_len, param_dtype=jnp.bfloat16, **widths,
-    )
+    key = repr((cfg, slots))
+    if key in compiled:
+        return compiled[key]
     params = jax.eval_shape(
         lambda k: unbox_params(init_params(cfg, k)), jax.random.PRNGKey(0)
     )
@@ -191,14 +232,42 @@ def test_decode_model_prefill_and_decode_compile(
         lambda s: jax.ShapeDtypeStruct((slots,) + s.shape[1:], s.dtype), row
     )
     last = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
+    active = jax.ShapeDtypeStruct((slots,), jnp.bool_)
+    counts = jax.eval_shape(lambda: _new_expert_counts(cfg))
+    counted = {} if counts is None else {"expert_counts": _on(chip, counts)}
 
-    prefill = _compile(
-        model._prefill_impl, _on(v5e_chip, params), _on(v5e_chip, prompt)
+    prefill = jax.jit(model._prefill_impl).lower(
+        _on(chip, params), _on(chip, prompt)
+    ).compile()
+    decode = model._decode.lower(
+        _on(chip, params), _on(chip, pool), _on(chip, last),
+        active=_on(chip, active), **counted,
+    ).compile()
+    compiled[key] = prefill, decode, params, pool
+    return compiled[key]
+
+
+def _dense_7b_programs(compiled, chip, pool, widths):
+    """At 7B widths, two layers deep."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    slots, h, hk, max_seq_len = pool
+    cfg = LlamaConfig(
+        dim=4096, n_layers=2, n_heads=h, n_kv_heads=hk,
+        max_seq_len=max_seq_len, param_dtype=jnp.bfloat16, **widths,
     )
-    decode = _compile(
-        model._decode_impl, _on(v5e_chip, params), _on(v5e_chip, pool),
-        _on(v5e_chip, last),
+    return _serving_programs(compiled, chip, cfg, slots)
+
+
+@DENSE_7B
+def test_decode_model_prefill_and_decode_compile(
+    v5e_chip, native_kernels, compiled, pool, widths
+):
+    slots, _, hk, max_seq_len = pool
+    prefill, decode, _, _ = _dense_7b_programs(
+        compiled, v5e_chip, pool, widths
     )
+    prefill, decode = prefill.as_text(), decode.as_text()
     # prefill attends by einsum: rmsnorm is its only kernel (two a layer
     # and the final one); a decode step adds the attention kernel a layer
     kernel = 'custom_call_target="tpu_custom_call"'
@@ -210,44 +279,39 @@ def test_decode_model_prefill_and_decode_compile(
     )
 
 
-def test_olmoe_prefill_and_decode_compile(v5e_chip, native_kernels):
-    """The `olmoe-chat-backlog` cell's two programs at its shapes (8 slots
-    x 4096, 8 layers of 64 experts at the published widths), inside the
-    configuration file's budget, with no capacity tensor anywhere."""
-    from ray_tpu.llm.engine import _DecodeModelBase, _new_expert_counts
-    from ray_tpu.models import init_params
-    from ray_tpu.models.moe import MoEConfig
-    from ray_tpu.parallel.expert import expert_capacity
-    from ray_tpu.parallel.sharding import unbox_params
+@DENSE_7B
+def test_decode_step_donates_its_cache(
+    v5e_chip, native_kernels, compiled, pool, widths
+):
+    """S1: the step the engine calls aliases all of its cache, copies none
+    of it, and so holds the pool once, not twice."""
+    _, decode, params, cache = _dense_7b_programs(
+        compiled, v5e_chip, pool, widths
+    )
+    _assert_steps_in_place(decode, params, cache)
 
-    slots, layers, experts, k = 8, 8, 64, 8
+
+def _olmoe_programs(compiled, chip):
+    """The `olmoe-chat-backlog` cell's programs at its shapes: 8 slots x
+    4096, 8 layers of 64 experts at the published widths."""
+    from ray_tpu.models.moe import MoEConfig
+
     cfg = MoEConfig(
-        vocab_size=50304, dim=2048, n_layers=layers, n_heads=16, n_kv_heads=16,
-        intermediate=1024, n_experts=experts, experts_per_token=k,
+        vocab_size=50304, dim=2048, n_layers=8, n_heads=16, n_kv_heads=16,
+        intermediate=1024, n_experts=64, experts_per_token=8,
         max_seq_len=4096, rope_theta=10000.0, param_dtype=jnp.bfloat16,
         dropless=True, norm_topk_prob=False, qk_norm=True, remat=False,
     )
-    params = jax.eval_shape(
-        lambda key: unbox_params(init_params(cfg, key)), jax.random.PRNGKey(0)
-    )
-    model = _DecodeModelBase(cfg, None)
-    prompt = jax.ShapeDtypeStruct((1, 512), jnp.int32)
-    row = jax.eval_shape(model._prefill_impl, params, prompt)[1]
-    pool = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct((slots,) + s.shape[1:], s.dtype), row
-    )
-    last = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
-    counts = jax.eval_shape(lambda: _new_expert_counts(cfg))
+    return (cfg,) + _serving_programs(compiled, chip, cfg, 8)
 
-    prefill = jax.jit(model._prefill_impl).lower(
-        _on(v5e_chip, params), _on(v5e_chip, prompt)
-    ).compile()
-    decode = jax.jit(
-        lambda p, c, t, n: model._decode_impl(p, c, t, expert_counts=n)
-    ).lower(
-        _on(v5e_chip, params), _on(v5e_chip, pool), _on(v5e_chip, last),
-        _on(v5e_chip, counts),
-    ).compile()
+
+def test_olmoe_prefill_and_decode_compile(v5e_chip, native_kernels, compiled):
+    """The cell's two programs hold the kernels they should and no
+    capacity tensor anywhere, inside the configuration file's budget."""
+    from ray_tpu.parallel.expert import expert_capacity
+
+    cfg, prefill, decode, params, pool = _olmoe_programs(compiled, v5e_chip)
+    slots, layers, experts, k = 8, 8, 64, 8
     kernel = 'custom_call_target="tpu_custom_call"'
     # a layer: four rmsnorms (two of them q_norm and k_norm) and the
     # grouped experts, in decode the attention kernel too; one final norm
@@ -258,20 +322,24 @@ def test_olmoe_prefill_and_decode_compile(v5e_chip, native_kernels):
         assert not re.search(
             rf"\[{tokens},{experts},{capacity}\]", program.as_text()
         )
-    # the configuration file's budget: weights 7.12 GB and the slot cache
-    # of 2.15 GB twice (S1: the step does not donate it) = 11.42 GB, which
-    # with the pool's 1.61 GB is the 13.0 GB; the step's own temporaries
-    # and a 512-token prefill's must be small beside that
-    def size(tree):
-        return sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(tree))
-
-    assert 7.0e9 < size(params) < 7.2e9
-    assert 2.1e9 < size(pool) < 2.2e9
-    mem = decode.memory_analysis()
-    assert mem.temp_size_in_bytes < 0.1e9
-    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes) < size(params) + 2 * size(pool) + 0.1e9
+    # the chip holds weights 7.12 GB, the slot cache of 2.15 GB once (S1:
+    # the step donates it and writes in place; the sibling test holds the
+    # step to weights + cache + 0.1 GB) and the pool's 1.61 GB; the step's
+    # own temporaries and a 512-token prefill's must be small beside that
+    assert 7.0e9 < _size(params) < 7.2e9
+    assert 2.1e9 < _size(pool) < 2.2e9
+    assert decode.memory_analysis().temp_size_in_bytes < 0.1e9
     assert prefill.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_olmoe_decode_step_donates_its_cache(
+    v5e_chip, native_kernels, compiled
+):
+    """S1 for the routed model: 8 layers' K, V and indices all aliased
+    (the expert counts ride through undonated), no [8,16,4096,128] copy,
+    and a footprint of params + pool, not params + 2 x pool."""
+    _, _, decode, params, pool = _olmoe_programs(compiled, v5e_chip)
+    _assert_steps_in_place(decode, params, pool)
 
 
 # ---------------------------------------------------------------------------
