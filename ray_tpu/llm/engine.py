@@ -109,6 +109,16 @@ def _greedy_sample(logits):
     return jnp.argmax(logits, axis=-1)
 
 
+@jax.jit
+def _merge_last(sampled, fresh, first):
+    """The tokens a pool step is fed, (slots, 1), made where the last
+    step's samples already are: a row admitted since takes the token its
+    prefill sampled, every other row what the step before sampled for it.
+    It runs in every step, fresh rows or none, so a batch of concurrent
+    requests reaches no program a lone request did not (jit__merge_last)."""
+    return jnp.where(fresh, first, sampled)[:, None]
+
+
 _span = _tracing.annotate_device_trace
 
 
@@ -306,14 +316,19 @@ class _DecodeModelBase:
             params = self._plan.shard_params(params)
         self._params = params
 
-    def _sample_tokens(self, logits, temps: np.ndarray, key) -> np.ndarray:
+    @staticmethod
+    def _sample_on_device(logits, temps: np.ndarray, key):
         """Greedy where temps==0, temperature-categorical elsewhere — the
         one sampling rule both engines use everywhere. All-greedy batches
-        skip the categorical entirely; mixed batches run the fused sampler
-        (one program, one transfer)."""
+        skip the categorical entirely (and need no key); mixed batches run
+        the fused sampler (one program). The ids stay on the device."""
         if temps.any():
-            return host_sync(_fused_sample(logits, jnp.asarray(temps), key))
-        return host_sync(_greedy_sample(logits))
+            return _fused_sample(logits, jnp.asarray(temps), key)
+        return _greedy_sample(logits)
+
+    def _sample_tokens(self, logits, temps: np.ndarray, key) -> np.ndarray:
+        """``_sample_on_device`` and the one transfer that reads it."""
+        return host_sync(self._sample_on_device(logits, temps, key))
 
 
 class LLMEngine(_DecodeModelBase):
@@ -487,6 +502,16 @@ class _Slot:
     last_emit_ts: float = 0.0  # monotonic stamp of the last emitted token
 
 
+@dataclasses.dataclass
+class _Step:
+    """A pool decode step the device has been given and the host has not
+    read: its sampled ids, still on the device, and the rows that were
+    live when it was dispatched (slot index -> the ``_Slot`` itself: a
+    slot index alone may name another request by the time it is read)."""
+    tokens: Any
+    rows: Dict[int, _Slot]
+
+
 class ContinuousBatchingEngine(_DecodeModelBase):
     """Continuous (in-flight) batching: a fixed pool of decode slots; new
     requests prefill into free slots while other slots keep decoding, so
@@ -499,6 +524,13 @@ class ContinuousBatchingEngine(_DecodeModelBase):
     decode path); prefill runs per request at its prompt length and the
     resulting K/V rows are inserted into the pooled cache. XLA compiles one
     decode program + one prefill program per prompt-length bucket.
+
+    The decode step runs one ahead of the host (``_dense_step``): a step's
+    sampled ids feed the next step on the device, and the host reads step
+    N while step N+1 runs. So a token reaches its caller one host read
+    after it was computed, and a row leaves the batch when the host has
+    *seen* its last token: it may ride one step more, whose token for it
+    nobody reads.
     """
 
     def __init__(
@@ -527,6 +559,10 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         self._next_id = 0
         self._rng = jax.random.PRNGKey(_resolve_seed(seed))
         self._step_count = 0
+        # the decode step in flight (dispatched, unread) and the newest
+        # sampled ids, on the device: what the next step is fed
+        self._inflight: Optional[_Step] = None
+        self._sampled = jnp.zeros((num_slots,), jnp.int32)
         # running expert counts of a routed model (None for a dense one),
         # device-side; expert_stats() reads them
         self._expert_counts = _new_expert_counts(model_config)
@@ -708,44 +744,35 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             finished: List[tuple] = self._admit()
             if self._prefilling:
                 self._advance_prefills(finished)
-            if not self._slots:
-                return finished
             if self._spec_k and self._draft is not None:
-                self._spec_step(finished)
+                # the accept counts place the next proposal: read every step
+                if self._slots:
+                    self._spec_step(finished)
             else:
                 self._dense_step(finished)
             return finished
 
     def _dense_step(self, finished: List[tuple]) -> None:
-        # one decode step for the whole pool; free rows compute garbage at
-        # position 0 (static-shape trade) and are ignored
-        with _span(
-            "engine.decode_dispatch", batch=len(self._slots),
-            live_tokens=self._live_tokens(),
-        ):
-            last = np.zeros((self._num_slots, 1), np.int32)
-            active = np.zeros(self._num_slots, bool)
-            for si, slot in self._slots.items():
-                last[si, 0] = slot.last_token
-                active[si] = True
-            counted = (
-                {} if self._expert_counts is None
-                else {"expert_counts": self._expert_counts}
-            )
-            logits, self._cache, *counts = self._decode(
-                self._params, self._cache, jnp.asarray(last),
-                *self._adapter_args(self._row_adapter_slots()),
-                active=active, **counted,
-            )
-            if counts:
-                (self._expert_counts,) = counts
-        self._step_count += 1
+        """Dispatch the next decode step, then read the one before it: the
+        host's work of a step (dispatch, the wake-up after the sync, emit,
+        retirements, the hand-over to the next caller) runs with a step
+        queued on the device. With none in flight (the engine was idle) two
+        are dispatched and the first is read, so a call still yields a
+        token. Whatever ``_admit`` queued above (prefill, row insert) runs
+        behind the step in flight and before the one dispatched here."""
+        step = self._inflight or self._dispatch_decode(None)
+        if step is None:
+            return
+        self._inflight = self._dispatch_decode(step)
         with _span("engine.sample_sync"):  # the host waits for the device
-            tokens = self._sample_rows(logits)
+            tokens = host_sync(step.tokens)
         with _span("engine.emit"):
             now = time.monotonic()
-            for si in list(self._slots):
-                slot = self._slots[si]
+            for si, slot in step.rows.items():
+                if self._slots.get(si) is not slot:
+                    # it left when the step before was read and rode this
+                    # one: its token here is nobody's
+                    continue
                 tok = int(tokens[si])
                 slot.generated.append(tok)
                 slot.last_token = tok
@@ -761,6 +788,66 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     self._finish_slot(
                         si, slot, "eos" if done_eos else "length", finished
                     )
+        if self._inflight is not None and not self._slots:
+            # every row of the step in flight ended in the one just read:
+            # nobody will read it, so it never happened, and the next step
+            # takes its number (and with it its sampling key)
+            self._inflight = None
+            self._step_count -= 1
+
+    def _dispatch_decode(self, unread: Optional[_Step]) -> Optional[_Step]:
+        """Queue one decode step of the whole pool, and its sampler, behind
+        whatever the device is doing; ``unread`` is the step in flight, if
+        any. None, and nothing dispatched, when no live row can want the
+        token: every one reaches its ``max_new_tokens`` in ``unread``.
+
+        Free rows compute garbage at position 0 (static-shape trade) and
+        are ignored. A row that ended in ``unread`` without the host's
+        knowing (``eos``), or by count beside rows that go on, steps once
+        more: it stays ``active``, so that its position is not reset and
+        position 0 not rewritten before the retirement's commit has read
+        the row; the step writes one position past the row's last full
+        block at most, which no commit takes."""
+        riding = unread.rows if unread is not None else {}
+        fresh = np.zeros(self._num_slots, bool)
+        first = np.zeros(self._num_slots, np.int32)
+        active = np.zeros(self._num_slots, bool)
+        temps = np.zeros(self._num_slots, np.float32)
+        batch = live_tokens = 0
+        for si, slot in self._slots.items():
+            active[si] = True
+            temps[si] = max(slot.request.temperature, 0.0)
+            in_flight = riding.get(si) is slot
+            if not in_flight:  # admitted since: the host knows its token
+                fresh[si] = True
+                first[si] = slot.last_token
+            have = len(slot.generated) + in_flight
+            if have < slot.request.max_new_tokens:
+                batch += 1
+                live_tokens += len(slot.request.token_ids) + have
+        if not batch:
+            return None
+        # live_tokens: the key positions this step attends over the rows
+        # that want it (prompt plus generated, the token fed included)
+        with _span(
+            "engine.decode_dispatch", batch=batch, live_tokens=live_tokens,
+            ahead=int(unread is not None),
+        ):
+            counted = (
+                {} if self._expert_counts is None
+                else {"expert_counts": self._expert_counts}
+            )
+            logits, self._cache, *counts = self._decode(
+                self._params, self._cache,
+                _merge_last(self._sampled, fresh, first),
+                *self._adapter_args(self._row_adapter_slots()),
+                active=active, **counted,
+            )
+            if counts:
+                (self._expert_counts,) = counts
+            self._step_count += 1
+            self._sampled = self._sample_rows(logits, temps)
+        return _Step(self._sampled, dict(self._slots))
 
     def _spec_step(self, finished: List[tuple]) -> None:
         """One speculative iteration for the whole pool: the draft model
@@ -846,7 +933,8 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         waits for the device; the step never does): ``decode_steps``,
         ``assignments`` [layer][expert] by live rows, ``touched`` [layer]
         = sum over steps of distinct experts live rows chose. None for a
-        model without routed experts."""
+        model without routed experts. A row is live to the device until the
+        host has seen its last token: the step it rides meanwhile counts."""
         if self._expert_counts is None:
             return None
         with self._lock:
@@ -1652,9 +1740,10 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             )
         return pooled
 
-    def _sample_rows(self, logits) -> np.ndarray:
-        temps = np.zeros(self._num_slots, np.float32)
-        for si, slot in self._slots.items():
-            temps[si] = max(slot.request.temperature, 0.0)
-        key = jax.random.fold_in(self._rng, 10_000 + self._step_count)
-        return self._sample_tokens(logits, temps, key)
+    def _sample_rows(self, logits, temps: np.ndarray):
+        """The pool step's ids, sampled on the device and left there."""
+        key = (
+            jax.random.fold_in(self._rng, 10_000 + self._step_count)
+            if temps.any() else None
+        )
+        return self._sample_on_device(logits, temps, key)

@@ -90,9 +90,9 @@ def _threaded(eng, base, step_s=0.0):
     out = [None] * 3
     sample = eng._sample_rows
 
-    def slow_sample(logits):
+    def slow_sample(*args):
         time.sleep(step_s)
-        return sample(logits)
+        return sample(*args)
 
     def stream(i):
         for item in eng.generate_stream(_request(base + i)):
@@ -132,8 +132,18 @@ def _read(logdir):
     return sorted(spans, key=lambda s: (s["start"], -s["end"]))
 
 
+@pytest.fixture(scope="module", autouse=True)
+def no_request_tracing():
+    """A file that ran earlier in this process may have left request
+    tracing on (``enable_tracing()`` has no undo); these cases are about a
+    process that traces no request."""
+    was, tracing._enabled = tracing._enabled, False
+    yield
+    tracing._enabled = was
+
+
 @pytest.fixture(scope="module")
-def recorded(tmp_path_factory):
+def recorded(tmp_path_factory, no_request_tracing):
     eng = _engine()
     # compile every program the scenarios use, outside the session
     _stepped(eng, 100)
@@ -222,11 +232,29 @@ def test_every_span_of_the_table_with_its_counts(recorded):
     # the first committed prompt block of the repeated prompt is cached
     assert [s["stats"]["blocks"] for s in _named(spans, "kv.commit")
             if not s["stats"].get("tail")] == [1, 1, 1, 1, 0]
-    # no span a token or a slot: a step opens one of each, whatever it carries
+    # no span a token or a slot: a step reads one decode step, whatever it
+    # carries, after dispatching the one that follows it. ``ahead`` counts
+    # the steps the device had been given and the host had not read when
+    # this one was dispatched: 1, but for the first of an engine found idle,
+    # which is dispatched with its successor in one call
     for step in steps:
         own = [s for s in spans if parent.get(id(s)) is step]
-        for name in ("engine.decode_dispatch", "engine.sample_sync", "engine.emit"):
+        for name in ("engine.sample_sync", "engine.emit"):
             assert len(_named(own, name)) <= 1
+        dispatched = _named(own, "engine.decode_dispatch")
+        ahead = [d["stats"]["ahead"] for d in dispatched]
+        assert ahead in ([], [1], [0, 1], [0]), ahead
+        for d, sync in zip(dispatched[-1:], _named(own, "engine.sample_sync")):
+            assert d["end"] <= sync["start"]  # dispatched before the read
+    # three runs of NEW - 1 decode steps, each begun on an idle engine; a
+    # run's last step is dispatched alone, and read in a call that
+    # dispatches nothing: no row could want another token
+    aheads = [s["stats"]["ahead"] for s in _named(spans, "engine.decode_dispatch")]
+    assert aheads == ([0] + [1] * (NEW - 2)) * 3
+    owned = [[s["name"] for s in spans if parent.get(id(s)) is step] for step in steps]
+    reads = [own.count("engine.decode_dispatch") for own in owned
+             if "engine.sample_sync" in own]
+    assert reads == ([2] + [1] * (NEW - 3) + [0]) * 3
 
 
 def test_batch_and_pending_are_what_was_arranged(recorded):
@@ -234,7 +262,10 @@ def test_batch_and_pending_are_what_was_arranged(recorded):
     steps = _named(spans, "engine.step")
     # four requests queued on three slots, then one, then the repeat alone
     assert [s["stats"]["pending"] for s in steps[:3]] == [4, 1, 1]
-    assert steps[0]["stats"]["step"] + 1 == steps[1]["stats"]["step"]
+    # ``step`` counts decode steps dispatched: two in the call that found
+    # the engine idle, one a call after that
+    assert [s["stats"]["step"] for s in steps[:3]] == [
+        steps[0]["stats"]["step"] + n for n in (0, 2, 3)]
     batches = [s["stats"]["batch"] for s in _named(spans, "engine.decode_dispatch")]
     # NEW - 1 decode steps a request: three together, the fourth alone (it
     # is admitted in the step after the three retire), then the repeat
