@@ -48,7 +48,7 @@ class AdapterConfig:
 class LLMConfig:
     model_id: str = "llama-tiny"
     # model construction: either a models.llama config name or kwargs
-    model_family: str = "llama"  # "llama" | "moe"
+    model_family: str = "llama"  # "llama" | "moe" | "deepseek"
     model_kwargs: Dict[str, Any] = field(default_factory=dict)
     max_seq_len: int = 512
     max_batch_size: int = 8
@@ -245,25 +245,22 @@ class LLMConfig:
 
     def build_model_config(self):
         if self.model_family == "llama":
-            from ..models.llama import LlamaConfig
-
-            kwargs = dict(self.model_kwargs)
-            kwargs.setdefault("max_seq_len", self.max_seq_len)
-            return LlamaConfig.tiny(**kwargs) if self.model_id.endswith(
-                "tiny"
-            ) else LlamaConfig(**kwargs)
+            from ..models.llama import LlamaConfig as config_type
+        elif self.model_family == "moe":
+            from ..models.moe import MoEConfig as config_type
+        elif self.model_family == "deepseek":
+            from ..models.deepseek import DeepseekConfig as config_type
+        else:
+            raise ValueError(f"unknown model family {self.model_family!r}")
+        kwargs = dict(self.model_kwargs)
+        kwargs.setdefault("max_seq_len", self.max_seq_len)
         if self.model_family == "moe":
-            from ..models.moe import MoEConfig
-
-            kwargs = dict(self.model_kwargs)
-            kwargs.setdefault("max_seq_len", self.max_seq_len)
             # serving never drops an assignment (__post_init__ refused
             # the other value)
             kwargs["dropless"] = True
-            return MoEConfig.tiny(**kwargs) if self.model_id.endswith(
-                "tiny"
-            ) else MoEConfig(**kwargs)
-        raise ValueError(f"unknown model family {self.model_family!r}")
+        return config_type.tiny(**kwargs) if self.model_id.endswith(
+            "tiny"
+        ) else config_type(**kwargs)
 
     def build_draft_model_config(self):
         """Model config for the speculative draft — same name grammar as

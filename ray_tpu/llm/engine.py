@@ -124,14 +124,19 @@ _span = _tracing.annotate_device_trace
 
 def _new_expert_counts(model_config) -> Optional[dict]:
     """Zeroed device-side counters for a model with routed experts, None
-    for one without: ``steps`` decode steps, ``assignments`` (layers,
-    experts) choices made by live rows, ``touched`` (layers,) the sum over
-    steps of distinct experts live rows chose. int32: at 8 choices a row
-    and 50 steps a second an expert's count lasts two months."""
+    for one without: ``steps`` decode steps, ``assignments`` (routed
+    layers, experts) choices made by live rows, ``touched`` (routed
+    layers,) the sum over steps of distinct experts live rows chose. A row
+    a layer that has routed experts: every layer, unless the config names
+    them (``routed_layers``; a dense layer has nothing to count). int32: at
+    8 choices a row and 50 steps a second an expert's count lasts two
+    months."""
     n_experts = getattr(model_config, "n_experts", 0)
     if not n_experts:
         return None
-    layers = model_config.n_layers
+    layers = len(getattr(
+        model_config, "routed_layers", range(model_config.n_layers)
+    ))
     return {
         "steps": jnp.zeros((), jnp.int32),
         "assignments": jnp.zeros((layers, n_experts), jnp.int32),
@@ -141,13 +146,14 @@ def _new_expert_counts(model_config) -> Optional[dict]:
 
 def _count_experts(counts: dict, routing: dict, active) -> dict:
     """``counts`` plus one decode step's choices (``routing``: the sown
-    collection, ``layer_<i>/moe/experts`` a tuple of one (rows, k) array),
-    free rows left out."""
+    collection, ``layer_<i>/moe/experts`` a tuple of one (rows, k) array
+    for each layer that routes, taken in layer order), free rows left
+    out."""
     n_experts = counts["assignments"].shape[1]
     step = jnp.stack([
-        routing[f"layer_{i}"]["moe"]["experts"][0]
-        for i in range(counts["touched"].shape[0])
-    ])  # (layers, rows, k)
+        routing[name]["moe"]["experts"][0]
+        for name in sorted(routing, key=lambda n: int(n.rpartition("_")[2]))
+    ])  # (routed layers, rows, k)
     hits = jax.nn.one_hot(step, n_experts, dtype=jnp.int32)
     if active is not None:
         hits = hits * jnp.asarray(active, jnp.int32)[None, :, None, None]
@@ -944,6 +950,19 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             "assignments": counts["assignments"].tolist(),
             "touched": counts["touched"].tolist(),
         }
+
+    def cache_bytes_per_token(self) -> Optional[int]:
+        """Bytes one cached position costs over all layers, read off the
+        live slot cache's leaves (so another width or dtype shows); None
+        before the first admission made the cache."""
+        with self._lock:
+            if self._cache is None:
+                return None
+            return sum(
+                leaf.dtype.itemsize * leaf.shape[-1]
+                * int(np.prod(leaf.shape[1:-2]))
+                for leaf in jax.tree.leaves(self._cache) if leaf.ndim >= 3
+            )
 
     def _live_tokens(self) -> int:
         """Key positions the coming decode step attends over all live rows

@@ -1,7 +1,8 @@
 """ray_tpu.models: TPU-first model families (GSPMD logical-axis sharding).
 
 Llama (causal LM + LoRA + KV-cache decode), MoE transformer (routed
-experts, dropless for serving), ViT (vision encoder). The reference
+experts, dropless for serving), DeepSeek-V3-shaped transformer (latent
+attention, shared + routed experts), ViT (vision encoder). The reference
 delegates model execution to torch/vLLM; this framework owns it.
 
 This module is also the one place that chooses a family for the serving
@@ -11,22 +12,42 @@ type of the model config. What the engine asks of a family:
 - ``build(config, mesh, decode)`` returns a flax module whose
   ``apply({"params": p[, "cache": c]}, tokens, adapters, adapter_slots)``
   gives ``(batch, seq, vocab)`` logits; with ``decode=True`` and
-  ``mutable=["cache"]`` it writes the ``cache`` collection of
-  ``models/llama.py``'s ``Attention``: per layer ``cached_key`` /
-  ``cached_value`` of ``(batch, kv_heads, max_seq_len, head_dim)`` (the
-  sequence axis at -2) and a per-row ``cache_index`` of ``(batch,)``
+  ``mutable=["cache"]`` it writes a ``cache`` collection, applied without
+  one it makes a fresh one (every row at position 0) and fills it
+- of that collection the engine and ``kvcache.KVCacheManager`` ask only
+  its shape, never what a leaf means: every leaf of ``ndim >= 3`` is
+  cached state of ``(batch, ..., max_seq_len, width)``, the sequence axis
+  at -2 and one ``max_seq_len`` for all of them (a slot row is a slice of
+  axis 0, a pool block a slice of axis -2); every leaf of ``ndim == 1`` is
+  a per-row write position ``(batch,)`` that a step advances by the tokens
+  it was fed and that the engine may reset. ``models/llama.py``'s
+  ``Attention`` (shared by ``moe``) keeps ``cached_key`` / ``cached_value``
+  of ``(batch, kv_heads, max_seq_len, head_dim)`` and a ``cache_index`` a
+  layer; ``models/deepseek.py`` a ``cached_latent`` of ``(batch, 1,
+  max_seq_len, kv_lora_rank)``, a ``cached_rope`` of ``(batch, 1,
+  max_seq_len, qk_rope_head_dim)`` and a ``cache_index``
+- a step of one token a row (``seq == 1`` against a cache) attends each
+  row up to its own position; a longer ``seq`` against a cache is a chunk
+  behind a cached prefix, row ``r``'s token ``i`` at ``index[r] + i``
 - ``init_params(config, rng, mesh)`` returns the boxed parameter tree
 - the config carries ``max_seq_len``, ``n_heads``, ``n_kv_heads``,
-  ``dtype`` and ``param_dtype``
+  ``n_layers``, ``dtype`` and ``param_dtype``
 - a family with routed experts has ``n_experts`` on its config and sows
   ``layer_<i>/moe/experts`` into the ``ROUTING`` collection when it is
-  mutable
+  mutable, for every layer ``i`` that has them: all ``n_layers`` unless
+  the config says which (``routed_layers``, ``deepseek``'s leading layers
+  are dense)
 - a feature the family has no rules for (adapter bank, speculative draft,
   a ``tp``/``sp`` mesh) is listed in ``_NO_RULES`` with the reason, and
   ``LLMConfig`` refuses it at construction: no silent fallback
 
 A family is added by a module with those two functions, a branch in
 ``_family`` and ``LLMConfig.build_model_config``, and its line here.
+``deepseek`` was added so (PR 30): ``models/deepseek.py`` with its own
+attention and cache leaves, the routed part ``moe.MoEFFN``'s under three new
+``MoEConfig`` fields, a latent form of the decode kernel
+(``ops/decode_attention.latent_decode_attention``); the engine changed
+only where it counted one expert row a layer.
 """
 
 from __future__ import annotations
@@ -56,6 +77,25 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "yet (ROADMAP R1: ep rules)"
         ),
     },
+    "deepseek": {
+        "adapters": (
+            "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
+            "wq/wk/wv/wo and has no placement for the latent projections "
+            "(wkv_a, wkv_b) or for expert weights"
+        ),
+        "draft_model": (
+            "speculative verify feeds several tokens a row against the "
+            "cache, which here is the absorbed-form einsum over all of "
+            "max_seq_len: unchecked against the published form at a "
+            "draft's shapes, and the expert counters count plain decode "
+            "steps"
+        ),
+        "mesh": (
+            "the latent cache row has no head axis for parallel/plan.py's "
+            "KV_SPEC to shard and the latent decode kernel no shard_map "
+            "form; the expert weights have no ep rule (ROADMAP R1)"
+        ),
+    },
 }
 
 
@@ -67,8 +107,10 @@ def refusals(family: str) -> Dict[str, str]:
 
 
 def _family(model_config):
-    from . import llama, moe
+    from . import deepseek, llama, moe
 
+    if isinstance(model_config, deepseek.DeepseekConfig):
+        return deepseek
     if isinstance(model_config, moe.MoEConfig):
         return moe
     if isinstance(model_config, llama.LlamaConfig):

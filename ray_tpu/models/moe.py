@@ -66,8 +66,25 @@ class MoEConfig:
     norm_topk_prob: bool = True
     # RMSNorm over the whole q and k projections (OLMoE)
     qk_norm: bool = False
+    # the router of parallel/expert.top_k_routing: "softmax" over all
+    # experts, or each expert's own "sigmoid" (DeepSeek-V3's noaux_tc)
+    router_scoring: str = "softmax"
+    # a learned per-expert bias added to the scores for the choice of
+    # experts only (``router_bias`` parameter, zero-initialised)
+    router_bias: bool = False
+    # the kept weights times this (``routed_scaling_factor``)
+    routed_scale: float = 1.0
 
     def __post_init__(self):
+        if not self.dropless and (
+            self.router_scoring != "softmax" or self.router_bias
+            or self.routed_scale != 1.0
+        ):
+            raise ValueError(
+                "MoEConfig: a sigmoid router, a selection bias or a routed "
+                "scale needs dropless=True: the capacity path "
+                "(parallel/expert.top_k_gating) is a plain softmax"
+            )
         if not self.dropless and not self.norm_topk_prob:
             raise ValueError(
                 "MoEConfig(norm_topk_prob=False) needs dropless=True: the "
@@ -141,8 +158,17 @@ class MoEFFN(nn.Module):
                 precision=jax.lax.Precision.HIGHEST,
             )
             if cfg.dropless:
+                bias = self.param(
+                    "router_bias",
+                    nn.with_logical_partitioning(
+                        nn.initializers.zeros_init(), ("expert",)
+                    ),
+                    (cfg.n_experts,),
+                    jnp.float32,
+                ) if cfg.router_bias else None
                 weights, chosen, aux = top_k_routing(
-                    logits, cfg.experts_per_token, cfg.norm_topk_prob
+                    logits, cfg.experts_per_token, cfg.norm_topk_prob,
+                    cfg.router_scoring, bias, cfg.routed_scale,
                 )
                 self.sow(ROUTING, "experts", chosen)
             else:

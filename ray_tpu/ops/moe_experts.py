@@ -66,7 +66,12 @@ def tile_rows(rows: int) -> int:
 def block_f(dim: int, inner: int, dtype) -> int:
     """Columns of the expert's inner width in one block: the largest
     divisor of ``inner`` that is a multiple of 128 and keeps a (dim, block)
-    slab within ``_BLOCK_BYTES``; the whole width where there is none."""
+    slab within ``_BLOCK_BYTES``; the whole width where there is none.
+    Moonlight's 1408 = 11 x 128 has no such divisor but 128 (0.5 MB slabs,
+    11 grid steps a matrix), which measured the same as the whole width in
+    one 5.8 MB block: 8.295 against 8.299 ms for a decode step's 144
+    assignments over 57.8 touched experts a layer, six layers chained, 88%
+    of the HBM's peak either way (PERF.md, PR 30). So the rule stands."""
     fit = _BLOCK_BYTES // (dim * jnp.dtype(dtype).itemsize)
     for block in range(min(fit, inner) // 128 * 128, 0, -128):
         if inner % block == 0:

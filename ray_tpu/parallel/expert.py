@@ -159,20 +159,48 @@ def top_k_routing(
     router_logits: jax.Array,  # (tokens, experts)
     k: int,
     normalize: bool = True,
+    scoring: str = "softmax",
+    selection_bias: Optional[jax.Array] = None,  # (experts,)
+    scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Each token's ``k`` experts: f32 softmax over all experts, the ``k``
-    largest probabilities and their experts; ``normalize`` divides the kept
-    weights by their sum (Mixtral), without it they sum to less than 1
-    (OLMoE's ``norm_topk_prob: false``).
+    """Each token's ``k`` experts and their weights, f32.
+
+    ``scoring="softmax"``: a softmax over all experts, the ``k`` largest
+    probabilities and their experts; ``normalize`` divides the kept weights
+    by their sum (Mixtral), without it they sum to less than 1 (OLMoE's
+    ``norm_topk_prob: false``).
+
+    ``scoring="sigmoid"`` (DeepSeek-V3's ``noaux_tc`` router without a
+    group limit): each expert's score is its own sigmoid; the experts are
+    the ``k`` largest of ``score + selection_bias``, a learned per-expert
+    correction that enters the *choice* only; the weights are the chosen
+    experts' unbiased scores, divided by ``sum + 1e-20`` with ``normalize``,
+    then times ``scale`` (``routed_scaling_factor``).
 
     Returns ``(weights (tokens, k) f32, experts (tokens, k) int32,
     aux_loss)`` with the same Switch-style load-balance loss as
-    ``top_k_gating``."""
+    ``top_k_gating`` (over the scores, whichever they are)."""
     n_experts = router_logits.shape[-1]
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    weights, experts = lax.top_k(probs, k)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    else:
+        raise ValueError(f"top_k_routing: unknown scoring {scoring!r}")
+    if selection_bias is None:
+        weights, experts = lax.top_k(probs, k)
+    else:
+        _, experts = lax.top_k(
+            probs + selection_bias.astype(jnp.float32)[None, :], k
+        )
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     if normalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        # the published guard of the sigmoid router; a softmax's kept
+        # weights divide as they always have
+        weights = weights / (total + 1e-20 if scoring == "sigmoid" else total)
+    if scale != 1.0:
+        weights = weights * scale
     chosen = jnp.zeros_like(probs).at[
         jnp.arange(probs.shape[0])[:, None], experts
     ].set(1.0)

@@ -71,6 +71,11 @@ class GcsServer:
         # new leases and serve replaces their replicas; the state clears on
         # the node's next report. Value: when suspicion started.
         self._node_suspect: Dict[NodeID, float] = {}
+        # nodes whose probe is in flight: not SUSPECT yet. A late report
+        # from a busy process lands while the probe runs, and nobody who
+        # asks for node states may act on a suspicion that was never
+        # confirmed (a serve controller replaces replicas on SUSPECT)
+        self._node_probing: set = set()
         # versioned delta sync (reference: RaySyncer ray_syncer.h:89): the
         # last applied per-raylet report version; a mismatched base on an
         # incoming delta triggers a resync (raylet re-sends a full snapshot)
@@ -408,10 +413,11 @@ class GcsServer:
             elif (
                 age > self.config.suspect_after_s
                 and node_id not in self._node_suspect
+                and node_id not in self._node_probing
             ):
                 # reports stopped: probe the raylet actively instead of
                 # sitting out the rest of the dead window passively
-                self._node_suspect[node_id] = now
+                self._node_probing.add(node_id)
                 self.spawn(self._probe_node(node_id, age))
         # Nodes referenced by restored state that never re-registered: their
         # raylets died with the previous GCS — fail their actors/bundles.
@@ -453,24 +459,26 @@ class GcsServer:
         the node is recorded SUSPECT with the probe verdict (reachable =
         control plane asymmetric, likely a directional partition; not
         reachable = node fully gone, the dead window will catch it)."""
-        node = self._nodes.get(node_id)
-        if node is None or not node.alive:
-            self._node_suspect.pop(node_id, None)
-            return
         reachable = False
         try:
-            await self.client_pool.get(*node.address).call(
-                "ping", timeout=max(self.config.health_check_period_s, 1.0)
-            )
-            reachable = True
-        except Exception:
-            pass
-        if node_id not in self._node_suspect:
-            return  # a report landed while probing
+            node = self._nodes.get(node_id)
+            if node is None or not node.alive:
+                return
+            try:
+                await self.client_pool.get(*node.address).call(
+                    "ping", timeout=max(self.config.health_check_period_s, 1.0)
+                )
+                reachable = True
+            except Exception:
+                pass
+        finally:
+            self._node_probing.discard(node_id)
+        if not node.alive:
+            return  # declared dead while probing
         age = time.time() - self._node_last_seen.get(node_id, 0.0)
         if age <= self.config.suspect_after_s:
-            self._node_suspect.pop(node_id, None)
-            return
+            return  # a report landed while probing
+        self._node_suspect[node_id] = time.time()
         logger.warning(
             "node %s SUSPECT: no report for %.1fs, raylet %s",
             node_id, age, "reachable" if reachable else "unreachable",

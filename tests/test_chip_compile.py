@@ -72,7 +72,7 @@ def native_kernels(monkeypatch):
     )
 
     for kernel in (decode_attention, flash_attention, moe_experts, rmsnorm):
-        monkeypatch.setattr(kernel, "_use_interpret", lambda: False)
+        monkeypatch.setattr(kernel, "_use_interpret", lambda *name: False)
 
 
 def _on(chip, tree):
@@ -340,6 +340,43 @@ def test_olmoe_decode_step_donates_its_cache(
     and a footprint of params + pool, not params + 2 x pool."""
     _, _, decode, params, pool = _olmoe_programs(compiled, v5e_chip)
     _assert_steps_in_place(decode, params, pool)
+
+
+def _moonlight_programs(compiled, chip):
+    """The `moonlight-longctx-backlog` cell's programs at its widths, two
+    layers deep (the dense one and a routed one): 24 slots x 8192."""
+    from ray_tpu.models.deepseek import DeepseekConfig
+
+    cfg = DeepseekConfig(n_layers=2, param_dtype=jnp.bfloat16)
+    return (cfg,) + _serving_programs(compiled, chip, cfg, 24)
+
+
+def test_moonlight_prefill_and_decode_compile(v5e_chip, native_kernels, compiled):
+    """The real compiler takes the latent kernel at 16 heads on a 576-wide
+    row and the grouped experts at an inner width of 1408; the step donates the latent rows and holds no copy and no
+    up-projected key or value of them."""
+    cfg, prefill, decode, params, pool = _moonlight_programs(compiled, v5e_chip)
+    kernel = 'custom_call_target="tpu_custom_call"'
+    # rmsnorms: two a layer, the latent's, the final one; the routed
+    # layer's experts; in decode the latent kernel a layer
+    assert prefill.as_text().count(kernel) == 3 * 2 + 1 + 1
+    assert decode.as_text().count(kernel) == 4 * 2 + 1 + 1
+    assert "latent_decode_attention" in decode.as_text()
+    kv = sorted(s.shape for s in jax.tree.leaves(pool) if s.ndim == 4)
+    assert kv == [(24, 1, 8192, 64)] * 2 + [(24, 1, 8192, 512)] * 2
+    text = decode.as_text()
+    # no key or value up-projected over the cache, and no copy of either
+    # leaf: the 512-wide one is row-major as stored, and the 64-wide one,
+    # which the TPU stores sequence-minor, is read as (64, seq) (a single
+    # 576-wide leaf was transposed whole every step)
+    assert not re.search(r"\[24,(16,)?8192,(16,)?(128|192|256)\]", text)
+    assert not re.search(r"= \w+\[24,1,(8192,\d+|\d+,8192)\]\S* (copy|transpose)\(", text)
+    header = text.split("\n", 1)[0]
+    aliases = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliases) == len(jax.tree.leaves(pool))  # every leaf in place
+    mem = decode.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert _size(pool) <= mem.alias_size_in_bytes <= _size(pool) + 512 * len(aliases)
 
 
 # ---------------------------------------------------------------------------

@@ -165,10 +165,13 @@ def test_every_row_choosing_one_expert_loses_nothing():
     assert float(jnp.min(jnp.abs(got[capacity:]).max(axis=-1))) > 1e-2
 
 
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
 @pytest.mark.parametrize("rows,k", [(1, 2), (5, 3), (40, 4)])
-def test_dropless_dispatch_at_ragged_sizes(rows, k):
+def test_dropless_dispatch_at_ragged_sizes(rows, k, scoring):
     """Rows that do not fill a tile, and more than one tile (40 x 4 = 160
-    assignments: two tiles of 128, experts that straddle the boundary)."""
+    assignments: two tiles of 128, experts that straddle the boundary),
+    under either router's weights (a sigmoid's, scaled, sum to more than
+    1)."""
     dim, inner, n_experts = 32, 16, 8
     keys = jax.random.split(jax.random.PRNGKey(rows), 5)
     x = jax.random.normal(keys[0], (rows, dim))
@@ -176,7 +179,8 @@ def test_dropless_dispatch_at_ragged_sizes(rows, k):
     w_up = jax.random.normal(keys[2], (n_experts, dim, inner)) / 4
     w_down = jax.random.normal(keys[3], (n_experts, inner, dim)) / 4
     weights, experts, _ = ep.top_k_routing(
-        jax.random.normal(keys[4], (rows, n_experts)), k, normalize=False)
+        jax.random.normal(keys[4], (rows, n_experts)), k, normalize=False,
+        scoring=scoring, scale=1.0 if scoring == "softmax" else 2.446)
     want = olmoe_arch.experts_loop(x, weights, experts, w_gate, w_up, w_down)
     got = jax.jit(ep.moe_apply_dropless)(x, weights, experts, w_gate, w_up, w_down)
     assert float(jnp.max(jnp.abs(got - want))) < TOL

@@ -423,3 +423,53 @@ def test_split_brain_fencing_and_failover():
             ray_tpu.shutdown()
         finally:
             cluster.shutdown()
+
+
+@pytest.mark.parametrize("report_lands", [True, False])
+def test_suspect_only_once_the_probe_confirms(report_lands):
+    """A node whose report is late is probed, and is SUSPECT to whoever asks
+    for node states only if the probe returns and the report is still
+    missing: a busy process's late report lands while the probe is in
+    flight, and the serve controller replaces replicas on SUSPECT."""
+    from ray_tpu._internal.config import Config
+    from ray_tpu._internal.ids import NodeID
+    from ray_tpu._internal.protocol import NodeInfo
+    from ray_tpu.runtime.gcs.server import GcsServer
+
+    async def scenario():
+        gcs = GcsServer(Config(suspect_after_s=0.05, health_check_timeout_s=30.0))
+        node_id = NodeID.from_random()
+        gcs._nodes[node_id] = NodeInfo(node_id, ("127.0.0.1", 1), "", {"CPU": 1.0})
+        gcs._node_last_seen[node_id] = time.time() - 1.0
+        answered = asyncio.Event()
+        published = []
+        gcs.publisher.publish = lambda channel, message: published.append(message[0])
+
+        class SlowRaylet:
+            async def call(self, method, timeout=None):
+                await answered.wait()
+
+        gcs.client_pool.get = lambda *address: SlowRaylet()
+
+        async def state():
+            return (await gcs.handle_get_node_states())[node_id.hex()]
+
+        await gcs._health_check()
+        await asyncio.sleep(0)  # the probe starts and waits for its answer
+        in_flight = await state()
+        await gcs._health_check()  # no second probe beside the first
+        assert gcs._node_probing == {node_id}
+        if report_lands:
+            gcs._node_last_seen[node_id] = time.time()
+        answered.set()
+        for _ in range(10):
+            await asyncio.sleep(0)
+        after = await state()
+        gcs._stopped = True
+        return in_flight, after, published, gcs._node_probing
+
+    in_flight, after, published, probing = asyncio.run(scenario())
+    assert in_flight == "ALIVE"
+    assert after == ("ALIVE" if report_lands else "SUSPECT")
+    assert published == ([] if report_lands else ["suspect"])
+    assert not probing
