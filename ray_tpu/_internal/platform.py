@@ -68,6 +68,29 @@ def is_tpu_backend() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def decode_step_compiler_options() -> Dict[str, str]:
+    """Compiler options of the engine's decode step: on a TPU, the
+    memory-bound loop optimiser off; none elsewhere (another backend
+    refuses a TPU option).
+
+    The layers of a decode step are an unrolled loop to the TPU compiler,
+    and that optimiser plans its prefetches into on-chip memory for them:
+    it keeps the widest matrix of a layer's MLP in HBM and fetches the
+    attention weights under the MLP's own matmuls, which are bound by the
+    same HBM, so nothing is hidden. Left to its general pass the compiler
+    fetches that MLP matrix while the attention kernel runs, which is
+    bound by its grid steps and leaves the HBM free: 1.1 ms of a 9.8 ms
+    step at Mistral-7B widths (0.4 by itself, the rest once the cache
+    write shares the attention kernel's lengths operand,
+    ops/kv_row_write.py), nothing at OLMoE's or Moonlight's (PERF.md,
+    finding 31.2). Until PR 31 the compiled loops of the per-row cache
+    write sat in every layer and kept the optimiser from seeing the
+    layers as one loop."""
+    if not is_tpu_backend():
+        return {}
+    return {"xla_tpu_memory_bound_loop_optimizer_options": "enabled:false"}
+
+
 # kernel name -> interpret modes it was traced with in this process
 _kernel_modes: Dict[str, set] = {}
 

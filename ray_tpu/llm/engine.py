@@ -33,7 +33,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .._internal.platform import decode_step_compiler_options
 from ..models import ROUTING as _ROUTING
+from ..ops.kv_row_write import traced_form
 from ..util import events as _events
 from ..util import tracing as _tracing
 
@@ -259,11 +261,15 @@ class _DecodeModelBase:
             self._decode = jax.jit(
                 self._decode_impl, donate_argnums=(1,),
                 out_shardings=(rep, cache_sh),
+                compiler_options=decode_step_compiler_options(),
             )
         else:
             self._params = params
             self._prefill = jax.jit(self._prefill_impl)
-            self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
+            self._decode = jax.jit(
+                self._decode_impl, donate_argnums=(1,),
+                compiler_options=decode_step_compiler_options(),
+            )
 
     def _prefill_impl(self, params, tokens, adapters=None, adapter_slots=None):
         logits, vars_out = self._model.apply(
@@ -963,6 +969,21 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 * int(np.prod(leaf.shape[1:-2]))
                 for leaf in jax.tree.leaves(self._cache) if leaf.ndim >= 3
             )
+
+    def row_write(self) -> Optional[Dict[str, Optional[str]]]:
+        """How the compiled decode step stores a new position in each leaf
+        of the live slot cache, by leaf name (``ops/kv_row_write.py``:
+        ``"tile"``, or None for a leaf no traced step wrote through the
+        kernel); None before the first admission made the cache."""
+        with self._lock:
+            if self._cache is None:
+                return None
+            return {
+                path[-1].key: traced_form(leaf.shape)
+                for path, leaf in jax.tree_util.tree_leaves_with_path(
+                    self._cache
+                ) if leaf.ndim == 4
+            }
 
     def _live_tokens(self) -> int:
         """Key positions the coming decode step attends over all live rows

@@ -322,10 +322,15 @@ class _LLMReplica:
             # a routed model's expert counters (engine.expert_stats());
             # None for a dense model or the grouped-batch engine
             "moe": getattr(self._engine, "expert_stats", lambda: None)(),
-            # what a cached position costs (None for the grouped-batch
-            # engine, and before the first admission)
-            "kv": {"cache_bytes_per_token": getattr(
-                self._engine, "cache_bytes_per_token", lambda: None)()},
+            # what a cached position costs, and how the decode step stores
+            # one in each cache leaf (None for the grouped-batch engine,
+            # and before the first admission)
+            "kv": {
+                "cache_bytes_per_token": getattr(
+                    self._engine, "cache_bytes_per_token", lambda: None)(),
+                "row_write": getattr(
+                    self._engine, "row_write", lambda: None)(),
+            },
         }
 
     def check_prefill_logits(self, token_ids) -> Dict[str, Any]:

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ..ops.decode_attention import latent_decode_attention
+from ..ops.kv_row_write import write_rows
 from ..ops.rmsnorm import rmsnorm
 from ..ops.rope import apply_rope, rope_table
 from .llama import _dense
@@ -227,16 +228,10 @@ class LatentAttention(nn.Module):
             q_rope = apply_rope(q_rope, cos, sin, offset=idx)
             k_rope = apply_rope(k_r, cos, sin, offset=idx)
 
-            def _insert(cache_row, new_row, pos):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    cache_row, new_row, pos, axis=1
-                )
-
-            cached_c.value = jax.vmap(_insert)(
-                cached_c.value, c[:, None].astype(cfg.dtype), idx
-            )
-            cached_r.value = jax.vmap(_insert)(
-                cached_r.value, k_rope.astype(cfg.dtype), idx
+            cached_c.value, cached_r.value = write_rows(
+                (cached_c.value, cached_r.value),
+                (c[:, None].astype(cfg.dtype), k_rope.astype(cfg.dtype)),
+                idx,
             )
             idx_var.value = idx + s
         else:

@@ -31,6 +31,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.decode_attention import decode_attention
 from ..ops.flash_attention import flash_attention
+from ..ops.kv_row_write import write_rows
 from ..ops.rmsnorm import rmsnorm
 from ..ops.rope import apply_rope, rope_table
 from ..parallel.ring_attention import ring_attention
@@ -239,17 +240,10 @@ class Attention(nn.Module):
             q = apply_rope(q, cos, sin, offset=idx)
             k = apply_rope(k, cos, sin, offset=idx)
 
-            # per-row insertion offset: vmap'd dynamic_update_slice
-            def _insert(cache_row, new_row, pos):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    cache_row, new_row, pos, axis=1
-                )
-
-            cached_k.value = jax.vmap(_insert)(
-                cached_k.value, k.astype(cfg.dtype), idx
-            )
-            cached_v.value = jax.vmap(_insert)(
-                cached_v.value, v.astype(cfg.dtype), idx
+            # each row's new keys and values at its own position
+            cached_k.value, cached_v.value = write_rows(
+                (cached_k.value, cached_v.value),
+                (k.astype(cfg.dtype), v.astype(cfg.dtype)), idx, self.mesh,
             )
             idx_var.value = idx + s
             if s == 1:

@@ -68,11 +68,15 @@ def native_kernels(monkeypatch):
     """Trace the Pallas kernels as the chip would: this process's default
     backend is the CPU, so the ops would otherwise pick interpret mode."""
     from ray_tpu.ops import (
-        decode_attention, flash_attention, moe_experts, rmsnorm,
+        decode_attention, flash_attention, kv_row_write, moe_experts, rmsnorm,
     )
 
-    for kernel in (decode_attention, flash_attention, moe_experts, rmsnorm):
+    for kernel in (decode_attention, flash_attention, kv_row_write,
+                   moe_experts, rmsnorm):
         monkeypatch.setattr(kernel, "_use_interpret", lambda *name: False)
+    # ... and compile the decode step with the options the chip gets, so
+    # a compiler that no longer knows one refuses it here
+    monkeypatch.setattr(platform, "is_tpu_backend", lambda: True)
 
 
 def _on(chip, tree):
@@ -116,6 +120,19 @@ def _assert_steps_in_place(decode, params, pool):
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes
             ) < _size(params) + _size(pool) + 0.1e9
+
+
+def _assert_rows_written_by_the_kernel(text, layers, pool):
+    """The decode step stores its new cache rows through ``kv_row_write``,
+    one call a layer, and not as the loop a scatter compiles to: no
+    ``while`` carries a leaf of the pool, and no ``dynamic-update-slice``
+    has a leaf's shape."""
+    assert len(re.findall(r"= \([^\n]*? custom-call\([^\n]*kv_row_write", text)
+               ) == layers
+    for shape in {s.shape for s in jax.tree.leaves(pool) if s.ndim == 4}:
+        leaf = re.escape("[" + ",".join(map(str, shape)) + "]")
+        assert not re.search(rf"{leaf}[^\n]* while\(", text)
+        assert not re.search(rf"= \w+{leaf}\S* dynamic-update-slice\(", text)
 
 
 def test_flash_attention_forward_and_backward_compile(v5e_chip, native_kernels):
@@ -164,6 +181,36 @@ def test_decode_attention_compiles(v5e_chip, native_kernels, pool):
     )
     lengths = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e_chip)
     assert "tpu_custom_call" in _compile(decode_attention, q, kv, kv, lengths)
+
+
+@pytest.mark.parametrize(
+    "leaves",
+    [[(16, 8, 4096, 128)] * 2, [(8, 16, 4096, 128)] * 2,
+     [(24, 1, 8192, 512), (24, 1, 8192, 64)]],
+    ids=["mistral", "olmoe", "moonlight"],
+)
+def test_kv_row_write_compiles_in_place(v5e_chip, native_kernels, leaves):
+    """A layer's leaves at each cell's shapes: one kernel, each leaf
+    aliased to its output and none copied or transposed on its way in or
+    out (the 64-wide leaf, stored sequence-minor, is written in that
+    view)."""
+    from ray_tpu.ops.kv_row_write import write_rows
+
+    def on(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    step = jax.jit(write_rows, donate_argnums=(0,)).lower(
+        [on(s) for s in leaves],
+        [on(s[:2] + (1,) + s[3:]) for s in leaves], on(leaves[0][:1], jnp.int32),
+    ).compile()
+    text = step.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "kv_row_write" in text and " while(" not in text
+    assert not re.search(
+        r"= \w+\[\d+,\d+,(\d+,[48]\d\d\d|[48]\d\d\d,\d+)\]\S* (copy|transpose)\(", text)
+    size = sum(2 * b * h * s * w for b, h, s, w in leaves)
+    assert step.memory_analysis().alias_size_in_bytes == size
+    assert step.memory_analysis().temp_size_in_bytes < 1e6
 
 
 def test_decode_attention_compiles_per_shard_under_tp4(v5e_host, native_kernels):
@@ -264,15 +311,17 @@ def test_decode_model_prefill_and_decode_compile(
     v5e_chip, native_kernels, compiled, pool, widths
 ):
     slots, _, hk, max_seq_len = pool
-    prefill, decode, _, _ = _dense_7b_programs(
+    prefill, decode, _, cache = _dense_7b_programs(
         compiled, v5e_chip, pool, widths
     )
     prefill, decode = prefill.as_text(), decode.as_text()
     # prefill attends by einsum: rmsnorm is its only kernel (two a layer
     # and the final one); a decode step adds the attention kernel a layer
+    # and the cache write
     kernel = 'custom_call_target="tpu_custom_call"'
     assert prefill.count(kernel) == 5
-    assert decode.count(kernel) == 7
+    assert decode.count(kernel) == 9
+    _assert_rows_written_by_the_kernel(decode, 2, cache)
     # and holds no f32 copy of a cache, whole or expanded over the group
     assert not re.search(
         rf"f32\[{slots},{hk},(\d+,)?{max_seq_len},128\]", decode
@@ -314,9 +363,11 @@ def test_olmoe_prefill_and_decode_compile(v5e_chip, native_kernels, compiled):
     slots, layers, experts, k = 8, 8, 64, 8
     kernel = 'custom_call_target="tpu_custom_call"'
     # a layer: four rmsnorms (two of them q_norm and k_norm) and the
-    # grouped experts, in decode the attention kernel too; one final norm
+    # grouped experts, in decode the cache write and the attention kernel
+    # too; one final norm
     assert prefill.as_text().count(kernel) == 5 * layers + 1
-    assert decode.as_text().count(kernel) == 6 * layers + 1
+    assert decode.as_text().count(kernel) == 7 * layers + 1
+    _assert_rows_written_by_the_kernel(decode.as_text(), layers, pool)
     for program, tokens in ((prefill, 512), (decode, slots)):
         capacity = expert_capacity(tokens, experts, cfg.capacity_factor, k)
         assert not re.search(
@@ -358,10 +409,12 @@ def test_moonlight_prefill_and_decode_compile(v5e_chip, native_kernels, compiled
     cfg, prefill, decode, params, pool = _moonlight_programs(compiled, v5e_chip)
     kernel = 'custom_call_target="tpu_custom_call"'
     # rmsnorms: two a layer, the latent's, the final one; the routed
-    # layer's experts; in decode the latent kernel a layer
+    # layer's experts; in decode the cache write and the latent kernel a
+    # layer
     assert prefill.as_text().count(kernel) == 3 * 2 + 1 + 1
-    assert decode.as_text().count(kernel) == 4 * 2 + 1 + 1
+    assert decode.as_text().count(kernel) == 5 * 2 + 1 + 1
     assert "latent_decode_attention" in decode.as_text()
+    _assert_rows_written_by_the_kernel(decode.as_text(), 2, pool)
     kv = sorted(s.shape for s in jax.tree.leaves(pool) if s.ndim == 4)
     assert kv == [(24, 1, 8192, 64)] * 2 + [(24, 1, 8192, 512)] * 2
     text = decode.as_text()
@@ -497,15 +550,22 @@ def test_pin_cpu_platform_keeps_the_inherited_value_for_chip_workers():
 
 
 def test_kernels_interpret_on_cpu_only(monkeypatch):
-    from ray_tpu.ops import decode_attention, flash_attention, rmsnorm
+    from ray_tpu.ops import (
+        decode_attention, flash_attention, kv_row_write, rmsnorm,
+    )
 
     assert platform.is_tpu_backend() is False
     assert platform.pallas_interpret("probe") is True
     assert platform.traced_kernel_modes()["probe"] == [True]
-    for kernel in (decode_attention, flash_attention, rmsnorm):
+    for kernel in (decode_attention, flash_attention, kv_row_write, rmsnorm):
         assert kernel._use_interpret() is True
         name = kernel.__name__.rsplit(".", 1)[1]
         assert platform.traced_kernel_modes()[name] == [True]
+    # a TPU compiler option only where the TPU's compiler reads it
+    assert platform.decode_step_compiler_options() == {}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert list(platform.decode_step_compiler_options()) == [
+        "xla_tpu_memory_bound_loop_optimizer_options"]
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     with pytest.raises(RuntimeError, match="no lowering for platform 'gpu'"):
         platform.pallas_interpret("probe")

@@ -544,7 +544,10 @@ def test_deepseek_serves_through_serve_run(shutdown_only):
             method_name="runtime_info", timeout_s=60).remote().result()
         assert info["moe"]["decode_steps"] == 4
         assert len(info["moe"]["touched"]) == 2
-        assert info["kv"] == {"cache_bytes_per_token": 3 * 40 * 4}
+        assert info["kv"] == {
+            "cache_bytes_per_token": 3 * 40 * 4,
+            "row_write": {"cached_latent": "tile", "cached_rope": "tile"},
+        }
         assert info["kernels"]["latent_decode_attention"] == [True]
         assert info["kernels"]["moe_experts"] == [True]
     finally:

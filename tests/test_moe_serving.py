@@ -384,5 +384,8 @@ def test_moe_serves_through_serve_run(shutdown_only):
             method_name="runtime_info", timeout_s=60).remote().result()
         assert info["moe"]["decode_steps"] == 4
         assert info["kernels"]["moe_experts"] == [True]
+        assert info["kernels"]["kv_row_write"] == [True]
+        assert info["kv"]["row_write"] == {
+            "cached_key": "tile", "cached_value": "tile"}
     finally:
         serve.shutdown()
