@@ -28,10 +28,16 @@ so shared prefixes never see partial writes. ``update_block`` exposes the
 copy-on-write path (shared block -> fresh copy) for callers that do mutate
 per-request state in place.
 
-Everything here assumes the flax decode-cache layout of models/llama.py:
-KV leaves are ``(1, ..., max_seq_len, head_dim)`` with the sequence axis at
--2, and every other cache leaf is a write-position index filled with the
-cached token count at assembly.
+Everything here assumes the cache layout ``ray_tpu.models`` sets out, and
+asks a leaf's kind there (``models.cache_kinds``), never by its rank:
+``SEQUENCE`` leaves are ``(1, ..., max_seq_len, head_dim)`` with the
+sequence axis at -2 and get a pool each; an ``INDEX`` leaf is a
+write-position filled with the cached token count at assembly. A family
+with ``STATE`` leaves (per-row state with no sequence axis: nothing to cut
+into blocks, and K/V blocks alone would resume its recurrent layers from a
+zero state) gets **no prefix reuse** (``refuse_prefix_reuse``): every lease
+is uncacheable, nothing is matched, committed, adopted or assembled, and no
+device pool is ever allocated.
 """
 
 from __future__ import annotations
@@ -42,6 +48,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..models import SEQUENCE, STATE, cache_kinds
 from .block_allocator import BlockAllocator
 from .prefix_index import PrefixIndex
 
@@ -76,7 +83,9 @@ class KVCacheManager:
         # device state, lazily shaped from the first committed cache row
         self._pools: Optional[List[jax.Array]] = None
         self._treedef = None
-        self._leaf_meta: List[tuple] = []  # (is_kv, shape, dtype) per leaf
+        self._leaf_meta: List[tuple] = []  # (kind, shape, dtype) per leaf
+        # why this manager matches and commits nothing, or None
+        self._reuse_refused: Optional[str] = None
         self._max_seq_len = 0
         self._assemble_fns: Dict[int, Any] = {}  # block count -> jitted gather
         self._jit_commit = None
@@ -94,6 +103,10 @@ class KVCacheManager:
             "prefill_tokens_computed": 0,
             "admission_blocked": 0,
             "adopted_blocks": 0,
+            # leases given without a match, and the full prompt blocks they
+            # would have committed, because prefix reuse is refused
+            "reuse_refused_leases": 0,
+            "reuse_refused_blocks": 0,
         }
 
     def adopt_plan(self, plan) -> None:
@@ -109,6 +122,26 @@ class KVCacheManager:
             )
         self._plan = plan
         self._mesh_tag = plan.describe()
+
+    def refuse_prefix_reuse(self, reason: str) -> None:
+        """Serve a family whose rows carry state no block holds: from here
+        on every lease is uncacheable and matches nothing, and no pool is
+        shaped. Before the first commit, as ``adopt_plan``."""
+        if self._pools is not None:
+            raise RuntimeError(
+                "refuse_prefix_reuse() after the block pools were "
+                "initialized: blocks are already shared"
+            )
+        self._reuse_refused = reason
+
+    @property
+    def prefix_reuse(self) -> bool:
+        return self._reuse_refused is None
+
+    @property
+    def prefix_reuse_refused(self) -> Optional[str]:
+        """Why no request is served a cached prefix, or None."""
+        return self._reuse_refused
 
     # -- accounting ----------------------------------------------------------
 
@@ -127,6 +160,8 @@ class KVCacheManager:
         prompt (capped like acquire: the last prompt token is never
         matched). Takes no references — the tier consult uses this to skip
         peer pulls that could not beat the local radix."""
+        if not self.prefix_reuse:
+            return 0
         plen = len(token_ids)
         max_blocks = (plen - 1) // self._block_size if plen else 0
         return len(self._index.match(token_ids, max_blocks))
@@ -152,6 +187,8 @@ class KVCacheManager:
             num_devices=(
                 self._plan.num_devices if self._plan is not None else 1
             ),
+            prefix_reuse=self.prefix_reuse,
+            prefix_reuse_refused=self._reuse_refused,
         )
         out.update(self.pool_accounting())
         return out
@@ -170,7 +207,7 @@ class KVCacheManager:
             }
         total = sum(int(p.nbytes) for p in self._pools)
         ndev = self._plan.num_devices if self._plan is not None else 1
-        heads = self._pools[0].shape[1] if self._pools[0].ndim >= 3 else 1
+        heads = self._pools[0].shape[1]
         tp = self._plan.tp if self._plan is not None else 1
         return {
             "kv_pool_bytes_total": total,
@@ -184,6 +221,10 @@ class KVCacheManager:
         """Match + admission gate. None == not enough blocks: the caller
         must keep the request queued and retry after a release."""
         plen = len(token_ids)
+        if not self.prefix_reuse:
+            self._stats["reuse_refused_leases"] += 1
+            self._stats["reuse_refused_blocks"] += plen // self._block_size
+            return KVCacheLease(0, [], [], [], cacheable=False)
         # never match the whole prompt: at least one token must be
         # prefilled to produce the first-token logits
         max_blocks = (plen - 1) // self._block_size if plen else 0
@@ -249,16 +290,25 @@ class KVCacheManager:
 
     def initialize(self, cache_row) -> None:
         """Shape the block pools from a solo cache row (no-op after the
-        first call). KV leaves (ndim >= 3, sequence axis -2) get a pooled
-        array; every other leaf is treated as a write-position index."""
+        first call). Sequence leaves (sequence axis -2) get a pooled array
+        each; an index leaf is a write position. A row with a state leaf
+        shapes nothing: prefix reuse is refused for it, here if the engine
+        has not said so already."""
         if self._pools is not None:
             return
         leaves, treedef = jax.tree_util.tree_flatten(cache_row)
+        kinds = jax.tree_util.tree_leaves(cache_kinds(cache_row))
+        if STATE in kinds and self.prefix_reuse:
+            self.refuse_prefix_reuse(
+                "the cache row holds per-row state with no sequence axis"
+            )
+        if not self.prefix_reuse:
+            return
         self._treedef = treedef
         self._leaf_meta = [
-            (l.ndim >= 3, tuple(l.shape), l.dtype) for l in leaves
+            (kind, tuple(l.shape), l.dtype) for kind, l in zip(kinds, leaves)
         ]
-        seq_lens = {s[-2] for kv, s, _ in self._leaf_meta if kv}
+        seq_lens = {s[-2] for kind, s, _ in self._leaf_meta if kind == SEQUENCE}
         if len(seq_lens) != 1:
             raise ValueError(f"inconsistent cache sequence axes: {seq_lens}")
         self._max_seq_len = seq_lens.pop()
@@ -275,8 +325,8 @@ class KVCacheManager:
                 + (self._block_size, shape[-1]),
                 dtype,
             )
-            for kv, shape, dtype in self._leaf_meta
-            if kv
+            for kind, shape, dtype in self._leaf_meta
+            if kind == SEQUENCE
         ]
         if kv_sh is not None:
             # pool layout (capacity, heads, block, d): heads is axis 1,
@@ -345,13 +395,27 @@ class KVCacheManager:
             fn = self._make_assemble(n)
             self._assemble_fns[n] = fn
         kv_out = list(fn(self._pools, jnp.asarray(lease.block_ids, jnp.int32)))
-        leaves = []
-        for kv, shape, dtype in self._leaf_meta:
-            if kv:
-                leaves.append(kv_out.pop(0))
-            else:
-                leaves.append(jnp.full(shape, lease.num_cached_tokens, dtype))
-        return jax.tree_util.tree_unflatten(self._treedef, leaves)
+        return self._row_of(kv_out, lease.num_cached_tokens)
+
+    def _sequence_leaves(self, cache_row) -> list:
+        """``cache_row``'s sequence leaves, in the pools' order."""
+        return [
+            leaf
+            for leaf, (kind, _, _) in zip(
+                jax.tree_util.tree_leaves(cache_row), self._leaf_meta
+            )
+            if kind == SEQUENCE
+        ]
+
+    def _row_of(self, sequence_leaves: list, position: int):
+        """A dense cache row from its sequence leaves (the pools' order),
+        its write position at ``position``."""
+        sequence_leaves = list(sequence_leaves)
+        return jax.tree_util.tree_unflatten(self._treedef, [
+            sequence_leaves.pop(0) if kind == SEQUENCE
+            else jnp.full(shape, position, dtype)
+            for kind, shape, dtype in self._leaf_meta
+        ])
 
     def _make_assemble(self, n: int):
         bs = self._block_size
@@ -395,13 +459,7 @@ class KVCacheManager:
         if lease.cacheable is False:
             return 0
         self.initialize(cache_row)
-        kv_row = [
-            leaf
-            for leaf, (kv, _, _) in zip(
-                jax.tree_util.tree_leaves(cache_row), self._leaf_meta
-            )
-            if kv
-        ]
+        kv_row = self._sequence_leaves(cache_row)
         committed = 0
         node = self._index.root
         for i in range(len(token_ids) // self._block_size):
@@ -443,13 +501,7 @@ class KVCacheManager:
         new_id = self._alloc.copy_on_write(block_id, copy_fn=self._copy_block)
         if new_id is None:
             return None
-        kv_row = [
-            leaf
-            for leaf, (kv, _, _) in zip(
-                jax.tree_util.tree_leaves(cache_row), self._leaf_meta
-            )
-            if kv
-        ]
+        kv_row = self._sequence_leaves(cache_row)
         self._write_block(new_id, kv_row, tok_offset)
         return new_id
 
@@ -502,13 +554,7 @@ class KVCacheManager:
         if fn is None:
             fn = self._make_extract(nblocks, tail_len)
             self._extract_fns[(nblocks, tail_len)] = fn
-        kv_row = [
-            leaf
-            for leaf, (kv, _, _) in zip(
-                jax.tree_util.tree_leaves(cache_row), self._leaf_meta
-            )
-            if kv
-        ]
+        kv_row = self._sequence_leaves(cache_row)
         blocks, tail = fn(kv_row)
         from ..llm.engine import host_sync
 
@@ -606,13 +652,7 @@ class KVCacheManager:
             fn = self._make_build(nblocks, tail_len)
             self._build_fns[(nblocks, tail_len)] = fn
         kv_out = list(fn(payload["blocks"], payload["tail"]))
-        leaves = []
-        for kv, shape, dtype in self._leaf_meta:
-            if kv:
-                leaves.append(kv_out.pop(0))
-            else:
-                leaves.append(jnp.full(shape, ntokens, dtype))
-        return jax.tree_util.tree_unflatten(self._treedef, leaves)
+        return self._row_of(kv_out, ntokens)
 
     def _make_build(self, nblocks: int, tail_len: int):
         bs = self._block_size

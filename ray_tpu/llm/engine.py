@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .._internal.platform import decode_step_compiler_options
-from ..models import ROUTING as _ROUTING
+from ..models import INDEX, ROUTING as _ROUTING, SEQUENCE, STATE, cache_kinds
 from ..ops.kv_row_write import traced_form
 from ..util import events as _events
 from ..util import tracing as _tracing
@@ -285,12 +285,20 @@ class _DecodeModelBase:
             # would otherwise run on, past the cache's end in time, and
             # attention reads as far as a row's position says: an inactive
             # row restarts at 0 each step, one key long, and whatever it
-            # writes there the next admission's row insert replaces
-            cache = jax.tree.map(
-                lambda leaf: jnp.where(active, leaf, 0)
-                if leaf.ndim == 1 else leaf,
-                cache,
-            )
+            # writes there the next admission's row insert replaces. State
+            # that is carried and not indexed restarts with it: a free
+            # row's is zero before every step, so it holds one step of
+            # garbage at most and never what a request left
+            def restart(leaf, kind):
+                if kind == INDEX:
+                    return jnp.where(active, leaf, 0)
+                if kind == STATE:
+                    live = jnp.asarray(active).reshape(
+                        (-1,) + (1,) * (leaf.ndim - 1))
+                    return jnp.where(live, leaf, jnp.zeros((), leaf.dtype))
+                return leaf
+
+            cache = jax.tree.map(restart, cache, cache_kinds(cache))
         # a routed model's step also says which experts its rows chose: the
         # running counts ride through the program, so counting costs the
         # host nothing and the step no sync
@@ -560,6 +568,8 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         prefill_chunk_tokens: int = 0,
         adapter_store=None,
     ):
+        from .. import models
+
         super().__init__(
             model_config, params, mesh, plan=plan, adapter_store=adapter_store
         )
@@ -584,6 +594,20 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # longest cached prefix, prefills only the suffix, and blocks
         # admission when the pool is out of blocks (backpressure, not OOM)
         self._kv = kv_cache
+        # a family whose rows carry state with no sequence axis: the span
+        # of a decode dispatch says how many rows' state the step carries
+        # (the pool's, live or free), and the manager leases without
+        # matching or committing, because K/V blocks alone would resume a
+        # recurrent layer from a zero state
+        self._state_span: Dict[str, int] = {}
+        if models.carries_row_state(model_config):
+            self._state_span = {"state_rows": num_slots}
+            if kv_cache is not None:
+                kv_cache.refuse_prefix_reuse(
+                    f"a {type(model_config).__name__} row carries per-row "
+                    "state with no sequence axis, which no K/V block holds: "
+                    "a hit would resume its recurrent layers from zero"
+                )
         if kv_cache is not None and self._plan is not None:
             # the manager's block pools must live in the same sharded
             # layout as the decode cache they exchange rows with
@@ -677,9 +701,9 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             # the next write overwrites — only the position moves back
             self._set_index = jax.jit(
                 lambda cache, idx: jax.tree.map(
-                    lambda leaf: idx.astype(leaf.dtype)
-                    if leaf.ndim == 1 else leaf,
-                    cache,
+                    lambda leaf, kind: idx.astype(leaf.dtype)
+                    if kind == INDEX else leaf,
+                    cache, cache_kinds(cache),
                 ),
                 donate_argnums=(0,),
             )
@@ -843,7 +867,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # that want it (prompt plus generated, the token fed included)
         with _span(
             "engine.decode_dispatch", batch=batch, live_tokens=live_tokens,
-            ahead=int(unread is not None),
+            ahead=int(unread is not None), **self._state_span,
         ):
             counted = (
                 {} if self._expert_counts is None
@@ -957,33 +981,55 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             "touched": counts["touched"].tolist(),
         }
 
-    def cache_bytes_per_token(self) -> Optional[int]:
-        """Bytes one cached position costs over all layers, read off the
-        live slot cache's leaves (so another width or dtype shows); None
-        before the first admission made the cache."""
+    def _cache_leaves(self, kind: str) -> Optional[List[tuple]]:
+        """The live slot cache's leaves of ``kind`` as (name, leaf), a
+        layer's after another's; None before the first admission made the
+        cache."""
         with self._lock:
             if self._cache is None:
                 return None
-            return sum(
-                leaf.dtype.itemsize * leaf.shape[-1]
-                * int(np.prod(leaf.shape[1:-2]))
-                for leaf in jax.tree.leaves(self._cache) if leaf.ndim >= 3
-            )
+            kinds = jax.tree.leaves(cache_kinds(self._cache))
+            return [
+                (path[-1].key, leaf)
+                for (path, leaf), k in zip(
+                    jax.tree_util.tree_leaves_with_path(self._cache), kinds
+                ) if k == kind
+            ]
+
+    def cache_bytes_per_token(self) -> Optional[int]:
+        """Bytes one cached position costs over all layers, read off the
+        live slot cache's sequence leaves (so another width or dtype
+        shows); None before the first admission made the cache."""
+        leaves = self._cache_leaves(SEQUENCE)
+        if leaves is None:
+            return None
+        return sum(
+            leaf.dtype.itemsize * leaf.shape[-1]
+            * int(np.prod(leaf.shape[1:-2]))
+            for _, leaf in leaves
+        )
+
+    def state_bytes_per_row(self) -> Optional[int]:
+        """Bytes of per-row state with no sequence axis a slot row carries
+        over all layers, however long the row is (0 for a family that
+        keeps none); None before the first admission made the cache."""
+        leaves = self._cache_leaves(STATE)
+        if leaves is None:
+            return None
+        return sum(
+            leaf.dtype.itemsize * int(np.prod(leaf.shape[1:]))
+            for _, leaf in leaves
+        )
 
     def row_write(self) -> Optional[Dict[str, Optional[str]]]:
         """How the compiled decode step stores a new position in each leaf
         of the live slot cache, by leaf name (``ops/kv_row_write.py``:
         ``"tile"``, or None for a leaf no traced step wrote through the
         kernel); None before the first admission made the cache."""
-        with self._lock:
-            if self._cache is None:
-                return None
-            return {
-                path[-1].key: traced_form(leaf.shape)
-                for path, leaf in jax.tree_util.tree_leaves_with_path(
-                    self._cache
-                ) if leaf.ndim == 4
-            }
+        leaves = self._cache_leaves(SEQUENCE)
+        if leaves is None:
+            return None
+        return {name: traced_form(leaf.shape) for name, leaf in leaves}
 
     def _live_tokens(self) -> int:
         """Key positions the coming decode step attends over all live rows
@@ -1083,6 +1129,9 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         sharing the prefix hits, then release the lease's pins."""
         slot = self._slots.pop(si)
         if self._kv is None or slot.lease is None:
+            return
+        if slot.lease.cacheable is False:  # nothing of the row is kept
+            self._kv.release(slot.lease)
             return
         req = slot.request
         # K/V exists for prompt + generated[:-1]: the final sampled token
@@ -1243,7 +1292,10 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # the cluster tier and directed shipments carry BASE-model KV;
         # adapter requests stay out of both (their prefixes live in the
         # adapter-salted local radix namespace instead)
-        if self._kv is not None and req.adapter_id is None:
+        if (
+            self._kv is not None and self._kv.prefix_reuse
+            and req.adapter_id is None
+        ):
             if ship is not None:
                 pulled = self._as_pulled(ship, req)
             elif self._tier is not None:
@@ -1640,9 +1692,9 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # garbage the causal mask never reads and the next verify
         # overwrites before attending
         new_cache = jax.tree.map(
-            lambda leaf: new_idx.astype(leaf.dtype)
-            if leaf.ndim == 1 else leaf,
-            new_cache,
+            lambda leaf, kind: new_idx.astype(leaf.dtype)
+            if kind == INDEX else leaf,
+            new_cache, cache_kinds(new_cache),
         )
         return emitted, counts, new_cache, new_idx
 
@@ -1703,6 +1755,9 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         — the caller falls back to fused serving, so a prefill-side
         problem costs latency, never a request."""
         if self._kv is None or self._tier is None:
+            return None
+        if not self._kv.prefix_reuse:
+            # nothing to ship: a row's state is in no block
             return None
         if request.adapter_id is not None:
             # adapter-tinted KV must not ship through the base-model tier;

@@ -322,14 +322,27 @@ class _LLMReplica:
             # a routed model's expert counters (engine.expert_stats());
             # None for a dense model or the grouped-batch engine
             "moe": getattr(self._engine, "expert_stats", lambda: None)(),
-            # what a cached position costs, and how the decode step stores
-            # one in each cache leaf (None for the grouped-batch engine,
-            # and before the first admission)
+            # what a cached position costs (sequence leaves only), what a
+            # row carries whatever its length (per-row state with no
+            # sequence axis), how the decode step stores a position in
+            # each sequence leaf (None for the grouped-batch engine, and
+            # before the first admission), and whether a request may be
+            # served a cached prefix (False, with the reason, for a family
+            # whose rows carry such state)
             "kv": {
                 "cache_bytes_per_token": getattr(
                     self._engine, "cache_bytes_per_token", lambda: None)(),
+                "state_bytes_per_row": getattr(
+                    self._engine, "state_bytes_per_row", lambda: None)(),
                 "row_write": getattr(
                     self._engine, "row_write", lambda: None)(),
+                **(
+                    {} if self._kv_cache is None else {
+                        "prefix_reuse": self._kv_cache.prefix_reuse,
+                        "prefix_reuse_refused":
+                            self._kv_cache.prefix_reuse_refused,
+                    }
+                ),
             },
         }
 

@@ -2,8 +2,10 @@
 
 Llama (causal LM + LoRA + KV-cache decode), MoE transformer (routed
 experts, dropless for serving), DeepSeek-V3-shaped transformer (latent
-attention, shared + routed experts), ViT (vision encoder). The reference
-delegates model execution to torch/vLLM; this framework owns it.
+attention, shared + routed experts), Falcon-H1-shaped transformer (a
+Mamba-2 mixer beside attention in every block; serving only), ViT (vision
+encoder). The reference delegates model execution to torch/vLLM; this
+framework owns it.
 
 This module is also the one place that chooses a family for the serving
 stack (``llm/engine.py``, ``llm/serving.py``, ``llm/batch.py``), by the
@@ -15,20 +17,36 @@ type of the model config. What the engine asks of a family:
   ``mutable=["cache"]`` it writes a ``cache`` collection, applied without
   one it makes a fresh one (every row at position 0) and fills it
 - of that collection the engine and ``kvcache.KVCacheManager`` ask only
-  its shape, never what a leaf means: every leaf of ``ndim >= 3`` is
-  cached state of ``(batch, ..., max_seq_len, width)``, the sequence axis
-  at -2 and one ``max_seq_len`` for all of them (a slot row is a slice of
-  axis 0, a pool block a slice of axis -2); every leaf of ``ndim == 1`` is
-  a per-row write position ``(batch,)`` that a step advances by the tokens
-  it was fed and that the engine may reset. ``models/llama.py``'s
-  ``Attention`` (shared by ``moe``) keeps ``cached_key`` / ``cached_value``
-  of ``(batch, kv_heads, max_seq_len, head_dim)`` and a ``cache_index`` a
-  layer; ``models/deepseek.py`` a ``cached_latent`` of ``(batch, 1,
-  max_seq_len, kv_lora_rank)``, a ``cached_rope`` of ``(batch, 1,
-  max_seq_len, qk_rope_head_dim)`` and a ``cache_index``
+  each leaf's *kind*, never what it means, and they ask it here
+  (``cache_leaf_kind`` / ``cache_kinds``), by the leaf's name; nobody tells
+  a kind by ``ndim``. Three kinds:
+
+  - ``SEQUENCE``: cached state of ``(batch, ..., max_seq_len, width)``,
+    the sequence axis at -2 and one ``max_seq_len`` for all of them (a slot
+    row is a slice of axis 0, a pool block a slice of axis -2); any name
+    that is neither of the two below. ``models/llama.py``'s ``Attention``
+    (shared by ``moe``) keeps ``cached_key`` / ``cached_value`` of
+    ``(batch, kv_heads, max_seq_len, head_dim)``; ``models/deepseek.py`` a
+    ``cached_latent`` of ``(batch, 1, max_seq_len, kv_lora_rank)`` and a
+    ``cached_rope`` of ``(batch, 1, max_seq_len, qk_rope_head_dim)``
+  - ``INDEX``: a per-row write position ``(batch,)`` that a step advances
+    by the tokens it was fed and that the engine may reset; the name
+    ``cache_index``
+  - ``STATE``: per-row state ``(batch, ...)`` with **no sequence axis**,
+    carried from step to step and not indexed; a name that starts with
+    ``state_``. A slot row is a slice of axis 0 as for the others, but
+    there is nothing to cut into pool blocks, no position to move back and
+    no prefix to share: a family that has such a leaf gets no prefix reuse
+    (the manager leases without matching or committing and allocates no
+    pool), a free row's state is zeroed at every step it is free, and the
+    admission's row insert replaces it whole. ``models/falcon_h1.py`` keeps
+    ``state_ssm`` ``(batch, heads, d_head, d_state)`` float32 and
+    ``state_conv`` ``(batch, d_conv - 1, channels)`` a layer beside
+    ``llama``'s three
 - a step of one token a row (``seq == 1`` against a cache) attends each
   row up to its own position; a longer ``seq`` against a cache is a chunk
-  behind a cached prefix, row ``r``'s token ``i`` at ``index[r] + i``
+  behind a cached prefix, row ``r``'s token ``i`` at ``index[r] + i``, and
+  continues from the row's ``STATE`` leaves
 - ``init_params(config, rng, mesh)`` returns the boxed parameter tree
 - the config carries ``max_seq_len``, ``n_heads``, ``n_kv_heads``,
   ``n_layers``, ``dtype`` and ``param_dtype``
@@ -47,12 +65,19 @@ A family is added by a module with those two functions, a branch in
 attention and cache leaves, the routed part ``moe.MoEFFN``'s under three new
 ``MoEConfig`` fields, a latent form of the decode kernel
 (``ops/decode_attention.latent_decode_attention``); the engine changed
-only where it counted one expert row a layer.
+only where it counted one expert row a layer. ``falcon_h1`` (PR 32) forced
+the leaf kinds above: its mixer's state is the first cached leaf without a
+sequence axis.
 """
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Any, Dict
+
+# the kinds of a cache leaf (module docstring)
+SEQUENCE, INDEX, STATE = "sequence", "index", "state"
+_INDEX_NAME = "cache_index"
+_STATE_PREFIX = "state_"
 
 # the collection a routed family's decode step sows each layer's (rows, k)
 # expert choices into, for the engine's expert counters (llm/engine.py)
@@ -96,7 +121,49 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "form; the expert weights have no ep rule (ROADMAP R1)"
         ),
     },
+    "falcon_h1": {
+        "adapters": (
+            "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
+            "wq/wk/wv/wo at dim = n_heads x head_dim and has no placement "
+            "for the mixer's projections"
+        ),
+        "draft_model": (
+            "a rejected draft run cannot be undone by moving an index "
+            "back: the mixer's state has moved on, and no snapshot of it "
+            "is kept to return to"
+        ),
+        "mesh": (
+            "parallel/plan.py has no partition rule for a per-row state "
+            "leaf (models.STATE) or for the mixer's projections"
+        ),
+    },
 }
+
+
+def cache_leaf_kind(name: str) -> str:
+    """The kind of the cache leaf called ``name`` (module docstring)."""
+    if name == _INDEX_NAME:
+        return INDEX
+    if name.startswith(_STATE_PREFIX):
+        return STATE
+    return SEQUENCE
+
+
+def cache_kinds(cache: Any) -> Any:
+    """``cache``'s tree with each leaf's kind in the leaf's place: what a
+    ``jax.tree.map`` over a cache takes beside it to treat each kind."""
+    import jax
+
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: cache_leaf_kind(str(path[-1].key)), cache,
+    )
+
+
+def carries_row_state(model_config) -> bool:
+    """Whether the family keeps ``STATE`` leaves: known from the config
+    alone, before any cache exists (a lease is asked for before the first
+    prefill)."""
+    return getattr(_family(model_config), "ROW_STATE", False)
 
 
 def refusals(family: str) -> Dict[str, str]:
@@ -107,8 +174,10 @@ def refusals(family: str) -> Dict[str, str]:
 
 
 def _family(model_config):
-    from . import deepseek, llama, moe
+    from . import deepseek, falcon_h1, llama, moe
 
+    if isinstance(model_config, falcon_h1.FalconH1Config):
+        return falcon_h1
     if isinstance(model_config, deepseek.DeepseekConfig):
         return deepseek
     if isinstance(model_config, moe.MoEConfig):

@@ -236,8 +236,19 @@ class PartitionPlan:
 
     def cache_shardings(self, cache_shape: Any) -> Any:
         """Shardings for a decode-cache pytree (from jax.eval_shape or a
-        live cache): KV leaves (ndim >= 3) shard heads, index leaves
-        replicate."""
-        kv = self.kv_sharding()
-        rep = self.replicated()
-        return jax.tree.map(lambda l: kv if l.ndim >= 3 else rep, cache_shape)
+        live cache), by each leaf's kind (``ray_tpu.models``): sequence
+        leaves shard heads, index leaves replicate, and a per-row state
+        leaf has no rule (``models.refusals`` keeps such a family off a
+        mesh)."""
+        from ..models import INDEX, SEQUENCE, cache_kinds
+
+        by_kind = {SEQUENCE: self.kv_sharding(), INDEX: self.replicated()}
+
+        def sharding(_, kind):
+            if kind not in by_kind:
+                raise ValueError(
+                    f"PartitionPlan: no partition rule for a {kind} cache leaf"
+                )
+            return by_kind[kind]
+
+        return jax.tree.map(sharding, cache_shape, cache_kinds(cache_shape))

@@ -432,6 +432,43 @@ def test_moonlight_prefill_and_decode_compile(v5e_chip, native_kernels, compiled
     assert _size(pool) <= mem.alias_size_in_bytes <= _size(pool) + 512 * len(aliases)
 
 
+def test_falcon_h1_decode_step_carries_its_state_in_place(
+    v5e_chip, native_kernels, compiled
+):
+    """`falconh1-chat-backlog`'s programs at its widths, two blocks deep, 64
+    slots x 1024: every cache leaf, the mixer's float32 state among them,
+    is an input aliased to the output that succeeds it; the state update is
+    one fusion a layer that reads the state once and writes it once (the
+    free rows' zeroing fused into it), so no Pallas kernel was written for
+    it; no instruction copies a state."""
+    from ray_tpu.models.falcon_h1 import FalconH1Config
+
+    cfg = FalconH1Config(n_layers=2, param_dtype=jnp.bfloat16, max_seq_len=1024)
+    prefill, decode, params, pool = _serving_programs(compiled, v5e_chip, cfg, 64)
+    text = decode.as_text()
+    header = text.split("\n", 1)[0]
+    aliases = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliases) == len(jax.tree.leaves(pool)) == 2 * 5
+    state = r"f32\[64,32,128,256\]"
+    assert not re.search(rf"= {state}\S* copy\(", text)
+    updates = re.findall(
+        rf"= \(f32\[64,32,128\]\S*, {state}\S*\) fusion\(", text)
+    assert len(updates) == 2  # one a layer: (y, the new state)
+    # nothing else produces a whole state (a second pass would)
+    entry = text[text.index("\nENTRY "):]
+    produced = re.findall(rf"^\s*%\S+ = {state}\S* (\w[\w\-]*)\(", entry, re.M)
+    assert set(produced) <= {"get-tuple-element", "parameter", "bitcast"}, produced
+    _assert_rows_written_by_the_kernel(text, 2, {
+        "k": s for s in jax.tree.leaves(pool) if s.shape == (64, 4, 1024, 128)})
+    mem = decode.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert _size(pool) <= mem.alias_size_in_bytes <= _size(pool) + 512 * len(aliases)
+    # 5.35 GB of embedding and head + 2 x 0.86 GB; 64 rows x 2 x 6.3 MB
+    assert 7.0e9 < _size(params) < 7.1e9
+    assert 0.80e9 < _size(pool) < 0.82e9
+    assert prefill.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 # ---------------------------------------------------------------------------
 # 2. one process per chip, no fallback
 # ---------------------------------------------------------------------------

@@ -547,6 +547,9 @@ def test_deepseek_serves_through_serve_run(shutdown_only):
         assert info["kv"] == {
             "cache_bytes_per_token": 3 * 40 * 4,
             "row_write": {"cached_latent": "tile", "cached_rope": "tile"},
+            # no per-row state without a sequence axis, so prefixes are shared
+            "state_bytes_per_row": 0,
+            "prefix_reuse": True, "prefix_reuse_refused": None,
         }
         assert info["kernels"]["latent_decode_attention"] == [True]
         assert info["kernels"]["moe_experts"] == [True]
