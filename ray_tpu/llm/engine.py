@@ -22,12 +22,16 @@ Greedy and temperature sampling; per-request max_new_tokens.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import itertools
 import os
+import queue
 import threading
 import time
+import weakref
 import zlib
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -167,22 +171,115 @@ def _count_experts(counts: dict, routing: dict, active) -> dict:
     }
 
 
-class _StepLock:
-    """The engine lock as the stepping entry points take it: the wait is
-    an ``engine.lock_wait`` region in the profiler's trace. One thread
-    holds the lock and steps; the others of a replica's pool sit here."""
+class _EngineLock:
+    """The engine lock: reentrant and a context manager, like the ``RLock``
+    it wraps, and handed over. A thread that asks for it (``acquire``,
+    ``with``) is counted while it waits; the stepping thread takes it with
+    ``acquire_behind``, which lets everybody counted go first. A plain lock
+    let go and taken again at once by one thread keeps every other thread
+    out for as long as that thread has steps to run."""
 
-    __slots__ = ("_lock",)
+    __slots__ = ("_lock", "_asked", "_asking")
 
-    def __init__(self, lock):
-        self._lock = lock
+    def __init__(self):
+        self._lock = threading.RLock()
+        self._asked = threading.Condition(threading.Lock())
+        self._asking = 0
+
+    def acquire(self) -> None:
+        with self._asked:
+            self._asking += 1
+        try:
+            self._lock.acquire()
+        finally:
+            with self._asked:
+                self._asking -= 1
+                if not self._asking:
+                    self._asked.notify_all()
+
+    def acquire_behind(self) -> None:
+        with self._asked:
+            while self._asking:
+                self._asked.wait()
+        self._lock.acquire()
+
+    def release(self) -> None:
+        self._lock.release()
 
     def __enter__(self):
-        with _span("engine.lock_wait"):
-            self._lock.acquire()
+        self.acquire()
 
     def __exit__(self, exc_type, exc, tb):
         self._lock.release()
+
+
+class _StepLock:
+    """The engine lock as a step takes it: the wait is an
+    ``engine.lock_wait`` region in the profiler's trace. ``behind`` is the
+    stepping thread's way in, after whoever else is asking."""
+
+    __slots__ = ("_lock", "_acquire")
+
+    def __init__(self, lock: _EngineLock, behind: bool = False):
+        self._lock = lock
+        self._acquire = lock.acquire_behind if behind else lock.acquire
+
+    def __enter__(self):
+        with _span("engine.lock_wait"):
+            self._acquire()
+
+    def __exit__(self, exc_type, exc, tb):
+        self._lock.release()
+
+
+class _Sink:
+    """Where a request's output goes. After a step, whoever ran it calls
+    ``post`` once with every delivery of that step whose sinks share the
+    callable: a list of ``(request id, new tokens, end)``, ``end`` None
+    while the request runs, then its ``GenerationResult``, or the exception
+    of the step that failed it. ``stream`` False asks for the end alone."""
+
+    __slots__ = ("post", "stream", "sent")
+
+    def __init__(self, post: Callable[[list], None], stream: bool):
+        self.post = post
+        self.stream = stream
+        self.sent = 0  # tokens handed over so far
+
+
+class _Stepper:
+    """What the stepping thread and its engine share while the thread holds
+    no reference to the engine: parked, it keeps no engine alive."""
+
+    __slots__ = ("cond", "kicked", "closed", "thread", "steps", "parked")
+
+    def __init__(self):
+        self.cond = threading.Condition()
+        self.kicked = False
+        self.closed = False
+        self.thread: Optional[threading.Thread] = None
+        self.steps = 0  # steps the thread ran
+        self.parked = 0  # times it found nothing to do and waited
+
+    def stop(self) -> None:
+        with self.cond:
+            self.closed = True
+            self.cond.notify_all()
+
+    def run(self, engine_ref) -> None:
+        while True:
+            with self.cond:
+                while not (self.kicked or self.closed):
+                    self.parked += 1
+                    self.cond.wait()
+                if self.closed:
+                    return
+                self.kicked = False
+            engine = engine_ref()
+            if engine is None:
+                return
+            engine._step_while_work(self)
+            del engine
 
 
 @dataclasses.dataclass
@@ -367,8 +464,47 @@ class LLMEngine(_DecodeModelBase):
         )
         self._max_batch = max_batch_size
         self._rng = jax.random.PRNGKey(_resolve_seed(seed))
+        self._stream_ids = itertools.count()
+        # open stream -> set once its consumer has gone
+        self._streams: Dict[int, threading.Event] = {}
 
     # -- generation ----------------------------------------------------------
+
+    def stream_to(self, request: GenerationRequest,
+                  post: Callable[[list], None]) -> int:
+        """``generate_stream`` in a thread of its own, handed over as the
+        continuous engine hands over (``_Sink``): one delivery a token,
+        then the result. This engine batches nothing across callers, so a
+        stream's thread is the one that steps for it."""
+        rid = next(self._stream_ids)
+        gone = self._streams[rid] = threading.Event()
+
+        def run():
+            try:
+                for item in self.generate_stream(request):
+                    if gone.is_set():
+                        return
+                    post([(rid, [item], None) if isinstance(item, int)
+                          else (rid, [], item)])
+            except Exception as exc:  # noqa: BLE001 - the consumer's
+                post([(rid, [], exc)])
+            finally:
+                del self._streams[rid]
+
+        threading.Thread(
+            target=run, daemon=True, name=f"llm-stream-{rid}"
+        ).start()
+        return rid
+
+    def drop_sink(self, rid: int) -> None:
+        """The consumer of stream ``rid`` has gone: its thread stops at
+        the next token."""
+        gone = self._streams.get(rid)
+        if gone is not None:
+            gone.set()
+
+    def close(self) -> None:
+        """Nothing to stop: a stream's thread ends with its stream."""
 
     def generate(self, requests: List[GenerationRequest]) -> List[GenerationResult]:
         """Generate for a list of requests, grouping same-length prompts
@@ -551,6 +687,19 @@ class ContinuousBatchingEngine(_DecodeModelBase):
     after it was computed, and a row leaves the batch when the host has
     *seen* its last token: it may ride one step more, whose token for it
     nobody reads.
+
+    One thread steps. ``generate``, ``generate_one``, ``generate_stream``
+    and ``stream_to`` enqueue, wake the engine's stepping thread and wait
+    for what it makes: after every step it hands each request that has a
+    sink its new tokens, and at its end its result (``_deliver``). With no
+    work the thread parks on a condition, without the lock. ``step()`` and
+    ``run_until_complete()`` remain a caller's own drive under the same
+    lock, for tests and batch callers that never start the thread; their
+    steps deliver to sinks too, and the two serialise on ``_lock`` if
+    mixed. ``_lock`` is handed over between steps (``_EngineLock``): what
+    reads or replays the engine's state from outside (``expert_stats``, a
+    benchmark driver's check) writes ``with engine._lock:`` and is in
+    before the next step starts.
     """
 
     def __init__(
@@ -577,7 +726,11 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         self._slots: Dict[int, _Slot] = {}  # slot index -> active request
         # (request_id, GenerationRequest, shipment-or-None): the third
         # element carries a directed prefill->decode handoff
-        self._pending: List[tuple] = []
+        # Appended by any thread under ``_submit_lock``, never under
+        # ``_lock``: a submission does not wait for a running step. Taken
+        # from the left by whoever steps
+        self._pending: Deque[tuple] = collections.deque()
+        self._submit_lock = threading.Lock()
         self._next_id = 0
         self._rng = jax.random.PRNGKey(_resolve_seed(seed))
         self._step_count = 0
@@ -618,13 +771,17 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # and computed prefixes are exported for the rest of the cluster.
         # Requires a kv_cache (the tier ships paged blocks).
         self._tier = kv_tier
-        # serve replicas call sync methods from a thread pool: every public
-        # entry point serializes on this (reentrant: step() inside generate)
-        self._lock = threading.RLock()
+        # whoever steps, or reads or replays the engine's state from
+        # outside a step, holds this (reentrant)
+        self._lock = _EngineLock()
         self._step_lock = _StepLock(self._lock)
-        # results finished by another thread's step() land here until the
-        # owning generate()/generate_stream() call collects them
-        self._finished_buf: Dict[int, GenerationResult] = {}
+        # rid -> where that request's tokens and result go; a request
+        # without one is a ``step()`` caller's, who reads the return value
+        self._sinks: Dict[int, _Sink] = {}
+        self._stepper = _Stepper()
+        weakref.finalize(self, self._stepper.stop)
+        # slot index -> monotonic stamp of its last retirement
+        self._slot_freed: Dict[int, float] = {}
         self._enqueue_ts: Dict[int, float] = {}  # rid -> wall, for TTFT
         # rid -> {"ctx", "wall"}: populated only while the submitting
         # request is traced, so the untraced path never touches it
@@ -727,7 +884,13 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         """``shipment`` is an optional directed KV handoff: a
         ``(KVShipment, payload)`` pair from a prefill replica (fetched by
         the caller through the tier backend). Admission adopts the shipped
-        blocks instead of re-running prefill."""
+        blocks instead of re-running prefill. The request is the caller's
+        to step (``step``, ``run_until_complete``); it never waits for a
+        running step."""
+        self._check(request)
+        return self._submit(request, shipment, None)
+
+    def _check(self, request: GenerationRequest) -> None:
         if len(request.token_ids) + request.max_new_tokens > self._cfg.max_seq_len:
             raise ValueError("prompt + max_new_tokens exceeds max_seq_len")
         if self._spec_k and (
@@ -742,19 +905,26 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 "prompt + max_new_tokens + spec_tokens exceeds max_seq_len "
                 "(speculative verification needs headroom)"
             )
-        # one stamp for the queue-wait span, TTFT and queue_wait_us, taken
-        # before the lock: waiting for a running step is queue wait too
+
+    def _submit(self, request: GenerationRequest, shipment,
+                sink: Optional[_Sink]) -> int:
+        # one stamp for the queue-wait span, TTFT and queue_wait_us
         now = time.time()
         tr = None
         if _tracing.is_tracing_enabled():
             tr = {"ctx": _tracing.current_context(), "wall": now}
-        with self._lock:
+        with self._submit_lock:
+            if sink is not None and self._stepper.closed:
+                raise RuntimeError("the engine is closed")
             rid = self._next_id
             self._next_id += 1
-            self._pending.append((rid, request, shipment))
             self._enqueue_ts[rid] = now
             if tr is not None:
                 self._req_trace[rid] = tr
+            if sink is not None:
+                self._sinks[rid] = sink
+            # last: from here on a step may take it
+            self._pending.append((rid, request, shipment))
         return rid
 
     @property
@@ -786,7 +956,131 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     self._spec_step(finished)
             else:
                 self._dense_step(finished)
+            self._deliver(finished)
             return finished
+
+    def _deliver(self, finished: List[tuple]) -> None:
+        """Hand every request that has a sink what this step made for it:
+        the tokens it had not been given, and its result if it ended. One
+        ``post`` a step for all the sinks that share it, so a loop with 60
+        streams is woken once a step and not once a token."""
+        sinks = self._sinks
+        if not sinks:
+            return
+        with _span("engine.deliver"):
+            batches: Dict[Callable, list] = {}
+            for slot in self._slots.values():
+                sink = sinks.get(slot.request_id)
+                if (sink is not None and sink.stream
+                        and len(slot.generated) > sink.sent):
+                    batches.setdefault(sink.post, []).append(
+                        (slot.request_id, slot.generated[sink.sent:], None))
+                    sink.sent = len(slot.generated)
+            for rid, result in finished:
+                sink = sinks.pop(rid, None)
+                if sink is not None:
+                    tokens = result.token_ids[sink.sent:] if sink.stream else []
+                    batches.setdefault(sink.post, []).append(
+                        (rid, tokens, result))
+            self._post(batches)
+
+    def _post(self, batches: Dict[Callable, list]) -> None:
+        for post, batch in batches.items():
+            try:
+                post(batch)
+            except Exception:  # noqa: BLE001 - a loop that has closed
+                for rid, _, _ in batch:
+                    self._sinks.pop(rid, None)
+
+    # -- the stepping thread ---------------------------------------------------
+
+    def _has_work(self) -> bool:
+        return bool(self.num_active or self._inflight is not None)
+
+    def _wake_stepper(self) -> None:
+        """Something was enqueued for the stepping thread (started here, by
+        the first caller that waits for it)."""
+        st = self._stepper
+        with st.cond:
+            if not st.closed and (st.thread is None or not st.thread.is_alive()):
+                st.thread = threading.Thread(
+                    target=st.run, args=(weakref.ref(self),), daemon=True,
+                    name="engine-stepper",
+                )
+                st.thread.start()
+            st.kicked = True
+            st.cond.notify()
+
+    def _step_while_work(self, st: _Stepper) -> None:
+        """The stepping thread's drive: steps back to back, each under the
+        lock and each behind whoever else asked for it meanwhile. A step
+        that raises fails every waiting request and the thread goes on."""
+        behind = _StepLock(self._lock, behind=True)
+        while not st.closed and self._has_work():
+            try:
+                with behind:
+                    if self._has_work():  # a caller's own step() drained it
+                        # counted first: whoever a step's delivery wakes
+                        # finds the step in the count
+                        st.steps += 1
+                        self._step_locked()
+            except Exception as exc:  # noqa: BLE001 - the waiters'
+                self._fail_waiters(exc)
+
+    def _fail_waiters(self, exc: BaseException) -> None:
+        """A step raised: what it had in hand is in no known state. Every
+        request the engine holds is given up, every waiting one gets the
+        exception, and the engine is empty for the next."""
+        with self._lock, self._submit_lock:
+            failed = self._give_up_locked()
+        batches: Dict[Callable, list] = {}
+        for rid, sink in failed.items():
+            batches.setdefault(sink.post, []).append((rid, [], exc))
+        self._post(batches)
+
+    def _give_up_locked(self) -> Dict[int, _Sink]:
+        """Empty the engine; returns the sinks that were waiting."""
+        held = [s.lease for s in self._slots.values()]
+        held += [st["lease"] for st in self._prefilling.values()]
+        if self._kv is not None:
+            for lease in held:
+                try:
+                    if lease is not None:
+                        self._kv.release(lease)
+                except Exception:  # noqa: BLE001 - the waiters come first
+                    pass
+        now = time.monotonic()
+        for si in list(self._slots) + list(self._prefilling):
+            self._slot_freed[si] = now
+        self._slots.clear()
+        self._prefilling.clear()
+        self._pending.clear()
+        self._inflight = None
+        self._enqueue_ts.clear()
+        self._req_trace.clear()
+        self._blocked_rids.clear()
+        # a donated cache whose step failed may be gone with it
+        for name in ("_cache", "_draft_cache"):
+            if any(leaf.is_deleted()
+                   for leaf in jax.tree.leaves(getattr(self, name))):
+                setattr(self, name, None)
+        failed, self._sinks = self._sinks, {}
+        return failed
+
+    def stepper_stats(self) -> Dict[str, int]:
+        """``steps`` the stepping thread ran and the times it ``parked``
+        with nothing to do (a caller's own ``step()`` counts in neither)."""
+        return {"steps": self._stepper.steps, "parked": self._stepper.parked}
+
+    def close(self) -> None:
+        """Stop the stepping thread and wait for it. Whatever still waits
+        is failed; the engine serves no waiting entry afterwards."""
+        st = self._stepper
+        st.stop()
+        if st.thread is not None and st.thread is not threading.current_thread():
+            st.thread.join()
+        if self._sinks:
+            self._fail_waiters(RuntimeError("the engine is closed"))
 
     def _dense_step(self, finished: List[tuple]) -> None:
         """Dispatch the next decode step, then read the one before it: the
@@ -1128,6 +1422,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         full blocks (prompt + generated tail) so a follow-up request
         sharing the prefix hits, then release the lease's pins."""
         slot = self._slots.pop(si)
+        self._slot_freed[si] = time.monotonic()
         if self._kv is None or slot.lease is None:
             return
         if slot.lease.cacheable is False:  # nothing of the row is kept
@@ -1157,85 +1452,83 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         out: Dict[int, GenerationResult] = {}
         with self._step_lock:
             while self.num_active:
-                for rid, result in self._step_locked():
-                    out[rid] = result
+                out.update(self._step_locked())
         return out
 
     def generate(
         self, requests: List[GenerationRequest]
     ) -> List[GenerationResult]:
-        """Batch API matching LLMEngine.generate: enqueue every request,
-        step the shared pool until all of them finish. Safe to call from
-        several threads at once — each caller steps under the engine lock
-        and results for other callers' requests are parked in a shared
-        buffer until their owner collects them."""
-        rids = [self.add_request(r) for r in requests]
-        want = set(rids)
-        out: Dict[int, GenerationResult] = {}
-        while len(out) < len(want):
-            with self._step_lock:
-                for rid in want:
-                    if rid in self._finished_buf:
-                        out[rid] = self._finished_buf.pop(rid)
-                if len(out) >= len(want):
-                    break
-                for frid, res in self._step_locked():
-                    if frid in want:
-                        out[frid] = res
-                    else:
-                        self._finished_buf[frid] = res
+        """Batch API matching LLMEngine.generate: enqueue every request and
+        wait until the stepping thread has finished all of them. Safe to
+        call from several threads at once."""
+        for r in requests:
+            self._check(r)
+        inbox: queue.SimpleQueue = queue.SimpleQueue()
+        rids = [
+            self._submit(r, None, _Sink(inbox.put, stream=False))
+            for r in requests
+        ]
+        out = self._wait_results(inbox, rids)
         return [out[rid] for rid in rids]
 
     def generate_one(self, request: GenerationRequest,
                      shipment=None) -> GenerationResult:
         """generate() for ONE request, with an optional directed KV
         shipment (see add_request) — the decode-role entry point."""
-        rid = self.add_request(request, shipment=shipment)
-        while True:
-            with self._step_lock:
-                if rid in self._finished_buf:
-                    return self._finished_buf.pop(rid)
-                for frid, res in self._step_locked():
-                    if frid == rid:
-                        return res
-                    self._finished_buf[frid] = res
+        self._check(request)
+        inbox: queue.SimpleQueue = queue.SimpleQueue()
+        rid = self._submit(request, shipment, _Sink(inbox.put, stream=False))
+        return self._wait_results(inbox, [rid])[rid]
+
+    def _wait_results(self, inbox: queue.SimpleQueue,
+                      rids: List[int]) -> Dict[int, GenerationResult]:
+        out: Dict[int, GenerationResult] = {}
+        try:
+            self._wake_stepper()
+            while len(out) < len(rids):
+                for rid, _, end in inbox.get():
+                    if isinstance(end, BaseException):
+                        raise end
+                    out[rid] = end
+        finally:
+            for rid in rids:
+                self._sinks.pop(rid, None)
+        return out
+
+    def stream_to(self, request: GenerationRequest,
+                  post: Callable[[list], None]) -> int:
+        """Enqueue ``request`` with a sink that hands ``post`` its tokens
+        as steps make them, then its result (``_Sink``); returns the id its
+        deliveries carry. ``post`` is called on the stepping thread, once a
+        step for all the requests that share it: it must not block."""
+        self._check(request)
+        rid = self._submit(request, None, _Sink(post, stream=True))
+        self._wake_stepper()
+        return rid
+
+    def drop_sink(self, rid: int) -> None:
+        """Nobody reads request ``rid`` any more (a stream its caller
+        closed): its row runs to its end and its result is dropped."""
+        self._sinks.pop(rid, None)
 
     def generate_stream(self, request: GenerationRequest):
         """Streaming API matching LLMEngine.generate_stream: yields each
         token of ONE request as the shared pool produces it, then the
         final GenerationResult. Other requests keep decoding in the same
         steps — this is what makes replica streaming continuous-batched."""
-        rid = self.add_request(request)
-        emitted = 0
-        final: Optional[GenerationResult] = None
-        while True:
-            with self._step_lock:
-                if rid in self._finished_buf:
-                    final = self._finished_buf.pop(rid)
-                if final is None:
-                    for frid, res in self._step_locked():
-                        if frid == rid:
-                            final = res
-                        else:
-                            self._finished_buf[frid] = res
-                if final is None:
-                    slot = next(
-                        (
-                            s
-                            for s in self._slots.values()
-                            if s.request_id == rid
-                        ),
-                        None,
-                    )
-                    new_tokens = list(slot.generated[emitted:]) if slot else []
-                else:
-                    new_tokens = list(final.token_ids[emitted:])
-            for tok in new_tokens:  # yield outside the lock
-                yield tok
-            emitted += len(new_tokens)
-            if final is not None:
-                yield final
-                return
+        inbox: queue.SimpleQueue = queue.SimpleQueue()
+        rid = self.stream_to(request, inbox.put)
+        try:
+            while True:
+                for _, tokens, end in inbox.get():
+                    yield from tokens
+                    if isinstance(end, BaseException):
+                        raise end
+                    if end is not None:
+                        yield end
+                        return
+        finally:
+            self.drop_sink(rid)
 
     # -- internals -----------------------------------------------------------
 
@@ -1265,14 +1558,22 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         ]
         while free and self._pending:
             si = free.pop(0)
-            rid, req, ship = self._pending.pop(0)
+            rid, req, ship = self._pending.popleft()
             now = time.time()
+            # slot_free_us: how long the slot taken had been nobody's (0
+            # for one never used). In a closed loop with requests waiting
+            # it is what the scheduler left on the table
+            freed = self._slot_freed.get(si)
             with _span(
                 "engine.admit", request_id=rid,
                 queue_wait_us=int(
                     (now - self._enqueue_ts.get(rid, now)) * 1e6
                 ),
                 prompt_tokens=len(req.token_ids),
+                slot_free_us=(
+                    0 if freed is None
+                    else int((time.monotonic() - freed) * 1e6)
+                ),
             ):
                 admitted = self._admit_one(si, rid, req, ship, finished)
             if admitted is None:  # backpressure: wait for a release
@@ -1327,7 +1628,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 else:
                     acquiring.set(cached_tokens=lease.num_cached_tokens)
             if lease is None:
-                self._pending.insert(0, (rid, req, ship))
+                self._pending.appendleft((rid, req, ship))
                 if rid not in self._blocked_rids:
                     self._blocked_rids.add(rid)
                     _events.record_event(
@@ -1770,8 +2071,9 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             lease = self._kv.acquire(request.token_ids)
             if lease is None:
                 return None
-            rid = self._next_id
-            self._next_id += 1
+            with self._submit_lock:
+                rid = self._next_id
+                self._next_id += 1
             try:
                 logits, solo_cache = self._prefill_leased(request, lease)
                 first = self._sample_first(logits, request, rid)

@@ -32,11 +32,31 @@ prefill. See docs/ARCHITECTURE.md §18.
 
 from __future__ import annotations
 
+import asyncio
 from typing import Any, Dict, Optional
 
 from .. import serve
 from .config import LLMConfig
 from .engine import ContinuousBatchingEngine, GenerationRequest, LLMEngine
+
+
+class _LoopStreams:
+    """The open streams of one event loop. The engine's stepping thread
+    ``post``s a step's deliveries for all of them with one
+    ``call_soon_threadsafe``; on the loop each goes to its stream's inbox."""
+
+    def __init__(self, loop: asyncio.AbstractEventLoop):
+        self._loop = loop
+        self.inboxes: Dict[int, asyncio.Queue] = {}
+
+    def post(self, batch: list) -> None:  # any thread; never blocks
+        self._loop.call_soon_threadsafe(self._fan_out, batch)
+
+    def _fan_out(self, batch: list) -> None:
+        for rid, tokens, end in batch:
+            inbox = self.inboxes.get(rid)
+            if inbox is not None:  # else: a stream closed meanwhile
+                inbox.put_nowait((tokens, end))
 
 
 class _LLMReplica:
@@ -199,6 +219,12 @@ class _LLMReplica:
             from transformers import AutoTokenizer
 
             self._tokenizer = AutoTokenizer.from_pretrained(tokenizer_name)
+        # event loop -> its open streams, while it has any
+        self._loop_streams: Dict[Any, _LoopStreams] = {}
+
+    def shutdown(self) -> None:
+        """Replica shutdown hook: stop the engine's stepping thread."""
+        self._engine.close()
 
     def warmup(self) -> Dict[str, Any]:
         """Serve replica warmup hook (runs at the end of Replica.__init__,
@@ -322,6 +348,12 @@ class _LLMReplica:
             # a routed model's expert counters (engine.expert_stats());
             # None for a dense model or the grouped-batch engine
             "moe": getattr(self._engine, "expert_stats", lambda: None)(),
+            # who steps: steps the engine's own thread ran and the times it
+            # parked with nothing to do (None for the grouped-batch engine)
+            "engine": {
+                "stepper": getattr(
+                    self._engine, "stepper_stats", lambda: None)(),
+            },
             # what a cached position costs (sequence leaves only), what a
             # row carries whatever its length (per-row state with no
             # sequence axis), how the decode step stores a position in
@@ -432,14 +464,7 @@ class _LLMReplica:
         finally:
             if self._adapter_store is not None:
                 self._adapter_store.release(lease)
-        out: Dict[str, Any] = {
-            "token_ids": result.token_ids,
-            "num_prompt_tokens": result.num_prompt_tokens,
-            "finished_reason": result.finished_reason,
-        }
-        if self._tokenizer is not None:
-            out["text"] = self._tokenizer.decode(result.token_ids)
-        return out
+        return self._summary(result, False)
 
     def weights_info(self) -> Dict[str, Any]:
         return {
@@ -506,8 +531,9 @@ class _LLMReplica:
         request backpressures like KV-pool exhaustion: BackPressureError
         is retryable, routers send the request elsewhere."""
         aid = self._requested_adapter_id(request)
-        if aid is None:
-            return None
+        return None if aid is None else self._acquire_adapter(aid)
+
+    def _acquire_adapter(self, aid: str):
         if self._adapter_store is None:
             raise ValueError(
                 f"request names adapter {aid!r} but the deployment has no "
@@ -540,10 +566,8 @@ class _LLMReplica:
         return self._adapter_store.stats()
 
     def __call__(self, request: Dict[str, Any]) -> Dict[str, Any]:
-        if request.get("stream"):
-            # through a plain (non-stream) handle this collapses to the
-            # buffered result; the HTTP/handle streaming path calls .stream
-            return list(self.stream(request))[-1]
+        # "stream": true through a plain (non-stream) handle is the
+        # buffered result; the HTTP/handle streaming path calls .stream
         lease = self._resolve_adapter(request)
         try:
             result = self._engine.generate(
@@ -552,56 +576,83 @@ class _LLMReplica:
         finally:
             if self._adapter_store is not None:
                 self._adapter_store.release(lease)
+        return self._summary(result, request.get("stream"))
+
+    def _summary(self, result, streamed) -> Dict[str, Any]:
         out: Dict[str, Any] = {
             "token_ids": result.token_ids,
             "num_prompt_tokens": result.num_prompt_tokens,
             "finished_reason": result.finished_reason,
         }
+        if streamed:
+            out["finished"] = True
         if self._tokenizer is not None:
             out["text"] = self._tokenizer.decode(result.token_ids)
         return out
 
-    def stream(self, request: Dict[str, Any]):
+    async def stream(self, request: Dict[str, Any]):
         """Token streaming (reference: ray.llm streaming responses through
         serve — DeploymentResponseGenerator): yields one dict per generated
         token as it is sampled, then a final summary dict. Time-to-first-
-        token is prefill latency instead of full-generation latency."""
-        lease = self._resolve_adapter(request)
+        token is prefill latency instead of full-generation latency.
+
+        An ``async def`` generator: the replica runs it on its event loop
+        and none of its pool's threads waits for a step. The request goes to
+        the engine with a sink that forwards to this loop
+        (``_LoopStreams``), under the context this coroutine runs in, so
+        the engine's request spans join the stream's trace; the engine's
+        stepping thread makes the tokens, and this coroutine awaits them. A
+        caller that closes the stream drops the sink: the row runs to its
+        end and its result goes nowhere."""
+        loop = asyncio.get_running_loop()
+        aid = self._requested_adapter_id(request)
+        lease = None
+        if aid is not None:
+            # a cold adapter is pulled, and a full store waited for, here:
+            # neither on the loop nor under the engine lock
+            lease = await loop.run_in_executor(
+                None, self._acquire_adapter, aid)
+        streams = self._loop_streams.get(loop)
+        if streams is None:
+            streams = self._loop_streams[loop] = _LoopStreams(loop)
+        rid = None
         try:
-            yield from self._stream_leased(request, lease)
+            # no await between the submission and the inbox's registration:
+            # deliveries reach this loop as callbacks, which run after it
+            rid = self._engine.stream_to(
+                self._parse_request(request, lease), streams.post)
+            inbox = streams.inboxes[rid] = asyncio.Queue()
+            index = 0
+            all_ids: list = []
+            prev_text = ""
+            while True:
+                tokens, end = await inbox.get()
+                for item in tokens:
+                    out: Dict[str, Any] = {"token_id": item, "index": index}
+                    if self._tokenizer is not None:
+                        # BPE/SentencePiece pieces don't decode standalone
+                        # (leading-space markers, multi-token unicode):
+                        # decode the running sequence and emit the delta so
+                        # clients can concatenate the streamed text verbatim
+                        all_ids.append(item)
+                        full = self._tokenizer.decode(all_ids)
+                        out["text"] = full[len(prev_text):]
+                        prev_text = full
+                    index += 1
+                    yield out
+                if isinstance(end, BaseException):
+                    raise end
+                if end is not None:
+                    yield self._summary(end, True)
+                    return
         finally:
+            if rid is not None:
+                self._engine.drop_sink(rid)
+                streams.inboxes.pop(rid, None)
+            if not streams.inboxes:
+                self._loop_streams.pop(loop, None)
             if self._adapter_store is not None:
                 self._adapter_store.release(lease)
-
-    def _stream_leased(self, request: Dict[str, Any], lease):
-        gen_req = self._parse_request(request, lease)
-        index = 0
-        all_ids: list = []
-        prev_text = ""
-        for item in self._engine.generate_stream(gen_req):
-            if isinstance(item, int):
-                out: Dict[str, Any] = {"token_id": item, "index": index}
-                if self._tokenizer is not None:
-                    # BPE/SentencePiece pieces don't decode standalone
-                    # (leading-space markers, multi-token unicode): decode
-                    # the running sequence and emit the delta so clients can
-                    # concatenate the streamed text verbatim
-                    all_ids.append(item)
-                    full = self._tokenizer.decode(all_ids)
-                    out["text"] = full[len(prev_text):]
-                    prev_text = full
-                index += 1
-                yield out
-            else:  # final GenerationResult
-                summary: Dict[str, Any] = {
-                    "token_ids": item.token_ids,
-                    "num_prompt_tokens": item.num_prompt_tokens,
-                    "finished_reason": item.finished_reason,
-                    "finished": True,
-                }
-                if self._tokenizer is not None:
-                    summary["text"] = self._tokenizer.decode(item.token_ids)
-                yield summary
 
 
 class _DisaggIngress:

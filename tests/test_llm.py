@@ -319,7 +319,7 @@ class TestAdmission:
         assert engine.num_active == 0
         assert not engine._slots
         assert not engine._pending
-        assert not engine._finished_buf
+        assert not engine._sinks
         assert not engine._enqueue_ts
 
     def test_memory_gated_admission_preserves_fifo(self, tiny_engine):
