@@ -33,9 +33,11 @@ prefill. See docs/ARCHITECTURE.md §18.
 from __future__ import annotations
 
 import asyncio
+import time
 from typing import Any, Dict, Optional
 
 from .. import serve
+from ..util.tracing import annotate_device_trace as _span
 from .config import LLMConfig
 from .engine import ContinuousBatchingEngine, GenerationRequest, LLMEngine
 
@@ -43,20 +45,27 @@ from .engine import ContinuousBatchingEngine, GenerationRequest, LLMEngine
 class _LoopStreams:
     """The open streams of one event loop. The engine's stepping thread
     ``post``s a step's deliveries for all of them with one
-    ``call_soon_threadsafe``; on the loop each goes to its stream's inbox."""
+    ``call_soon_threadsafe``; on the loop each goes to its stream's inbox,
+    with the ``perf_counter_ns`` at which it was posted."""
 
     def __init__(self, loop: asyncio.AbstractEventLoop):
         self._loop = loop
         self.inboxes: Dict[int, asyncio.Queue] = {}
 
     def post(self, batch: list) -> None:  # any thread; never blocks
-        self._loop.call_soon_threadsafe(self._fan_out, batch)
+        self._loop.call_soon_threadsafe(
+            self._fan_out, batch, time.perf_counter_ns())
 
-    def _fan_out(self, batch: list) -> None:
-        for rid, tokens, end in batch:
-            inbox = self.inboxes.get(rid)
-            if inbox is not None:  # else: a stream closed meanwhile
-                inbox.put_nowait((tokens, end))
+    def _fan_out(self, batch: list, posted_ns: int) -> None:
+        # post_lag_us: how far this loop runs behind the stepping thread
+        with _span(
+            "replica.fan_out", streams=len(batch),
+            post_lag_us=(time.perf_counter_ns() - posted_ns) // 1000,
+        ):
+            for rid, tokens, end in batch:
+                inbox = self.inboxes.get(rid)
+                if inbox is not None:  # else: a stream closed meanwhile
+                    inbox.put_nowait((tokens, end, posted_ns))
 
 
 class _LLMReplica:
@@ -626,7 +635,19 @@ class _LLMReplica:
             all_ids: list = []
             prev_text = ""
             while True:
-                tokens, end = await inbox.get()
+                tokens, end, posted_ns = await inbox.get()
+                if end is not None:
+                    # once a request: how late a finished answer leaves,
+                    # behind the acknowledgements of its earlier tokens. A
+                    # count on a region that closes at once: the loop's
+                    # coroutines interleave on one thread, so none stays
+                    # open across an await
+                    with _span(
+                        "replica.stream_end", request_id=rid,
+                        inbox_wait_us=(time.perf_counter_ns() - posted_ns) // 1000,
+                        tokens=len(tokens),
+                    ):
+                        pass
                 for item in tokens:
                     out: Dict[str, Any] = {"token_id": item, "index": index}
                     if self._tokenizer is not None:

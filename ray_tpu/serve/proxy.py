@@ -57,7 +57,10 @@ class HTTPProxy:
             target=self._serve_forever, daemon=True, name="http-proxy"
         )
         self._thread.start()
-        if not self._ready.wait(timeout=15):
+        # _ready is set on the error path too: a proxy that could not bind
+        # its port is no proxy, and a request meant for it would be answered
+        # by whoever holds the port
+        if not self._ready.wait(timeout=15) or self._error:
             raise RuntimeError(f"HTTP proxy failed to start: {self._error}")
 
     # -- server --------------------------------------------------------------

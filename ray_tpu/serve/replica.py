@@ -49,6 +49,7 @@ class Replica:
         self._ongoing = 0
         self._queued = 0
         self._total_served = 0
+        self._streams_opened = 0
         self._shed_total = 0
         self._doa_total = 0
         self._draining = False
@@ -462,6 +463,30 @@ class Replica:
                     time.perf_counter() - t0,
                     ongoing=self._ongoing, queued=self._queued,
                 )
+            # the way in, in the profiler's trace; ``stream`` tells this
+            # stream's regions from those of the others on this loop
+            self._streams_opened += 1
+            stream = self._streams_opened
+            with _tracing.annotate_device_trace(
+                "replica.stream_open", stream=stream,
+                admit_wait_us=int((time.perf_counter() - t0) * 1e6),
+            ):
+                pass
+
+            def item_acknowledged(sent_ns: int):
+                # this generator resumes after a ``yield`` only when the
+                # runtime asks for the stream's next item, which it does
+                # once the last was packed, sent to its owner and
+                # acknowledged: the awaited way out of one item. A count on
+                # a region that closes at once: 64 streams interleave on
+                # this thread and a region held across the yield would
+                # nest falsely
+                with _tracing.annotate_device_trace(
+                    "replica.stream_item", stream=stream,
+                    rtt_us=(time.perf_counter_ns() - sent_ns) // 1000,
+                ):
+                    pass
+
             self._note_affinity(metadata)
             try:
                 fn, args, kwargs = await self._prepare_call(
@@ -470,7 +495,9 @@ class Replica:
                 if inspect.isasyncgenfunction(fn):
                     async for item in fn(*args, **kwargs):
                         _note_first()
+                        sent_ns = time.perf_counter_ns()
                         yield item
+                        item_acknowledged(sent_ns)
                     return
                 if inspect.iscoroutinefunction(fn):
                     raise TypeError(
@@ -517,7 +544,9 @@ class Replica:
                     if item is _SENTINEL:
                         return
                     _note_first()
+                    sent_ns = time.perf_counter_ns()
                     yield item
+                    item_acknowledged(sent_ns)
             finally:
                 self._release()
         finally:

@@ -25,6 +25,20 @@ def pytest_configure(config):
     )
 
 
+@pytest.fixture(scope="module")
+def http_port():
+    """A free port for this module's serve proxy. The driver runs the test
+    files in six processes at once, and a request to the default 8000 is
+    answered by whichever file's proxy bound it first: 404 for a route of
+    another file's cluster. Should another process take the port before the
+    proxy binds it, ``serve.start`` raises."""
+    import socket
+
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
 @pytest.fixture
 def ray_start_regular():
     """Single-node cluster, torn down after the test (reference:

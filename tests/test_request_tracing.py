@@ -25,9 +25,12 @@ from ray_tpu.util import watchdog
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(http_port):
+    """Yields the base URL of the cluster's HTTP proxy, on a port of this
+    module's own (``conftest.http_port``)."""
     ray_tpu.init(num_cpus=8, resources={"TPU": 4})
-    yield
+    serve.start(http_port=http_port)
+    yield f"http://127.0.0.1:{http_port}"
     serve.shutdown()
     ray_tpu.shutdown()
 
@@ -86,7 +89,7 @@ def test_http_trace_chain_end_to_end(cluster):
     trace_id = "trace-chain-e2e-test"
     payload = json.dumps({"x": 1}).encode()
     req = urllib.request.Request(
-        "http://127.0.0.1:8000/traced", data=payload,
+        f"{cluster}/traced", data=payload,
         headers={"Content-Type": "application/json",
                  "X-Trace-Id": trace_id},
     )

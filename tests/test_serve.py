@@ -11,9 +11,12 @@ from ray_tpu import serve
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(http_port):
+    """Yields the base URL of the cluster's HTTP proxy, on a port of this
+    module's own (``conftest.http_port``)."""
     ray_tpu.init(num_cpus=6, resources={"TPU": 4})
-    yield
+    serve.start(http_port=http_port)
+    yield f"http://127.0.0.1:{http_port}"
     serve.shutdown()
     ray_tpu.shutdown()
 
@@ -182,7 +185,7 @@ def test_http_proxy_end_to_end(cluster):
     while time.time() < deadline:
         try:
             req = urllib.request.Request(
-                "http://127.0.0.1:8000/echo",
+                f"{cluster}/echo",
                 data=json.dumps({"msg": "hi"}).encode(),
                 headers={"Content-Type": "application/json"},
             )
@@ -194,9 +197,19 @@ def test_http_proxy_end_to_end(cluster):
     assert result == {"result": {"echo": {"msg": "hi"}}}, result
 
     with urllib.request.urlopen(
-        "http://127.0.0.1:8000/-/healthz", timeout=10
+        f"{cluster}/-/healthz", timeout=10
     ) as resp:
         assert json.loads(resp.read())["status"] == "ok"
+
+
+def test_a_proxy_that_cannot_bind_its_port_fails_to_start(cluster):
+    """A second cluster asking for a port that is taken must hear of it: its
+    requests would reach whoever holds the port (here: this module's)."""
+    from ray_tpu.serve.proxy import HTTPProxy
+
+    port = int(cluster.rsplit(":", 1)[1])
+    with pytest.raises(RuntimeError, match="failed to start.*in use"):
+        HTTPProxy(None, "127.0.0.1", port, "http#late")
 
 
 def test_autoscaling_up_and_down(cluster):

@@ -14,9 +14,12 @@ from ray_tpu.serve import DeploymentResponseGenerator
 
 
 @pytest.fixture(scope="module")
-def cluster():
+def cluster(http_port):
+    """Yields the base URL of the cluster's HTTP proxy, on a port of this
+    module's own (``conftest.http_port``)."""
     ray_tpu.init(num_cpus=6, resources={"TPU": 4})
-    yield
+    serve.start(http_port=http_port)
+    yield f"http://127.0.0.1:{http_port}"
     serve.shutdown()
     ray_tpu.shutdown()
 
@@ -97,7 +100,7 @@ def test_http_streaming_ndjson(cluster):
 
     serve.run(SlowTokens.bind(), name="htstream")
     # streaming flag must have reached the controller via auto-detection
-    url = "http://127.0.0.1:8000/htstream"
+    url = f"{cluster}/htstream"
     req = urllib.request.Request(
         url, data=json.dumps({}).encode(), method="POST"
     )
@@ -123,7 +126,7 @@ def test_http_streaming_sse(cluster):
 
     serve.run(SSEGen.bind(), name="ssestream")
     req = urllib.request.Request(
-        "http://127.0.0.1:8000/ssestream",
+        f"{cluster}/ssestream",
         data=b"{}",
         method="POST",
         headers={"Accept": "text/event-stream"},
@@ -197,7 +200,7 @@ def test_asgi_ingress_end_to_end(cluster):
 
     serve.run(ASGIApp.bind(), name="asgiapp")
     req = urllib.request.Request(
-        "http://127.0.0.1:8000/asgiapp/hello",
+        f"{cluster}/asgiapp/hello",
         data=b"ping",
         method="POST",
     )
@@ -208,14 +211,14 @@ def test_asgi_ingress_end_to_end(cluster):
     assert data == {"echo": "ping", "method": "POST", "has_replica": True}
 
     with urllib.request.urlopen(
-        "http://127.0.0.1:8000/asgiapp/stream", timeout=30
+        f"{cluster}/asgiapp/stream", timeout=30
     ) as resp:
         body = resp.read().decode()
     assert body == "part0;part1;part2;done"
 
     with pytest.raises(urllib.error.HTTPError) as err:
         urllib.request.urlopen(
-            "http://127.0.0.1:8000/asgiapp/missing", timeout=30
+            f"{cluster}/asgiapp/missing", timeout=30
         )
     assert err.value.code == 404
 

@@ -6,7 +6,10 @@ options (host tracer level 1, Python tracer off).
 One tiny engine and one profile session serve the whole file: a stepped
 scenario whose counts the test arranges, a scenario held back by a full
 block pool, and three threads that each wait on a ``generate_stream`` while
-the engine's own thread steps.
+the engine's own thread steps. A second tiny engine, built by an
+``_LLMReplica`` inside a serve ``Replica``, streams two answers to consumers
+that take 31 ms an item against steps of a few ms (the way out's spans, on
+the loop's thread), in the same session.
 """
 
 import asyncio
@@ -111,6 +114,43 @@ def _threaded(eng, base, step_s=0.0):
     return out
 
 
+ITEM_S = 0.031  # what a way-out consumer takes over an item
+WAYOUT_NEW = 10
+
+
+def _wayout_replica():
+    """A serve replica around ``_LLMReplica``, as the controller builds it."""
+    from ray_tpu._internal import serialization
+    from ray_tpu.llm import LLMConfig
+    from ray_tpu.llm.serving import _LLMReplica
+    from ray_tpu.serve.replica import Replica
+
+    config = LLMConfig(
+        model_id="llama-tiny", max_seq_len=128, max_batch_size=SLOTS,
+        kv_cache_blocks=8, kv_block_size=BLOCK, seed=0)
+    return Replica("d", "r0", serialization.dumps(_LLMReplica), (config,), {}, None)
+
+
+def _wayout(replica, base, item_s=0.0):
+    """Two streams through ``handle_request_stream``, each consumer pausing
+    ``item_s`` over an item before it asks for the next; returns what each
+    got and how long its stream lived."""
+    async def consume(i):
+        request = {"token_ids": _prompt(base + i), "max_new_tokens": WAYOUT_NEW,
+                   "temperature": 0.0}
+        began = time.perf_counter()
+        items = []
+        async for item in replica.handle_request_stream("stream", (request,), {}, None):
+            items.append(item)
+            await asyncio.sleep(item_s)
+        return items, time.perf_counter() - began
+
+    async def both():
+        return await asyncio.gather(consume(0), consume(1))
+
+    return asyncio.run(both())
+
+
 def _read(logdir):
     """[{name, thread, start, end, stats}] of the host plane's step spans,
     nanoseconds, in start order (outer before inner)."""
@@ -148,6 +188,8 @@ def recorded(tmp_path_factory, no_request_tracing):
     # compile every program the scenarios use, outside the session
     _stepped(eng, 100)
     _blocked(eng, 110, hold_s=0.0)
+    replica = _wayout_replica()
+    _wayout(replica, 130)
     logdir = str(tmp_path_factory.mktemp("step_spans"))
     tracing.clear_spans()
     with tracing.device_profile(logdir):
@@ -157,6 +199,8 @@ def recorded(tmp_path_factory, no_request_tracing):
             held, waited_s = _blocked(eng, 10)
         with tracing.annotate_device_trace("test.threaded"):
             threaded = _threaded(eng, 20, step_s=0.02)
+        with tracing.annotate_device_trace("test.wayout"):
+            wayout = _wayout(replica, 30, item_s=ITEM_S)
     spans = _read(logdir)
 
     def scenario(name):
@@ -164,13 +208,17 @@ def recorded(tmp_path_factory, no_request_tracing):
         return [s for s in spans if mark["start"] <= s["start"]
                 and s["end"] <= mark["end"] and s is not mark]
 
-    return {
+    yield {
         "engine": eng, "stepped": scenario("stepped"),
         "blocked": scenario("blocked"), "threaded": scenario("threaded"),
-        "tokens": {"stepped": stepped, "threaded": threaded},
+        "wayout": scenario("wayout"), "replica": replica,
+        "streamed": wayout,
+        "tokens": {"stepped": stepped, "threaded": threaded,
+                   "wayout": [items for items, _ in wayout]},
         "held": held, "waited_s": waited_s,
         "request_spans": tracing.get_spans(),
     }
+    replica._callable.shutdown()
 
 
 def _parents(spans):
@@ -332,6 +380,71 @@ def test_one_thread_steps_and_no_stream_waits_for_the_lock(recorded):
     assert all(len([d for d in delivers if parent[id(d)] is s]) <= 1 for s in steps)
 
 
+def _way_out_fan_out(spans, recorded):
+    """A step's deliveries reach the loop in one callback, a real region on
+    the loop's thread, which is not the stepping one."""
+    fans = _named(spans, "replica.fan_out")
+    assert fans and all(f["stats"]["post_lag_us"] >= 0 for f in fans)
+    assert all(1 <= f["stats"]["streams"] <= 2 for f in fans)
+    # a row gets a token a step: ten deliveries a stream or fewer
+    assert WAYOUT_NEW <= sum(f["stats"]["streams"] for f in fans) <= 2 * WAYOUT_NEW
+    (loop,) = {f["thread"] for f in fans}
+    assert loop not in {s["thread"] for s in _named(spans, "engine.step")}
+    assert {s["thread"] for s in _named(spans, "replica.stream_end")} == {loop}
+
+
+def _way_out_stream_end(spans, recorded):
+    """Steps of a few ms against 31 ms an item: the engine is done with an
+    answer while most of its tokens still wait for the acknowledgement the
+    coroutine awaits, and the result waits the arranged delay for every item
+    that was ahead of it. Once a request: nothing a token."""
+    ends = _named(spans, "replica.stream_end")
+    assert len(ends) == 2 and len({e["stats"]["request_id"] for e in ends}) == 2
+    assert all(1 <= e["stats"]["tokens"] <= WAYOUT_NEW for e in ends)
+    assert not _named(spans, "replica.stream_take")
+    posted = _named(spans, "replica.fan_out")[-1]["start"]  # the results' post
+    by_stream = defaultdict(list)
+    for s in _named(spans, "replica.stream_item"):
+        by_stream[s["stats"]["stream"]].append(s["start"])
+    # a stream's items acknowledged between that post and the earlier end's
+    # take are 31 ms apart or more, and each end is behind its own
+    ahead = min(sum(posted <= at <= ends[0]["start"] for at in acks)
+                for acks in by_stream.values())
+    assert ahead >= 3
+    assert min(e["stats"]["inbox_wait_us"] for e in ends) >= (ahead - 1) * ITEM_S * 1e6
+
+
+def _way_out_stream_item(spans, recorded):
+    """An item's round trip ends when the consumer asks for the next one."""
+    by_stream = defaultdict(list)
+    for s in _named(spans, "replica.stream_item"):
+        by_stream[s["stats"]["stream"]].append(s["stats"]["rtt_us"])
+    assert len(by_stream) == 2
+    # the consumer that began first was let in first
+    for (items, lived_s), (_, rtts) in zip(recorded["streamed"], sorted(by_stream.items())):
+        assert len(rtts) == len(items) == WAYOUT_NEW + 1  # tokens and the summary
+        assert min(rtts) >= 30_000
+        assert sum(rtts) <= lived_s * 1e6
+
+
+def _way_out_stream_open(spans, recorded):
+    """Once a request, when the replica's admission let it in."""
+    opened = [s["stats"] for s in _named(spans, "replica.stream_open")]
+    assert len(opened) == 2 and len({o["stream"] for o in opened}) == 2
+    assert {o["stream"] for o in opened} == {
+        s["stats"]["stream"] for s in _named(spans, "replica.stream_item")}
+    assert all(0 <= o["admit_wait_us"] < 1_000_000 for o in opened)
+
+
+@pytest.mark.parametrize("holds", [
+    _way_out_fan_out, _way_out_stream_end, _way_out_stream_item,
+    _way_out_stream_open], ids=lambda f: f.__name__[len("_way_out_"):])
+def test_the_way_out_has_its_spans(recorded, holds):
+    spans = recorded["wayout"]
+    _parents(spans)  # the loop's regions nest in nothing falsely
+    holds(spans, recorded)
+
+
 def test_without_a_session_the_same_tokens_and_no_request_span(recorded):
     # nothing recorded a wall-clock request span while the profiler ran
     assert recorded["request_spans"] == []
@@ -339,6 +452,9 @@ def test_without_a_session_the_same_tokens_and_no_request_span(recorded):
     tracing.clear_spans()
     assert _stepped(eng, 0) == recorded["tokens"]["stepped"]
     assert _threaded(eng, 20) == recorded["tokens"]["threaded"]
+    # the way out's regions are no-ops: the same items, nothing recorded
+    assert [items for items, _ in _wayout(recorded["replica"], 30)] == (
+        recorded["tokens"]["wayout"])
     assert tracing.get_spans() == []
     # a fresh engine stepped alone agrees token for token
     assert _stepped(_engine(), 0)[:1] == recorded["tokens"]["stepped"][:1]
@@ -407,6 +523,14 @@ def test_replica_stream_next_counts_the_wait_for_a_pool_thread(tmp_path):
     assert min(s["stats"]["executor_wait_us"] for s in first) < 20_000
     assert max(s["stats"]["executor_wait_us"] for s in later) >= 20_000
     assert len({s["thread"] for s in later}) == 8
+    # the pool-driven branch counts an item's way out too, on the loop's
+    # thread: three a stream, none for the end of a stream
+    items = _named(spans, "replica.stream_item")
+    assert len(items) == 13 * 3
+    assert len({s["stats"]["stream"] for s in items}) == 13
+    assert all(s["stats"]["rtt_us"] >= 0 for s in items)
+    assert not {s["thread"] for s in items} & {s["thread"] for s in nexts}
+    assert len(_named(spans, "replica.stream_open")) == 13
 
 
 def test_a_process_without_jax_is_not_made_to_import_it():
