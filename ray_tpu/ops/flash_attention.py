@@ -11,9 +11,22 @@ Design (flash-attention-2 schedule):
   softmax; causal blocks beyond the diagonal are predicated off
 - backward: recompute P per block from the saved LSE (no S×S residuals);
   one kernel for dq (grid over q blocks) and one for dk/dv (grid over k
-  blocks)
-- everything MXU-shaped: 128-aligned blocks, matmuls in f32 accumulate
-  (preferred_element_type), bf16-friendly inputs
+  blocks, scores computed transposed so no tile is ever transposed)
+- tiles are large (``default_blocks``: a grid step costs what it costs
+  whatever it does) and worked through ``_CHUNK`` columns at a time, so the
+  f32 score temporaries stay small and one chunk's matmuls overlap
+  another's softmax; in a square tile on the causal diagonal each chunk
+  takes only the q rows at or below it
+- what a tile gives the MXU: operands in the *input's* dtype (bf16 callers
+  get single-pass bf16 matmuls, f32 callers keep f32 operands), f32
+  accumulation (preferred_element_type). p and ds are cast to the input
+  dtype just before their matmuls, as the activations they multiply are
+- what a tile asks of the VPU: the softmax (max, exp, sum, lse, delta,
+  accumulators, rescale) in f32, and nothing a tile does not need: the
+  causal mask only in tiles the diagonal crosses, the padding mask only
+  when a length is no multiple of its block (static), ``sm_scale`` on the
+  accumulated dq and dk instead of on every ds, row statistics replicated
+  over the 128 lanes
 """
 
 from __future__ import annotations
@@ -28,12 +41,131 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+_LANES = 128
+# columns of a tile that one pass of a kernel's (unrolled) inner loop handles
+_CHUNK = 256
+# contract the last axis of both operands (a @ b.T), and the plain a @ b
+_NT = (((1,), (1,)), ((), ()))
+_NN = (((1,), (0,)), ((), ()))
+
+
+def _chunks(n: int):
+    """(start, width) of the column chunks of a tile side of n: the f32
+    score temporaries are (rows, _CHUNK) instead of (rows, n), and the
+    matmuls of one chunk overlap the softmax of another. A side that is no
+    multiple of _CHUNK (a whole short sequence) is one chunk."""
+    w = _CHUNK if n % _CHUNK == 0 else n
+    return [(c, w) for c in range(0, n, w)]
+
+
+def _skips_above(masked: bool, causal: bool, pad: bool, block_q, block_k):
+    """In a square tile on the diagonal (the only masked tiles of a causal
+    call with block_q == block_k and no padding) chunk c needs only the q
+    rows from c on: the rest of its columns is above the diagonal. With
+    2048-wide tiles and 256-wide chunks that leaves 6% of the executed
+    scores masked, as 256 x 256 tiles would, in 1/64 of the grid steps."""
+    return masked and causal and not pad and block_q == block_k
 
 
 def _use_interpret() -> bool:
     from ray_tpu._internal.platform import pallas_interpret
 
     return pallas_interpret("flash_attention")
+
+
+def default_blocks(
+    seq_q: int, seq_k: int, head_dim: int, dtype, causal: bool = True
+) -> Tuple[int, int]:
+    """(block_q, block_k) for a call nobody gave tiles: chosen from the shape
+    alone, never by timing (set-up time is a judged metric).
+
+    A grid step costs ~0.2 us whatever it does, and what a tile does once
+    (rescale the accumulator, write it back, the pipeline's bookkeeping)
+    is paid a step, so tiles are as large as VMEM allows and the work
+    inside one is done ``_CHUNK`` columns at a time: 2048 x 2048 for a
+    causal call, 1024 x 1024 for a call without a diagonal to skip above
+    (its tiles keep every row in every chunk), both for a head row of at
+    most 256 bytes (128 wide in bf16) and halved for each doubling of it,
+    which keeps every call inside the 16 MiB of VMEM a kernel gets.
+    A side of at most 1024 is one tile with no padding; a longer one takes
+    the largest such tile that divides it (down to 512) and pads, at 1024,
+    only when none does. Measured at (64, 4096, 128) bf16 causal
+    on a v5e, a layer's four calls (PERF.md, PR 35): 256 x 256 31.3 ms,
+    1024 x 1024 14.4, 2048 x 2048 in chunks 10.8.
+    """
+    row_bytes = max(head_dim, _LANES) * jnp.dtype(dtype).itemsize
+    most = 2048 if causal and seq_q == seq_k else 1024
+    most = max(512, most * 256 // max(256, row_bytes) // 512 * 512)
+
+    def side(seq: int) -> int:
+        if seq <= min(most, 1024):
+            return seq
+        for block in (most, most // 2, most // 4):
+            if block >= 512 and seq % block == 0:
+                return block
+        return min(most, 1024)
+
+    return side(seq_q), side(seq_k)
+
+
+def _resolve_blocks(q, k, causal, block_q, block_k) -> Tuple[int, int]:
+    auto_q, auto_k = default_blocks(
+        q.shape[1], k.shape[1], q.shape[2], q.dtype, causal
+    )
+    return (
+        auto_q if block_q is None else min(block_q, q.shape[1]),
+        auto_k if block_k is None else min(block_k, k.shape[1]),
+    )
+
+
+def _lanes(x, n: int):
+    """(rows, _LANES) with every lane of a row equal -> (rows, n)."""
+    if n == _LANES:
+        return x
+    if n % _LANES == 0:
+        return jnp.concatenate([x] * (n // _LANES), axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _mask_scores(s, q_axis: int, causal: bool, q0, k0, k_left=None):
+    """Scores with q positions from ``q0`` along ``q_axis`` and k positions
+    from ``k0`` along the other axis, -inf where the key comes after the
+    query (``causal``) or lies past the sequence's end (``k_left``: the k
+    positions of this tile that exist; None when none is padding). A
+    subtract and a compare against a scalar, not two position tiles."""
+    k_idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    valid = None
+    if causal:  # q_pos >= k_pos
+        q_idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+        valid = k_idx - q_idx <= q0 - k0
+    if k_left is not None:
+        valid = k_idx < k_left if valid is None else valid & (k_idx < k_left)
+    return s if valid is None else jnp.where(valid, s, _NEG_INF)
+
+
+def _tile_kinds(causal: bool, q_idx, k_idx, block_q: int, block_k: int, edge):
+    """(plain, masked) predicates of grid tile (q_idx, k_idx): a tile runs
+    the masked body iff the causal diagonal crosses it or it is an ``edge``
+    tile holding padding (``edge`` is None when there is no padding); a
+    causal tile wholly above the diagonal runs nothing. ``None`` for a
+    predicate that is statically true/false."""
+    if causal:
+        runs = q_idx * block_q + block_q - 1 >= k_idx * block_k
+        below = q_idx * block_q >= k_idx * block_k + block_k - 1
+        if edge is None:
+            return below, runs & ~below
+        return below & ~edge, runs & (~below | edge)
+    if edge is None:
+        return None, False
+    return ~edge, edge
+
+
+def _run_tiles(body, plain, masked):
+    if plain is None:
+        body(False)
+        return
+    pl.when(plain)(lambda: body(False))
+    pl.when(masked)(lambda: body(True))
 
 
 # ---------------------------------------------------------------------------
@@ -50,6 +182,8 @@ def _fwd_kernel(
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    pad_k = seq_k % block_k != 0
+    d = acc_ref.shape[1]
 
     @pl.when(ki == 0)
     def _init():
@@ -57,56 +191,49 @@ def _fwd_kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _body():
-        q = q_ref[0].astype(jnp.float32)  # (block_q, d)
-        k = k_ref[0].astype(jnp.float32)  # (block_k, d)
-        v = v_ref[0].astype(jnp.float32)  # (block_k, d)
-        # zero padding rows: their probabilities are masked to 0, but the
-        # uninitialized pad values would still poison matmuls via 0*NaN
-        k_row = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0
-        )
-        v = jnp.where(k_row < seq_k, v, 0.0)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # (block_q, block_k)
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        # padding rows/cols beyond the true lengths must not contribute
-        valid = k_pos < seq_k
-        if causal:
-            valid = valid & (q_pos >= k_pos)
-        s = jnp.where(valid, s, _NEG_INF)
-        m_prev = m_ref[...]  # (block_q, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)  # (block_q, block_k)
-        alpha = jnp.exp(m_prev - m_new)  # (block_q, 1)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
+    def _body(masked: bool):
+        tri = _skips_above(masked, causal, pad_k, block_q, block_k)
+        for c, w in _chunks(block_k):
+            r0 = c if tri else 0
+            rows = slice(r0, block_q)
+            k = k_ref[0, c:c + w, :]  # (w, d)
+            v = v_ref[0, c:c + w, :]
+            s = jax.lax.dot_general(
+                q_ref[0, rows, :], k, _NT, preferred_element_type=jnp.float32
+            ) * sm_scale  # (rows, w)
+            if masked:
+                k_left = seq_k - ki * block_k - c if pad_k else None
+                s = _mask_scores(
+                    s, 0, causal, qi * block_q + r0, ki * block_k + c, k_left
+                )
+                if pad_k:
+                    # the pad rows of v are uninitialized, and 0 * NaN would
+                    # poison the matmul
+                    k_row = jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0)
+                    v = jnp.where(k_row < k_left, v, 0)
+            m_prev = m_ref[rows, :]  # (rows, _LANES), lanes equal
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, w))
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(
+                p, axis=1, keepdims=True
+            )
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32
+            )
+            acc_ref[rows, :] = acc_ref[rows, :] * _lanes(alpha, d) + pv
+            m_ref[rows, :] = m_new
 
-    if causal:
-        # whole block above the diagonal: skip
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _body()
-    else:
-        _body()
+    edge = (ki == nk - 1) if pad_k else None
+    _run_tiles(_body, *_tile_kinds(causal, qi, ki, block_q, block_k, edge))
 
     @pl.when(ki == nk - 1)
     def _finish():
         l = l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] * _lanes(1.0 / l_safe, d)).astype(o_ref.dtype)
         # log-sum-exp per q row, used by backward and ring merging
-        lse_ref[0] = m_ref[...] + jnp.log(l_safe)
+        lse_ref[0] = (m_ref[...] + jnp.log(l_safe))[:, :1]
 
 
 def _causal_kv_index(block_q: int, block_k: int):
@@ -123,35 +250,36 @@ def _causal_kv_index(block_q: int, block_k: int):
     return index_map
 
 
+def _kv_index(causal: bool, sq: int, sk: int, block_q: int, block_k: int):
+    if causal and sq == sk:
+        return _causal_kv_index(block_q, block_k)
+    return lambda b, i, j: (b, j, 0)
+
+
+# no vmem_limit_bytes: default_blocks keeps a call inside the compiler's 16
+# MiB (a 2048 x 2048 bf16 call takes 11-15.6 of them, as much as it is left),
+# and a call that reserves 20 or 32 MiB slows the step's own fusions around
+# it by 0.8% of the step (PERF.md, PR 35)
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+)
+
+
 def _flash_forward(
-    q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int
+    q, k, v, sm_scale: float, causal: bool,
+    block_q: Optional[int], block_k: Optional[int],
 ) -> Tuple[jax.Array, jax.Array]:
     bh, sq, d = q.shape
     _, sk, _ = k.shape
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    grid = (bh, pl.cdiv(sq, block_q), pl.cdiv(sk, block_k))
-    out_shape = [
-        jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
-    ]
-    kernel = functools.partial(
-        _fwd_kernel,
-        sm_scale=sm_scale,
-        causal=causal,
-        block_q=block_q,
-        block_k=block_k,
-        seq_q=sq,
-        seq_k=sk,
-    )
-    kv_index = (
-        _causal_kv_index(block_q, block_k)
-        if causal and sq == sk
-        else (lambda b, i, j: (b, j, 0))
-    )
+    block_q, block_k = _resolve_blocks(q, k, causal, block_q, block_k)
+    kv_index = _kv_index(causal, sq, sk, block_q, block_k)
     o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(
+            _fwd_kernel,
+            sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
+            seq_q=sq, seq_k=sk,
+        ),
+        grid=(bh, pl.cdiv(sq, block_q), pl.cdiv(sk, block_k)),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_index),
@@ -163,11 +291,16 @@ def _flash_forward(
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        out_shape=out_shape,
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+        ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=_use_interpret(),
+        name="flash_fwd",  # the op's name in a device trace
     )(q, k, v)
     return o, lse
 
@@ -179,62 +312,58 @@ def _flash_forward(
 
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    acc_ref,
+    lse_s, delta_s, acc_ref,
     *, sm_scale: float, causal: bool, block_q: int, block_k: int,
     seq_q: int, seq_k: int,
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    pad_k = seq_k % block_k != 0
 
     @pl.when(ki == 0)
     def _init():
+        # resident for the whole k loop: the row statistics are spread
+        # over the lanes once
+        lse_s[...] = jnp.broadcast_to(lse_ref[0], lse_s.shape)
+        delta_s[...] = jnp.broadcast_to(delta_ref[0], delta_s.shape)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        k_row = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0
-        )
-        k = jnp.where(k_row < seq_k, k, 0.0)
-        v = jnp.where(k_row < seq_k, v, 0.0)
-        lse = lse_ref[0]  # (block_q, 1)
-        delta = delta_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        valid = (k_pos < seq_k) & (q_pos < seq_q)
-        if causal:
-            valid = valid & (q_pos >= k_pos)
-        s = jnp.where(valid, s, _NEG_INF)
-        p = jnp.exp(s - lse)  # (block_q, block_k)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * sm_scale
-        acc_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    def _body(masked: bool):
+        tri = _skips_above(masked, causal, pad_k, block_q, block_k)
+        for c, w in _chunks(block_k):
+            r0 = c if tri else 0
+            rows = slice(r0, block_q)
+            k = k_ref[0, c:c + w, :]
+            v = v_ref[0, c:c + w, :]
+            do = do_ref[0, rows, :]
+            k_left = seq_k - ki * block_k - c if masked and pad_k else None
+            if k_left is not None:
+                k_row = jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0)
+                k = jnp.where(k_row < k_left, k, 0)
+                v = jnp.where(k_row < k_left, v, 0)
+            s = jax.lax.dot_general(
+                q_ref[0, rows, :], k, _NT, preferred_element_type=jnp.float32
+            ) * sm_scale
+            if masked:
+                s = _mask_scores(
+                    s, 0, causal, qi * block_q + r0, ki * block_k + c, k_left
+                )
+            p = jnp.exp(s - _lanes(lse_s[rows, :], w))  # (rows, w)
+            dp = jax.lax.dot_general(
+                do, v, _NT, preferred_element_type=jnp.float32
+            )
+            ds = p * (dp - _lanes(delta_s[rows, :], w))  # sm_scale: at the end
+            acc_ref[rows, :] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32
+            )
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _body()
-    else:
-        _body()
+    edge = (ki == nk - 1) if pad_k else None
+    _run_tiles(_body, *_tile_kinds(causal, qi, ki, block_q, block_k, edge))
 
     @pl.when(ki == nk - 1)
     def _finish():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_ref[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
@@ -243,78 +372,74 @@ def _bwd_dkv_kernel(
     *, sm_scale: float, causal: bool, block_q: int, block_k: int,
     seq_q: int, seq_k: int,
 ):
+    """Scores are computed transposed, (block_k, block_q), so that p^T @ dO
+    and ds^T @ q are plain matmuls; lse and delta arrive as rows."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
+    pad_q = seq_q % block_q != 0
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        q_row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0
-        )
-        q = jnp.where(q_row < seq_q, q, 0.0)
-        do = jnp.where(q_row < seq_q, do, 0.0)
-        # padded lse/delta rows are uninitialized reads; exp(-inf - NaN)=NaN
-        lse = jnp.where(q_row < seq_q, lse_ref[0], 0.0)
-        delta = jnp.where(q_row < seq_q, delta_ref[0], 0.0)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        valid = (k_pos < seq_k) & (q_pos < seq_q)
-        if causal:
-            valid = valid & (q_pos >= k_pos)
-        s = jnp.where(valid, s, _NEG_INF)
-        p = jnp.exp(s - lse)  # (block_q, block_k)
-        dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * sm_scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    def _body(masked: bool):
+        tri = _skips_above(masked, causal, pad_q, block_q, block_k)
+        for c, w in _chunks(block_q):
+            r1 = c + w if tri else block_k  # k rows [0, r1) see q rows [c, c+w)
+            rows = slice(0, r1)
+            q = q_ref[0, c:c + w, :]
+            do = do_ref[0, c:c + w, :]
+            lse = lse_ref[0, :, c:c + w]
+            delta = delta_ref[0, :, c:c + w]
+            if masked and pad_q:
+                # padded q rows are uninitialized reads: as zeros (lse and
+                # delta too) they give p = 1 against dO = 0, and ds = 0
+                q_left = seq_q - qi * block_q - c
+                q_row = jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0)
+                q = jnp.where(q_row < q_left, q, 0)
+                do = jnp.where(q_row < q_left, do, 0)
+                q_col = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+                lse = jnp.where(q_col < q_left, lse, 0.0)
+                delta = jnp.where(q_col < q_left, delta, 0.0)
+            st = jax.lax.dot_general(
+                k_ref[0, rows, :], q, _NT, preferred_element_type=jnp.float32
+            ) * sm_scale  # (rows, w)
+            if masked:
+                st = _mask_scores(
+                    st, 1, causal, qi * block_q + c, ki * block_k
+                )
+            pt = jnp.exp(st - lse)
+            dv_acc[rows, :] += jax.lax.dot_general(
+                pt.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32
+            )
+            dpt = jax.lax.dot_general(
+                v_ref[0, rows, :], do, _NT, preferred_element_type=jnp.float32
+            )
+            dst = pt * (dpt - delta)  # sm_scale: at the end
+            dk_acc[rows, :] += jax.lax.dot_general(
+                dst.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32
+            )
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _body()
-    else:
-        _body()
+    edge = (qi == nq - 1) if pad_q else None
+    _run_tiles(_body, *_tile_kinds(causal, qi, ki, block_q, block_k, edge))
 
     @pl.when(qi == nq - 1)
     def _finish():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def flash_bwd_dq(q, k, v, do, lse, delta, *, sm_scale, causal, block_q=256, block_k=256):
+def flash_bwd_dq(
+    q, k, v, do, lse, delta, *, sm_scale, causal, block_q=None, block_k=None
+):
     """dq for one (q-block, kv-block) pairing; reused by ring attention.
     lse/delta: (bh, sq, 1) f32."""
     bh, sq, d = q.shape
     _, sk, _ = k.shape
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    kv_index = (
-        _causal_kv_index(block_q, block_k)
-        if causal and sq == sk
-        else (lambda b, i, j: (b, j, 0))
-    )
+    block_q, block_k = _resolve_blocks(q, k, causal, block_q, block_k)
+    kv_index = _kv_index(causal, sq, sk, block_q, block_k)
     return pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel,
@@ -331,30 +456,46 @@ def flash_bwd_dq(q, k, v, do, lse, delta, *, sm_scale, causal, block_q=256, bloc
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+        ],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_COMPILER_PARAMS,
         interpret=_use_interpret(),
+        name="flash_bwd_dq",  # the op's name in a device trace
     )(q, k, v, do, lse, delta)
 
 
-def flash_bwd_dkv(q, k, v, do, lse, delta, *, sm_scale, causal, block_q=256, block_k=256):
+def flash_bwd_dkv(
+    q, k, v, do, lse, delta, *, sm_scale, causal, block_q=None, block_k=None
+):
     """dk/dv contribution of one q shard to one kv shard; reused by ring
     attention. lse/delta: (bh, sq, 1) f32."""
     bh, sq, d = q.shape
     _, sk, _ = k.shape
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    block_q, block_k = _resolve_blocks(q, k, causal, block_q, block_k)
     if causal and sq == sk:
         # mirror of _causal_kv_index: early q blocks entirely above the
         # diagonal are compute-skipped; clamp their loads to the first
         # contributing q block so the repeated index elides the copy
-        def q_index(b, j, i):
-            first = (j * block_k) // block_q
-            return (b, jnp.maximum(i, first), 0)
+        def first(j):
+            return (j * block_k) // block_q
     else:
-        def q_index(b, j, i):
-            return (b, i, 0)
+        def first(j):
+            return 0
 
+    def q_index(b, j, i):
+        return (b, jnp.maximum(i, first(j)), 0)
+
+    def row_index(b, j, i):
+        return (b, 0, jnp.maximum(i, first(j)))
+
+    # the kernel's scores have q along the lanes: hand it lse and delta as
+    # rows (a reshape of (bh, sq, 1), no data moves)
+    lse = lse.reshape(bh, 1, sq)
+    delta = delta.reshape(bh, 1, sq)
     return pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel,
@@ -367,8 +508,8 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, sm_scale, causal, block_q=256, blo
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_q, d), q_index),
-            pl.BlockSpec((1, block_q, 1), q_index),
-            pl.BlockSpec((1, block_q, 1), q_index),
+            pl.BlockSpec((1, 1, block_q), row_index),
+            pl.BlockSpec((1, 1, block_q), row_index),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -382,7 +523,9 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, sm_scale, causal, block_q=256, blo
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=_use_interpret(),
+        name="flash_bwd_dkv",  # the op's name in a device trace
     )(q, k, v, do, lse, delta)
 
 
@@ -438,11 +581,12 @@ def flash_attention_with_lse(
     *,
     causal: bool = True,
     sm_scale: Optional[float] = None,
-    block_q: int = 256,
-    block_k: int = 256,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Attention over (batch, heads, seq, head_dim); also returns per-row
-    log-sum-exp (batch, heads, seq) for ring-step merging."""
+    log-sum-exp (batch, heads, seq) for ring-step merging. Tiles nobody
+    names are ``default_blocks`` of the shape."""
     b, h, sq, d = q.shape
     _, hk, sk, _ = k.shape
     if h != hk:  # grouped-query attention: repeat kv heads

@@ -135,12 +135,18 @@ def _assert_rows_written_by_the_kernel(text, layers, pool):
         assert not re.search(rf"= \w+{leaf}\S* dynamic-update-slice\(", text)
 
 
-def test_flash_attention_forward_and_backward_compile(v5e_chip, native_kernels):
+# Llama-2-7B's heads at chip_smoke.py's length, and what one chip of the
+# `mistral7b-lora-fsdp4` cell is handed a layer (2 x 4096 tokens, 32 heads
+# after the GQA repeat): its default tiles are 1024 x 1024
+@pytest.mark.parametrize(
+    "shape", [(1, 32, 2048, 128), (2, 32, 4096, 128)], ids=["s2048", "lora4096"]
+)
+def test_flash_attention_forward_and_backward_compile(
+    v5e_chip, native_kernels, shape
+):
     from ray_tpu.ops.flash_attention import flash_attention
 
-    qkv = jax.ShapeDtypeStruct(
-        (1, 32, 2048, 128), jnp.bfloat16, sharding=v5e_chip
-    )
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e_chip)
 
     def forward(q, k, v):
         return flash_attention(q, k, v, causal=True)
@@ -150,9 +156,14 @@ def test_flash_attention_forward_and_backward_compile(v5e_chip, native_kernels):
             lambda *a: forward(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
         )(q, k, v)
 
-    assert "tpu_custom_call" in _compile(forward, qkv, qkv, qkv)
-    # dq and dk/dv are separate kernels, after the recomputed forward
-    assert _compile(backward, qkv, qkv, qkv).count("tpu_custom_call") >= 3
+    text = _compile(forward, qkv, qkv, qkv)
+    assert "tpu_custom_call" in text and "flash_fwd" in text
+    # dq and dk/dv are separate kernels, after the recomputed forward; a
+    # device trace lists each under its name
+    text = _compile(backward, qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") >= 3
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in text, name
 
 
 def test_rmsnorm_compiles(v5e_chip, native_kernels):

@@ -40,53 +40,64 @@ def test_logical_to_spec():
     assert logical_to_spec((None, "mlp")) == P(None, "tp")
 
 
-def test_ring_attention_matches_reference():
+def _ring(mesh):
+    return jax.jit(
+        shard_map(
+            lambda q, k, v: ring_attention(q, k, v, axis_name="sp"),
+            mesh=mesh,
+            in_specs=(P(None, None, "sp", None),) * 3,
+            out_specs=P(None, None, "sp", None),
+            check_vma=False,
+        )
+    )
+
+
+# bf16: the kernels hand the MXU bf16 operands (p and ds rounded to 2^-9
+# relative, tests/test_ops.py), the reference is float32 math on the same
+# inputs; sp = 4 runs the non-causal body under lax.cond three times a rank
+DTYPES = pytest.mark.parametrize(
+    "dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"]
+)
+
+
+@DTYPES
+def test_ring_attention_matches_reference(dtype):
     devs = np.array(jax.devices()[:4]).reshape(4)
     mesh = Mesh(devs, ("sp",))
     b, h, s, d = 2, 2, 256, 32
     q, k, v = (
-        jax.random.normal(jax.random.PRNGKey(i), (b, h, s, d), jnp.float32)
+        jax.random.normal(jax.random.PRNGKey(i), (b, h, s, d)).astype(dtype)
         for i in range(3)
     )
-    ring = jax.jit(
-        shard_map(
-            lambda q, k, v: ring_attention(q, k, v, axis_name="sp"),
-            mesh=mesh,
-            in_specs=(P(None, None, "sp", None),) * 3,
-            out_specs=P(None, None, "sp", None),
-            check_vma=False,
-        )
+    out = _ring(mesh)(q, k, v)
+    assert out.dtype == dtype
+    ref = reference_attention(
+        *(x.astype(jnp.float32) for x in (q, k, v)), causal=True
     )
-    out = ring(q, k, v)
-    ref = reference_attention(q, k, v, causal=True)
     assert float(jnp.abs(out - ref).max()) < 2e-2
 
 
-def test_ring_attention_grads_match():
+@DTYPES
+def test_ring_attention_grads_match(dtype):
     devs = np.array(jax.devices()[:4]).reshape(4)
     mesh = Mesh(devs, ("sp",))
     b, h, s, d = 1, 2, 256, 32
     q, k, v = (
-        jax.random.normal(jax.random.PRNGKey(i), (b, h, s, d), jnp.float32)
+        jax.random.normal(jax.random.PRNGKey(i), (b, h, s, d)).astype(dtype)
         for i in range(3)
     )
-    ring = jax.jit(
-        shard_map(
-            lambda q, k, v: ring_attention(q, k, v, axis_name="sp"),
-            mesh=mesh,
-            in_specs=(P(None, None, "sp", None),) * 3,
-            out_specs=P(None, None, "sp", None),
-            check_vma=False,
-        )
-    )
-    g1 = jax.grad(lambda q, k, v: (ring(q, k, v) ** 2).sum(), argnums=(0, 1, 2))(
-        q, k, v
-    )
+    ring = _ring(mesh)
+    g1 = jax.grad(
+        lambda q, k, v: (ring(q, k, v).astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1, 2),
+    )(q, k, v)
     g2 = jax.grad(
         lambda q, k, v: (reference_attention(q, k, v, causal=True) ** 2).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
+    )(*(x.astype(jnp.float32) for x in (q, k, v)))
     for a, b_ in zip(g1, g2):
+        assert a.dtype == dtype
+        a = a.astype(jnp.float32)
         rel = float(jnp.abs(a - b_).max()) / (float(jnp.abs(b_).max()) + 1e-9)
         assert rel < 2e-2, rel
 

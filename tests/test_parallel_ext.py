@@ -28,16 +28,9 @@ pytestmark = pytest.mark.skipif(
 )
 
 
-def test_ulysses_matches_reference():
-    devs = np.array(jax.devices()[:4]).reshape(4)
-    mesh = Mesh(devs, ("sp",))
-    b, h, s, d = 2, 4, 128, 16
-    q, k, v = (
-        jax.random.normal(jax.random.PRNGKey(i), (b, h, s, d), jnp.float32)
-        for i in range(3)
-    )
+def _ulysses(mesh):
     spec = P(None, None, "sp", None)
-    out = jax.jit(
+    return jax.jit(
         shard_map(
             lambda q, k, v: ulysses_attention(q, k, v, axis_name="sp"),
             mesh=mesh,
@@ -45,9 +38,57 @@ def test_ulysses_matches_reference():
             out_specs=spec,
             check_vma=False,
         )
+    )
+
+
+# bf16: the flash kernels' MXU operands are the input's dtype; the reference
+# is float32 math on the same inputs (tolerance: tests/test_ops.py)
+DTYPES = pytest.mark.parametrize(
+    "dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"]
+)
+
+
+@DTYPES
+def test_ulysses_matches_reference(dtype):
+    devs = np.array(jax.devices()[:4]).reshape(4)
+    mesh = Mesh(devs, ("sp",))
+    b, h, s, d = 2, 4, 128, 16
+    q, k, v = (
+        jax.random.normal(jax.random.PRNGKey(i), (b, h, s, d)).astype(dtype)
+        for i in range(3)
+    )
+    out = _ulysses(mesh)(q, k, v)
+    assert out.dtype == dtype
+    ref = reference_attention(
+        *(x.astype(jnp.float32) for x in (q, k, v)), causal=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(out.astype(jnp.float32)), np.asarray(ref), atol=2e-2
+    )
+
+
+@DTYPES
+def test_ulysses_grads_match(dtype):
+    devs = np.array(jax.devices()[:4]).reshape(4)
+    mesh = Mesh(devs, ("sp",))
+    b, h, s, d = 1, 4, 128, 16
+    q, k, v = (
+        jax.random.normal(jax.random.PRNGKey(i), (b, h, s, d)).astype(dtype)
+        for i in range(3)
+    )
+    attn = _ulysses(mesh)
+    g1 = jax.grad(
+        lambda q, k, v: (attn(q, k, v).astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1, 2),
     )(q, k, v)
-    ref = reference_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2)
+    g2 = jax.grad(
+        lambda q, k, v: (reference_attention(q, k, v, causal=True) ** 2).sum(),
+        argnums=(0, 1, 2),
+    )(*(x.astype(jnp.float32) for x in (q, k, v)))
+    for a, b_ in zip(g1, g2):
+        a = a.astype(jnp.float32)
+        rel = float(jnp.abs(a - b_).max()) / (float(jnp.abs(b_).max()) + 1e-9)
+        assert rel < 2e-2, rel
 
 
 def test_ulysses_gqa():
