@@ -128,47 +128,74 @@ def _merge_last(sampled, fresh, first):
 _span = _tracing.annotate_device_trace
 
 
-def _new_expert_counts(model_config) -> Optional[dict]:
+def _new_expert_counts(model_config, rows: int = 0) -> Optional[dict]:
     """Zeroed device-side counters for a model with routed experts, None
     for one without: ``steps`` decode steps, ``assignments`` (routed
-    layers, experts) choices made by live rows, ``touched`` (routed
-    layers,) the sum over steps of distinct experts live rows chose. A row
-    a layer that has routed experts: every layer, unless the config names
-    them (``routed_layers``; a dense layer has nothing to count). int32: at
-    8 choices a row and 50 steps a second an expert's count lasts two
-    months."""
+    layers, experts held) choices made by live rows, ``touched`` (routed
+    layers,) the sum over steps of distinct held experts live rows chose. A
+    row a layer that has routed experts: every layer, unless the config
+    names them (``routed_layers``; a dense layer has nothing to count).
+    int32: at 8 choices a row and 50 steps a second an expert's count lasts
+    two months.
+
+    A config that holds a share of its experts (``experts_held``, a
+    ``(first, stop)`` range of the ``n_experts`` routed over) counts over
+    the experts held and has two entries more: ``absent`` (routed layers,),
+    live rows' choices that fell on experts held elsewhere, and ``choice``
+    (routed layers, ``rows``, k), the last step's choice a row over all
+    ``n_experts`` (free rows' too): what a check that follows the
+    program's routing reads where more than one row is live, since a sum
+    over rows names no row. A family that holds all its experts has
+    neither, and its step is the program it was."""
     n_experts = getattr(model_config, "n_experts", 0)
     if not n_experts:
         return None
     layers = len(getattr(
         model_config, "routed_layers", range(model_config.n_layers)
     ))
-    return {
+    held = getattr(model_config, "experts_held", None)
+    counts = {
         "steps": jnp.zeros((), jnp.int32),
-        "assignments": jnp.zeros((layers, n_experts), jnp.int32),
+        "assignments": jnp.zeros(
+            (layers, held[1] - held[0] if held else n_experts), jnp.int32),
         "touched": jnp.zeros((layers,), jnp.int32),
     }
+    if held:
+        counts["absent"] = jnp.zeros((layers,), jnp.int32)
+        counts["choice"] = jnp.zeros(
+            (layers, rows, model_config.experts_per_token), jnp.int32)
+    return counts
 
 
-def _count_experts(counts: dict, routing: dict, active) -> dict:
+def _count_experts(counts: dict, routing: dict, active,
+                   first: int = 0) -> dict:
     """``counts`` plus one decode step's choices (``routing``: the sown
     collection, ``layer_<i>/moe/experts`` a tuple of one (rows, k) array
     for each layer that routes, taken in layer order), free rows left
-    out."""
+    out. ``first``: the first expert held (``_new_expert_counts``)."""
     n_experts = counts["assignments"].shape[1]
     step = jnp.stack([
         routing[name]["moe"]["experts"][0]
         for name in sorted(routing, key=lambda n: int(n.rpartition("_")[2]))
     ])  # (routed layers, rows, k)
-    hits = jax.nn.one_hot(step, n_experts, dtype=jnp.int32)
+    # (an expert held elsewhere is no column of these: a row of zeros)
+    hits = jax.nn.one_hot(
+        step - first if first else step, n_experts, dtype=jnp.int32)
     if active is not None:
         hits = hits * jnp.asarray(active, jnp.int32)[None, :, None, None]
     per_expert = hits.sum(axis=(1, 2))  # (layers, experts)
-    return {
+    out = {
         "steps": counts["steps"] + 1,
         "assignments": counts["assignments"] + per_expert,
         "touched": counts["touched"] + (per_expert > 0).sum(axis=1),
     }
+    if "absent" in counts:
+        live = step.shape[2] * (
+            step.shape[1] if active is None
+            else jnp.sum(jnp.asarray(active, jnp.int32)))
+        out["absent"] = counts["absent"] + live - per_expert.sum(axis=1)
+        out["choice"] = step
+    return out
 
 
 class _EngineLock:
@@ -314,6 +341,7 @@ class _DecodeModelBase:
         from .. import models
 
         self._cfg = model_config
+        self._zeroes_free_state = not models.restarts_own_state(model_config)
         self._mesh = mesh
         # multi-tenant LoRA slot bank (ray_tpu.lora.AdapterStore) or None.
         # With a store, every prefill/decode call threads (bank, slots)
@@ -385,11 +413,12 @@ class _DecodeModelBase:
             # writes there the next admission's row insert replaces. State
             # that is carried and not indexed restarts with it: a free
             # row's is zero before every step, so it holds one step of
-            # garbage at most and never what a request left
+            # garbage at most and never what a request left (a family
+            # that reads a row at position 0 as zero itself is left to)
             def restart(leaf, kind):
                 if kind == INDEX:
                     return jnp.where(active, leaf, 0)
-                if kind == STATE:
+                if kind == STATE and self._zeroes_free_state:
                     live = jnp.asarray(active).reshape(
                         (-1,) + (1,) * (leaf.ndim - 1))
                     return jnp.where(live, leaf, jnp.zeros((), leaf.dtype))
@@ -406,8 +435,10 @@ class _DecodeModelBase:
             mutable=["cache", _ROUTING] if counting else ["cache"],
         )
         if counting:
+            held = getattr(self._cfg, "experts_held", None)
             return logits[:, -1, :], vars_out["cache"], _count_experts(
-                expert_counts, vars_out[_ROUTING], active
+                expert_counts, vars_out[_ROUTING], active,
+                held[0] if held else 0,
             )
         return logits[:, -1, :], vars_out["cache"]
 
@@ -740,7 +771,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         self._sampled = jnp.zeros((num_slots,), jnp.int32)
         # running expert counts of a routed model (None for a dense one),
         # device-side; expert_stats() reads them
-        self._expert_counts = _new_expert_counts(model_config)
+        self._expert_counts = _new_expert_counts(model_config, num_slots)
         self._cache = None  # pooled cache, allocated on first prefill
         # paged prefix cache (ray_tpu.kvcache.KVCacheManager) or None for
         # the dense per-slot pool; with a manager, _admit serves the
@@ -1262,18 +1293,30 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         """The routed model's running counts as plain numbers (this read
         waits for the device; the step never does): ``decode_steps``,
         ``assignments`` [layer][expert] by live rows, ``touched`` [layer]
-        = sum over steps of distinct experts live rows chose. None for a
-        model without routed experts. A row is live to the device until the
-        host has seen its last token: the step it rides meanwhile counts."""
+        = sum over steps of distinct experts live rows chose; where the
+        model holds a share of its experts, both over the experts held, and
+        ``experts_routed``, ``experts_held`` and ``assignments_absent``
+        [layer] (live rows' choices that fell on experts held elsewhere)
+        beside them. None for a model without routed experts. A row is live
+        to the device until the host has seen its last token: the step it
+        rides meanwhile counts."""
         if self._expert_counts is None:
             return None
         with self._lock:
             counts = jax.tree.map(host_sync, self._expert_counts)
-        return {
+        out = {
             "decode_steps": int(counts["steps"]),
             "assignments": counts["assignments"].tolist(),
             "touched": counts["touched"].tolist(),
         }
+        if "absent" in counts:
+            first, stop = self._cfg.experts_held
+            out.update(
+                experts_routed=int(self._cfg.n_experts),
+                experts_held=stop - first,
+                assignments_absent=counts["absent"].tolist(),
+            )
+        return out
 
     def _cache_leaves(self, kind: str) -> Optional[List[tuple]]:
         """The live slot cache's leaves of ``kind`` as (name, leaf), a

@@ -3,9 +3,12 @@
 Llama (causal LM + LoRA + KV-cache decode), MoE transformer (routed
 experts, dropless for serving), DeepSeek-V3-shaped transformer (latent
 attention, shared + routed experts), Falcon-H1-shaped transformer (a
-Mamba-2 mixer beside attention in every block; serving only), ViT (vision
-encoder). The reference delegates model execution to torch/vLLM; this
-framework owns it.
+Mamba-2 mixer beside attention in every block; serving only),
+Solar-Open2-shaped transformer (a gated delta-rule linear-attention mixer
+three layers in four, a gated NoPE GQA layer the fourth, routed experts of
+which one chip's share may be held; serving only), ViT (vision encoder).
+The reference delegates model execution to torch/vLLM; this framework owns
+it.
 
 This module is also the one place that chooses a family for the serving
 stack (``llm/engine.py``, ``llm/serving.py``, ``llm/batch.py``), by the
@@ -39,10 +42,18 @@ type of the model config. What the engine asks of a family:
     no prefix to share: a family that has such a leaf gets no prefix reuse
     (the manager leases without matching or committing and allocates no
     pool), a free row's state is zeroed at every step it is free, and the
-    admission's row insert replaces it whole. ``models/falcon_h1.py`` keeps
+    admission's row insert replaces it whole. The engine does the zeroing
+    before the step, unless the family says it does it itself
+    (``RESTARTS_OWN_STATE``): such a family keeps an ``INDEX`` leaf beside
+    its state and reads the state of a row whose index is 0 as zero, which
+    is what the engine's reset of a free row's index makes of it, inside
+    the update that reads the state anyway (``models/solar_open2.py``).
+    ``models/falcon_h1.py`` keeps
     ``state_ssm`` ``(batch, heads, d_head, d_state)`` float32 and
     ``state_conv`` ``(batch, d_conv - 1, channels)`` a layer beside
-    ``llama``'s three
+    ``llama``'s three; ``models/solar_open2.py`` a ``state_kda`` ``(batch,
+    heads, d_k, d_v)`` float32 and a ``state_conv`` in each KDA layer, and
+    ``llama``'s three in each GQA layer
 - a step of one token a row (``seq == 1`` against a cache) attends each
   row up to its own position; a longer ``seq`` against a cache is a chunk
   behind a cached prefix, row ``r``'s token ``i`` at ``index[r] + i``, and
@@ -54,7 +65,9 @@ type of the model config. What the engine asks of a family:
   ``layer_<i>/moe/experts`` into the ``ROUTING`` collection when it is
   mutable, for every layer ``i`` that has them: all ``n_layers`` unless
   the config says which (``routed_layers``, ``deepseek``'s leading layers
-  are dense)
+  are dense). A config whose ``experts_held`` is a ``(first, stop)`` range
+  holds that share of each layer's experts (``MoEConfig.experts_held``):
+  the choices sown are still over all ``n_experts``
 - a feature the family has no rules for (adapter bank, speculative draft,
   a ``tp``/``sp`` mesh) is listed in ``_NO_RULES`` with the reason, and
   ``LLMConfig`` refuses it at construction: no silent fallback
@@ -67,7 +80,11 @@ attention and cache leaves, the routed part ``moe.MoEFFN``'s under three new
 (``ops/decode_attention.latent_decode_attention``); the engine changed
 only where it counted one expert row a layer. ``falcon_h1`` (PR 32) forced
 the leaf kinds above: its mixer's state is the first cached leaf without a
-sequence axis.
+sequence axis. ``solar_open2`` (PR 36) is the first with ``STATE`` leaves
+*and* routed layers, the first whose layers differ by index (``gqa_layers``),
+and the first to hold a share of its experts: ``llama.Attention`` gained
+``rope`` / ``attn_gate`` / ``attn_head_dim``, ``MoEConfig`` ``experts_held``,
+and the engine's expert counters count over the experts held.
 """
 
 from __future__ import annotations
@@ -137,6 +154,25 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "leaf (models.STATE) or for the mixer's projections"
         ),
     },
+    "solar_open2": {
+        "adapters": (
+            "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
+            "wq/wk/wv/wo at dim = n_heads x head_dim and has no placement "
+            "for the KDA mixer's projections or for expert weights"
+        ),
+        "draft_model": (
+            "a rejected draft run cannot be undone by moving an index "
+            "back: the delta rule's state has moved on and no snapshot of "
+            "it is kept to return to, and the expert counters count plain "
+            "decode steps"
+        ),
+        "mesh": (
+            "parallel/plan.py has no partition rule for a per-row state "
+            "leaf (models.STATE), for the KDA mixer's projections or for "
+            "the (expert, ...) weights; a held share of the experts has no "
+            "ep exchange yet (ROADMAP R1)"
+        ),
+    },
 }
 
 
@@ -166,6 +202,12 @@ def carries_row_state(model_config) -> bool:
     return getattr(_family(model_config), "ROW_STATE", False)
 
 
+def restarts_own_state(model_config) -> bool:
+    """Whether the family zeroes a free row's ``STATE`` leaves itself
+    (module docstring): the engine then leaves them as they are."""
+    return getattr(_family(model_config), "RESTARTS_OWN_STATE", False)
+
+
 def refusals(family: str) -> Dict[str, str]:
     """Serving features ``family`` has no rules for yet, with the reason."""
     if family not in _NO_RULES:
@@ -174,8 +216,10 @@ def refusals(family: str) -> Dict[str, str]:
 
 
 def _family(model_config):
-    from . import deepseek, falcon_h1, llama, moe
+    from . import deepseek, falcon_h1, llama, moe, solar_open2
 
+    if isinstance(model_config, solar_open2.SolarOpen2Config):
+        return solar_open2
     if isinstance(model_config, falcon_h1.FalconH1Config):
         return falcon_h1
     if isinstance(model_config, deepseek.DeepseekConfig):

@@ -63,10 +63,18 @@ class LlamaConfig:
     # RMSNorm over the whole q and k projections, before the split into
     # heads and before RoPE (OLMoE's q_norm / k_norm)
     qk_norm: bool = False
+    # rotary position embedding on q and k; without it the causal mask is
+    # all the order attention sees (a NoPE layer)
+    rope: bool = True
+    # an elementwise sigmoid gate on the attention's output before ``wo``,
+    # from the layer's input (``w_gate``, as wide as the heads together)
+    attn_gate: bool = False
+    # a head's width where it is not ``dim / n_heads``
+    attn_head_dim: Optional[int] = None
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.attn_head_dim or self.dim // self.n_heads
 
     @staticmethod
     def llama2_7b(**kw) -> "LlamaConfig":
@@ -237,8 +245,9 @@ class Attention(nn.Module):
                 "cache", "cache_index", lambda: jnp.zeros((b,), jnp.int32)
             )
             idx = idx_var.value  # (b,)
-            q = apply_rope(q, cos, sin, offset=idx)
-            k = apply_rope(k, cos, sin, offset=idx)
+            if cfg.rope:
+                q = apply_rope(q, cos, sin, offset=idx)
+                k = apply_rope(k, cos, sin, offset=idx)
 
             # each row's new keys and values at its own position
             cached_k.value, cached_v.value = write_rows(
@@ -273,8 +282,9 @@ class Attention(nn.Module):
                     "bhqk,bhkd->bhqd", probs, v_all.astype(jnp.float32)
                 ).astype(cfg.dtype)
         elif self.mesh is not None:
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            if cfg.rope:
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
             # ring attention under shard_map: batch over data axes, heads
             # over tp, sequence over sp (ICI neighbor exchanges)
             qkv_spec = P(("dcn", "dp", "fsdp"), "tp", "sp", None)
@@ -287,10 +297,18 @@ class Attention(nn.Module):
             )
             out = attn(q, k, v)
         else:
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            if cfg.rope:
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
             out = flash_attention(q, k, v, causal=True)
         out = out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+        if cfg.attn_gate:
+            with jax.named_scope("attn.gate"):
+                gate = _dense(
+                    h * d, ("embed", "heads"), "w_gate", cfg.param_dtype,
+                    cfg.dtype,
+                )(x)
+                out = out * jax.nn.sigmoid(gate)
         return LoRADense(
             features=cfg.dim,
             logical_axes=("heads", "embed"),

@@ -22,7 +22,7 @@ renormalised.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -74,8 +74,25 @@ class MoEConfig:
     router_bias: bool = False
     # the kept weights times this (``routed_scaling_factor``)
     routed_scale: float = 1.0
+    # ``(first, stop)``: the experts whose weights live here, one chip's
+    # share of a layer whose ``n_experts`` lie on several; None is all of
+    # them. The router keeps its ``n_experts`` outputs and its
+    # ``experts_per_token``, the kept weights are normalised over the
+    # experts chosen wherever they live, and the layer gives the part of
+    # the result its own experts give (parallel/expert.moe_apply_dropless)
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
+        if self.experts_held is not None:
+            first, stop = self.experts_held
+            object.__setattr__(self, "experts_held", (first, stop))
+            if not self.dropless or not 0 <= first < stop <= self.n_experts:
+                raise ValueError(
+                    f"MoEConfig: experts_held {self.experts_held} of "
+                    f"{self.n_experts} experts needs dropless=True and a "
+                    "range inside them: the capacity path dispatches to "
+                    "every expert"
+                )
         if not self.dropless and (
             self.router_scoring != "softmax" or self.router_bias
             or self.routed_scale != 1.0
@@ -96,6 +113,12 @@ class MoEConfig:
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def n_experts_held(self) -> int:
+        if self.experts_held is None:
+            return self.n_experts
+        return self.experts_held[1] - self.experts_held[0]
 
     def attention_config(self) -> LlamaConfig:
         return LlamaConfig(
@@ -191,7 +214,7 @@ class MoEFFN(nn.Module):
             nn.with_logical_partitioning(
                 per_expert, ("expert", "embed", "mlp")
             ),
-            (cfg.n_experts, cfg.dim, cfg.intermediate),
+            (cfg.n_experts_held, cfg.dim, cfg.intermediate),
             cfg.param_dtype,
         )
         w_up = self.param(
@@ -199,7 +222,7 @@ class MoEFFN(nn.Module):
             nn.with_logical_partitioning(
                 per_expert, ("expert", "embed", "mlp")
             ),
-            (cfg.n_experts, cfg.dim, cfg.intermediate),
+            (cfg.n_experts_held, cfg.dim, cfg.intermediate),
             cfg.param_dtype,
         )
         w_down = self.param(
@@ -207,7 +230,7 @@ class MoEFFN(nn.Module):
             nn.with_logical_partitioning(
                 per_expert, ("expert", "mlp", "embed")
             ),
-            (cfg.n_experts, cfg.intermediate, cfg.dim),
+            (cfg.n_experts_held, cfg.intermediate, cfg.dim),
             cfg.param_dtype,
         )
 
@@ -223,6 +246,7 @@ class MoEFFN(nn.Module):
                 out = moe_apply_dropless(
                     tokens, weights, chosen, w_gate.astype(tokens.dtype),
                     w_up.astype(tokens.dtype), w_down.astype(tokens.dtype),
+                    held=cfg.experts_held,
                 )
             else:
                 out = moe_apply_gspmd(tokens, dispatch, combine, experts)

@@ -3,9 +3,10 @@ in, each row through its own expert's SwiGLU out.
 
 ``y[r] = down_e(silu(gate_e x[r]) * up_e x[r])`` for the expert ``e`` that
 owns row ``r``, where the rows of one expert are contiguous
-(``group_sizes`` says how many each has). Every row is computed whatever
-the imbalance: there is no capacity and no ``(tokens, experts, capacity)``
-tensor.
+(``group_sizes`` says how many each has). Every row of a group is computed
+whatever the imbalance: there is no capacity and no ``(tokens, experts,
+capacity)`` tensor. Rows behind the last group are no expert's here and
+are left alone.
 
 Design:
 - grid (visits, f blocks). A visit is one (row tile, expert) pair that
@@ -121,7 +122,11 @@ def _kernel(
 def moe_experts(x, w_gate, w_up, w_down, group_sizes):
     """``x (m, d)`` rows sorted by expert, ``m`` a multiple of
     ``tile_rows(m)``; ``w_gate``/``w_up (E, d, f)``, ``w_down (E, f, d)``;
-    ``group_sizes (E,) int32`` summing to ``m``. Returns ``(m, d)`` f32."""
+    ``group_sizes (E,) int32`` summing to ``m`` or to less: rows past the
+    last group's end belong to no visit, are not computed and come back as
+    whatever the output buffer held (``moe_apply_dropless`` puts the
+    assignments to experts held elsewhere there). Returns ``(m, d)``
+    f32."""
     m, d = x.shape
     n_experts, _, f = w_gate.shape
     tm = tile_rows(m)

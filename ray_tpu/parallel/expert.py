@@ -217,12 +217,21 @@ def moe_apply_dropless(
     w_gate: jax.Array,  # (E, dim, f)
     w_up: jax.Array,  # (E, dim, f)
     w_down: jax.Array,  # (E, f, dim)
+    held: Optional[Tuple[int, int]] = None,
 ) -> jax.Array:
     """``sum_j weights[t, j] * swiglu_{experts[t, j]}(x[t])`` for every
     token, no assignment dropped: the ``tokens x k`` assignments sorted by
     expert, one grouped matmul over the sorted rows, then back in token
     order and summed. Static shapes: the rows are padded to whole tiles,
-    and the padding rides with the last expert at weight zero."""
+    and the padding rides with the last expert at weight zero.
+
+    ``held = (first, stop)``: the weights are those of experts ``first ..
+    stop - 1`` of the ``experts`` routed over (one chip's share of a layer
+    whose experts lie on several), and the result is the part of the sum
+    those experts give. The assignments to them are sorted first and are
+    the only rows the grouped matmul visits; an assignment to an expert
+    held elsewhere, like the padding, sorts behind them, belongs to no
+    group and adds nothing: the work follows the held assignments."""
     from ..ops.moe_experts import moe_experts, tile_rows
 
     tokens, k = experts.shape
@@ -231,16 +240,33 @@ def moe_apply_dropless(
     tm = tile_rows(n)
     padded = -(-n // tm) * tm
     flat = experts.reshape(n)
+    if held is not None:
+        first, stop = held
+        if stop - first != n_experts:
+            raise ValueError(
+                f"moe_apply_dropless: {n_experts} experts' weights for the "
+                f"held range {held}"
+            )
+        here = (flat >= first) & (flat < stop)
+        # a key past the last held expert: behind every held assignment
+        flat = jnp.where(here, flat - first, n_experts)
+        outside = n_experts
+    else:
+        outside = n_experts - 1
     if padded != n:
         flat = jnp.concatenate(
-            [flat, jnp.full((padded - n,), n_experts - 1, jnp.int32)]
+            [flat, jnp.full((padded - n,), outside, jnp.int32)]
         )
     order = jnp.argsort(flat, stable=True)
     # an assignment's token: row i of ``flat`` belongs to token i // k
     # (padding rows read the last token; nothing reads their result)
     source = jnp.minimum(order // k, tokens - 1)
+    # (a key past the last expert is out of bounds here, and dropped)
     group_sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
     y = moe_experts(x[source], w_gate, w_up, w_down, group_sizes)
     back = jnp.argsort(order)[:n]  # sorted row of each assignment
     y = y[back].reshape(tokens, k, -1)
+    if held is not None:
+        # a row no group owns was never written: whatever the buffer held
+        y = jnp.where(here.reshape(tokens, k, 1), y, 0.0)
     return jnp.sum(y * weights[..., None], axis=1).astype(x.dtype)

@@ -68,10 +68,11 @@ def native_kernels(monkeypatch):
     """Trace the Pallas kernels as the chip would: this process's default
     backend is the CPU, so the ops would otherwise pick interpret mode."""
     from ray_tpu.ops import (
-        decode_attention, flash_attention, kv_row_write, moe_experts, rmsnorm,
+        decode_attention, flash_attention, kda_step, kv_row_write,
+        moe_experts, rmsnorm,
     )
 
-    for kernel in (decode_attention, flash_attention, kv_row_write,
+    for kernel in (decode_attention, flash_attention, kda_step, kv_row_write,
                    moe_experts, rmsnorm):
         monkeypatch.setattr(kernel, "_use_interpret", lambda *name: False)
     # ... and compile the decode step with the options the chip gets, so
@@ -291,7 +292,7 @@ def _serving_programs(compiled, chip, cfg, slots):
     )
     last = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
     active = jax.ShapeDtypeStruct((slots,), jnp.bool_)
-    counts = jax.eval_shape(lambda: _new_expert_counts(cfg))
+    counts = jax.eval_shape(lambda: _new_expert_counts(cfg, slots))
     counted = {} if counts is None else {"expert_counts": _on(chip, counts)}
 
     prefill = jax.jit(model._prefill_impl).lower(
@@ -478,6 +479,54 @@ def test_falcon_h1_decode_step_carries_its_state_in_place(
     assert 7.0e9 < _size(params) < 7.1e9
     assert 0.80e9 < _size(pool) < 0.82e9
     assert prefill.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_solar_open2_decode_step_moves_its_state_once(
+    v5e_chip, native_kernels, compiled
+):
+    """`solar2-longgen-backlog`'s programs at its widths, a GQA and a KDA
+    layer deep, 32 slots x 2048, 40 of 320 experts held: every cache leaf is
+    an input aliased to the output that succeeds it; the delta rule is one
+    `kda_step` call a KDA layer whose state operand is the cache's own
+    parameter (the engine zeroes nothing in front of it: the family reads a
+    fresh row's state as zero inside the kernel), and no other instruction
+    produces or copies a state; the expert kernel is handed 40 experts'
+    weights."""
+    from ray_tpu.models.solar_open2 import SolarOpen2Config
+
+    cfg = SolarOpen2Config(
+        vocab_size=24576, n_layers=2, gqa_layers=(0,), experts_held=(0, 40),
+        param_dtype=jnp.bfloat16, max_seq_len=2048)
+    prefill, decode, params, pool = _serving_programs(compiled, v5e_chip, cfg, 32)
+    text = decode.as_text()
+    header = text.split("\n", 1)[0]
+    aliases = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
+    # K, V and their index; the state, the tail and their index
+    assert len(aliases) == len(jax.tree.leaves(pool)) == 6
+    state = r"f32\[32,64,128,128\]"
+    entry = text[text.index("\nENTRY "):]
+    calls = re.findall(
+        rf"%kda_step\S* = \({state}\S*, f32\[32,2,32,128\]\S*\) "
+        r"custom-call\(([^)]*)\)", entry)
+    assert len(calls) == 1
+    assert "state_kda" in calls[0].split(", ")[1]  # the parameter itself
+    assert not re.search(rf"= {state}\S* copy\(", text)
+    produced = re.findall(rf"^\s*%\S+ = {state}\S* (\w[\w\-]*)\(", entry, re.M)
+    assert set(produced) <= {"get-tuple-element", "parameter", "bitcast"}, produced
+    assert not re.search(rf"= \([^=]*{state}[^=]*\) fusion\(", entry)
+    assert len(re.findall(r"%moe_experts\S* = f32\[256,4096\]", entry)) == 2  # 32 x 8 in two tiles
+    assert "bf16[40,4096,1280]" in entry and "bf16[320,4096,1280]" not in text
+    _assert_rows_written_by_the_kernel(text, 1, {
+        "k": s for s in jax.tree.leaves(pool) if s.shape == (32, 8, 2048, 128)})
+    mem = decode.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert _size(pool) <= mem.alias_size_in_bytes <= _size(pool) + 512 * len(aliases)
+    # 0.40 GB of embedding and head; a KDA layer 0.28 and a GQA layer 0.22 GB
+    # of mixer; 2 x (40 experts 1.26 + shared and router 0.03)
+    assert 3.4e9 < _size(params) < 3.6e9
+    # 32 rows x (4.19 MB of state + 0.15 of tail + 8.39 of K and V)
+    assert 0.40e9 < _size(pool) < 0.41e9
+    assert prefill.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
 # ---------------------------------------------------------------------------
