@@ -16,10 +16,12 @@ talks to it through four calls:
   row (jitted gather; one compiled program per block-count bucket, so XLA
   sees a bounded program set) with the cache write position set to the
   cached length; the engine then prefills only the uncached suffix.
-- ``commit(lease, token_ids, cache_row)`` — slice full blocks out of a
-  prefetched/decoded row into reserved pool blocks (jitted
-  ``dynamic_update_slice``; block id and token offset are traced scalars,
-  so it is ONE program) and insert them into the radix tree.
+- ``commit(lease, token_ids, cache_row)`` — insert the row's full blocks
+  into the radix tree and slice the missing ones out of the
+  prefilled/decoded row into reserved pool blocks: ONE jitted program a
+  call and one compiled program in all (block ids, token offsets and
+  their count are data: a device loop of ``dynamic_update_slice`` over
+  the donated pools).
 - ``release(lease)`` — drop the request's pins; blocks whose only
   remaining reference is the index become LRU-evictable.
 
@@ -43,10 +45,11 @@ device pool is ever allocated.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models import SEQUENCE, STATE, cache_kinds
 from .block_allocator import BlockAllocator
@@ -63,6 +66,37 @@ class KVCacheLease:
     pinned: List[int]  # every block this lease holds a reference on
     cacheable: bool = True  # False: prompt exceeds pool, serve hits only
     closed: bool = False
+
+
+def commit_program(block_size: int, out_shardings=None):
+    """The one program behind every ``commit`` and ``update_block``:
+    ``(pools, kv_row, writes, count) -> pools`` with the pools donated and
+    written in place. ``writes`` is ``(max_seq_len // block_size, 2)`` int32
+    rows of (block id, token offset), the first ``count`` of them live and
+    written in order (a block id met twice keeps the later write), so the
+    number of blocks is data and one compilation serves every call. A
+    trace names it ``jit_commit_impl``."""
+
+    def commit_impl(pools, kv_row, writes, count):
+        def write_one(i, pools):
+            bid, off = writes[i, 0], writes[i, 1]
+            return [
+                jax.lax.dynamic_update_index_in_dim(
+                    p,
+                    jax.lax.dynamic_slice_in_dim(
+                        r[0], off, block_size, axis=-2
+                    ),
+                    bid,
+                    axis=0,
+                )
+                for p, r in zip(pools, kv_row)
+            ]
+
+        return jax.lax.fori_loop(0, count, write_one, list(pools))
+
+    return jax.jit(
+        commit_impl, donate_argnums=(0,), out_shardings=out_shardings
+    )
 
 
 class KVCacheManager:
@@ -103,6 +137,8 @@ class KVCacheManager:
             "prefill_tokens_computed": 0,
             "admission_blocked": 0,
             "adopted_blocks": 0,
+            # device programs queued by commit / update_block's writes
+            "commit_dispatches": 0,
             # leases given without a match, and the full prompt blocks they
             # would have committed, because prefix reuse is refused
             "reuse_refused_leases": 0,
@@ -173,6 +209,12 @@ class KVCacheManager:
     @property
     def blocks_in_use(self) -> int:
         return self._alloc.num_allocated
+
+    def commit_counts(self) -> Tuple[int, int]:
+        """(programs ``commit`` / ``update_block`` queued to write blocks,
+        blocks evicted) so far: a caller's ``kv.commit`` span counts one
+        call by the difference across it."""
+        return self._stats["commit_dispatches"], self._index.num_evictions
 
     def stats(self) -> Dict[str, Any]:
         out: Dict[str, Any] = dict(self._stats)
@@ -332,16 +374,6 @@ class KVCacheManager:
             # pool layout (capacity, heads, block, d): heads is axis 1,
             # the same axis the decode cache shards — place, don't copy
             self._pools = [jax.device_put(p, kv_sh) for p in self._pools]
-        bs = self._block_size
-
-        def commit_impl(pools, kv_row, bid, off):
-            out = []
-            for p, r in zip(pools, kv_row):
-                blk = jax.lax.dynamic_slice_in_dim(r[0], off, bs, axis=-2)
-                out.append(
-                    jax.lax.dynamic_update_index_in_dim(p, blk, bid, axis=0)
-                )
-            return out
 
         def copy_impl(pools, src, dst):
             return [
@@ -364,16 +396,16 @@ class KVCacheManager:
                 for p, blk in zip(pools, blk_leaves)
             ]
 
-        # block id / token offset are traced scalars: ONE compiled program
-        # each, reused for every commit, COW copy and adopted shipment
-        # block. Under a plan the outputs are pinned to the pool sharding
-        # so the buffers stay sharded through every donation cycle
-        # (inference would keep them sharded too, but pinning makes drift
-        # impossible).
+        # block ids, token offsets and their count are data: ONE compiled
+        # program each, reused for every commit (whatever its number of
+        # missing blocks), COW copy and adopted shipment block. The host
+        # hands them over as numpy values: a ``jnp.asarray`` of a Python
+        # int is a device program of its own. Under a plan the outputs are
+        # pinned to the pool sharding so the buffers stay sharded through
+        # every donation cycle (inference would keep them sharded too, but
+        # pinning makes drift impossible).
         out_sh = [kv_sh] * len(self._pools) if kv_sh is not None else None
-        self._jit_commit = jax.jit(
-            commit_impl, donate_argnums=(0,), out_shardings=out_sh
-        )
+        self._jit_commit = commit_program(self._block_size, out_sh)
         self._jit_copy = jax.jit(
             copy_impl, donate_argnums=(0,), out_shardings=out_sh
         )
@@ -455,12 +487,16 @@ class KVCacheManager:
         past the reservation (decode tail at retire) allocation is
         best-effort — on exhaustion the tail simply is not cached. With
         ``pin``, blocks touched are pinned into the lease so they survive
-        until release. Returns the number of newly committed blocks."""
+        until release. Returns the number of newly committed blocks.
+
+        The walk only collects the missing blocks; one program writes them
+        all once it is over (``_write_blocks``). The index runs ahead of
+        the device by that much, which no reader can see: whatever reads
+        the pools next takes them from that program's outputs."""
         if lease.cacheable is False:
             return 0
         self.initialize(cache_row)
-        kv_row = self._sequence_leaves(cache_row)
-        committed = 0
+        writes: List[Tuple[int, int]] = []  # (block id, token offset)
         node = self._index.root
         for i in range(len(token_ids) // self._block_size):
             key = tuple(
@@ -477,9 +513,8 @@ class KVCacheManager:
                     bid = self._allocate_or_evict()
                     if bid is None:
                         break
-                self._write_block(bid, kv_row, i * self._block_size)
+                writes.append((bid, i * self._block_size))
                 child = self._index.insert_child(node, key, bid)
-                committed += 1
                 if pin:
                     lease.pinned.append(bid)  # reservation ref becomes pin
                 else:
@@ -490,8 +525,9 @@ class KVCacheManager:
                     self._alloc.ref(child.block_id)
                     lease.pinned.append(child.block_id)
             node = child
+        self._write_blocks(writes, cache_row)
         self._update_gauges()
-        return committed
+        return len(writes)
 
     def update_block(self, block_id: int, cache_row, tok_offset: int):
         """Overwrite one block from ``cache_row`` at ``tok_offset``,
@@ -501,27 +537,30 @@ class KVCacheManager:
         new_id = self._alloc.copy_on_write(block_id, copy_fn=self._copy_block)
         if new_id is None:
             return None
-        kv_row = self._sequence_leaves(cache_row)
-        self._write_block(new_id, kv_row, tok_offset)
+        self._write_blocks([(new_id, tok_offset)], cache_row)
         return new_id
 
-    def _write_block(self, bid: int, kv_row, tok_offset: int) -> None:
+    def _write_blocks(self, writes: List[Tuple[int, int]], cache_row) -> None:
+        """Queue the one program that copies ``cache_row``'s block at each
+        ``(block id, token offset)`` of ``writes`` into the pools, in
+        order; nothing for an empty list."""
+        if not writes:
+            return
+        table = np.zeros((self._max_seq_len // self._block_size, 2), np.int32)
+        table[: len(writes)] = writes
         self._pools = list(
             self._jit_commit(
                 self._pools,
-                kv_row,
-                jnp.asarray(bid, jnp.int32),
-                jnp.asarray(tok_offset, jnp.int32),
+                self._sequence_leaves(cache_row),
+                table,
+                np.int32(len(writes)),
             )
         )
+        self._stats["commit_dispatches"] += 1
 
     def _copy_block(self, src: int, dst: int) -> None:
         self._pools = list(
-            self._jit_copy(
-                self._pools,
-                jnp.asarray(src, jnp.int32),
-                jnp.asarray(dst, jnp.int32),
-            )
+            self._jit_copy(self._pools, np.int32(src), np.int32(dst))
         )
 
     def _allocate_or_evict(self) -> Optional[int]:

@@ -23,6 +23,7 @@ Greedy and temperature sampling; per-request max_new_tokens.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import itertools
 import os
@@ -1447,18 +1448,34 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         """Read slot ``si``'s row back and commit its new full blocks: the
         decode-tail commit both the retire path and the speculative path
         make."""
-        with _tracing.step_span(
-            "kv.commit", trace, request_span="kvcache.commit",
-            category="kvcache",
-            attrs={"request_id": slot.request_id,
-                   "tokens": len(key_tokens), "tail": True},
+        with self._kv_commit_span(
+            trace,
+            {"request_id": slot.request_id, "tokens": len(key_tokens),
+             "tail": True},
             tail=1, blocks=blocks,
         ):
             with _span("kv.extract_row", blocks=blocks):
-                row = self._extract_row(
-                    self._cache, jnp.asarray(si, jnp.int32)
-                )
+                row = self._extract_row(self._cache, np.int32(si))
             self._kv.commit(slot.lease, key_tokens, row, pin=False)
+
+    @contextlib.contextmanager
+    def _kv_commit_span(self, trace, attrs: Optional[dict] = None, **counts):
+        """The ``kv.commit`` step span around one ``KVCacheManager.commit``.
+        As it closes it counts what the call cost: ``dispatches``, the
+        device programs queued inside it (one for all of the call's
+        missing blocks, none where nothing was missing, and a ``tail``'s
+        row read), and ``evictions``, the blocks the call had to evict."""
+        queued, evicted = self._kv.commit_counts()
+        with _tracing.step_span(
+            "kv.commit", trace, request_span="kvcache.commit",
+            category="kvcache", attrs=attrs, **counts,
+        ) as span:
+            yield
+            queued_now, evicted_now = self._kv.commit_counts()
+            span.count(
+                dispatches=queued_now - queued + counts.get("tail", 0),
+                evictions=evicted_now - evicted,
+            )
 
     def _retire_slot(self, si: int) -> None:
         """Free the slot; with a KV manager, first commit the sequence's
@@ -1764,10 +1781,8 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 # row is at hand; reserved blocks are consumed here
                 # (the fast path adopted them instead)
                 bs = self._kv.block_size
-                with _tracing.step_span(
-                    "kv.commit", tr, request_span="kvcache.commit",
-                    category="kvcache",
-                    attrs={"request_id": rid, "tokens": plen},
+                with self._kv_commit_span(
+                    tr, {"request_id": rid, "tokens": plen},
                     blocks=plen // bs - cached // bs,
                 ):
                     self._kv.commit(
@@ -1881,8 +1896,8 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                         # partial commit: completed full blocks become
                         # hittable for concurrent shared-prefix admissions
                         # NOW, not when the whole prompt lands
-                        with _span(
-                            "kv.commit", blocks=pos // bs - st["committed"]
+                        with self._kv_commit_span(
+                            None, blocks=pos // bs - st["committed"]
                         ):
                             self._kv.commit(
                                 lease,

@@ -466,7 +466,8 @@ def annotate_device_trace(name: str, **counts):
     """Named region in the profiler's own trace (jax.profiler.TraceAnnotation):
     lands in the ``/host:CPU`` plane of any running ``jax.profiler`` session,
     on the clock the device planes use, with ``counts`` as the event's
-    stats. Counts are fixed when the region opens. With no session running
+    stats; the region's ``set_metadata(**counts)`` adds, before it closes,
+    the ones known only inside it. With no session running
     it is a no-op of about a microsecond, so hot paths call it
     unconditionally. A process that has not imported jax has no session to
     write to, and is not made to import it."""
@@ -487,7 +488,8 @@ class step_span:
     request carries a trace context (``trace`` = ``{"ctx": ...}`` as the
     engine keeps it per traced request, else None). The request span's
     attributes are ``counts`` plus ``attrs`` plus whatever ``set()`` added
-    inside the block; ``cancel()`` drops it (the region stays)."""
+    inside the block; ``cancel()`` drops it (the region stays). ``count()``
+    adds counts known only inside the block to both."""
 
     __slots__ = ("_region", "_trace", "_name", "_category", "_attrs", "_wall")
 
@@ -504,6 +506,11 @@ class step_span:
     def set(self, **attrs) -> None:
         if self._trace is not None:
             self._attrs.update(attrs)
+
+    def count(self, **counts) -> None:
+        if self._region is not _NO_REGION:
+            self._region.set_metadata(**counts)
+        self.set(**counts)
 
     def cancel(self) -> None:
         self._trace = None

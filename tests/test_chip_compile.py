@@ -225,6 +225,43 @@ def test_kv_row_write_compiles_in_place(v5e_chip, native_kernels, leaves):
     assert step.memory_analysis().temp_size_in_bytes < 1e6
 
 
+@pytest.mark.parametrize(
+    "block, max_seq_len, pools",
+    [(32, 4096, [(1536, 8, 32, 128)] * 24),
+     (128, 8192, [(1536, 1, 128, 512), (1536, 1, 128, 64)] * 7)],
+    ids=["mistral", "moonlight"],
+)
+def test_commit_program_writes_the_pools_in_place(
+        v5e_chip, block, max_seq_len, pools):
+    """The KV manager's one commit program at a cell's pools (S9): every
+    pool an input aliased to its output, none copied, and nothing held
+    beside them but a block's worth of scratch. A program that copied
+    2.4 GB a commit would turn the host's idle into the device's busy."""
+    from ray_tpu.kvcache.manager import commit_program
+
+    def on(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    rows = [(1, p[1], max_seq_len, p[3]) for p in pools]
+    step = commit_program(block).lower(
+        [on(p) for p in pools], [on(r) for r in rows],
+        on((max_seq_len // block, 2), jnp.int32), on((), jnp.int32),
+    ).compile()
+    text = step.as_text()
+    aliases = re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", text.split("\n", 1)[0])
+    assert sorted((int(o), int(i)) for o, i in aliases) == [
+        (i, i) for i in range(len(pools))]
+    for shape in set(pools):
+        leaf = re.escape("[" + ",".join(map(str, shape)) + "]")
+        assert not re.search(rf"= \w+{leaf}\S* copy\(", text)
+    mem = step.memory_analysis()
+    size = sum(2 * n * h * b * w for n, h, b, w in pools)
+    assert mem.alias_size_in_bytes == size
+    assert mem.temp_size_in_bytes < min(2 * h * s * w for _, h, s, w in rows)
+    assert text.count(" while(") == 1  # the count is data: one loop, no unrolling
+
+
 def test_decode_attention_compiles_per_shard_under_tp4(v5e_host, native_kernels):
     """The chat cells' pool with its KV heads over four chips, as
     PartitionPlan lays the decode cache out: two KV heads a chip."""

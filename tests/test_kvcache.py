@@ -278,6 +278,312 @@ class TestKVCacheManager:
 
 
 # ---------------------------------------------------------------------------
+# commit: one program a call, the block-by-block commit's bookkeeping
+
+
+CS, CBS = 64, 8  # the commit cases' max_seq_len and block size: 8 blocks a row
+
+
+def _np_row(seed, family="llama"):
+    """A cache row of seeded values, as numpy: ``llama``'s one K leaf of two
+    heads, or ``deepseek``'s two leaves of one head and unequal widths."""
+    rng = np.random.default_rng(seed)
+    widths = {"cached_latent": 16, "cached_rope": 4} if family == "deepseek" else {"k": D}
+    heads = 1 if family == "deepseek" else 2
+    row = {name: rng.standard_normal((1, heads, CS, w)).astype(np.float32)
+           for name, w in widths.items()}
+    row["cache_index"] = np.zeros((1,), np.int32)
+    return row
+
+
+def _toks(base, nblocks):
+    return [base + i for i in range(min(nblocks * CBS + 3, CS))]
+
+
+def _sequence_leaves(row):
+    return [row[name] for name in sorted(row) if name != "cache_index"]
+
+
+class _BlockByBlock:
+    """The commit this file's cases are held to: the walk as it was before
+    one program wrote a call's blocks, over the manager's own allocator and
+    index and a plain numpy copy a block. None of the manager's device
+    programs run."""
+
+    def __init__(self, m):
+        self.m = m
+        self.pools = [np.asarray(p).copy() for p in m._pools]
+        m._pools, m._jit_commit, m._jit_copy = "not the device's", None, None
+        self.written = 0  # calls that wrote at least one block
+
+    def _write(self, bid, row, off):
+        for pool, leaf in zip(self.pools, _sequence_leaves(row)):
+            pool[bid] = leaf[0, ..., off:off + CBS, :]
+
+    def commit(self, lease, toks, row, pin=True):
+        m = self.m
+        if lease.cacheable is False:
+            return 0
+        committed, node = 0, m._index.root
+        for i in range(len(toks) // CBS):
+            key = tuple(toks[i * CBS:(i + 1) * CBS])
+            child = m._index.child(node, key)
+            if child is None:
+                if lease.reserved:
+                    bid = lease.reserved.pop(0)
+                else:
+                    bid = m._allocate_or_evict()
+                    if bid is None:
+                        break
+                self._write(bid, row, i * CBS)
+                child = m._index.insert_child(node, key, bid)
+                committed += 1
+                if pin:
+                    lease.pinned.append(bid)
+                else:
+                    m._alloc.release(bid)
+            else:
+                m._index.touch(child)
+                if pin and child.block_id not in lease.pinned:
+                    m._alloc.ref(child.block_id)
+                    lease.pinned.append(child.block_id)
+            node = child
+        self.written += committed > 0
+        return committed
+
+    def update_block(self, bid, row, tok_offset):
+        def copy(src, dst):
+            for pool in self.pools:
+                pool[dst] = pool[src]
+
+        new_id = self.m._alloc.copy_on_write(bid, copy_fn=copy)
+        if new_id is not None:
+            self._write(new_id, row, tok_offset)
+            self.written += 1
+        return new_id
+
+
+class _OneProgram:
+    """The manager's own commit, its jitted program's calls counted."""
+
+    def __init__(self, m):
+        self.m = m
+        self.calls = 0
+        program = m._jit_commit
+
+        def counted(*args):
+            self.calls += 1
+            return program(*args)
+
+        m._jit_commit = counted
+        self.program = program
+        self.commit, self.update_block = m.commit, m.update_block
+
+
+def _tree(node):
+    return {key: (child.block_id, child.last_used, _tree(child))
+            for key, child in node.children.items()}
+
+
+def _bookkeeping(m, leases, freed):
+    return {
+        "tree": _tree(m._index.root),
+        "refcounts": list(m._alloc._refcounts),
+        "free": list(m._alloc._free),
+        "freed_in_order": freed,
+        "evictions": m._index.num_evictions,
+        "leases": [(l.block_ids, l.reserved, l.pinned, l.num_cached_tokens)
+                   for l in leases],
+    }
+
+
+def _missing(n, pin):
+    def case(kv, row):
+        lease = kv.m.acquire(_toks(0, n))
+        return [kv.commit(lease, _toks(0, n), row(0), pin=pin)], [lease]
+
+    return case
+
+
+def _partly_shared(pin):
+    def case(kv, row):
+        a, b = _toks(0, 4), _toks(0, 4)[:2 * CBS] + _toks(500, 3)
+        la = kv.m.acquire(a)
+        got = [kv.commit(la, a, row(0), pin=pin)]
+        lb = kv.m.acquire(b)  # two blocks matched, three reserved
+        got.append(kv.commit(lb, b, row(1), pin=pin))
+        got.append(kv.commit(la, a, row(2), pin=pin))  # nothing missing
+        return got, [la, lb]
+
+    return case
+
+
+def _reservation_runs_out(kv, row):
+    """A decoded tail past the reservation: one block extended for, the
+    rest allocated as the walk meets them."""
+    lease = kv.m.acquire(_toks(0, 2))
+    got = [kv.commit(lease, _toks(0, 2), row(0))]
+    assert kv.m.extend(lease, 1) == 1
+    got.append(kv.commit(lease, _toks(0, 6), row(1), pin=False))
+    return got, [lease]
+
+
+def _pool_runs_out(kv, row):
+    """Four blocks, every one pinned by the time the walk wants a fifth:
+    it stops there."""
+    lease = kv.m.acquire(_toks(0, 2))
+    got = [kv.commit(lease, _toks(0, 2), row(0))]
+    got.append(kv.commit(lease, _toks(0, 7), row(1)))
+    return got, [lease]
+
+
+def _evicts_least_recent_leaves(kv, row):
+    """A full pool of other requests' blocks, some used since: the tail's
+    walk evicts as it goes, the least recently used leaf first."""
+    got, leases = [], []
+    for base in (100, 200, 300):
+        lease = kv.m.acquire(_toks(base, 2))
+        got.append(kv.commit(lease, _toks(base, 2), row(base)))
+        kv.m.release(lease)
+        leases.append(lease)
+    kv.m.release(kv.m.acquire(_toks(100, 2)))  # 100's first block: used last
+    lease = kv.m.acquire(_toks(0, 1))
+    got.append(kv.commit(lease, _toks(0, 1), row(1)))
+    got.append(kv.commit(lease, _toks(0, 6), row(2), pin=False))
+    return got, leases + [lease]
+
+
+def _evicts_its_own_leaf(kv, row):
+    """Five blocks and a tail of seven with nothing pinned past the second:
+    the only leaf the sixth block can evict is the fifth, written in the
+    same call, and the seventh takes the sixth's: one block id, written
+    more than once, keeps what was written last."""
+    lease = kv.m.acquire(_toks(0, 2))
+    got = [kv.commit(lease, _toks(0, 2), row(0))]
+    got.append(kv.commit(lease, _toks(0, 7), row(1), pin=False))
+    return got, [lease]
+
+
+def _copy_on_write(kv, row):
+    lease = kv.m.acquire(_toks(0, 2))
+    got = [kv.commit(lease, _toks(0, 2), row(0))]
+    shared = lease.pinned[0]  # the index holds it too
+    new_id = kv.update_block(shared, row(1), CBS)
+    lease.pinned[0] = new_id
+    got.append(new_id)
+    # ... and the copy, the caller's alone, is written where it is
+    got.append(kv.update_block(new_id, row(2), 0))
+    return got, [lease]
+
+
+COMMIT_CASES = [
+    pytest.param(_missing(n, pin), {}, id=f"{name}-missing-pin-{pin}")
+    for n, name in ((1, "1"), (2, "2"), (7, "7"), (CS // CBS, "all"))
+    for pin in (True, False)
+] + [
+    pytest.param(_partly_shared(True), {}, id="partly-shared-pin-True"),
+    pytest.param(_partly_shared(False), {}, id="partly-shared-pin-False"),
+    pytest.param(_reservation_runs_out, {}, id="reservation-runs-out"),
+    pytest.param(_pool_runs_out, {"blocks": 4}, id="pool-runs-out"),
+    pytest.param(_evicts_least_recent_leaves, {"blocks": 8},
+                 id="evicts-least-recent-leaves"),
+    pytest.param(_evicts_its_own_leaf, {"blocks": 5}, id="evicts-its-own-leaf"),
+    pytest.param(_copy_on_write, {}, id="update-block-copy-on-write"),
+    pytest.param(_missing(3, True), {"family": "deepseek"},
+                 id="deepseek-two-leaves"),
+    pytest.param(_missing(3, True), {"tp": 2}, id="tp2-plan"),
+]
+
+
+def _commit_manager(blocks, family, tp):
+    plan = None
+    if tp > 1:
+        if len(jax.devices()) < tp:
+            pytest.skip(f"needs {tp} (host) devices")
+        from ray_tpu.models.llama import LlamaConfig
+        from ray_tpu.parallel.plan import PartitionPlan
+
+        plan = PartitionPlan.for_model(LlamaConfig.tiny(max_seq_len=CS), tp)
+    m = KVCacheManager(num_blocks=blocks, block_size=CBS, plan=plan)
+    # the block gauges are the process's, a series a mesh, and a later test
+    # of this file reads their sum
+    m._update_gauges = lambda: None
+
+    def on_device(row):
+        row = {name: jnp.asarray(leaf) for name, leaf in row.items()}
+        if plan is not None:
+            row = {name: leaf if name == "cache_index"
+                   else jax.device_put(leaf, plan.kv_sharding())
+                   for name, leaf in row.items()}
+        return row
+
+    m.initialize(on_device(_np_row(0, family)))
+    return m, on_device
+
+
+def _log_freed(m):
+    """Block ids in the order their last reference went (evictions and
+    releases alike)."""
+    freed, release = [], m._alloc.release
+
+    def logged(bid):
+        left = release(bid)
+        if left == 0:
+            freed.append(bid)
+        return left
+
+    m._alloc.release = logged
+    return freed
+
+
+@pytest.mark.parametrize("case, options", COMMIT_CASES)
+def test_commit_is_one_program_and_the_block_by_block_bookkeeping(
+        case, options, monkeypatch):
+    blocks = options.get("blocks", 16)
+    family, tp = options.get("family", "llama"), options.get("tp", 1)
+    m, on_device = _commit_manager(blocks, family, tp)
+    change = _OneProgram(m)
+    rows = {seed: on_device(_np_row(seed, family))
+            for seed in (0, 1, 2, 100, 200, 300)}
+    converts = []
+    impl = jax.lax.convert_element_type_p.impl
+    monkeypatch.setattr(
+        jax.lax.convert_element_type_p, "impl",
+        lambda *a, **k: converts.append(1) or impl(*a, **k))
+    freed = _log_freed(m)
+    got, leases = case(change, rows.__getitem__)
+    monkeypatch.undo()
+    after = _bookkeeping(m, leases, freed)
+
+    parent = _BlockByBlock(_commit_manager(blocks, family, 1)[0])
+    freed = _log_freed(parent.m)
+    want, leases = case(parent, lambda seed: _np_row(seed, family))
+    assert got == want
+    assert after == _bookkeeping(parent.m, leases, freed)
+    assert len(m._pools) == len(parent.pools)
+    for pool, plain in zip(m._pools, parent.pools):
+        assert np.asarray(pool).tobytes() == plain.tobytes()
+    if tp > 1:
+        assert all(p.sharding == m._plan.kv_sharding() for p in m._pools)
+    # one call of the one program for each call that had a block to write,
+    # and no scalar made on the device on the way
+    assert change.calls == parent.written == m.commit_counts()[0]
+    assert change.program._cache_size() == 1
+    assert converts == []
+
+
+def test_one_compiled_commit_program_for_every_count():
+    m, on_device = _commit_manager(64, "llama", 1)
+    row = on_device(_np_row(1))
+    for n in range(1, CS // CBS + 1):
+        lease = m.acquire(_toks(1000 * n, n))
+        assert m.commit(lease, _toks(1000 * n, n), row) == n
+        m.release(lease)
+    assert m.commit_counts() == (CS // CBS, 0)
+    assert m._jit_commit._cache_size() == 1
+
+
+# ---------------------------------------------------------------------------
 # End-to-end: paged engine == dense engine, token for token
 
 

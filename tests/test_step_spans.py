@@ -305,6 +305,24 @@ def test_every_span_of_the_table_with_its_counts(recorded):
     assert reads == ([2] + [1] * (NEW - 3) + [0]) * 3
 
 
+def test_kv_commit_counts_its_dispatches_and_evictions(recorded):
+    """A commit is one program whatever it writes, a tail's row read one
+    more, a commit with nothing missing none; and the blocks a call had to
+    evict are counted on it (the 8-block pool is full before the session)."""
+    commits = _named(recorded["stepped"], "kv.commit")
+    assert [(s["stats"]["blocks"], s["stats"]["dispatches"]) for s in commits
+            if not s["stats"].get("tail")] == [(1, 1)] * 4 + [(0, 0)]
+    # each tail's block takes an evicted one's place; the repeated prompt's
+    # greedy tail is the first's, already there: its row is read and
+    # nothing is written
+    assert [(s["stats"]["dispatches"], s["stats"]["evictions"]) for s in commits
+            if s["stats"].get("tail")] == [(2, 1)] * 4 + [(1, 0)]
+    for name in ("stepped", "blocked", "threaded"):
+        for s in _named(recorded[name], "kv.commit"):
+            assert 0 <= s["stats"]["dispatches"] <= 2, s
+            assert s["stats"]["evictions"] >= 0, s
+
+
 def test_batch_and_pending_are_what_was_arranged(recorded):
     spans = recorded["stepped"]
     steps = _named(spans, "engine.step")
