@@ -50,7 +50,7 @@ import time
 from typing import Callable, Dict, List
 
 from ..harness import manifest, stats, traffic, xplane, xplane_scopes
-from ..harness.cli import Run, emit, no_compilation, wait_gone
+from ..harness.cli import Run, emit, no_compilation
 from .serve_common import (
     APP, CHECK_TOKENS, BenchReplica, Clients, call, end_to_end,
 )
@@ -234,7 +234,7 @@ def llm_config(config: dict, seed: int):
 
 
 @contextlib.contextmanager
-def serving(cfg):
+def serving(cfg, run: Run):
     """One cluster serving ``cfg`` through ``ArchReplica``: yields (handle,
     pids), as ``serve_common.serving``."""
     import ray_tpu
@@ -257,7 +257,7 @@ def serving(cfg):
             serve.shutdown()
         finally:
             ray_tpu.shutdown()
-        wait_gone(pids, "serve")
+        run.reap(pids)
 
 
 def check_and_warm(handle, cell: dict, seed: int, tolerance: dict) -> bool:
@@ -302,11 +302,13 @@ def run_serving(run: Run, load: Callable) -> dict:
     window = float(args.seconds)
     ramp = float(mix["ramp_s"])
     trace_dir = os.path.join(run.out_dir, "trace")
-    with serving(cfg) as (handle, pids):
+    with serving(cfg, run) as (handle, pids):
         device = call(handle, "bench_device")
         pids.append(device["pid"])
         run.check_device(device)
+        run.phase = "check"
         checked = check_and_warm(handle, cell, args.seed, mix["tolerance"])
+        run.phase = "setup"
         # before the compile counters are read: this reads the cache once
         scope_of = (call(handle, "bench_op_scopes", scope_names)
                     if args.trace and scope_names else {})
@@ -366,6 +368,7 @@ def run_serving(run: Run, load: Callable) -> dict:
         after = call(handle, "runtime_info")
         kv = call(handle, "kvcache_stats")
         device = call(handle, "bench_device")
+        run.phase = "teardown"
     judged = stats.due_in(records, 0.0, window)
     no_compiles = no_compilation(before["compile"], after["compile"])
     emit(check="serve.no_compilation_in_window", ok=no_compiles,

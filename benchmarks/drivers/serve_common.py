@@ -23,7 +23,7 @@ from typing import Callable, Dict, List, Optional
 from ray_tpu.llm.serving import _LLMReplica
 
 from ..harness import stats, traffic, xplane
-from ..harness.cli import Run, emit, no_compilation, wait_gone
+from ..harness.cli import Run, emit, no_compilation
 
 APP = "bench"
 CHECK_TOKENS = 16
@@ -102,9 +102,10 @@ def llm_config(config: dict, seed: int):
 
 
 @contextlib.contextmanager
-def serving(cfg):
+def serving(cfg, run: Run):
     """One cluster serving ``cfg`` through ``BenchReplica``: yields (handle,
-    pids). The caller adds the chip-owning worker's pid; it is gone on exit."""
+    pids). The caller adds the chip-owning worker's pid, for the line; on exit
+    every process the run started is gone (``Run.reap``)."""
     import ray_tpu
     from ray_tpu import serve
 
@@ -125,7 +126,7 @@ def serving(cfg):
             serve.shutdown()
         finally:
             ray_tpu.shutdown()
-        wait_gone(pids, "serve")
+        run.reap(pids)
 
 
 def call(handle, method: str, *args):
@@ -221,11 +222,13 @@ def run_serving(run: Run, load: Callable) -> dict:
     window = float(args.seconds)
     ramp = float(mix["ramp_s"])
     trace_dir = os.path.join(run.out_dir, "trace")
-    with serving(cfg) as (handle, pids):
+    with serving(cfg, run) as (handle, pids):
         device = call(handle, "bench_device")
         pids.append(device["pid"])
         run.check_device(device)
+        run.phase = "check"
         checked = check_and_warm(handle, cell, args.seed, mix["tolerance"])
+        run.phase = "setup"
         before = call(handle, "runtime_info")["compile"]
 
         opened = time.perf_counter() + ramp
@@ -286,6 +289,7 @@ def run_serving(run: Run, load: Callable) -> dict:
         after = call(handle, "runtime_info")
         kv = call(handle, "kvcache_stats")
         device = call(handle, "bench_device")
+        run.phase = "teardown"
     judged = stats.due_in(records, 0.0, window)
     no_compiles = no_compilation(before, after["compile"])
     emit(check="serve.no_compilation_in_window", ok=no_compiles,

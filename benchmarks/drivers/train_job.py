@@ -21,7 +21,7 @@ import os
 import time
 
 from ..harness import xplane
-from ..harness.cli import Run, emit, no_compilation, wait_gone
+from ..harness.cli import Run, emit, no_compilation
 
 
 def train_loop(job: dict) -> None:
@@ -166,7 +166,7 @@ def run(run: Run) -> dict:
     chips = cell["chips"]
     trace_dir = os.path.join(run.out_dir, "trace") if args.trace else None
     ray_tpu.init()
-    pid = None
+    pids: list = []
     try:
         fitted = train.JaxTrainer(
             train_loop,
@@ -178,13 +178,16 @@ def run(run: Run) -> dict:
                 name=cell["name"], storage_path=os.path.join(run.out_dir, "train_runs")),
         ).fit()
         if fitted.error is not None:
+            # set-up, check and window are one call here: a worker that had
+            # reported a step had opened the window
+            run.phase = "window" if fitted.metrics_history else "setup"
             raise SystemExit(f"benchmark: {cell['name']}: {fitted.error}")
         facts = next(h["bench"] for h in fitted.metrics_history if "bench" in h)
-        pid = facts["pid"]
+        pids.append(facts["pid"])
+        run.phase = "teardown"
     finally:
         ray_tpu.shutdown()
-        if pid is not None:
-            wait_gone([pid], cell["name"])
+        run.reap(pids)
     run.check_device(facts["device"])
     run.setup_done(facts["opened_wall"])
 

@@ -2,12 +2,16 @@
 
     python benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Starts a cluster, loads, checks correctness, warms the cell's shapes,
-measures for ``--seconds``, shuts the cluster down and prints the result
-line last. This process never initialises a JAX backend: the chip belongs to
-the worker the raylet leases it to, and that worker reports the device.
-Everything else worth keeping goes to earlier stdout lines (one JSON object
-each) or to ``benchmarks/out/<workload>/``.
+Waits until no other process holds a chip, starts a cluster, loads, checks
+correctness, warms the cell's shapes, measures for ``--seconds``, shuts the
+cluster down, waits until every process the run started is out of ``/proc``
+(``harness/boundaries.py``) and prints the result line last. This process
+never initialises a JAX backend: the chip belongs to the worker the raylet
+leases it to, and that worker reports the device. Everything else worth
+keeping goes to earlier stdout lines (one JSON object each) or to
+``benchmarks/out/<workload>/``. A run that ends any other way prints a
+failure line last (``error``, ``phase``, and the holders or leftovers where
+it has them) and exits non-zero.
 """
 
 from __future__ import annotations
@@ -16,30 +20,16 @@ import argparse
 import importlib
 import json
 import os
+import sys
 import time
+import traceback
+from typing import Optional
 
-from . import manifest
+from . import boundaries, manifest
 
 
 def emit(**fields) -> None:
     print(json.dumps(fields, default=str), flush=True)
-
-
-def _pid_gone(pid: int) -> bool:
-    """Exited, reaped or not: a zombie has released its devices."""
-    try:
-        with open(f"/proc/{pid}/stat") as f:
-            return f.read().rsplit(")", 1)[1].split()[0] == "Z"
-    except (FileNotFoundError, ProcessLookupError):
-        return True
-
-
-def wait_gone(pids, what: str, timeout_s: float = 60.0) -> None:
-    deadline = time.time() + timeout_s
-    while not all(_pid_gone(p) for p in pids):
-        if time.time() > deadline:
-            raise RuntimeError(f"{what}: chip worker pid(s) {pids} still alive")
-        time.sleep(0.1)
 
 
 def peaks() -> dict:
@@ -74,14 +64,28 @@ def no_compilation(before: dict, after: dict) -> bool:
 
 
 class Run:
-    """One run's arguments, clock and output directory."""
+    """One run's arguments, clock, output directory and how far it has come:
+    ``phase`` is ``gate``, ``setup``, ``check``, ``window`` or ``teardown``,
+    moved by ``gate``, ``setup_done`` and the driver, named by a failure line."""
 
     def __init__(self, cell: dict, args, started_wall: float):
         self.cell, self.args = cell, args
         self.started_wall = started_wall
         self.setup_s = None
+        self.phase = "gate"
+        self.teardown = None
         self.out_dir = os.path.join(manifest.BENCH_DIR, "out", cell["name"])
+
+    def gate(self) -> None:
+        """The run begins when no other process holds a chip. ``setup_s`` is
+        this run's set-up, not the last run's death: the clock moves forward
+        by the wait."""
+        waited = boundaries.wait_chips_free()
+        self.started_wall += waited["chips_wait_s"]
+        emit(**waited)
+        boundaries.become_subreaper()
         os.makedirs(self.out_dir, exist_ok=True)
+        self.phase = "setup"
 
     def check_device(self, device: dict) -> None:
         require_device(device, self.cell["chips"])
@@ -89,6 +93,15 @@ class Run:
     def setup_done(self, opened_wall: float) -> None:
         """``opened_wall``: time.time() at which the measured window opens."""
         self.setup_s = opened_wall - self.started_wall
+        self.phase = "window"
+
+    def reap(self, pids=()) -> None:
+        """The run ends when nothing it started is left in ``/proc``. Called
+        once ``ray_tpu.shutdown()`` has returned; ``pids`` are the ones the
+        driver collected, for the line. Leaves ``phase`` as it is: a driver
+        that is on its way out with an exception gets here too."""
+        self.teardown = boundaries.reap()
+        emit(**self.teardown, reported_pids=list(pids))
 
 
 def _layer_metrics(cell_name: str, result: dict, reported: set) -> dict:
@@ -107,8 +120,7 @@ def _layer_metrics(cell_name: str, result: dict, reported: set) -> dict:
     return out
 
 
-def main(argv=None, started_wall: float = None) -> int:
-    started_wall = started_wall or time.time()
+def _arguments(argv):
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, default=0)
@@ -117,29 +129,13 @@ def main(argv=None, started_wall: float = None) -> int:
     args = parser.parse_args(argv)
     if args.seconds is None:
         args.seconds = float(manifest.benchmark()["run_seconds"])
+    return args
 
-    cell = manifest.cell(args.workload)
-    if not os.path.isdir(os.path.join(manifest.ROOT, "ray_tpu")):
-        raise SystemExit("benchmark: no system under test beside benchmarks/ (ray_tpu/)")
-    require_chips(cell["chips"])
 
-    # the program honours JAX_COMPILATION_CACHE_DIR and workers inherit it:
-    # a fixed path inside the checkout, unless the machine already names one
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR", os.path.join(manifest.ROOT, ".jax_cache"))
-    for key, value in cell["traffic_file"].get("environment", {}).items():
-        os.environ.setdefault(key, str(value))
-
-    run = Run(cell, args, started_wall)
-    emit(start="benchmark", workload=cell["name"], seed=args.seed,
-         seconds=args.seconds, trace=args.trace, pid=os.getpid(),
-         compile_cache_dir=os.environ["JAX_COMPILATION_CACHE_DIR"])
-    driver = importlib.import_module(
-        f"benchmarks.drivers.{cell['traffic_file']['kind']}")
-    result = driver.run(run)
-
+def _result_line(run: Run, result: dict) -> dict:
     from ray_tpu._internal.platform import backend_initialized
 
+    cell, args = run.cell, run.args
     if backend_initialized():
         raise SystemExit("benchmark: the harness process initialised a JAX backend")
     end_to_end = dict(result["end_to_end"], setup_s=run.setup_s)
@@ -170,7 +166,87 @@ def main(argv=None, started_wall: float = None) -> int:
             "idle_gaps": [[n, s] for n, s, _ in trace["gaps"]],
         }
         emit(trace_modules=trace["modules"], gaps=trace["gaps"])
+    return line
+
+
+def _failure(run: Optional[Run], exc: BaseException) -> None:
+    """The last stdout line of a run that prints no result: what failed, in
+    which phase, and the holders or leftovers where the failure has them.
+    A driver that raised before its own shutdown and reap gets both here, so
+    a failed run leaves the machine as clean as a good one."""
+    line = {"error": f"{type(exc).__name__}: {exc}",
+            "phase": run.phase if run else "gate"}
+    line.update(getattr(exc, "details", {}))
+    started = run is not None and run.phase != "gate"
+    if started and run.teardown is None and not isinstance(exc, boundaries.RunVoid):
+        try:
+            import ray_tpu
+
+            ray_tpu.shutdown()
+            line.update(boundaries.reap())
+        except boundaries.RunVoid as left:
+            line.update(left.details)
+        except Exception:  # the failure asked about is exc: this one is said beside it
+            line["teardown_error"] = traceback.format_exc(limit=4)
+    emit(threads_alive_at_exit=boundaries.threads_alive())
+    print(json.dumps(line, default=str), flush=True)
+
+
+def main(argv=None, started_wall: float = None) -> int:
+    started_wall = started_wall or time.time()
+    args = _arguments(argv)
+    run = None
+    try:
+        cell = manifest.cell(args.workload)
+        if not os.path.isdir(os.path.join(manifest.ROOT, "ray_tpu")):
+            raise SystemExit("benchmark: no system under test beside benchmarks/ (ray_tpu/)")
+        require_chips(cell["chips"])
+
+        # the program honours JAX_COMPILATION_CACHE_DIR and workers inherit it:
+        # a fixed path inside the checkout, unless the machine already names one
+        os.environ.setdefault(
+            "JAX_COMPILATION_CACHE_DIR", os.path.join(manifest.ROOT, ".jax_cache"))
+        for key, value in cell["traffic_file"].get("environment", {}).items():
+            os.environ.setdefault(key, str(value))
+
+        run = Run(cell, args, started_wall)
+        emit(start="benchmark", workload=cell["name"], seed=args.seed,
+             seconds=args.seconds, trace=args.trace, pid=os.getpid(),
+             compile_cache_dir=os.environ["JAX_COMPILATION_CACHE_DIR"])
+        run.gate()
+        driver = importlib.import_module(
+            f"benchmarks.drivers.{cell['traffic_file']['kind']}")
+        result = driver.run(run)
+        line = _result_line(run, result)
+    except BaseException as exc:
+        _failure(run, exc)
+        raise
+    # the in-process GCS and raylet are the program's to stop (ROADMAP D20):
+    # what they leave alive is named here, before the line that comes last
+    emit(threads_alive_at_exit=boundaries.threads_alive())
     with open(os.path.join(run.out_dir, "result.json"), "w") as f:
         json.dump(line, f)
     print(json.dumps(line), flush=True)
     return 0
+
+
+def main_then_leave(started_wall: float) -> None:
+    """What ``run.py`` calls: ``main``, and then out of the process at once.
+    An interpreter's ordinary exit joins every non-daemon thread, so a
+    thread the program left alive (named on the ``threads_alive_at_exit``
+    line) would hold this process, and the chips' next user, behind it. The
+    benchmark's exit is not the product's."""
+    try:
+        code = main(started_wall=started_wall)
+    except SystemExit as exc:
+        code = exc.code if isinstance(exc.code, int) else int(exc.code is not None)
+        if code and not isinstance(exc.code, int):
+            print(exc.code, file=sys.stderr)
+    except BaseException:
+        traceback.print_exc()
+        code = 1
+    sys.stdout.flush()
+    sys.stderr.flush()
+    if boundaries.threads_alive(wait_s=0.0):
+        os._exit(code)
+    sys.exit(code)

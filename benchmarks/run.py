@@ -11,4 +11,4 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 if __name__ == "__main__":
     from benchmarks.harness import cli
 
-    sys.exit(cli.main(started_wall=STARTED))
+    cli.main_then_leave(started_wall=STARTED)

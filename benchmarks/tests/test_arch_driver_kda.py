@@ -153,9 +153,8 @@ def test_the_real_cell_entered_only_by_additions(tiny_moe_benchmark):
     assert not {m for m in per_layer
                 if m.startswith(("mla_", "ssm_")) or m.endswith("decode_roofline")
                 and m != "kda_decode_roofline" or m == "moe_experts_roofline"}
-    # no pool, and a counter that reads null since PR 33
-    assert not {"kv_copy_busy_share", "kv_pool_used_peak",
-                "replica_executor_wait_p50_ms"} & per_layer
+    # no pool
+    assert not {"kv_copy_busy_share", "kv_pool_used_peak"} & per_layer
     assert {"decode_step_device_ms", "kv_bytes_per_token", "state_bytes_per_row",
             "moe_experts_busy_share", "moe_experts_touched_mean",
             "moe_expert_load_max_over_mean", "sched_decode_batch_mean",
