@@ -40,6 +40,7 @@ import numpy as np
 
 from .._internal.platform import decode_step_compiler_options
 from ..models import INDEX, ROUTING as _ROUTING, SEQUENCE, STATE, cache_kinds
+from ..ops.decode_attention import traced_chunk, visits
 from ..ops.kv_row_write import traced_form
 from ..util import events as _events
 from ..util import tracing as _tracing
@@ -773,6 +774,12 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # running expert counts of a routed model (None for a dense one),
         # device-side; expert_stats() reads them
         self._expert_counts = _new_expert_counts(model_config, num_slots)
+        # the decode kernel's chunks over the steps dispatched: those it
+        # visited and those a grid of rows x the whole cache would have
+        # (attention_chunks()), one call's worth a step, counted from the
+        # rows' positions as the host knows them
+        self._attention_chunks = [0, 0]
+        self._attention_grid: Optional[tuple] = None  # (chunk, max_seq_len)
         self._cache = None  # pooled cache, allocated on first prefill
         # paged prefix cache (ray_tpu.kvcache.KVCacheManager) or None for
         # the dense per-slot pool; with a manager, _admit serves the
@@ -1175,6 +1182,9 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         first = np.zeros(self._num_slots, np.int32)
         active = np.zeros(self._num_slots, bool)
         temps = np.zeros(self._num_slots, np.float32)
+        # keys a row holds for the step's attention: a free row restarts
+        # at position 0 every step and is one key long
+        keys = np.ones(self._num_slots, np.int64)
         batch = live_tokens = 0
         for si, slot in self._slots.items():
             active[si] = True
@@ -1184,6 +1194,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 fresh[si] = True
                 first[si] = slot.last_token
             have = len(slot.generated) + in_flight
+            keys[si] = len(slot.request.token_ids) + have
             if have < slot.request.max_new_tokens:
                 batch += 1
                 live_tokens += len(slot.request.token_ids) + have
@@ -1207,6 +1218,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             )
             if counts:
                 (self._expert_counts,) = counts
+            self._count_attention_chunks(keys)
             self._step_count += 1
             self._sampled = self._sample_rows(logits, temps)
         return _Step(self._sampled, dict(self._slots))
@@ -1368,6 +1380,33 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         if leaves is None:
             return None
         return {name: traced_form(leaf.shape) for name, leaf in leaves}
+
+    def _count_attention_chunks(self, keys: np.ndarray) -> None:
+        """Add the decode step just dispatched over rows ``keys`` long to
+        ``attention_chunks()``; nothing while no traced step took the
+        kernel (``ops/decode_attention.traced_chunk``)."""
+        if self._attention_grid is None:
+            for leaf in jax.tree.leaves(self._cache):
+                chunk = traced_chunk(leaf.shape)
+                if chunk:
+                    self._attention_grid = (chunk, leaf.shape[2])
+                    break
+            else:
+                return
+        chunk, max_seq_len = self._attention_grid
+        self._attention_chunks[0] += int(
+            visits(np.minimum(keys, max_seq_len), chunk))
+        self._attention_chunks[1] += self._num_slots * -(-max_seq_len // chunk)
+
+    def attention_chunks(self) -> Dict[str, int]:
+        """``attention_chunks_visited``: chunks of keys the decode kernel
+        visited (and copied) over the decode steps dispatched so far, one
+        call a step; ``attention_chunks_dense``: what rows x the whole
+        cache would have been. Their ratio is the share of the dense grid
+        the traffic's lengths leave (1.0: every row full)."""
+        visited, dense = self._attention_chunks
+        return {"attention_chunks_visited": visited,
+                "attention_chunks_dense": dense}
 
     def _live_tokens(self) -> int:
         """Key positions the coming decode step attends over all live rows

@@ -377,6 +377,10 @@ class _LLMReplica:
                     self._engine, "state_bytes_per_row", lambda: None)(),
                 "row_write": getattr(
                     self._engine, "row_write", lambda: None)(),
+                # chunks of keys the decode kernel visited over the steps
+                # dispatched, and what a dense grid would have
+                # (attention_chunks_visited / attention_chunks_dense)
+                **getattr(self._engine, "attention_chunks", dict)(),
                 **(
                     {} if self._kv_cache is None else {
                         "prefix_reuse": self._kv_cache.prefix_reuse,

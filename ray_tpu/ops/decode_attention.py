@@ -8,31 +8,45 @@ both caches ``h // hk`` times, casts them to f32 and scores all
 grows with the configured maximum, not with the tokens present.
 
 Design:
-- grid (rows, key blocks), key blocks innermost; one block holds all of a
-  row's KV heads, so a grid step moves 1 MB each of K and V and a row costs
-  ``max_seq_len / block_k`` steps however many heads it has
-- ``lengths`` is the scalar-prefetch argument: the K/V index map clamps the
-  block index to the row's last live block, so the index repeats past the
-  length and Pallas elides the copy; ``pl.when`` skips the arithmetic
+- one call of the kernel walks the whole step (``_walk``): a loop over
+  *visits*, one chunk of ``block_k`` key positions of one row, all of the
+  row's KV heads. Its extent is the step's live chunks, ``sum(cdiv(max(
+  length, 1), chunk))`` over the rows, counted in the kernel from
+  ``lengths``: nothing of it is a function of ``max_seq_len``, and no XLA
+  op prepares it. (Until PR 48 a grid of rows x ``cdiv(max_seq_len,
+  block)`` steps, ~0.35 us each, live or dead. A grid of that dynamic
+  extent needs one reduction a layer in XLA, whose operand is fetched a
+  second time behind the weights' prefetches: 0.08-0.57 ms of a 12-layer
+  step, PERF.md.) A row of length 0 keeps one visit, in which every
+  position is masked, so its output is written (as zeros) like any other's
+- ``lengths`` is the scalar-prefetch argument and the whole schedule
+- K and V stay in HBM; a visit waits for its own chunk's copy, and the
+  copies of the next ``_SLOTS - 1`` visits are on their way, so only chunks
+  that hold a key are ever copied. Queries and outputs of all rows are
+  resident in VMEM (a few hundred KB)
 - the ``h // hk`` query heads of a group are the rows of one small matmul
-  against the group's K/V block: nothing is repeated in HBM
+  against the group's K/V chunk: nothing is repeated in HBM
 - the einsum's arithmetic: exact products accumulated in f32 (stored values
   times stored values for q.k, f32 probabilities for p.v), f32 online
   softmax with running max, sum and accumulator in VMEM scratch
+- the Pallas call sits under a ``jax.jit`` of its own (``_attend``,
+  ``_latent_attend``): a program of twelve layers traces and lowers the
+  kernel once a shape, not twelve times
 
 ``latent_decode_attention`` is the same kernel for a latent cache (DeepSeek's
 MLA in its absorbed form, models/deepseek.py): one shared row a position
 (``rank`` latent columns and ``rope`` rotary ones, a cache each), every
 query head a row of one matmul against it, and the values are the latent
-columns themselves, so a block is read once and serves as K and as V. It shares the length clamp, the online softmax
-(``_accumulate``) and ``_dot``.
+columns themselves, so a chunk is read once and serves as K and as V. It
+shares the schedule (``_walk``), the online softmax (``_init``,
+``_accumulate``, ``_normalised``) and ``_dot``.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,13 +60,50 @@ _NEG_INF = -1e30
 # query heads of a group are padded to one f32 sublane tile, so every
 # in-kernel slice of the group's rows is tile-aligned
 _GROUP_ROWS = 8
-# K (or V) bytes one grid step moves. Measured on a v5e at 16 rows x 8 KV
-# heads x 4096 x 128 bf16, twelve layers: 0.5 / 1 / 2 MB take 1.9 / 1.8 / 2.1
-# ms with 128-832 keys a row and 7.8 / 5.1 / 4.4 ms with every row full. A
-# larger block amortises the ~0.35 us a grid step costs, live or skipped; a
-# smaller one wastes less of a short row's last block. K and V,
-# double-buffered, are four blocks of VMEM.
-_BLOCK_BYTES = 1024 * 1024
+# K (or V) bytes one visit copies: 128 key positions at every serving
+# shape there is (8 / 16 / 4 KV heads of 128, bf16), more where a shard holds
+# one head or two. Measured on a v5e, the kernel alone, a program of the
+# cell's layers, ms (PERF.md, PR 48; before: a grid of rows x cdiv(max_seq_len,
+# block) steps over blocks of 1 MB, 512 / 256 / 1024 / 2048 keys):
+#   16 rows x 8 heads x 4096, 12 layers   128 keys  256 keys  512 keys  before
+#     15 rows of one key + one of 480       0.261     0.600       -      1.436
+#     128-832 keys a row                    0.766     1.341       -      1.810
+#     every row full                        4.619     7.673       -      5.141
+#   8 x 16 heads x 4096, 8 layers, 128-832  0.379     0.687       -      0.969
+#     every row full                        2.897     4.958       -      5.011
+#   64 x 4 heads x 1024, 6 layers, 128-832  1.091     1.367     1.043    1.173
+#     every row full                        1.861     2.110     1.380    1.172
+#   32 x 8 heads x 2048, 2 layers, 200-2048 0.479     0.816     0.575    0.752
+#   16 x 2 heads x 4096 (a tp=4 shard)      0.502     0.459     0.370    1.061
+#     every row full                        2.918     2.407     1.483    1.139
+# A visit costs ~0.35 us besides its copy, so a chunk wants bytes (the rows
+# of few heads, the last three shapes); a row's last chunk is copied and
+# multiplied whole, so short rows want it small. 256 keys are slower than
+# either neighbour wherever four heads or more share a chunk (the f32 p.v
+# at a contraction of 256), so the rule stops at 128 for those.
+_BLOCK_BYTES = 128 * 1024
+# ... and of a latent cache, keys and values in one: 1024 positions of 576
+# values. Its rows are long where it is served (1024-5120 keys of 8192, 24
+# rows, 7 layers): 512 / 1024 / 2048 positions took 1.229 / 1.120 / 1.192 ms
+# (before: 1.552), every row full 3.085 / 2.625 / 2.430 (2.743), 23 rows of
+# one key beside one of 3000 0.298 / 0.421 / 0.699 (0.866).
+_LATENT_BLOCK_BYTES = 2 * 1024 * 1024
+# visits whose chunks are in VMEM or on their way there at once. With two, a
+# 0.5 MB copy started one visit ahead has not landed when its visit comes
+# (0.945 / 5.923 ms for the 128-832 and the full rows of the first shape);
+# with three it has (0.766 / 4.619); four give nothing more (0.759 / 4.597).
+_SLOTS = 3
+
+# cache shape -> key positions a visit of the kernel traced for it covers:
+# what the engine counts a step's visits in (``traced_chunk``)
+_traced_chunks: Dict[Tuple[int, ...], int] = {}
+
+
+def traced_chunk(cache_shape: Sequence[int]) -> Optional[int]:
+    """Key positions in one visit of the kernel a program of this process
+    traced for a cache (K, or the latent leaf) of this shape; None if none
+    was."""
+    return _traced_chunks.get(tuple(cache_shape))
 
 
 def _use_interpret(kernel: str = "decode_attention") -> bool:
@@ -61,13 +112,21 @@ def _use_interpret(kernel: str = "decode_attention") -> bool:
     return pallas_interpret(kernel)
 
 
+def _chunk(max_seq_len: int, per_position: int, fit_bytes: int) -> int:
+    """A power of two of positions of about ``fit_bytes``, at least 128
+    (the lane width of the scores), that divides the cache: a chunk never
+    hangs over the cache's end, where a copy could not follow it. (A cache
+    whose length is no multiple of 128 gets the largest power of two that
+    divides it.)"""
+    fit = max(128, fit_bytes // per_position)
+    return math.gcd(max_seq_len, 1 << (fit.bit_length() - 1))
+
+
 def block_k(max_seq_len: int, kv_heads: int, head_dim: int, dtype) -> int:
-    """Key positions in one block: a power of two of about ``_BLOCK_BYTES``
-    across the block's KV heads, at least 128 (the lane width of the scores)
-    and at most the cache itself."""
+    """Key positions in one chunk: about ``_BLOCK_BYTES`` across the
+    chunk's KV heads (``_chunk``'s rule)."""
     per_position = kv_heads * head_dim * jnp.dtype(dtype).itemsize
-    fit = max(128, _BLOCK_BYTES // per_position)
-    return min(max_seq_len, 1 << (fit.bit_length() - 1))
+    return _chunk(max_seq_len, per_position, _BLOCK_BYTES)
 
 
 def _dot(a, b, dims):
@@ -87,9 +146,15 @@ def _dot(a, b, dims):
     )
 
 
+def _init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
 def _accumulate(s, v, acc_ref, m_ref, l_ref, j):
-    """One block of the online softmax: masked scores ``s (rows, block)``
-    and values ``v (block, d)`` into slot ``j`` of the running max, sum and
+    """One chunk of the online softmax: masked scores ``s (rows, chunk)``
+    and values ``v (chunk, d)`` into slot ``j`` of the running max, sum and
     accumulator."""
     m_prev = m_ref[j]  # (rows, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -100,80 +165,147 @@ def _accumulate(s, v, acc_ref, m_ref, l_ref, j):
     m_ref[j] = m_new
 
 
-def _last_live_block(lengths_ref, bi, ki, block: int):
-    """The key block grid step ``ki`` of row ``bi`` reads: its own up to
-    the row's last live one, which then repeats, so Pallas elides the copy
-    of every block past the row's length."""
-    last_live = jnp.maximum(lengths_ref[bi] - 1, 0) // block
-    return jnp.minimum(ki, last_live)
+def _normalised(acc_ref, l_ref):
+    l = l_ref[...]
+    l = jnp.where(l == 0.0, 1.0, l)  # a row of length 0 attends nothing
+    return acc_ref[...] / l
+
+
+def visits(lengths, chunk: int):
+    """Chunks of a step that hold a key, a row of length 0 counted as one:
+    what the kernels walk (``_walk`` counts the same in SMEM), of NumPy
+    ``lengths``, for the engine's counter."""
+    return ((lengths.clip(1) + (chunk - 1)) // chunk).sum()
+
+
+def _after(lengths_ref, row, ci, chunk: int):
+    """The visit that follows ``(row, ci)``, and whether the row ends at
+    ``ci``. (Past the last row there is nothing to name: the clamp only
+    keeps the read inside ``lengths``.)"""
+    last_row = lengths_ref.shape[0] - 1
+    ends = (ci + 1) * chunk >= lengths_ref[jnp.minimum(row, last_row)]
+    return jnp.where(ends, row + 1, row), jnp.where(ends, 0, ci + 1), ends
+
+
+def _walk(lengths_ref, chunk: int, copies, visit):
+    """The schedule both kernels share: ``visit(row, ci, slot, ends)`` once
+    for every chunk ``ci`` of every row that holds a key (a row of length
+    0 has one, all masked), rows in order, with that chunk's copies landed
+    in buffer ``slot``; ``ends`` says the row's last chunk.
+
+    ``copies(row, ci, slot)`` describes a visit's HBM -> VMEM copies. Up
+    to ``_SLOTS - 1`` visits' copies are in flight ahead of the one being
+    worked on: each iteration starts one more before it waits for its
+    own, so a copy runs under the arithmetic of the visits before it."""
+    ahead = _SLOTS - 1
+    n_visits = jax.lax.fori_loop(
+        0, lengths_ref.shape[0],
+        lambda row, n: n + (
+            jnp.maximum(lengths_ref[row], 1) + (chunk - 1)) // chunk,
+        jnp.int32(0),
+    )
+
+    def start(row, ci, nth):
+        for copy in copies(row, ci, nth % _SLOTS):
+            copy.start()
+
+    fetch_row = fetch_ci = jnp.int32(0)
+    for nth in range(ahead):
+        pl.when(nth < n_visits)(
+            functools.partial(start, fetch_row, fetch_ci, nth))
+        fetch_row, fetch_ci, _ = _after(lengths_ref, fetch_row, fetch_ci, chunk)
+
+    def step(nth, at):
+        row, ci, fetch_row, fetch_ci = at
+        pl.when(nth + ahead < n_visits)(
+            functools.partial(start, fetch_row, fetch_ci, nth + ahead))
+        slot = nth % _SLOTS
+        for copy in copies(row, ci, slot):
+            copy.wait()
+        next_row, next_ci, ends = _after(lengths_ref, row, ci, chunk)
+        visit(row, ci, slot, ends)
+        return (next_row, next_ci,
+                *_after(lengths_ref, fetch_row, fetch_ci, chunk)[:2])
+
+    jax.lax.fori_loop(
+        0, n_visits, step, (jnp.int32(0), jnp.int32(0), fetch_row, fetch_ci))
+
+
+def _live(ci, chunk: int, length, rows: int):
+    """Masks of the chunk's positions that lie inside the row's length:
+    ``(rows, chunk)`` for the scores, ``(chunk, 1)`` for the values."""
+    first = ci * chunk
+    k_pos = first + jax.lax.broadcasted_iota(jnp.int32, (rows, chunk), 1)
+    k_row = first + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+    return k_pos < length, k_row < length
 
 
 def _kernel(
-    lengths_ref, q_ref, k_ref, v_ref, o_ref,
-    acc_ref, m_ref, l_ref,
-    *, sm_scale: float, block: int, kv_heads: int,
+    lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
+    k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
+    *, sm_scale: float, chunk: int, kv_heads: int,
 ):
-    bi = pl.program_id(0)
-    ki = pl.program_id(1)
-    length = lengths_ref[bi]
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(ki * block < length)
-    def _live_block():
-        k_pos = ki * block + jax.lax.broadcasted_iota(
-            jnp.int32, (_GROUP_ROWS, block), 1
+    def copies(row, ci, slot):
+        keys = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[row, :, keys], k_buf.at[slot], sems.at[0, slot]),
+            pltpu.make_async_copy(
+                v_hbm.at[row, :, keys], v_buf.at[slot], sems.at[1, slot]),
         )
-        k_row = ki * block + jax.lax.broadcasted_iota(
-            jnp.int32, (block, 1), 0
-        )
+
+    def visit(row, ci, slot, ends):
+        @pl.when(ci == 0)
+        def _start_row():
+            _init(acc_ref, m_ref, l_ref)
+
+        in_scores, in_values = _live(ci, chunk, lengths_ref[row], _GROUP_ROWS)
         for j in range(kv_heads):
-            q = q_ref[0, j]  # (_GROUP_ROWS, d)
-            k = k_ref[0, j]  # (block, d)
-            # rows past the length hold whatever the cache held before (or
-            # nothing, past a partial last block): their probabilities are
-            # 0, but 0 * NaN would still poison the matmul
-            v = jnp.where(k_row < length, v_ref[0, j], 0)
+            q = q_ref[row, j]  # (_GROUP_ROWS, d)
+            k = k_buf[slot, j]  # (chunk, d)
+            # positions past the length hold whatever the cache held before:
+            # their probabilities are 0, but 0 * NaN would still poison the
+            # matmul
+            v = jnp.where(in_values, v_buf[slot, j], 0)
             s = _dot(q, k, ((1,), (1,))) * sm_scale
-            s = jnp.where(k_pos < length, s, _NEG_INF)
+            s = jnp.where(in_scores, s, _NEG_INF)
             _accumulate(s, v, acc_ref, m_ref, l_ref, j)
 
-    @pl.when(ki == pl.num_programs(1) - 1)
-    def _finish():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)  # a row of length 0 attends nothing
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        @pl.when(ends)
+        def _finish_row():
+            o_ref[row] = _normalised(acc_ref, l_ref).astype(o_ref.dtype)
+
+    _walk(lengths_ref, chunk, copies, visit)
 
 
-def _decode_attention(q, k_cache, v_cache, lengths):
+# a whole operand in VMEM for the length of the call, and one that stays in
+# HBM for the kernel to copy from itself
+_RESIDENT = pl.BlockSpec(memory_space=pltpu.VMEM)
+_IN_HBM = pl.BlockSpec(memory_space=pl.ANY)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _attend(q, k_cache, v_cache, lengths, *, chunk: int, interpret: bool):
     b, h, d = q.shape
-    _, hk, max_seq_len, _ = k_cache.shape
+    hk = k_cache.shape[1]
     group = h // hk
-    block = block_k(max_seq_len, hk, d, k_cache.dtype)
     rows = -(-group // _GROUP_ROWS) * _GROUP_ROWS
     qg = q.reshape(b, hk, group, d)
     if rows != group:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - group), (0, 0)))
-
-    def kv_index(bi, ki, lengths_ref):
-        return (bi, 0, _last_live_block(lengths_ref, bi, ki, block), 0)
-
-    q_spec = pl.BlockSpec((1, hk, rows, d), lambda bi, ki, _: (bi, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, hk, block, d), kv_index)
     out = pl.pallas_call(
         functools.partial(
-            _kernel, sm_scale=1.0 / math.sqrt(d), block=block, kv_heads=hk
+            _kernel, sm_scale=1.0 / math.sqrt(d), chunk=chunk, kv_heads=hk
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, pl.cdiv(max_seq_len, block)),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=q_spec,
+            grid=(),
+            in_specs=[_RESIDENT, _IN_HBM, _IN_HBM],
+            out_specs=_RESIDENT,
             scratch_shapes=[
+                pltpu.VMEM((_SLOTS, hk, chunk, d), k_cache.dtype),
+                pltpu.VMEM((_SLOTS, hk, chunk, d), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, _SLOTS)),
                 pltpu.VMEM((hk, rows, d), jnp.float32),
                 pltpu.VMEM((hk, rows, 1), jnp.float32),
                 pltpu.VMEM((hk, rows, 1), jnp.float32),
@@ -181,9 +313,19 @@ def _decode_attention(q, k_cache, v_cache, lengths):
         ),
         out_shape=jax.ShapeDtypeStruct((b, hk, rows, d), q.dtype),
         name="decode_attention",  # the op's name in a device trace
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(lengths, qg, k_cache, v_cache)
     return out[:, :, :group].reshape(b, h, d)
+
+
+def _decode_attention(q, k_cache, v_cache, lengths):
+    _, hk, max_seq_len, d = k_cache.shape
+    chunk = block_k(max_seq_len, hk, d, k_cache.dtype)
+    # (under a mesh the shape is a shard's, which no engine asks for)
+    _traced_chunks[k_cache.shape] = chunk
+    return _attend(
+        q, k_cache, v_cache, lengths, chunk=chunk, interpret=_use_interpret()
+    )
 
 
 def decode_attention(
@@ -214,53 +356,84 @@ def decode_attention(
 
 
 def latent_block_k(max_seq_len: int, width: int, dtype) -> int:
-    """Positions in one block of a latent cache of ``width`` values a
-    position: ``block_k``'s rule with twice the bytes, because the one
-    block a grid step moves is its keys and its values together."""
-    fit = max(128, 2 * _BLOCK_BYTES // (width * jnp.dtype(dtype).itemsize))
-    return min(max_seq_len, 1 << (fit.bit_length() - 1))
+    """Positions in one chunk of a latent cache of ``width`` values a
+    position: ``_chunk``'s rule at ``_LATENT_BLOCK_BYTES``."""
+    per_position = width * jnp.dtype(dtype).itemsize
+    return _chunk(max_seq_len, per_position, _LATENT_BLOCK_BYTES)
 
 
 def _latent_kernel(
-    lengths_ref, q_ref, qr_ref, c_ref, r_ref, o_ref,
-    acc_ref, m_ref, l_ref,
-    *, sm_scale: float, block: int,
+    lengths_ref, q_ref, qr_ref, c_hbm, r_hbm, o_ref,
+    c_buf, r_buf, sems, acc_ref, m_ref, l_ref,
+    *, sm_scale: float, chunk: int,
 ):
-    bi = pl.program_id(0)
-    ki = pl.program_id(1)
-    length = lengths_ref[bi]
-    rows = q_ref.shape[1]
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(ki * block < length)
-    def _live_block():
-        k_pos = ki * block + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block), 1
+    def copies(row, ci, slot):
+        keys = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
+        return (
+            pltpu.make_async_copy(
+                c_hbm.at[row, 0, keys], c_buf.at[slot], sems.at[0, slot]),
+            pltpu.make_async_copy(
+                r_hbm.at[row, 0, :, keys], r_buf.at[slot], sems.at[1, slot]),
         )
-        k_row = ki * block + jax.lax.broadcasted_iota(
-            jnp.int32, (block, 1), 0
-        )
-        latent = c_ref[0, 0]  # (block, rank): the keys' latent part
+
+    def visit(row, ci, slot, ends):
+        @pl.when(ci == 0)
+        def _start_row():
+            _init(acc_ref, m_ref, l_ref)
+
+        in_scores, in_values = _live(
+            ci, chunk, lengths_ref[row], q_ref.shape[1])
+        latent = c_buf[slot]  # (chunk, rank): the keys' latent part
         # ... and the values: read once, used twice; past the length they
         # are zeroed for the reason given in ``_kernel``
-        v = jnp.where(k_row < length, latent, 0)
+        v = jnp.where(in_values, latent, 0)
         s = (
-            _dot(q_ref[0], latent, ((1,), (1,)))
-            + _dot(qr_ref[0], r_ref[0, 0], ((1,), (0,)))  # (rope, block)
+            _dot(q_ref[row], latent, ((1,), (1,)))
+            + _dot(qr_ref[row], r_buf[slot], ((1,), (0,)))  # (rope, chunk)
         ) * sm_scale
-        s = jnp.where(k_pos < length, s, _NEG_INF)
+        s = jnp.where(in_scores, s, _NEG_INF)
         _accumulate(s, v, acc_ref, m_ref, l_ref, 0)
 
-    @pl.when(ki == pl.num_programs(1) - 1)
-    def _finish():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        @pl.when(ends)
+        def _finish_row():
+            o_ref[row] = _normalised(acc_ref, l_ref)[0].astype(o_ref.dtype)
+
+    _walk(lengths_ref, chunk, copies, visit)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("sm_scale", "chunk", "interpret"))
+def _latent_attend(
+    q_latent, q_rope, latent_cache, rope_cache, lengths,
+    *, sm_scale: float, chunk: int, interpret: bool,
+):
+    b, h, rank = q_latent.shape
+    rope = q_rope.shape[-1]
+    rows = -(-h // _GROUP_ROWS) * _GROUP_ROWS
+    if rows != h:
+        pad = ((0, 0), (0, rows - h), (0, 0))
+        q_latent, q_rope = jnp.pad(q_latent, pad), jnp.pad(q_rope, pad)
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, sm_scale=sm_scale, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(),
+            in_specs=[_RESIDENT, _RESIDENT, _IN_HBM, _IN_HBM],
+            out_specs=_RESIDENT,
+            scratch_shapes=[
+                pltpu.VMEM((_SLOTS, chunk, rank), latent_cache.dtype),
+                pltpu.VMEM((_SLOTS, rope, chunk), rope_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, _SLOTS)),
+                pltpu.VMEM((1, rows, rank), jnp.float32),
+                pltpu.VMEM((1, rows, 1), jnp.float32),
+                pltpu.VMEM((1, rows, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, rows, rank), q_latent.dtype),
+        name="latent_decode_attention",  # the op's name in a device trace
+        interpret=interpret,
+    )(lengths, q_latent, q_rope, latent_cache, jnp.swapaxes(rope_cache, 2, 3))
+    return out[:, :h]
 
 
 def latent_decode_attention(
@@ -281,9 +454,10 @@ def latent_decode_attention(
     operand is row-major, so every step transposed every layer's rows
     first (PERF.md, PR 30). The rotary cache, 64 wide, is stored
     sequence-minor too: the kernel takes it as ``(b, 1, rope, seq)``, which
-    is that array's own bytes, so the swap of axes below is a bitcast on
-    the TPU (and the score a plain ``(rows, rope) x (rope, block)``). One device only: a latent row has no head axis
-    to shard (``models.refusals("deepseek")["mesh"]``)."""
+    is that array's own bytes, so the swap of axes is a bitcast on the TPU
+    (and the score a plain ``(rows, rope) x (rope, chunk)``). One device
+    only: a latent row has no head axis to shard
+    (``models.refusals("deepseek")["mesh"]``)."""
     b, h, rank = q_latent.shape
     rope = q_rope.shape[-1]
     max_seq_len = latent_cache.shape[2]
@@ -294,42 +468,10 @@ def latent_decode_attention(
             f"latent caches {latent_cache.shape} / {rope_cache.shape} do "
             f"not match queries {q_latent.shape} / {q_rope.shape}"
         )
-    block = latent_block_k(max_seq_len, rank + rope, latent_cache.dtype)
-    rows = -(-h // _GROUP_ROWS) * _GROUP_ROWS
-    if rows != h:
-        pad = ((0, 0), (0, rows - h), (0, 0))
-        q_latent, q_rope = jnp.pad(q_latent, pad), jnp.pad(q_rope, pad)
-
-    def kv_index(bi, ki, lengths_ref):
-        return (bi, 0, _last_live_block(lengths_ref, bi, ki, block), 0)
-
-    def q_spec(width):
-        return pl.BlockSpec((1, rows, width), lambda bi, ki, _: (bi, 0, 0))
-
-    out = pl.pallas_call(
-        functools.partial(_latent_kernel, sm_scale=sm_scale, block=block),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, pl.cdiv(max_seq_len, block)),
-            in_specs=[
-                q_spec(rank), q_spec(rope),
-                pl.BlockSpec((1, 1, block, rank), kv_index),
-                pl.BlockSpec(
-                    (1, 1, rope, block),
-                    lambda bi, ki, lengths_ref: (
-                        bi, 0, 0, _last_live_block(lengths_ref, bi, ki, block)
-                    ),
-                ),
-            ],
-            out_specs=q_spec(rank),
-            scratch_shapes=[
-                pltpu.VMEM((1, rows, rank), jnp.float32),
-                pltpu.VMEM((1, rows, 1), jnp.float32),
-                pltpu.VMEM((1, rows, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, rows, rank), q_latent.dtype),
-        name="latent_decode_attention",  # the op's name in a device trace
+    chunk = latent_block_k(max_seq_len, rank + rope, latent_cache.dtype)
+    _traced_chunks[latent_cache.shape] = chunk
+    return _latent_attend(
+        q_latent, q_rope, latent_cache, rope_cache, lengths,
+        sm_scale=float(sm_scale), chunk=chunk,
         interpret=_use_interpret("latent_decode_attention"),
-    )(lengths, q_latent, q_rope, latent_cache, jnp.swapaxes(rope_cache, 2, 3))
-    return out[:, :h]
+    )

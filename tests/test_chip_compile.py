@@ -305,19 +305,15 @@ def compiled():
     return {}
 
 
-def _serving_programs(compiled, chip, cfg, slots):
-    """The serving engine's two programs for ``cfg`` on a described chip,
-    compiled once: the prefill function over a 512-token prompt, and the
-    engine's own ``_decode`` (the jit object, as a pooled step calls it:
-    ``active`` rows, and a routed model's running expert counts). Returns
-    (prefill, decode, params, pool), the last two as shapes."""
+def _decode_step(chip, cfg, slots):
+    """The engine's own ``_decode`` for ``cfg`` (the jit object) with the
+    operands a pooled step of ``slots`` rows hands it, as shapes on
+    ``chip``: ``(model, params, pool, args, kwargs)`` with ``active`` rows
+    and a routed model's running expert counts among the keywords."""
     from ray_tpu.llm.engine import _DecodeModelBase, _new_expert_counts
     from ray_tpu.models import init_params
     from ray_tpu.parallel.sharding import unbox_params
 
-    key = repr((cfg, slots))
-    if key in compiled:
-        return compiled[key]
     params = jax.eval_shape(
         lambda k: unbox_params(init_params(cfg, k)), jax.random.PRNGKey(0)
     )
@@ -331,14 +327,24 @@ def _serving_programs(compiled, chip, cfg, slots):
     active = jax.ShapeDtypeStruct((slots,), jnp.bool_)
     counts = jax.eval_shape(lambda: _new_expert_counts(cfg, slots))
     counted = {} if counts is None else {"expert_counts": _on(chip, counts)}
+    args = (_on(chip, params), _on(chip, pool), _on(chip, last))
+    return model, params, pool, args, dict(active=_on(chip, active), **counted)
 
+
+def _serving_programs(compiled, chip, cfg, slots):
+    """The serving engine's two programs for ``cfg`` on a described chip,
+    compiled once: the prefill function over a 512-token prompt, and the
+    engine's own ``_decode`` as a pooled step calls it (``_decode_step``).
+    Returns (prefill, decode, params, pool), the last two as shapes."""
+    key = repr((cfg, slots))
+    if key in compiled:
+        return compiled[key]
+    model, params, pool, args, kwargs = _decode_step(chip, cfg, slots)
+    prompt = jax.ShapeDtypeStruct((1, 512), jnp.int32)
     prefill = jax.jit(model._prefill_impl).lower(
-        _on(chip, params), _on(chip, prompt)
+        args[0], _on(chip, prompt)
     ).compile()
-    decode = model._decode.lower(
-        _on(chip, params), _on(chip, pool), _on(chip, last),
-        active=_on(chip, active), **counted,
-    ).compile()
+    decode = model._decode.lower(*args, **kwargs).compile()
     compiled[key] = prefill, decode, params, pool
     return compiled[key]
 
@@ -375,6 +381,29 @@ def test_decode_model_prefill_and_decode_compile(
     assert not re.search(
         rf"f32\[{slots},{hk},(\d+,)?{max_seq_len},128\]", decode
     )
+
+
+def test_a_twelve_layer_decode_step_lowers_the_kernel_once(
+    v5e_chip, native_kernels
+):
+    """What keeps ``setup_s``: the attention kernel sits under a jit of its
+    own, so the chat cells' program (12 layers, one shape) traces and
+    lowers it once and calls that twelve times; as an op of the layer it
+    was lowered twelve times over (+19 s of set-up, ROADMAP S11(c))."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    slots, h, hk, max_seq_len = MISTRAL_POOL
+    cfg = LlamaConfig(
+        dim=4096, n_layers=12, n_heads=h, n_kv_heads=hk,
+        max_seq_len=max_seq_len, param_dtype=jnp.bfloat16,
+        vocab_size=32768, intermediate=14336, rope_theta=1e6,
+    )
+    model, _, _, args, kwargs = _decode_step(v5e_chip, cfg, slots)
+    text = model._decode.lower(*args, **kwargs).as_text()
+    assert len(re.findall(r"func\.func private @_attend\b", text)) == 1
+    assert len(re.findall(r"call @_attend\b", text)) == 12
+    # the one kernel body in the text is that function's
+    assert len(re.findall(r'kernel_name = "decode_attention"', text)) == 1
 
 
 @DENSE_7B

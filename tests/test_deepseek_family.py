@@ -302,7 +302,7 @@ def test_latent_kernel_matches_the_einsum_on_ragged_rows(
     """Moonlight's own shape (16 heads on 512 + 64 columns) and two small
     ones, over rows of length 1, inside a block, at a block's edge, and the
     whole cache; blocks of 128 so that four of them are in play."""
-    monkeypatch.setattr(da, "_BLOCK_BYTES", 64 * (rank + rope) * jnp.dtype(dtype).itemsize)
+    monkeypatch.setattr(da, "_LATENT_BLOCK_BYTES", 0)
     max_seq_len = 512
     assert da.latent_block_k(max_seq_len, rank + rope, dtype) == 128
     lengths = jnp.asarray([1, 77, 128, 129, 511, 512], jnp.int32)
@@ -327,10 +327,11 @@ def test_latent_kernel_matches_the_einsum_on_ragged_rows(
 
 
 def test_latent_block_is_keys_and_values_together():
-    # a position's 576 bf16 values are 1152 B: 1024 positions a block,
-    # where K and V of as many bytes a position would take 512 each
+    # a position's 576 bf16 values are 1152 B, its keys and its values in
+    # one: 1024 positions a chunk (the cell's rows are 1024-5120 keys long),
+    # where K and V of as many bytes a position would take 128 each
     assert da.latent_block_k(8192, 576, jnp.bfloat16) == 1024
-    assert da.block_k(8192, 1, 576, jnp.bfloat16) == 512
+    assert da.block_k(8192, 1, 576, jnp.bfloat16) == 128
     assert da.latent_block_k(256, 576, jnp.bfloat16) == 256
     with pytest.raises(ValueError, match="latent caches"):
         da.latent_decode_attention(
@@ -547,6 +548,9 @@ def test_deepseek_serves_through_serve_run(shutdown_only):
         assert info["kv"] == {
             "cache_bytes_per_token": 3 * 40 * 4,
             "row_write": {"cached_latent": "tile", "cached_rope": "tile"},
+            # four decode steps of two rows, each one chunk (the whole
+            # 64-position cache) long: nothing for the schedule to skip
+            "attention_chunks_visited": 8, "attention_chunks_dense": 8,
             # no per-row state without a sequence axis, so prefixes are shared
             "state_bytes_per_row": 0,
             "prefix_reuse": True, "prefix_reuse_refused": None,
