@@ -14,7 +14,6 @@ from .engine import (
     ContinuousBatchingEngine,
     GenerationRequest,
     GenerationResult,
-    LLMEngine,
 )
 from .serving import build_llm_deployment, publish_llm_weights
 from .batch import LLMPredictor
@@ -22,7 +21,6 @@ from .batch import LLMPredictor
 __all__ = [
     "AdapterConfig",
     "LLMConfig",
-    "LLMEngine",
     "ContinuousBatchingEngine",
     "GenerationRequest",
     "GenerationResult",

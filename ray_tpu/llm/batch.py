@@ -12,7 +12,7 @@ from __future__ import annotations
 from typing import Any, Dict, Optional
 
 from .config import LLMConfig
-from .engine import GenerationRequest, LLMEngine
+from .engine import ContinuousBatchingEngine, GenerationRequest
 
 
 class LLMPredictor:
@@ -65,11 +65,22 @@ class LLMPredictor:
                 source=ac.source,
                 param_dtype=model_config.param_dtype,
             )
-        self._engine = LLMEngine(
+        # the engine the serving replicas run, without a block pool: a
+        # batch's rows decode together in max_batch_size slots
+        self._engine = ContinuousBatchingEngine(
             model_config, params,
-            max_batch_size=self._config.max_batch_size,
+            num_slots=self._config.max_batch_size,
             adapter_store=self._adapter_store,
         )
+
+    def close(self) -> None:
+        """Stop the engine's stepping thread and wait for it."""
+        self._engine.close()
+
+    def __del__(self):
+        # the map actor drops its predictor: the thread goes with it
+        if hasattr(self, "_engine"):  # else the constructor raised
+            self.close()
 
     def __call__(self, batch: Dict[str, Any]) -> Dict[str, Any]:
         prompts = batch["token_ids"]

@@ -89,10 +89,10 @@ class LLMConfig:
     # (a replica deployed without weights also draws its random-init
     # parameters from this seed, 0 when None)
     seed: Optional[int] = None
-    # paged KV cache (ray_tpu.kvcache): when kv_cache_blocks is set, each
-    # replica runs a ContinuousBatchingEngine over a block pool of that
-    # many kv_block_size-token blocks with prefix reuse and memory-gated
-    # admission; None keeps the dense grouped-batch engine
+    # paged KV cache (ray_tpu.kvcache): how many kv_block_size-token blocks
+    # the replica's engine keeps in a pool under its slots, with prefix
+    # reuse and memory-gated admission; None = no pool, a dense row a slot
+    # (the engine is the same one either way)
     kv_cache_blocks: Optional[int] = None
     kv_block_size: int = 32
     # leading prompt tokens hashed for prefix-affinity replica routing
@@ -127,21 +127,18 @@ class LLMConfig:
     # model_id/model_kwargs) proposes spec_tokens tokens per engine step;
     # the target verifies all of them in ONE forward pass and keeps the
     # longest accepted prefix — lossless at temperature 0, rejection-
-    # sampled (distribution-preserving) otherwise. Requires the paged
-    # engine (kv_cache_blocks). spec_tokens defaults to 4 when a
-    # draft_model is named without an explicit k.
+    # sampled (distribution-preserving) otherwise. spec_tokens defaults
+    # to 4 when a draft_model is named without an explicit k.
     draft_model: Optional[str] = None
     draft_model_kwargs: Dict[str, Any] = field(default_factory=dict)
     spec_tokens: int = 0
     # chunked prefill: per-engine-step prefill token budget so a long
     # prompt admission interleaves with in-flight decodes instead of
     # stalling them; 0 = prefill runs to completion at admission.
-    # Requires the paged engine.
     prefill_chunk_tokens: int = 0
     # multi-tenant LoRA plane (ray_tpu.lora): an AdapterConfig (or its
     # dict form) turns each replica into a multiplexed adapter server —
-    # paged slots, batched-gather decode, weight-plane refill. Requires
-    # the paged engine.
+    # paged slots, batched-gather decode, weight-plane refill.
     adapters: Optional[AdapterConfig] = None
 
     def __post_init__(self):
@@ -211,8 +208,8 @@ class LLMConfig:
                     )
         if (self.roles is not None or self.kv_tier) and not self.kv_cache_blocks:
             raise ValueError(
-                "disaggregated roles / kv_tier need the paged engine: "
-                "set kv_cache_blocks"
+                "disaggregated roles / kv_tier ship KV blocks and need a "
+                "block pool: set kv_cache_blocks"
             )
         if self.draft_model is not None and self.spec_tokens <= 0:
             self.spec_tokens = 4
@@ -222,20 +219,8 @@ class LLMConfig:
             )
         if self.prefill_chunk_tokens < 0:
             raise ValueError("prefill_chunk_tokens must be >= 0")
-        if (
-            self.draft_model is not None or self.prefill_chunk_tokens
-        ) and not self.kv_cache_blocks:
-            raise ValueError(
-                "speculative decoding / chunked prefill run on the "
-                "continuous-batching engine: set kv_cache_blocks"
-            )
         if isinstance(self.adapters, dict):
             self.adapters = AdapterConfig(**self.adapters)
-        if self.adapters is not None and not self.kv_cache_blocks:
-            raise ValueError(
-                "multi-tenant adapters run on the continuous-batching "
-                "engine: set kv_cache_blocks"
-            )
 
     def effective_parallelism(self) -> tuple:
         """(tp, sp) with ``mesh`` winning over the scalar fields."""

@@ -1,23 +1,24 @@
 """The TPU LLM engine: jitted prefill + decode with a KV cache.
 
 Role-equivalent of the vLLM engine the reference wraps
-(llm/_internal/batch/stages/vllm_engine_stage.py submits prompts to
-AsyncLLMEngine); TPU-native design:
+(llm/_internal/batch/stages/vllm_engine_stage.py submits prompts to vLLM's
+async engine); TPU-native design:
 
-- **prefill** runs the model over the whole prompt batch in decode mode,
-  writing every layer's K/V into the cache collection in one MXU-heavy pass
-- **decode** is one token per step for the whole batch — a single jit
-  program re-run with the carried cache, so XLA compiles exactly two
-  programs per (batch, prompt_len) bucket and the HBM-resident cache never
-  leaves the device
-- **static shapes**: requests are grouped by prompt length (no padding — a
-  left pad would sit inside the causal window and pollute attention; a
-  right pad would desync the shared cache index). Each group is one
-  prefill + decode loop; distinct shapes compile once and hit the jit
-  cache afterwards. EOS'd rows keep decoding with outputs masked — wasted
-  FLOPs on finished rows are the standard TPU trade for static shapes.
+- **prefill** runs the model over one request's prompt in decode mode,
+  writing every layer's K/V into the cache collection in one MXU-heavy
+  pass, and the row is inserted into the slot pool
+- **decode** is one token per step for the whole pool of slots: a single
+  jit program re-run with the carried (donated) cache and a per-row cache
+  index, so the HBM-resident cache never leaves the device
+- **static shapes**: prefill compiles once a prompt length (or chunk) and
+  hits the jit cache afterwards; the decode step has one shape. Free rows
+  keep decoding at position 0, their outputs unread: wasted FLOPs on idle
+  rows are the standard TPU trade for static shapes.
 
-Greedy and temperature sampling; per-request max_new_tokens.
+One engine, ``ContinuousBatchingEngine``, serves (``serving.py``) and runs
+batch inference (``batch.py``), with a block pool (``kv_cache``) or with a
+dense row a slot. Greedy and temperature sampling; per-request
+max_new_tokens.
 """
 
 from __future__ import annotations
@@ -25,7 +26,6 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
-import itertools
 import os
 import queue
 import threading
@@ -90,7 +90,7 @@ def _record_spec(proposed: int, accepted: int, mesh: str = "tp=1") -> None:
 
 def host_sync(x) -> np.ndarray:
     """The ONE audited device->host materialization point on the serving
-    hot path. Everything the engines move to the host — sampled token ids,
+    hot path. Everything the engine moves to the host — sampled token ids,
     nothing else — funnels through here, so the RT009 lint rule can forbid
     ad-hoc ``jax.device_get``/``np.asarray(jnp...)``/``float(jnp...)``
     round-trips everywhere else in engine/kvcache code (each one is a
@@ -335,8 +335,8 @@ class GenerationResult:
 class _DecodeModelBase:
     """Shared jitted prefill/decode programs over the cached model of
     whatever family ``model_config`` belongs to (``ray_tpu.models`` says
-    what a family has to offer; both engines compile the identical two
-    programs)."""
+    what a family has to offer; the engine and its speculative draft are
+    each one of these)."""
 
     def __init__(self, model_config, params, mesh=None, plan=None,
                  adapter_store=None):
@@ -469,7 +469,7 @@ class _DecodeModelBase:
     @staticmethod
     def _sample_on_device(logits, temps: np.ndarray, key):
         """Greedy where temps==0, temperature-categorical elsewhere — the
-        one sampling rule both engines use everywhere. All-greedy batches
+        one sampling rule used everywhere. All-greedy batches
         skip the categorical entirely (and need no key); mixed batches run
         the fused sampler (one program). The ids stay on the device."""
         if temps.any():
@@ -479,201 +479,6 @@ class _DecodeModelBase:
     def _sample_tokens(self, logits, temps: np.ndarray, key) -> np.ndarray:
         """``_sample_on_device`` and the one transfer that reads it."""
         return host_sync(self._sample_on_device(logits, temps, key))
-
-
-class LLMEngine(_DecodeModelBase):
-    def __init__(
-        self,
-        model_config,
-        params,
-        mesh=None,
-        max_batch_size: int = 8,
-        seed: Optional[int] = None,
-        plan=None,
-        adapter_store=None,
-    ):
-        super().__init__(
-            model_config, params, mesh, plan=plan, adapter_store=adapter_store
-        )
-        self._max_batch = max_batch_size
-        self._rng = jax.random.PRNGKey(_resolve_seed(seed))
-        self._stream_ids = itertools.count()
-        # open stream -> set once its consumer has gone
-        self._streams: Dict[int, threading.Event] = {}
-
-    # -- generation ----------------------------------------------------------
-
-    def stream_to(self, request: GenerationRequest,
-                  post: Callable[[list], None]) -> int:
-        """``generate_stream`` in a thread of its own, handed over as the
-        continuous engine hands over (``_Sink``): one delivery a token,
-        then the result. This engine batches nothing across callers, so a
-        stream's thread is the one that steps for it."""
-        rid = next(self._stream_ids)
-        gone = self._streams[rid] = threading.Event()
-
-        def run():
-            try:
-                for item in self.generate_stream(request):
-                    if gone.is_set():
-                        return
-                    post([(rid, [item], None) if isinstance(item, int)
-                          else (rid, [], item)])
-            except Exception as exc:  # noqa: BLE001 - the consumer's
-                post([(rid, [], exc)])
-            finally:
-                del self._streams[rid]
-
-        threading.Thread(
-            target=run, daemon=True, name=f"llm-stream-{rid}"
-        ).start()
-        return rid
-
-    def drop_sink(self, rid: int) -> None:
-        """The consumer of stream ``rid`` has gone: its thread stops at
-        the next token."""
-        gone = self._streams.get(rid)
-        if gone is not None:
-            gone.set()
-
-    def close(self) -> None:
-        """Nothing to stop: a stream's thread ends with its stream."""
-
-    def generate(self, requests: List[GenerationRequest]) -> List[GenerationResult]:
-        """Generate for a list of requests, grouping same-length prompts
-        into batched prefill/decode programs."""
-        groups: Dict[int, List[int]] = {}
-        for i, r in enumerate(requests):
-            groups.setdefault(len(r.token_ids), []).append(i)
-        results: List[Optional[GenerationResult]] = [None] * len(requests)
-        for _plen, indices in sorted(groups.items()):
-            for start in range(0, len(indices), self._max_batch):
-                chunk = indices[start:start + self._max_batch]
-                out = self._generate_group([requests[i] for i in chunk])
-                for i, res in zip(chunk, out):
-                    results[i] = res
-        return results  # type: ignore[return-value]
-
-    def _generate_group(
-        self, requests: List[GenerationRequest]
-    ) -> List[GenerationResult]:
-        cfg = self._cfg
-        b = len(requests)
-        plen = len(requests[0].token_ids)
-        max_new = max(r.max_new_tokens for r in requests)
-        if plen + max_new > cfg.max_seq_len:
-            raise ValueError(
-                f"prompt ({plen}) + max_new_tokens ({max_new}) exceeds "
-                f"max_seq_len ({cfg.max_seq_len})"
-            )
-        tokens = np.asarray(
-            [r.token_ids for r in requests], np.int32
-        )  # (b, plen), no padding by construction
-
-        slots = [r.adapter_slot for r in requests]
-        logits, cache = self._prefill(
-            self._params, jnp.asarray(tokens), *self._adapter_args(slots)
-        )
-        rng = self._rng
-        generated: List[List[int]] = [[] for _ in range(b)]
-        finished = [False] * b
-        reasons = ["length"] * b
-
-        def record(last):
-            for i, r in enumerate(requests):
-                if finished[i] or len(generated[i]) >= r.max_new_tokens:
-                    continue
-                tok = int(last[i])
-                generated[i].append(tok)
-                if r.eos_token_id is not None and tok == r.eos_token_id:
-                    finished[i] = True
-                    reasons[i] = "eos"
-
-        last = self._sample(logits, requests, rng, 0)
-        record(last)
-        for step in range(1, max_new):
-            if all(
-                finished[i] or len(generated[i]) >= requests[i].max_new_tokens
-                for i in range(b)
-            ):
-                break
-            logits, cache = self._decode(
-                self._params, cache, jnp.asarray(last).reshape(b, 1),
-                *self._adapter_args(slots),
-            )
-            last = self._sample(logits, requests, rng, step)
-            record(last)
-
-        return [
-            GenerationResult(
-                token_ids=generated[i][: r.max_new_tokens],
-                num_prompt_tokens=plen,
-                finished_reason=reasons[i],
-            )
-            for i, r in enumerate(requests)
-        ]
-
-    def _sample(self, logits, requests, rng, step):
-        temps = np.array(
-            [max(r.temperature, 0.0) for r in requests], np.float32
-        )
-        return self._sample_tokens(logits, temps, jax.random.fold_in(rng, step))
-
-    def generate_stream(self, request: GenerationRequest):
-        """Token-by-token generation for ONE request: yields each generated
-        token id as soon as it is sampled (time-to-first-token = prefill
-        latency, not full-generation latency), then a final
-        GenerationResult. Same programs and sampling rule as generate(), so
-        at temperature 0 the streamed tokens equal the batch path's."""
-        cfg = self._cfg
-        plen = len(request.token_ids)
-        if plen + request.max_new_tokens > cfg.max_seq_len:
-            raise ValueError(
-                f"prompt ({plen}) + max_new_tokens "
-                f"({request.max_new_tokens}) exceeds max_seq_len "
-                f"({cfg.max_seq_len})"
-            )
-        if request.max_new_tokens <= 0:  # matches generate()'s empty result
-            yield GenerationResult(
-                token_ids=[], num_prompt_tokens=plen, finished_reason="length"
-            )
-            return
-        tokens = np.asarray([request.token_ids], np.int32)
-        logits, cache = self._prefill(
-            self._params, jnp.asarray(tokens),
-            *self._adapter_args([request.adapter_slot]),
-        )
-        rng = self._rng
-        generated: List[int] = []
-        reason = "length"
-        last = self._sample_step(logits, request, rng, 0)
-        generated.append(last)
-        yield last
-        if request.eos_token_id is not None and last == request.eos_token_id:
-            reason = "eos"
-        else:
-            for step in range(1, request.max_new_tokens):
-                logits, cache = self._decode(
-                    self._params, cache, jnp.asarray([[last]], jnp.int32),
-                    *self._adapter_args([request.adapter_slot]),
-                )
-                last = self._sample_step(logits, request, rng, step)
-                generated.append(last)
-                yield last
-                if (
-                    request.eos_token_id is not None
-                    and last == request.eos_token_id
-                ):
-                    reason = "eos"
-                    break
-        yield GenerationResult(
-            token_ids=generated,
-            num_prompt_tokens=plen,
-            finished_reason=reason,
-        )
-
-    def _sample_step(self, logits, request, rng, step) -> int:
-        return int(self._sample(logits, [request], rng, step)[0])
 
 
 @dataclasses.dataclass
@@ -707,7 +512,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
     short requests don't wait for long ones and the decode batch stays full.
 
     Role-equivalent of vLLM's continuous batching scheduler behind
-    ``ray.llm`` (llm/_internal/serve — AsyncLLMEngine admission), TPU-style:
+    ``ray.llm`` (llm/_internal/serve — its async engine's admission), TPU-style:
     static shapes throughout. The decode program is ONE jitted step over the
     full (num_slots, 1) batch with a PER-ROW cache index (models/llama.py
     decode path); prefill runs per request at its prompt length and the
@@ -1557,7 +1362,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
     def generate(
         self, requests: List[GenerationRequest]
     ) -> List[GenerationResult]:
-        """Batch API matching LLMEngine.generate: enqueue every request and
+        """Batch API: enqueue every request and
         wait until the stepping thread has finished all of them. Safe to
         call from several threads at once."""
         for r in requests:
@@ -1611,7 +1416,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         self._sinks.pop(rid, None)
 
     def generate_stream(self, request: GenerationRequest):
-        """Streaming API matching LLMEngine.generate_stream: yields each
+        """Streaming API: yields each
         token of ONE request as the shared pool produces it, then the
         final GenerationResult. Other requests keep decoding in the same
         steps — this is what makes replica streaming continuous-batched."""

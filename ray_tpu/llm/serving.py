@@ -13,9 +13,9 @@ Request/response shape (token-level; bring-your-own tokenizer, or pass
   {"prompt": "text", ...}   (with a tokenizer configured)
 -> {"token_ids": [...], "num_prompt_tokens": N, "finished_reason": ...}
 
-With ``LLMConfig.kv_cache_blocks`` set, replicas run the paged
-prefix-reusing engine (ray_tpu.kvcache): admission is gated on free KV
-blocks and shared prompt prefixes prefill only their uncached suffix. Pair
+With ``LLMConfig.kv_cache_blocks`` set, the replica's engine runs over a
+block pool (ray_tpu.kvcache): admission is gated on free KV blocks and
+shared prompt prefixes prefill only their uncached suffix. Pair
 it with prefix-affinity routing on the caller side —
 ``handle.options(prefix_affinity_tokens=cfg.prefix_affinity_tokens)`` —
 so repeated prefixes (chat sessions, shared system prompts) land on the
@@ -39,7 +39,7 @@ from typing import Any, Dict, Optional
 from .. import serve
 from ..util.tracing import annotate_device_trace as _span
 from .config import LLMConfig
-from .engine import ContinuousBatchingEngine, GenerationRequest, LLMEngine
+from .engine import ContinuousBatchingEngine, GenerationRequest
 
 
 class _LoopStreams:
@@ -147,10 +147,11 @@ class _LLMReplica:
             raise ValueError(f"unknown replica role {role!r}")
         self._role = role
         self._kv_tier = None
+        self._kv_cache = None
         if llm_config.kv_cache_blocks:
-            # paged prefix-reusing engine: requests stream through a slot
-            # pool over a shared KV block pool; admission is memory-gated
-            # and prompts sharing cached prefixes prefill only the suffix
+            # a shared KV block pool under the engine's slots: admission is
+            # memory-gated and prompts sharing cached prefixes prefill only
+            # the suffix. Without one, a slot is a dense row
             from ..kvcache import KVCacheManager
 
             self._kv_cache = KVCacheManager(
@@ -173,56 +174,47 @@ class _LLMReplica:
                     block_size=llm_config.kv_block_size,
                     codec=llm_config.kv_ship_codec,
                 )
-            draft = None
-            if llm_config.draft_model is not None:
-                # speculative draft: initialized per replica (the draft is
-                # tiny — no weight plane, no sharded publish)
-                from .. import models
+        draft = None
+        if llm_config.draft_model is not None:
+            # speculative draft: initialized per replica (the draft is
+            # tiny — no weight plane, no sharded publish)
+            from .. import models
 
-                draft_cfg = llm_config.build_draft_model_config()
-                draft_params = unbox_params(
-                    models.init_params(draft_cfg, jax.random.PRNGKey(1))
-                )
-                draft = (draft_cfg, draft_params)
-            self._adapter_store = None
-            if llm_config.adapters is not None:
-                # multi-tenant LoRA plane: one paged AdapterStore per
-                # replica; request threads resolve slot leases before
-                # admission so cold weight-plane pulls never block the
-                # engine loop
-                from ..lora import AdapterStore
+            draft_cfg = llm_config.build_draft_model_config()
+            draft_params = unbox_params(
+                models.init_params(draft_cfg, jax.random.PRNGKey(1))
+            )
+            draft = (draft_cfg, draft_params)
+        self._adapter_store = None
+        if llm_config.adapters is not None:
+            # multi-tenant LoRA plane: one paged AdapterStore per
+            # replica; request threads resolve slot leases before
+            # admission so cold weight-plane pulls never block the
+            # engine loop
+            from ..lora import AdapterStore
 
-                ac = llm_config.adapters
-                self._adapter_store = AdapterStore(
-                    model_config,
-                    max_live=ac.max_live,
-                    rank=ac.slot_rank,
-                    alpha=ac.alpha,
-                    source=ac.source,
-                    plan=plan,
-                    param_dtype=model_config.param_dtype,
-                )
-            self._engine = ContinuousBatchingEngine(
-                model_config, params, mesh,
-                num_slots=llm_config.max_batch_size,
-                kv_cache=self._kv_cache,
-                seed=llm_config.seed,
+            ac = llm_config.adapters
+            self._adapter_store = AdapterStore(
+                model_config,
+                max_live=ac.max_live,
+                rank=ac.slot_rank,
+                alpha=ac.alpha,
+                source=ac.source,
                 plan=plan,
-                kv_tier=self._kv_tier,
-                draft=draft,
-                spec_tokens=llm_config.spec_tokens,
-                prefill_chunk_tokens=llm_config.prefill_chunk_tokens,
-                adapter_store=self._adapter_store,
+                param_dtype=model_config.param_dtype,
             )
-        else:
-            self._kv_cache = None
-            self._adapter_store = None
-            self._engine = LLMEngine(
-                model_config, params, mesh,
-                max_batch_size=llm_config.max_batch_size,
-                seed=llm_config.seed,
-                plan=plan,
-            )
+        self._engine = ContinuousBatchingEngine(
+            model_config, params, mesh,
+            num_slots=llm_config.max_batch_size,
+            kv_cache=self._kv_cache,
+            seed=llm_config.seed,
+            plan=plan,
+            kv_tier=self._kv_tier,
+            draft=draft,
+            spec_tokens=llm_config.spec_tokens,
+            prefill_chunk_tokens=llm_config.prefill_chunk_tokens,
+            adapter_store=self._adapter_store,
+        )
         self._tokenizer = None
         if tokenizer_name:
             from transformers import AutoTokenizer
@@ -355,32 +347,25 @@ class _LLMReplica:
             "compile": compile_cache.stats(),
             "kernels": traced_kernel_modes(),
             # a routed model's expert counters (engine.expert_stats());
-            # None for a dense model or the grouped-batch engine
-            "moe": getattr(self._engine, "expert_stats", lambda: None)(),
+            # None for a dense model
+            "moe": self._engine.expert_stats(),
             # who steps: steps the engine's own thread ran and the times it
-            # parked with nothing to do (None for the grouped-batch engine)
-            "engine": {
-                "stepper": getattr(
-                    self._engine, "stepper_stats", lambda: None)(),
-            },
+            # parked with nothing to do
+            "engine": {"stepper": self._engine.stepper_stats()},
             # what a cached position costs (sequence leaves only), what a
             # row carries whatever its length (per-row state with no
             # sequence axis), how the decode step stores a position in
-            # each sequence leaf (None for the grouped-batch engine, and
-            # before the first admission), and whether a request may be
-            # served a cached prefix (False, with the reason, for a family
-            # whose rows carry such state)
+            # each sequence leaf (None before the first admission), and
+            # whether a request may be served a cached prefix (False, with
+            # the reason, for a family whose rows carry such state)
             "kv": {
-                "cache_bytes_per_token": getattr(
-                    self._engine, "cache_bytes_per_token", lambda: None)(),
-                "state_bytes_per_row": getattr(
-                    self._engine, "state_bytes_per_row", lambda: None)(),
-                "row_write": getattr(
-                    self._engine, "row_write", lambda: None)(),
+                "cache_bytes_per_token": self._engine.cache_bytes_per_token(),
+                "state_bytes_per_row": self._engine.state_bytes_per_row(),
+                "row_write": self._engine.row_write(),
                 # chunks of keys the decode kernel visited over the steps
                 # dispatched, and what a dense grid would have
                 # (attention_chunks_visited / attention_chunks_dense)
-                **getattr(self._engine, "attention_chunks", dict)(),
+                **self._engine.attention_chunks(),
                 **(
                     {} if self._kv_cache is None else {
                         "prefix_reuse": self._kv_cache.prefix_reuse,
@@ -423,7 +408,7 @@ class _LLMReplica:
         }
 
     def kvcache_stats(self) -> Optional[Dict[str, Any]]:
-        """Replica-local KV-cache stats (None on the dense engine); routed
+        """Replica-local KV-cache stats (None without a block pool); routed
         through handle.options(method_name="kvcache_stats")."""
         if self._kv_cache is None:
             return None

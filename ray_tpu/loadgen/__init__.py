@@ -4,8 +4,8 @@ Compose an arrival process (``PoissonArrivals`` / ``BurstyRampArrivals``)
 with a workload (``RequestClass`` mix over ``ZipfPrefixes``) into a
 replayable ``Trace``, then drive it open loop with ``LoadGenerator``
 against a serve handle, HTTP proxy, or plain callable. The bundled
-ramp-burst-decay trace (``bundled_trace()``) powers the closed-loop
-autoscaling demo in ``bench.py serve_autoscale``.
+ramp-burst-decay trace (``bundled_trace()``) is what the closed-loop
+autoscaling test replays (``tests/test_autoscale_serve.py``).
 """
 
 from .arrival import BurstyRampArrivals, PoissonArrivals

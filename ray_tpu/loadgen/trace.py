@@ -9,7 +9,8 @@ run back into a trace so real traffic can be captured once and replayed.
 
 The bundled trace (``traces/ramp_burst_decay.json``, regenerable with
 ``python -m ray_tpu.loadgen.trace``) is the small ramp -> burst -> decay
-profile the ``bench.py serve_autoscale`` closed-loop demo replays.
+profile ``tests/test_autoscale_serve.py`` replays against an autoscaled
+deployment.
 """
 
 from __future__ import annotations

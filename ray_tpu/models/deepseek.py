@@ -357,7 +357,7 @@ class DeepseekTransformer(nn.Module):
     @nn.compact
     def __call__(self, tokens, adapters=None, adapter_slots=None):
         # tokens: (batch, seq) int32. The family has no adapter placement
-        # (models.refusals): the two arguments are the engines' calling
+        # (models.refusals): the two arguments are the engine's calling
         # convention and must stay None
         if adapters is not None:
             raise ValueError("the deepseek family takes no adapter bank")

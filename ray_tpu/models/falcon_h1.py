@@ -506,7 +506,7 @@ class FalconH1(nn.Module):
     @nn.compact
     def __call__(self, tokens, adapters=None, adapter_slots=None):
         # tokens: (batch, seq) int32. The family has no adapter placement
-        # (models.refusals): the two arguments are the engines' calling
+        # (models.refusals): the two arguments are the engine's calling
         # convention and must stay None
         if adapters is not None:
             raise ValueError("the falcon_h1 family takes no adapter bank")

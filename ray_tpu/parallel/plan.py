@@ -1,10 +1,10 @@
-"""Partition-rule planner: compile-with-plan for the serving engines.
+"""Partition-rule planner: compile-with-plan for the serving engine.
 
 The t5x/EasyLM ``match_partition_rules`` idiom applied to this framework's
 serving plane: a :class:`PartitionPlan` owns a mesh plus an ordered table of
 ``(path regex, PartitionSpec)`` rules, matches them against flax parameter
-*path names* (``layer_0/attn/wq/base/kernel``), and hands the engines
-everything they need to compile sharded programs — parameter shardings,
+*path names* (``layer_0/attn/wq/base/kernel``), and hands the engine
+everything it needs to compile sharded programs — parameter shardings,
 decode-cache shardings (KV heads over ``tp``), and the paged block-pool
 sharding.
 
@@ -131,7 +131,7 @@ def validate_mesh_for_model(
 class PartitionPlan:
     """One replica's sharding contract: mesh + rules + derived shardings.
 
-    Built once per replica (``PartitionPlan.for_model``); the engines and
+    Built once per replica (``PartitionPlan.for_model``); the engine and
     the KV manager consume it instead of re-deriving specs locally, so the
     parameter layout, the decode-cache layout, and the block-pool layout
     can never drift apart.

@@ -10,7 +10,8 @@ and each worker runs the same pjit/GSPMD-sharded LoRA step:
 - base params bf16, frozen (no wgrads, no optimizer moments — train/lora.py
   split); LoRA adapters in adamw
 - stacked layers under lax.scan + full per-layer remat (models/llama.py
-  scan_layers — the form bench.py measures at ~0.70 MFU on one v5e chip)
+  scan_layers — the form the ``mistral7b-lora-*`` cells of BENCHMARK.json
+  measure)
 - params sharded by the logical-axis rule table (embed→fsdp, mlp/heads→tp)
   over a mesh built from however many devices the slice exposes
 

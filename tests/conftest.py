@@ -7,6 +7,7 @@ subprocesses the runtime spawns inherit both. What the TPU's compiler says
 about the kernels and step programs is tests/test_chip_compile.py.
 """
 
+import functools
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
@@ -23,6 +24,38 @@ def pytest_configure(config):
         "slow: long-running coverage excluded from the budgeted tier-1 lane "
         "(-m 'not slow'); run explicitly or without the marker filter",
     )
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_program(cfg):
+    """``cfg``'s whole forward pass, jitted once: (params, tokens padded to
+    ``max_seq_len``, a position) -> that position's logits."""
+    import jax
+
+    from ray_tpu import models
+
+    model = models.build(cfg)
+    return jax.jit(
+        lambda p, tokens, last: model.apply({"params": p}, tokens)[0, last]
+    )
+
+
+def greedy_reference(cfg, params, prompt, n_new):
+    """The test reference for an engine's greedy tokens: whole forward
+    passes of the family's training-form model (``models.build(cfg)``), no
+    cache and none of the engine's code. One program a config: the tokens
+    are padded to ``max_seq_len`` and the logits read at the last real
+    position, which a causal model computes from the real tokens alone."""
+    import numpy as np
+
+    forward = _reference_program(cfg)
+    toks = list(prompt)
+    for _ in range(n_new):
+        padded = np.zeros((1, cfg.max_seq_len), np.int32)
+        padded[0, :len(toks)] = toks
+        logits = forward(params, padded, np.int32(len(toks) - 1))
+        toks.append(int(np.argmax(np.asarray(logits, np.float32))))
+    return toks[len(prompt):]
 
 
 @pytest.fixture(scope="module")

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import greedy_reference
 
 from ray_tpu.ops import decode_attention as da
 
@@ -371,17 +372,6 @@ def gqa():
     return cfg, unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
 
 
-def _greedy_reference(cfg, params, prompt, n_new):
-    """Greedy decoding by whole forward passes of the training model."""
-    from ray_tpu.models.llama import Llama
-
-    model, toks = Llama(cfg, None), list(prompt)
-    for _ in range(n_new):
-        logits = model.apply({"params": params}, jnp.asarray([toks], jnp.int32))
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
-
-
 def _cache_indexes(engine):
     return [np.asarray(leaf) for leaf in jax.tree.leaves(engine._cache)
             if leaf.ndim == 1]
@@ -399,7 +389,7 @@ def test_one_live_row_of_eight_after_the_others_ran_long(gqa):
     fresh = ContinuousBatchingEngine(cfg, params, num_slots=8)
     rid = fresh.add_request(lone)
     want = fresh.run_until_complete()[rid].token_ids
-    assert want[:6] == _greedy_reference(cfg, params, lone.token_ids, 6)
+    assert want[:6] == greedy_reference(cfg, params, lone.token_ids, 6)
 
     engine = ContinuousBatchingEngine(cfg, params, num_slots=8)
     for i in range(7):

@@ -15,8 +15,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.kvcache import KVCacheManager
-from ray_tpu.llm import GenerationRequest, LLMEngine
-from ray_tpu.llm.engine import ContinuousBatchingEngine
+from ray_tpu.llm import GenerationRequest
+from ray_tpu.llm.engine import ContinuousBatchingEngine, _DecodeModelBase
 from ray_tpu.models.llama import LlamaConfig, init_params
 from ray_tpu.parallel.sharding import unbox_params
 
@@ -38,7 +38,7 @@ def reference(tiny):
     """Greedy tokens from the engine's own two functions under plain
     ``jax.jit``: no donation, one request at a time."""
     cfg, params, _ = tiny
-    model = LLMEngine(cfg, params)
+    model = _DecodeModelBase(cfg, params)
     prefill = jax.jit(model._prefill_impl)
     decode = jax.jit(model._decode_impl)
     memo = {}
@@ -134,9 +134,8 @@ def _prefix_hit_then_retirement_commit(tiny, reference, paged=True):
 
 
 def _generate_and_stream_match_the_undonated_loop(tiny, reference, paged):
-    cfg, params, _ = tiny
-    if paged is None:  # the static engine: one cache a call, rebound a token
-        eng = LLMEngine(cfg, params, max_batch_size=2)
+    if paged is None:  # a pool of one dense row: the batch is the request
+        eng, _ = _engine(tiny, paged=False, num_slots=1)
     else:
         eng, _ = _engine(tiny, paged=paged)
     a = _prompt(9, 11)
@@ -146,10 +145,10 @@ def _generate_and_stream_match_the_undonated_loop(tiny, reference, paged):
     assert batch == streamed == final.token_ids == reference(a)
 
 
-def _speculative_equals_plain(tiny, reference, paged=True):
+def _speculative_equals_plain(tiny, reference, paged):
     # _propose donates the draft pool, _verify the target's, _set_index
     # the draft's again; a random draft makes every step roll back
-    eng, _ = _engine(tiny, spec=3)
+    eng, _ = _engine(tiny, paged=paged, spec=3)
     a, b = _prompt(10, 13), _prompt(11, 9)
     out = eng.generate([
         GenerationRequest(token_ids=p, max_new_tokens=2 * N_NEW)
@@ -160,8 +159,8 @@ def _speculative_equals_plain(tiny, reference, paged=True):
     ]
 
 
-def _speculative_over_chunked_prefill(tiny, reference, paged=True):
-    eng, _ = _engine(tiny, spec=3, chunk=BS)
+def _speculative_over_chunked_prefill(tiny, reference, paged):
+    eng, _ = _engine(tiny, paged=paged, spec=3, chunk=BS)
     a = _prompt(12, 3 * BS + 2)
     assert _run(eng, a, 2 * N_NEW) == reference(a, 2 * N_NEW)
 
@@ -176,11 +175,13 @@ def _speculative_over_chunked_prefill(tiny, reference, paged=True):
         (_generate_and_stream_match_the_undonated_loop, None),
         (_generate_and_stream_match_the_undonated_loop, False),
         (_generate_and_stream_match_the_undonated_loop, True),
+        (_speculative_equals_plain, False),
         (_speculative_equals_plain, True),
+        (_speculative_over_chunked_prefill, False),
         (_speculative_over_chunked_prefill, True),
     ],
     ids=lambda v: v.__name__.strip("_") if callable(v)
-    else {None: "static", False: "dense", True: "paged"}[v],
+    else {None: "one_slot", False: "dense", True: "paged"}[v],
 )
 def test_no_donated_buffer_is_reused(tiny, reference, scenario, paged):
     scenario(tiny, reference, paged)

@@ -518,7 +518,7 @@ def test_close_joins_and_an_unreferenced_engine_takes_its_thread_with_it():
     assert gone() is None and not thread.is_alive()
 
 
-def test_the_static_engine_streams_through_the_same_method():
+def test_a_replica_without_a_pool_streams_through_the_same_method():
     rep = _LLMReplica(_llm_config(kv_cache_blocks=None))
     req = _requests(950, 0.0)[0]
 
@@ -529,5 +529,5 @@ def test_the_static_engine_streams_through_the_same_method():
     (want,) = rep._engine.generate([req])
     assert [t["token_id"] for t in tokens] == want.token_ids == summary["token_ids"]
     assert rep({**_as_dict(req), "stream": True}) == summary
-    assert not rep._engine._streams and not rep._loop_streams
+    assert not rep._engine._sinks and not rep._loop_streams
     rep.shutdown()

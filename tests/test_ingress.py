@@ -203,9 +203,11 @@ def test_multiproxy_spread_affinity_metrics_drain(cluster):
     ingress = {}
     while time.time() < deadline:
         ingress = rt_state.metrics_summary()["ingress"]
-        if ingress.get("num_proxies", 0) >= 2 and ingress.get(
-            "requests_total", 0
-        ) > 0:
+        # each proxy pushes on its own clock: wait for both snapshots
+        if ingress.get("num_proxies", 0) >= 2 and all(
+            ingress["proxies"].get(p, {}).get("requests", {}).get("ok", 0) > 0
+            for p in proxies
+        ):
             break
         time.sleep(0.5)
     assert ingress["num_proxies"] >= 2, ingress

@@ -584,18 +584,19 @@ def test_one_compiled_commit_program_for_every_count():
 
 
 # ---------------------------------------------------------------------------
-# End-to-end: paged engine == dense engine, token for token
+# End-to-end: the engine over a block pool == the engine over dense rows,
+# token for token
 
 
 @pytest.fixture(scope="module")
 def paged_setup():
-    from ray_tpu.llm.engine import ContinuousBatchingEngine, LLMEngine
+    from ray_tpu.llm.engine import ContinuousBatchingEngine
     from ray_tpu.models.llama import LlamaConfig, init_params
     from ray_tpu.parallel.sharding import unbox_params
 
     cfg = LlamaConfig.tiny(max_seq_len=128)
     params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
-    dense = LLMEngine(cfg, params, max_batch_size=4, seed=7)
+    dense = ContinuousBatchingEngine(cfg, params, num_slots=4, seed=7)
     kv = KVCacheManager(num_blocks=32, block_size=16)
     paged = ContinuousBatchingEngine(
         cfg, params, num_slots=4, kv_cache=kv, seed=7

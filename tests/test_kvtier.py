@@ -22,6 +22,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu.kvcache import KVCacheManager
@@ -272,6 +273,14 @@ def test_disagg_handoff_matches_fused(tiny):
 
 
 def test_int8_shipment_parity_and_wire_ratio(tiny_f32):
+    """What an 8-bit codec promises, and no more: half the wire, every
+    adopted value within half a quantisation step of the exact one, and
+    the next token decoded over the adopted K/V unchanged. Token-for-token
+    equality further on is not promised: a step whose two best logits are
+    closer than the rounding moves them (the sixth here, 0.004 apart) may
+    go either way."""
+    from ray_tpu._internal.quantization import DEFAULT_BLOCK
+
     cfg, params = tiny_f32
     backend = LocalTierBackend()
     pre, _ = _engine(cfg, params, backend, "pre8", codec="int8")
@@ -287,9 +296,29 @@ def test_int8_shipment_parity_and_wire_ratio(tiny_f32):
     wire = t1["transfer_wire_bytes"] - t0["transfer_wire_bytes"]
     logical = t1["transfer_logical_bytes"] - t0["transfer_logical_bytes"]
     assert 0 < wire <= 0.51 * logical
+
+    # the same prefill shipped raw: the exact blocks
+    exact_eng, exact_tier = _engine(
+        cfg, params, LocalTierBackend(), "exact", codec="raw")
+    exact = exact_tier.fetch_shipment(exact_eng.prefill_only(_req(prompt)))
+    adopted = jax.tree.leaves(payload)
+    assert len(adopted) == len(jax.tree.leaves(exact)) > 0
+    for got, want in zip(adopted, jax.tree.leaves(exact)):
+        want = np.asarray(want, np.float32).reshape(-1, DEFAULT_BLOCK)
+        got = np.asarray(got, np.float32).reshape(want.shape)
+        # a scale is its block's largest magnitude / 127; the float32
+        # divide and multiply around the rounding add a few ulps of it
+        amax = np.abs(want).max(axis=1, keepdims=True)
+        half_step = amax / 254.0 + 4 * np.finfo(np.float32).eps * amax
+        assert (np.abs(got - want) <= half_step).all()
+        assert (got != want).any()  # it really went through the codec
+
     out = dec.generate_one(_req(prompt), shipment=(shipment, payload))
-    # int8-adopted KV vs the prefill engine's exact f32 KV lineage
-    assert out.token_ids == pre.generate_one(_req(prompt)).token_ids
+    lineage = pre.generate_one(_req(prompt)).token_ids
+    assert len(out.token_ids) == len(lineage)
+    # the shipped first token, and the first one decoded over int8-adopted
+    # K/V, against the prefill engine's exact f32 KV lineage
+    assert out.token_ids[:2] == lineage[:2]
 
 
 # -------------------------------------- scenario 4: dead-holder fallback

@@ -7,65 +7,77 @@ round trip, and the batch-inference stage.
 """
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
+from conftest import greedy_reference
 
-import ray_tpu
+from ray_tpu.kvcache import KVCacheManager
 from ray_tpu.llm import (
+    ContinuousBatchingEngine,
     GenerationRequest,
     LLMConfig,
-    LLMEngine,
     LLMPredictor,
     build_llm_deployment,
 )
-from ray_tpu.models.llama import Llama, LlamaConfig, init_params
+from ray_tpu.models.llama import LlamaConfig, init_params
 from ray_tpu.parallel.sharding import unbox_params
+
+
+def _with_engine(**model_kwargs):
+    """(cfg, params, a four-slot engine with no block pool)."""
+    cfg = LlamaConfig.tiny(max_seq_len=64, **model_kwargs)
+    params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
+    engine = ContinuousBatchingEngine(cfg, params, num_slots=4)
+    yield cfg, params, engine
+    engine.close()
 
 
 @pytest.fixture(scope="module")
 def tiny_engine():
-    cfg = LlamaConfig.tiny(max_seq_len=64)
-    params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
-    return cfg, params, LLMEngine(cfg, params, max_batch_size=4)
+    yield from _with_engine()
 
 
 @pytest.fixture(scope="module")
 def gqa_engine():
     """Two query heads a KV head: the decode kernel's grouped path."""
-    cfg = LlamaConfig.tiny(n_heads=4, n_kv_heads=2, max_seq_len=64)
-    params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
-    return cfg, params, LLMEngine(cfg, params, max_batch_size=4)
+    yield from _with_engine(n_heads=4, n_kv_heads=2)
 
 
-def _greedy_reference(cfg, params, prompt, n_new):
-    """Greedy decoding via repeated FULL forward passes (no cache)."""
-    model = Llama(cfg, None)
-    toks = list(prompt)
-    for _ in range(n_new):
-        logits = model.apply(
-            {"params": params}, jnp.asarray([toks], jnp.int32)
-        )
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
-
-
-@pytest.mark.slow
+@pytest.mark.parametrize(
+    "num_slots,block,prompt",
+    [
+        (1, None, [3, 14, 15, 92, 65, 35]),
+        # a pool of 4-token blocks: the prompt is two blocks and a tail,
+        # and the decode crosses into a fourth
+        (4, 4, [3, 14, 15, 92, 65, 35, 89, 79, 32]),
+    ],
+    ids=["one_dense_slot", "four_paged_slots_prompt_over_a_block"],
+)
 @pytest.mark.parametrize("engine_fixture", ["tiny_engine", "gqa_engine"])
-def test_cache_decode_matches_full_forward(engine_fixture, request):
-    cfg, params, engine = request.getfixturevalue(engine_fixture)
-    prompt = [3, 14, 15, 92, 65, 35]
+def test_cache_decode_matches_full_forward(
+    engine_fixture, num_slots, block, prompt, request
+):
+    """The KV-cache decode path against whole forward passes, token for
+    token: a lone dense row through the stepping thread, and a row over
+    pool blocks stepped by the caller."""
+    cfg, params, _ = request.getfixturevalue(engine_fixture)
     n_new = 8
-    ref = _greedy_reference(cfg, params, prompt, n_new)
-    out = engine.generate(
-        [GenerationRequest(token_ids=prompt, max_new_tokens=n_new)]
-    )[0]
+    ref = greedy_reference(cfg, params, prompt, n_new)
+    kv = KVCacheManager(num_blocks=16, block_size=block) if block else None
+    engine = ContinuousBatchingEngine(
+        cfg, params, num_slots=num_slots, kv_cache=kv
+    )
+    req = GenerationRequest(token_ids=prompt, max_new_tokens=n_new)
+    if kv is None:
+        out = engine.generate([req])[0]
+        engine.close()
+    else:
+        rid = engine.add_request(req)
+        out = engine.run_until_complete()[rid]
     assert out.token_ids == ref
     assert out.num_prompt_tokens == len(prompt)
     assert out.finished_reason == "length"
 
 
-@pytest.mark.slow
 def test_batched_same_length_prompts(tiny_engine):
     cfg, params, engine = tiny_engine
     prompts = [[1, 2, 3, 4], [9, 8, 7, 6], [5, 5, 5, 5]]
@@ -73,32 +85,34 @@ def test_batched_same_length_prompts(tiny_engine):
         [GenerationRequest(token_ids=p, max_new_tokens=5) for p in prompts]
     )
     for p, o in zip(prompts, outs):
-        assert o.token_ids == _greedy_reference(cfg, params, p, 5)
+        assert o.token_ids == greedy_reference(cfg, params, p, 5)
 
 
-@pytest.mark.slow
-def test_mixed_length_prompts_grouped(tiny_engine):
+def test_mixed_length_prompts_in_one_batch(tiny_engine):
     cfg, params, engine = tiny_engine
     prompts = [[1, 2], [3, 4, 5, 6], [7, 8], [9, 10, 11, 12]]
     outs = engine.generate(
         [GenerationRequest(token_ids=p, max_new_tokens=4) for p in prompts]
     )
     for p, o in zip(prompts, outs):
-        assert o.token_ids == _greedy_reference(cfg, params, p, 4)
+        assert o.token_ids == greedy_reference(cfg, params, p, 4)
 
 
-@pytest.mark.slow
 def test_eos_stops_generation(tiny_engine):
+    """A row that meets its EOS leaves the batch; its neighbour goes on."""
     cfg, params, engine = tiny_engine
-    prompt = [3, 14, 15, 92]
-    ref = _greedy_reference(cfg, params, prompt, 8)
-    eos = ref[0]  # the first greedy token acts as EOS
-    out = engine.generate(
-        [GenerationRequest(token_ids=prompt, max_new_tokens=8,
-                           eos_token_id=eos)]
-    )[0]
+    prompt, other = [3, 14, 15, 92], [9, 8, 7, 6, 5]
+    ref = greedy_reference(cfg, params, prompt, 8)
+    eos = ref[1]  # the second greedy token acts as EOS
+    out, beside = engine.generate([
+        GenerationRequest(token_ids=prompt, max_new_tokens=8,
+                          eos_token_id=eos),
+        GenerationRequest(token_ids=other, max_new_tokens=8),
+    ])
     assert out.finished_reason == "eos"
-    assert out.token_ids == [eos]
+    assert out.token_ids == ref[:ref.index(eos) + 1]
+    assert beside.finished_reason == "length"
+    assert beside.token_ids == greedy_reference(cfg, params, other, 8)
 
 
 def test_temperature_sampling_changes_output(tiny_engine):
@@ -164,35 +178,41 @@ def test_llm_batch_stage(ray_start_regular):
     assert all(len(r["generated"]) == 3 for r in out)
 
 
-@pytest.mark.slow
+def test_batch_predictor_returns_the_reference_greedy_tokens():
+    """``LLMPredictor`` steps the engine the replicas serve with: at
+    temperature 0 a batch of mixed lengths, more rows than slots, gets the
+    whole-forward greedy tokens, and closing it stops the thread."""
+    llm_config = LLMConfig(
+        model_id="llama-tiny", max_seq_len=64, max_new_tokens=5,
+        max_batch_size=2,
+    )
+    predictor = LLMPredictor(llm_config)
+    assert isinstance(predictor._engine, ContinuousBatchingEngine)
+    assert predictor._engine._kv is None
+    cfg = llm_config.build_model_config()
+    params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
+    prompts = [[1, 2, 3], [9, 8, 7, 6, 5, 4], [5], [31, 41, 59, 26]]
+    out = predictor({"token_ids": prompts})
+    assert out["generated"] == [
+        greedy_reference(cfg, params, p, 5) for p in prompts
+    ]
+    thread = predictor._engine._stepper.thread
+    assert thread.is_alive()
+    predictor.close()
+    assert not thread.is_alive()
+
+
 class TestContinuousBatching:
-    @pytest.mark.parametrize("engine_fixture", ["tiny_engine", "gqa_engine"])
-    def test_matches_full_forward(self, engine_fixture, request):
-        from ray_tpu.llm.engine import ContinuousBatchingEngine
-
-        cfg, params, _ = request.getfixturevalue(engine_fixture)
-        engine = ContinuousBatchingEngine(cfg, params, num_slots=4)
-        prompt = [3, 14, 15, 92, 65, 35]
-        ref = _greedy_reference(cfg, params, prompt, 8)
-        rid = engine.add_request(
-            GenerationRequest(token_ids=prompt, max_new_tokens=8)
-        )
-        results = engine.run_until_complete()
-        assert results[rid].token_ids == ref
-        assert results[rid].finished_reason == "length"
-
     def test_interleaved_mixed_lengths(self, tiny_engine):
         """Different prompt lengths decode TOGETHER in one pool (the whole
-        point of continuous batching; the grouped LLMEngine cannot)."""
-        from ray_tpu.llm.engine import ContinuousBatchingEngine
-
+        point of continuous batching)."""
         cfg, params, _ = tiny_engine
         engine = ContinuousBatchingEngine(cfg, params, num_slots=4)
         prompts = [[3, 14, 15], [92, 65, 35, 89, 79], [4], [31, 41]]
         refs = {
             engine.add_request(
                 GenerationRequest(token_ids=p, max_new_tokens=6)
-            ): _greedy_reference(cfg, params, p, 6)
+            ): greedy_reference(cfg, params, p, 6)
             for p in prompts
         }
         results = engine.run_until_complete()
@@ -201,15 +221,13 @@ class TestContinuousBatching:
 
     def test_late_admission_into_freed_slot(self, tiny_engine):
         """More requests than slots: later requests admit as slots free."""
-        from ray_tpu.llm.engine import ContinuousBatchingEngine
-
         cfg, params, _ = tiny_engine
         engine = ContinuousBatchingEngine(cfg, params, num_slots=2)
         prompts = [[3, 14], [92, 65, 35], [4, 5, 6, 7], [31]]
         refs = {
             engine.add_request(
                 GenerationRequest(token_ids=p, max_new_tokens=4)
-            ): _greedy_reference(cfg, params, p, 4)
+            ): greedy_reference(cfg, params, p, 4)
             for p in prompts
         }
         # step manually: at most 2 slots busy at once
@@ -222,12 +240,10 @@ class TestContinuousBatching:
             assert results[rid].token_ids == ref, rid
 
     def test_eos_frees_slot(self, tiny_engine):
-        from ray_tpu.llm.engine import ContinuousBatchingEngine
-
         cfg, params, _ = tiny_engine
         engine = ContinuousBatchingEngine(cfg, params, num_slots=2)
         prompt = [3, 14, 15]
-        ref = _greedy_reference(cfg, params, prompt, 8)
+        ref = greedy_reference(cfg, params, prompt, 8)
         eos = ref[2]  # force eos at the 3rd generated token
         rid = engine.add_request(
             GenerationRequest(
@@ -245,8 +261,6 @@ class TestAdmission:
 
     def test_pending_fifo_under_full_slots(self, tiny_engine):
         """More requests than slots: admission order == arrival order."""
-        from ray_tpu.llm.engine import ContinuousBatchingEngine
-
         cfg, params, _ = tiny_engine
         engine = ContinuousBatchingEngine(cfg, params, num_slots=2)
         rids = [
@@ -266,8 +280,6 @@ class TestAdmission:
     def test_slot_reuse_after_finish_at_admission(self, tiny_engine):
         """max_new_tokens=1 finishes AT admission: its slot must be handed
         to the next pending request in the same step, not leaked."""
-        from ray_tpu.llm.engine import ContinuousBatchingEngine
-
         cfg, params, _ = tiny_engine
         engine = ContinuousBatchingEngine(cfg, params, num_slots=1)
         r1 = engine.add_request(
@@ -285,11 +297,9 @@ class TestAdmission:
 
     def test_finish_at_admission_via_eos(self, tiny_engine):
         cfg, params, _ = tiny_engine
-        from ray_tpu.llm.engine import ContinuousBatchingEngine
-
         engine = ContinuousBatchingEngine(cfg, params, num_slots=2)
         prompt = [3, 14, 15, 92]
-        ref = _greedy_reference(cfg, params, prompt, 1)
+        ref = greedy_reference(cfg, params, prompt, 1)
         rid = engine.add_request(
             GenerationRequest(
                 token_ids=prompt, max_new_tokens=8, eos_token_id=ref[0]
@@ -303,8 +313,6 @@ class TestAdmission:
     def test_run_until_complete_leaks_nothing(self, tiny_engine):
         """After draining, every per-request structure must be empty (a
         serving loop runs forever; any residue is a leak)."""
-        from ray_tpu.llm.engine import ContinuousBatchingEngine
-
         cfg, params, _ = tiny_engine
         engine = ContinuousBatchingEngine(cfg, params, num_slots=2)
         for i in range(6):
@@ -326,9 +334,6 @@ class TestAdmission:
         """With a KV pool too small for two prompts, the blocked request
         waits at the HEAD of the queue (no reordering, no crash) and
         admits after the holder retires."""
-        from ray_tpu.kvcache import KVCacheManager
-        from ray_tpu.llm.engine import ContinuousBatchingEngine
-
         cfg, params, _ = tiny_engine
         kv = KVCacheManager(num_blocks=2, block_size=16)
         engine = ContinuousBatchingEngine(
@@ -351,6 +356,44 @@ class TestAdmission:
         assert engine.num_active == 0
 
 
+@pytest.mark.parametrize(
+    "kwargs,refused",
+    [
+        (dict(prefill_chunk_tokens=4), False),
+        (dict(draft_model="llama-tiny", spec_tokens=3), False),
+        (dict(adapters={"max_live": 2}), False),
+        (dict(roles={"prefill": 1, "decode": 1}), True),
+        (dict(kv_tier=True), True),
+    ],
+    ids=["chunked_prefill", "draft_model", "adapters", "roles", "kv_tier"],
+)
+def test_what_a_config_without_kv_cache_blocks_may_ask(kwargs, refused):
+    """``kv_cache_blocks`` says how many blocks, not which engine: chunked
+    prefill, a draft and adapters serve over dense rows, from a replica
+    whose greedy tokens are the whole-forward ones; what ships KV blocks is
+    refused, and the message names the pool."""
+    from ray_tpu.llm.serving import _LLMReplica
+
+    base = dict(model_id="llama-tiny", max_seq_len=64, max_batch_size=2, seed=0)
+    if refused:
+        with pytest.raises(ValueError, match="block pool: set kv_cache_blocks"):
+            LLMConfig(**base, **kwargs)
+        LLMConfig(**base, kv_cache_blocks=8, **kwargs)
+        return
+    config = LLMConfig(**base, **kwargs)
+    assert config.kv_cache_blocks is None
+    replica = _LLMReplica(config)
+    try:
+        assert replica._kv_cache is None and replica.kvcache_stats() is None
+        prompt = [3, 14, 15, 92, 65, 35, 89, 79, 32]
+        reply = replica({"token_ids": prompt, "max_new_tokens": 6})
+        cfg = config.build_model_config()
+        params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
+        assert reply["token_ids"] == greedy_reference(cfg, params, prompt, 6)
+    finally:
+        replica.shutdown()
+
+
 def test_engine_seed_reproducible_and_per_instance():
     """Sampling seed control: an explicit seed reproduces the sampled
     stream exactly; different seeds diverge at high temperature (the old
@@ -360,9 +403,14 @@ def test_engine_seed_reproducible_and_per_instance():
     req = lambda: GenerationRequest(  # noqa: E731
         token_ids=[1, 2, 3, 4], max_new_tokens=16, temperature=5.0
     )
-    a = LLMEngine(cfg, params, max_batch_size=2, seed=11).generate([req()])
-    b = LLMEngine(cfg, params, max_batch_size=2, seed=11).generate([req()])
-    c = LLMEngine(cfg, params, max_batch_size=2, seed=12).generate([req()])
+    def sampled(seed):
+        engine = ContinuousBatchingEngine(cfg, params, num_slots=2, seed=seed)
+        try:
+            return engine.generate([req()])
+        finally:
+            engine.close()
+
+    a, b, c = sampled(11), sampled(11), sampled(12)
     assert a[0].token_ids == b[0].token_ids
     assert a[0].token_ids != c[0].token_ids
 
@@ -370,8 +418,9 @@ def test_engine_seed_reproducible_and_per_instance():
 @pytest.mark.slow
 def test_tp_sharded_decode_matches_single_device():
     """Serving tensor parallelism: an engine over GSPMD-sharded params on a
-    tp x fsdp mesh decodes token-for-token identically to the unsharded
-    engine (the role vLLM's tensor_parallel_size plays behind ray.llm)."""
+    tp x fsdp mesh decodes the whole-forward greedy tokens of the unsharded
+    model (the role vLLM's tensor_parallel_size plays behind ray.llm), on
+    the stepping thread as under a caller's own drive."""
     from ray_tpu.parallel.mesh import make_mesh
     from ray_tpu.parallel.sharding import param_shardings
 
@@ -380,21 +429,18 @@ def test_tp_sharded_decode_matches_single_device():
     params = unbox_params(boxed)
     prompt = [3, 14, 15, 92, 65]
 
-    ref_out = LLMEngine(cfg, params, max_batch_size=2).generate(
-        [GenerationRequest(prompt, max_new_tokens=8)]
-    )[0].token_ids
+    ref_out = greedy_reference(cfg, params, prompt, 8)
 
     mesh = make_mesh(8, tp=4, fsdp=2)
     sharded = jax.device_put(params, param_shardings(mesh, boxed))
     with mesh:
-        tp_out = LLMEngine(cfg, sharded, mesh=mesh, max_batch_size=2).generate(
-            [GenerationRequest(prompt, max_new_tokens=8)]
-        )[0].token_ids
-        from ray_tpu.llm import ContinuousBatchingEngine
-
         cb = ContinuousBatchingEngine(cfg, sharded, mesh=mesh, num_slots=2)
         rid = cb.add_request(GenerationRequest(prompt, max_new_tokens=8))
         cb_out = cb.run_until_complete()[rid].token_ids
+        tp_out = cb.generate(
+            [GenerationRequest(prompt, max_new_tokens=8)]
+        )[0].token_ids
+        cb.close()
     assert tp_out == ref_out
     assert cb_out == ref_out
 

@@ -198,22 +198,22 @@ class TestStoreLifecycle:
             publish_adapter("t/x", "bad", {"w": jnp.zeros((2, 2))})
 
 
-# -- mixed-adapter batches on the paged engine -------------------------------
+# -- mixed-adapter batches, over a block pool and over dense rows ------------
 
 
 PROMPT = [3, 14, 15, 9, 2, 6, 5]  # ONE length: prefill compiles are per length
 TENANTS = ["tenant_a", "tenant_b", "tenant_c"]
 
 
-@pytest.fixture(scope="module")
-def lora_engine(tiny):
+@pytest.fixture(scope="module", params=[True, False], ids=["paged", "dense"])
+def lora_engine(tiny, request):
     cfg, params = tiny
     trees = {t: _adapter_tree(cfg, 10 + i) for i, t in enumerate(TENANTS)}
     store = AdapterStore(
         cfg, max_live=4, rank=RANK, source=trees.__getitem__,
         param_dtype=jnp.float32,
     )
-    kv = KVCacheManager(num_blocks=64, block_size=8)
+    kv = KVCacheManager(num_blocks=64, block_size=8) if request.param else None
     eng = ContinuousBatchingEngine(
         cfg, params, num_slots=4, kv_cache=kv, seed=0,
         adapter_store=store,
@@ -477,7 +477,6 @@ def test_batch_predictor_per_row_adapters(tiny):
         model_id="llama-tiny",
         max_seq_len=64,
         max_new_tokens=3,
-        kv_cache_blocks=32,
         adapters=AdapterConfig(
             max_live=2, slot_rank=RANK, source=trees.__getitem__
         ),

@@ -28,7 +28,7 @@ from benchmarks.reference import olmoe_arch  # noqa: E402
 from ray_tpu import models  # noqa: E402
 from ray_tpu.llm import LLMConfig  # noqa: E402
 from ray_tpu.llm.engine import (  # noqa: E402
-    ContinuousBatchingEngine, GenerationRequest, LLMEngine,
+    ContinuousBatchingEngine, GenerationRequest,
 )
 from ray_tpu.models.moe import ROUTING, MoEConfig  # noqa: E402
 from ray_tpu.parallel import expert as ep  # noqa: E402
@@ -326,11 +326,14 @@ def test_llm_config_builds_the_family_and_refuses_what_has_no_rules():
 def test_the_llama_engine_is_what_it_was():
     """The dense family through the same interface: the module the engine
     builds is ``Llama(cfg, mesh, decode=True)``, its decode program takes
-    no counters and returns two outputs, and its tokens are those of the
-    grouped-batch engine's."""
+    no counters and returns two outputs, and its tokens are the greedy
+    tokens of whole forward passes."""
+    from conftest import greedy_reference
+
     from ray_tpu.models.llama import Llama, LlamaConfig
 
-    cfg = LlamaConfig.tiny(max_seq_len=64)
+    # float32: in bf16 two of this toy's logits tie exactly
+    cfg = LlamaConfig.tiny(max_seq_len=64, dtype=jnp.float32)
     params = unbox_params(models.init_params(cfg, jax.random.PRNGKey(0)))
     engine = ContinuousBatchingEngine(cfg, params, num_slots=2, seed=0)
     assert engine._model == Llama(cfg, None, decode=True)
@@ -340,8 +343,10 @@ def test_the_llama_engine_is_what_it_was():
         GenerationRequest(token_ids=[3, 1, 4, 1, 5, 9, 2, 6], max_new_tokens=4),
     ]
     got = engine.generate(requests)
-    want = LLMEngine(cfg, params, seed=0).generate(requests)
-    assert [r.token_ids for r in got] == [r.token_ids for r in want]
+    assert [r.token_ids for r in got] == [
+        greedy_reference(cfg, params, r.token_ids, r.max_new_tokens)
+        for r in requests
+    ]
     text = engine._decode.lower(
         params, engine._cache, jnp.zeros((2, 1), jnp.int32),
         active=np.ones(2, bool)).as_text()

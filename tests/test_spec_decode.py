@@ -279,11 +279,11 @@ class TestConfigKnobs:
         assert cfg.spec_tokens == 4
         assert cfg.build_draft_model_config().max_seq_len == cfg.max_seq_len
 
-    def test_spec_requires_paged_engine(self):
-        with pytest.raises(ValueError, match="kv_cache_blocks"):
-            LLMConfig(draft_model="llama-tiny")
-        with pytest.raises(ValueError, match="kv_cache_blocks"):
-            LLMConfig(prefill_chunk_tokens=256)
+    def test_spec_and_chunking_need_no_pool(self):
+        cfg = LLMConfig(draft_model="llama-tiny", prefill_chunk_tokens=256)
+        assert cfg.kv_cache_blocks is None and cfg.spec_tokens == 4
+        with pytest.raises(ValueError, match="prefill_chunk_tokens"):
+            LLMConfig(prefill_chunk_tokens=-1)
 
     def test_draft_max_seq_len_must_cover_target(self, tiny_pair):
         cfg, params, _, _, _ = tiny_pair

@@ -21,7 +21,6 @@ from ray_tpu.llm.config import LLMConfig
 from ray_tpu.llm.engine import (
     ContinuousBatchingEngine,
     GenerationRequest,
-    LLMEngine,
 )
 from ray_tpu.models.llama import LlamaConfig, init_params
 from ray_tpu.parallel.plan import (
@@ -120,7 +119,7 @@ def test_tp2_paged_matches_dense_temperature0(tiny_setup, tp2):
     to the dense single-device engine at temperature 0, for cold prompts
     AND a warm request that rides the shared-prefix cache."""
     cfg, params = tiny_setup
-    dense = LLMEngine(cfg, params, max_batch_size=4, seed=7)
+    dense = ContinuousBatchingEngine(cfg, params, num_slots=4, seed=7)
     paged, kv, _ = tp2
 
     rng = np.random.RandomState(0)
