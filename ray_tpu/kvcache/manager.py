@@ -37,7 +37,8 @@ sequence axis at -2 and get a pool each; an ``INDEX`` leaf is a
 write-position filled with the cached token count at assembly. A family
 with ``STATE`` leaves (per-row state with no sequence axis: nothing to cut
 into blocks, and K/V blocks alone would resume its recurrent layers from a
-zero state) gets **no prefix reuse** (``refuse_prefix_reuse``): every lease
+zero state) or ``WINDOW`` leaves (a ring of a window layer's last positions:
+what a hit would restore into it is ROADMAP R4) gets **no prefix reuse** (``refuse_prefix_reuse``): every lease
 is uncacheable, nothing is matched, committed, adopted or assembled, and no
 device pool is ever allocated.
 """
@@ -51,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import SEQUENCE, STATE, cache_kinds
+from ..models import SEQUENCE, STATE, WINDOW, cache_kinds
 from .block_allocator import BlockAllocator
 from .prefix_index import PrefixIndex
 
@@ -340,9 +341,10 @@ class KVCacheManager:
             return
         leaves, treedef = jax.tree_util.tree_flatten(cache_row)
         kinds = jax.tree_util.tree_leaves(cache_kinds(cache_row))
-        if STATE in kinds and self.prefix_reuse:
+        if (STATE in kinds or WINDOW in kinds) and self.prefix_reuse:
             self.refuse_prefix_reuse(
-                "the cache row holds per-row state with no sequence axis"
+                "the cache row holds per-row state with no sequence axis, "
+                "or a window layer's ring"
             )
         if not self.prefix_reuse:
             return

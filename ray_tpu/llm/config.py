@@ -48,7 +48,7 @@ class AdapterConfig:
 class LLMConfig:
     model_id: str = "llama-tiny"
     # model construction: either a models.llama config name or kwargs
-    model_family: str = "llama"  # "llama" | "moe" | "deepseek" | "falcon_h1" | "solar_open2"
+    model_family: str = "llama"  # "llama" | "moe" | "deepseek" | "falcon_h1" | "solar_open2" | "motif"
     model_kwargs: Dict[str, Any] = field(default_factory=dict)
     max_seq_len: int = 512
     max_batch_size: int = 8
@@ -149,6 +149,7 @@ class LLMConfig:
             "adapters": self.adapters is not None,
             "draft_model": self.draft_model is not None,
             "mesh": tp > 1 or sp > 1,
+            "prefill_chunk": self.prefill_chunk_tokens > 0,
         }
         for feature, reason in refusals(self.model_family).items():
             if using[feature]:
@@ -239,6 +240,8 @@ class LLMConfig:
             from ..models.falcon_h1 import FalconH1Config as config_type
         elif self.model_family == "solar_open2":
             from ..models.solar_open2 import SolarOpen2Config as config_type
+        elif self.model_family == "motif":
+            from ..models.motif import MotifConfig as config_type
         else:
             raise ValueError(f"unknown model family {self.model_family!r}")
         kwargs = dict(self.model_kwargs)

@@ -39,7 +39,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .._internal.platform import decode_step_compiler_options
-from ..models import INDEX, ROUTING as _ROUTING, SEQUENCE, STATE, cache_kinds
+from ..models import (
+    INDEX, ROUTING as _ROUTING, SEQUENCE, STATE, WINDOW, cache_kinds,
+)
 from ..ops.decode_attention import traced_chunk, visits
 from ..ops.kv_row_write import traced_form
 from ..util import events as _events
@@ -602,8 +604,9 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             if kv_cache is not None:
                 kv_cache.refuse_prefix_reuse(
                     f"a {type(model_config).__name__} row carries per-row "
-                    "state with no sequence axis, which no K/V block holds: "
-                    "a hit would resume its recurrent layers from zero"
+                    "state with no sequence axis or a window layer's ring, "
+                    "which no K/V block holds: a hit would resume those "
+                    "layers from nothing"
                 )
         if kv_cache is not None and self._plan is not None:
             # the manager's block pools must live in the same sharded
@@ -1164,17 +1167,29 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             for _, leaf in leaves
         )
 
-    def state_bytes_per_row(self) -> Optional[int]:
-        """Bytes of per-row state with no sequence axis a slot row carries
-        over all layers, however long the row is (0 for a family that
-        keeps none); None before the first admission made the cache."""
-        leaves = self._cache_leaves(STATE)
+    def _row_bytes(self, kind: str) -> Optional[int]:
+        """Bytes a slot row carries in its leaves of ``kind`` over all
+        layers, whatever its length; None before the first admission."""
+        leaves = self._cache_leaves(kind)
         if leaves is None:
             return None
         return sum(
             leaf.dtype.itemsize * int(np.prod(leaf.shape[1:]))
             for _, leaf in leaves
         )
+
+    def state_bytes_per_row(self) -> Optional[int]:
+        """Bytes of per-row state with no sequence axis a slot row carries
+        over all layers, however long the row is (0 for a family that
+        keeps none); None before the first admission made the cache."""
+        return self._row_bytes(STATE)
+
+    def window_bytes_per_row(self) -> Optional[int]:
+        """Bytes of window rings a slot row carries over all layers,
+        however long the row is (0 for a family that keeps none);
+        ``cache_bytes_per_token`` counts the full-length layers only. None
+        before the first admission made the cache."""
+        return self._row_bytes(WINDOW)
 
     def row_write(self) -> Optional[Dict[str, Optional[str]]]:
         """How the compiled decode step stores a new position in each leaf
@@ -1191,7 +1206,8 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         ``attention_chunks()``; nothing while no traced step took the
         kernel (``ops/decode_attention.traced_chunk``)."""
         if self._attention_grid is None:
-            for leaf in jax.tree.leaves(self._cache):
+            # (the full-length leaves': a window ring is walked whole)
+            for _, leaf in self._cache_leaves(SEQUENCE):
                 chunk = traced_chunk(leaf.shape)
                 if chunk:
                     self._attention_grid = (chunk, leaf.shape[2])

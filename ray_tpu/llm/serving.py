@@ -361,6 +361,7 @@ class _LLMReplica:
             "kv": {
                 "cache_bytes_per_token": self._engine.cache_bytes_per_token(),
                 "state_bytes_per_row": self._engine.state_bytes_per_row(),
+                "window_bytes_per_row": self._engine.window_bytes_per_row(),
                 "row_write": self._engine.row_write(),
                 # chunks of keys the decode kernel visited over the steps
                 # dispatched, and what a dense grid would have

@@ -6,7 +6,10 @@ attention, shared + routed experts), Falcon-H1-shaped transformer (a
 Mamba-2 mixer beside attention in every block; serving only),
 Solar-Open2-shaped transformer (a gated delta-rule linear-attention mixer
 three layers in four, a gated NoPE GQA layer the fourth, routed experts of
-which one chip's share may be held; serving only), ViT (vision encoder).
+which one chip's share may be held; serving only), Motif-shaped
+transformer (grouped differential latent attention on window and full
+layers, a four-stream mHC residual, PolyNorm experts of which a share may be
+held; serving only), ViT (vision encoder).
 The reference delegates model execution to torch/vLLM; this framework owns
 it.
 
@@ -22,7 +25,7 @@ type of the model config. What the engine asks of a family:
 - of that collection the engine and ``kvcache.KVCacheManager`` ask only
   each leaf's *kind*, never what it means, and they ask it here
   (``cache_leaf_kind`` / ``cache_kinds``), by the leaf's name; nobody tells
-  a kind by ``ndim``. Three kinds:
+  a kind by ``ndim``. Four kinds:
 
   - ``SEQUENCE``: cached state of ``(batch, ..., max_seq_len, width)``,
     the sequence axis at -2 and one ``max_seq_len`` for all of them (a slot
@@ -54,6 +57,22 @@ type of the model config. What the engine asks of a family:
     ``llama``'s three; ``models/solar_open2.py`` a ``state_kda`` ``(batch,
     heads, d_k, d_v)`` float32 and a ``state_conv`` in each KDA layer, and
     ``llama``'s three in each GQA layer
+  - ``WINDOW``: a ring of a window layer's last ``ring`` positions,
+    ``(batch, 1, ring, width)``, beside the layer's ``INDEX``; a name that
+    starts with ``window_``. Position ``p`` lives at slot ``p % ring``: a
+    step writes its token there and attends ``min(index + 1, ring)`` slots
+    (a cached latent row carries no position and a cached rotary row is
+    already rotated, and a softmax over a set does not depend on its
+    order, so the ring read up to that length *is* the window); a
+    whole-prompt prefill leaves the prompt's last ``min(len, ring)``
+    positions; a free row needs no zeroing (index 0 is an empty ring). A
+    slot row is a slice of axis 0 as for the others; like ``STATE`` it is
+    nothing a pool block holds, so a family with such a leaf gets no prefix
+    reuse either (``carries_row_state``), and a chunk behind a cached
+    prefix has no ring-aware form (``_NO_RULES``: ``prefill_chunk``).
+    ``models/motif.py`` keeps ``window_latent`` ``(batch, 1, 128, 512)``
+    and ``window_rope`` ``(batch, 1, 128, 64)`` in three layers of four,
+    ``deepseek``'s two ``SEQUENCE`` leaves in the fourth
 - a step of one token a row (``seq == 1`` against a cache) attends each
   row up to its own position; a longer ``seq`` against a cache is a chunk
   behind a cached prefix, row ``r``'s token ``i`` at ``index[r] + i``, and
@@ -84,7 +103,11 @@ sequence axis. ``solar_open2`` (PR 36) is the first with ``STATE`` leaves
 *and* routed layers, the first whose layers differ by index (``gqa_layers``),
 and the first to hold a share of its experts: ``llama.Attention`` gained
 ``rope`` / ``attn_gate`` / ``attn_head_dim``, ``MoEConfig`` ``experts_held``,
-and the engine's expert counters count over the experts held.
+and the engine's expert counters count over the experts held. ``motif``
+(PR 50) forced the fourth leaf kind (two kinds of attention cache side by
+side in one row), shares ``deepseek``'s latent rows as functions
+(``latent_rows`` / ``latent_cache``), and gave ``MoEConfig`` an
+``expert_activation`` (``ops/moe_experts.py``'s PolyNorm form).
 """
 
 from __future__ import annotations
@@ -92,9 +115,10 @@ from __future__ import annotations
 from typing import Any, Dict
 
 # the kinds of a cache leaf (module docstring)
-SEQUENCE, INDEX, STATE = "sequence", "index", "state"
+SEQUENCE, INDEX, STATE, WINDOW = "sequence", "index", "state", "window"
 _INDEX_NAME = "cache_index"
 _STATE_PREFIX = "state_"
+_WINDOW_PREFIX = "window_"
 
 # the collection a routed family's decode step sows each layer's (rows, k)
 # expert choices into, for the engine's expert counters (llm/engine.py)
@@ -173,6 +197,30 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "ep exchange yet (ROADMAP R1)"
         ),
     },
+    "motif": {
+        "adapters": (
+            "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
+            "wq/wk/wv/wo and has no placement for the query and latent "
+            "low-rank projections or for expert weights"
+        ),
+        "draft_model": (
+            "a rejected draft run cannot be undone by moving an index "
+            "back: a window layer's ring has overwritten the positions the "
+            "run would return to, and the expert counters count plain "
+            "decode steps (the model's own MTP head: ROADMAP R3)"
+        ),
+        "mesh": (
+            "the latent row and the ring have no head axis for "
+            "parallel/plan.py's KV_SPEC to shard and the latent decode "
+            "kernel no shard_map form; a held share of the experts has no "
+            "ep exchange yet (ROADMAP R1)"
+        ),
+        "prefill_chunk": (
+            "a chunk behind a cached prefix would have to read a window "
+            "layer's ring while it overwrites it: the ring has no form "
+            "for more than one new position a row (ROADMAP R4)"
+        ),
+    },
 }
 
 
@@ -182,6 +230,8 @@ def cache_leaf_kind(name: str) -> str:
         return INDEX
     if name.startswith(_STATE_PREFIX):
         return STATE
+    if name.startswith(_WINDOW_PREFIX):
+        return WINDOW
     return SEQUENCE
 
 
@@ -196,10 +246,12 @@ def cache_kinds(cache: Any) -> Any:
 
 
 def carries_row_state(model_config) -> bool:
-    """Whether the family keeps ``STATE`` leaves: known from the config
-    alone, before any cache exists (a lease is asked for before the first
-    prefill)."""
-    return getattr(_family(model_config), "ROW_STATE", False)
+    """Whether the family keeps ``STATE`` or ``WINDOW`` leaves, which no
+    pool block holds: known from the config alone, before any cache exists
+    (a lease is asked for before the first prefill)."""
+    family = _family(model_config)
+    return getattr(family, "ROW_STATE", False) or getattr(
+        family, "ROW_WINDOW", False)
 
 
 def restarts_own_state(model_config) -> bool:
@@ -216,8 +268,10 @@ def refusals(family: str) -> Dict[str, str]:
 
 
 def _family(model_config):
-    from . import deepseek, falcon_h1, llama, moe, solar_open2
+    from . import deepseek, falcon_h1, llama, moe, motif, solar_open2
 
+    if isinstance(model_config, motif.MotifConfig):
+        return motif
     if isinstance(model_config, solar_open2.SolarOpen2Config):
         return solar_open2
     if isinstance(model_config, falcon_h1.FalconH1Config):

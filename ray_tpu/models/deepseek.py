@@ -162,6 +162,52 @@ def _causal_attention(q, k, v, scale: float):
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=2)
 
 
+def latent_rows(module, cfg, x):
+    """The latent down-projection of ``x (b, s, dim)`` inside ``module``'s
+    compact call: ``[c_raw | k_r] = x W_kva``, ``c = RMSNorm(c_raw)``.
+    Returns ``c (b, s, rank)`` and ``k_r (b, 1, s, rope)`` (one row for all
+    heads, before RoPE). Shared by every family with latent attention
+    (``models/motif.py``); ``cfg`` gives ``kv_lora_rank``,
+    ``qk_rope_head_dim``, ``norm_eps`` and the two dtypes."""
+    rank = cfg.kv_lora_rank
+    kva = _dense(
+        rank + cfg.qk_rope_head_dim, ("embed", None), "wkv_a",
+        cfg.param_dtype, cfg.dtype,
+    )(x)
+    kv_norm_w = module.param(
+        "kv_norm",
+        nn.with_logical_partitioning(nn.initializers.ones_init(), (None,)),
+        (rank,),
+        cfg.param_dtype,
+    )
+    c = rmsnorm(kva[..., :rank], kv_norm_w.astype(x.dtype), cfg.norm_eps)
+    return c, kva[..., None, :, rank:]
+
+
+def latent_cache(module, cfg, batch: int, positions: int,
+                 prefix: str = "cached"):
+    """``module``'s latent cache of ``positions`` positions a row: the
+    leaves ``<prefix>_latent (batch, 1, positions, rank)`` and
+    ``<prefix>_rope (batch, 1, positions, rope)`` and the row's
+    ``cache_index``, and whether this very call made them (a prefill into
+    a fresh cache). Two leaves, not one of rank + rope columns: 576 is 4.5
+    lane tiles, which the TPU stores sequence-minor and a kernel cannot
+    read without a transpose (ops/decode_attention.py)."""
+    fresh = not module.has_variable("cache", f"{prefix}_latent")
+    cached_c = module.variable(
+        "cache", f"{prefix}_latent",
+        jnp.zeros, (batch, 1, positions, cfg.kv_lora_rank), cfg.dtype,
+    )
+    cached_r = module.variable(
+        "cache", f"{prefix}_rope",
+        jnp.zeros, (batch, 1, positions, cfg.qk_rope_head_dim), cfg.dtype,
+    )
+    idx_var = module.variable(
+        "cache", "cache_index", lambda: jnp.zeros((batch,), jnp.int32)
+    )
+    return cached_c, cached_r, idx_var, fresh
+
+
 class LatentAttention(nn.Module):
     config: DeepseekConfig
     decode: bool = False
@@ -183,15 +229,7 @@ class LatentAttention(nn.Module):
         q = dense(h * (nope + rope), ("embed", "heads"), "wq")(x)
         q = q.reshape(b, s, h, nope + rope).transpose(0, 2, 1, 3)
         q_nope, q_rope = q[..., :nope], q[..., nope:]
-        kva = dense(rank + rope, ("embed", None), "wkv_a")(x)
-        kv_norm_w = self.param(
-            "kv_norm",
-            nn.with_logical_partitioning(nn.initializers.ones_init(), (None,)),
-            (rank,),
-            cfg.param_dtype,
-        )
-        c = rmsnorm(kva[..., :rank], kv_norm_w.astype(x.dtype), cfg.norm_eps)
-        k_r = kva[..., None, :, rank:]  # (b, 1, s, rope): one row, all heads
+        c, k_r = latent_rows(self, cfg, x)
         # (rank, heads, nope | v): a head's up-projection of the latent to
         # its keys' nope part and to its values, kept whole so the decode
         # step can absorb either half
@@ -209,21 +247,8 @@ class LatentAttention(nn.Module):
         ).astype(cfg.dtype)
 
         if self.decode:
-            fresh = not self.has_variable("cache", "cached_latent")
-            # two leaves, not one of rank + rope columns: 576 is 4.5 lane
-            # tiles, which the TPU stores sequence-minor and a kernel
-            # cannot read without a transpose (ops/decode_attention.py)
-            cached_c = self.variable(
-                "cache", "cached_latent",
-                jnp.zeros, (b, 1, cfg.max_seq_len, rank), cfg.dtype,
-            )
-            cached_r = self.variable(
-                "cache", "cached_rope",
-                jnp.zeros, (b, 1, cfg.max_seq_len, rope), cfg.dtype,
-            )
-            idx_var = self.variable(
-                "cache", "cache_index", lambda: jnp.zeros((b,), jnp.int32)
-            )
+            cached_c, cached_r, idx_var, fresh = latent_cache(
+                self, cfg, b, cfg.max_seq_len)
             idx = idx_var.value  # (b,): a row's write position
             q_rope = apply_rope(q_rope, cos, sin, offset=idx)
             k_rope = apply_rope(k_r, cos, sin, offset=idx)

@@ -81,8 +81,25 @@ class MoEConfig:
     # experts chosen wherever they live, and the layer gives the part of
     # the result its own experts give (parallel/expert.moe_apply_dropless)
     experts_held: Optional[Tuple[int, int]] = None
+    # an expert's activation: "swiglu" (``silu(gate) * up``), or Motif's
+    # "poly_norm" (``P(gate) * up``, ops/moe_experts.py), an expert's four
+    # coefficients in a ``poly`` parameter: three weights, and a bias
+    # clipped to +-``polynorm_bias_clamp``, all times ``polynorm_scale``
+    expert_activation: str = "swiglu"
+    polynorm_scale: float = 0.5
+    polynorm_bias_clamp: float = 0.5
 
     def __post_init__(self):
+        if self.expert_activation not in ("swiglu", "poly_norm"):
+            raise ValueError(
+                f"MoEConfig: unknown expert_activation "
+                f"{self.expert_activation!r}"
+            )
+        if self.expert_activation != "swiglu" and not self.dropless:
+            raise ValueError(
+                "MoEConfig: expert_activation 'poly_norm' needs "
+                "dropless=True: the capacity path's experts are SwiGLU"
+            )
         if self.experts_held is not None:
             first, stop = self.experts_held
             object.__setattr__(self, "experts_held", (first, stop))
@@ -150,6 +167,40 @@ class MoEConfig:
         )
         defaults.update(kw)
         return MoEConfig(**defaults)
+
+
+def poly_init(key, shape, dtype=jnp.float32):
+    """A PolyNorm's ``[w1, w2, w3, b]`` (last axis): the weights a third
+    each, as published (arXiv:2411.03884), with a tenth of noise so that a
+    seeded model's three terms differ; the bias normal at 0.4, so that the
+    clamp at 0.5 bites on a fifth of them."""
+    noise = jax.random.normal(key, shape, jnp.float32)
+    centre = jnp.asarray([1 / 3, 1 / 3, 1 / 3, 0.0], jnp.float32)
+    spread = jnp.asarray([0.1, 0.1, 0.1, 0.4], jnp.float32)
+    return (centre + spread * noise).astype(dtype)
+
+
+def poly_coefficients(poly, scale: float, bias_clamp: float):
+    """``c1 .. c4`` of ``P(z) = c1 N(z^3) + c2 N(z^2) + c3 N(z) + c4`` from
+    a ``poly`` parameter ``(..., 4)``: the output scale times the weights
+    and times the bias clipped to +-``bias_clamp``."""
+    poly = poly.astype(jnp.float32)
+    return scale * jnp.concatenate(
+        [poly[..., :3], jnp.clip(poly[..., 3:], -bias_clamp, bias_clamp)],
+        axis=-1,
+    )
+
+
+def poly_norm(z, c, eps: float):
+    """``P(z)`` over ``z``'s last axis in XLA, ``c`` the four coefficients
+    (``poly_coefficients``): what ``ops/moe_experts.py`` computes a routed
+    expert's rows with, for a dense feed-forward."""
+    def normed(t):
+        return t * jax.lax.rsqrt(
+            jnp.mean(t * t, axis=-1, keepdims=True) + eps)
+
+    z2 = z * z
+    return c[0] * normed(z2 * z) + c[1] * normed(z2) + c[2] * normed(z) + c[3]
 
 
 class MoEFFN(nn.Module):
@@ -241,12 +292,26 @@ class MoEFFN(nn.Module):
                 "ecf,efd->ecd", nn.silu(gate) * up, w_down.astype(inp.dtype)
             )
 
+        activation = {}
+        if cfg.expert_activation == "poly_norm":
+            poly = self.param(
+                "poly",
+                nn.with_logical_partitioning(poly_init, ("expert", None)),
+                (cfg.n_experts_held, 4),
+                jnp.float32,
+            )
+            activation = dict(
+                activation="poly_norm", eps=cfg.norm_eps,
+                poly=poly_coefficients(
+                    poly, cfg.polynorm_scale, cfg.polynorm_bias_clamp),
+            )
+
         with jax.named_scope("moe.experts"):
             if cfg.dropless:
                 out = moe_apply_dropless(
                     tokens, weights, chosen, w_gate.astype(tokens.dtype),
                     w_up.astype(tokens.dtype), w_down.astype(tokens.dtype),
-                    held=cfg.experts_held,
+                    held=cfg.experts_held, **activation,
                 )
             else:
                 out = moe_apply_gspmd(tokens, dispatch, combine, experts)

@@ -25,6 +25,20 @@ Design:
 - the arithmetic of the einsum it stands for: operands as stored (bf16
   products are exact in f32), f32 accumulation, the hidden activation
   rounded to the weights' dtype before the down product
+
+The activation is an argument (``activation=``). ``"swiglu"``, the
+default, is the kernel above and nothing of it changes. ``"poly_norm"``
+(Motif: ``y[r] = down_e(P_e(gate_e x[r]) * up_e x[r])``, ``P(z) = c1 N(z^3)
++ c2 N(z^2) + c3 N(z) + c4``, ``N(z) = z / sqrt(mean(z^2) + eps)`` over the
+expert's whole inner row) normalises over all of f, so a block of gate
+columns cannot be activated when it arrives: a visit takes two sweeps of
+the f blocks in one grid axis of ``2 x f // block`` steps. The first sweep
+multiplies the gate columns, keeps them in VMEM (f32, ``(tile, f)``) and
+adds up the row sums of ``z^2``, ``z^4`` and ``z^6``; the second multiplies
+the up columns against the finished activation and accumulates the down
+product. Each matrix is still read once: the gate's block index stands
+still through the second sweep and the other two's through the first, and
+a block whose index does not change is not fetched again.
 """
 
 from __future__ import annotations
@@ -92,6 +106,18 @@ def _dot(a, b):
     )
 
 
+def _store_own_rows(offsets_ref, group_ids_ref, tile_ids_ref, acc_ref,
+                    o_ref, visit, tm: int):
+    """A visit's result into its tile: only this expert's rows, the others
+    belong to the visits before and after."""
+    group = group_ids_ref[visit]
+    row = tile_ids_ref[visit] * tm + jax.lax.broadcasted_iota(
+        jnp.int32, (tm, 1), 0
+    )
+    mine = (row >= offsets_ref[group]) & (row < offsets_ref[group + 1])
+    o_ref[...] = jnp.where(mine, acc_ref[...], o_ref[...])
+
+
 def _kernel(
     offsets_ref, group_ids_ref, tile_ids_ref,
     x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *, tm: int,
@@ -109,29 +135,129 @@ def _kernel(
 
     @pl.when(fi == pl.num_programs(1) - 1)
     def _store():
-        # only this expert's rows of the tile: the others belong to the
-        # visits before and after
-        group = group_ids_ref[visit]
-        row = tile_ids_ref[visit] * tm + jax.lax.broadcasted_iota(
-            jnp.int32, (tm, 1), 0
+        _store_own_rows(
+            offsets_ref, group_ids_ref, tile_ids_ref, acc_ref, o_ref, visit, tm)
+
+
+def _poly_kernel(
+    offsets_ref, group_ids_ref, tile_ids_ref,
+    x_ref, wg_ref, wu_ref, wd_ref, poly_ref, o_ref, acc_ref, gate_ref,
+    sums_ref, *, tm: int, nf: int, inner: int, eps: float,
+):
+    visit = pl.program_id(0)
+    fi = pl.program_id(1)
+    x = x_ref[...]  # (tm, d)
+
+    @pl.when(fi == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    @pl.when(fi < nf)
+    def _gate_sweep():
+        z = _dot(x, wg_ref[...])  # (tm, tf) f32
+        gate_ref[fi] = z
+        z2 = z * z
+        for power, term in enumerate((z2, z2 * z2, z2 * z2 * z2)):
+            sums_ref[power] += jnp.sum(term, axis=1, keepdims=True)
+
+    @pl.when(fi >= nf)
+    def _up_sweep():
+        coeff = poly_ref[0]  # (4, tf): a coefficient a sublane row
+        z = gate_ref[fi - nf]
+        z2 = z * z
+        # N(z^p) = z^p / sqrt(mean(z^2p) + eps), the mean over all of f
+        scale = [
+            jax.lax.rsqrt(sums_ref[power][:, :1] / inner + eps)
+            for power in range(3)
+        ]
+        activated = (
+            coeff[0:1] * (z2 * z) * scale[2]
+            + coeff[1:2] * z2 * scale[1]
+            + coeff[2:3] * z * scale[0]
+            + coeff[3:4]
         )
-        mine = (row >= offsets_ref[group]) & (row < offsets_ref[group + 1])
-        o_ref[...] = jnp.where(mine, acc_ref[...], o_ref[...])
+        hidden = activated * _dot(x, wu_ref[...])
+        acc_ref[...] += _dot(hidden.astype(wd_ref.dtype), wd_ref[...])
+
+    @pl.when(fi == 2 * nf - 1)
+    def _store():
+        _store_own_rows(
+            offsets_ref, group_ids_ref, tile_ids_ref, acc_ref, o_ref, visit, tm)
 
 
-def moe_experts(x, w_gate, w_up, w_down, group_sizes):
+def _poly_norm_experts(x, w_gate, w_up, w_down, schedule, visits, tm, tf,
+                       rows, poly, eps):
+    """``moe_experts``' PolyNorm form on its schedule, row tile ``tm`` and
+    f block ``tf``."""
+    m, d = x.shape
+    n_experts, _, f = w_gate.shape
+    nf = f // tf
+
+    def gate_columns(v, fi, offsets, group_ids, tile_ids):
+        return group_ids[v], 0, jnp.minimum(fi, nf - 1)
+
+    def up_columns(v, fi, offsets, group_ids, tile_ids):
+        return group_ids[v], 0, jnp.maximum(fi - nf, 0)
+
+    def down_rows(v, fi, offsets, group_ids, tile_ids):
+        return group_ids[v], jnp.maximum(fi - nf, 0), 0
+
+    def coefficients(v, fi, offsets, group_ids, tile_ids):
+        return group_ids[v], 0, 0
+
+    # an expert's four coefficients as a (4, tf) f32 tile, the value
+    # repeated along the lanes (Mosaic broadcasts along sublanes or along
+    # lanes, not a scalar along both)
+    poly = jnp.broadcast_to(
+        poly.astype(jnp.float32)[:, :, None], (n_experts, 4, tf))
+    return pl.pallas_call(
+        functools.partial(
+            _poly_kernel, tm=tm, nf=nf, inner=f, eps=float(eps)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(visits, 2 * nf),
+            in_specs=[
+                pl.BlockSpec((tm, d), rows),
+                pl.BlockSpec((None, d, tf), gate_columns),
+                pl.BlockSpec((None, d, tf), up_columns),
+                pl.BlockSpec((None, tf, d), down_rows),
+                pl.BlockSpec((1, 4, tf), coefficients),
+            ],
+            out_specs=pl.BlockSpec((tm, d), rows),
+            scratch_shapes=[
+                pltpu.VMEM((tm, d), jnp.float32),
+                pltpu.VMEM((nf, tm, tf), jnp.float32),
+                pltpu.VMEM((3, tm, 128), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        name="moe_experts",  # the op's name in a device trace
+        interpret=_use_interpret(),
+    )(*schedule, x, w_gate, w_up, w_down, poly)
+
+
+def moe_experts(x, w_gate, w_up, w_down, group_sizes,
+                activation: str = "swiglu", poly=None, eps: float = 1e-5):
     """``x (m, d)`` rows sorted by expert, ``m`` a multiple of
     ``tile_rows(m)``; ``w_gate``/``w_up (E, d, f)``, ``w_down (E, f, d)``;
     ``group_sizes (E,) int32`` summing to ``m`` or to less: rows past the
     last group's end belong to no visit, are not computed and come back as
     whatever the output buffer held (``moe_apply_dropless`` puts the
     assignments to experts held elsewhere there). Returns ``(m, d)``
-    f32."""
+    f32. ``activation="poly_norm"`` takes ``poly (E, 4)``, an expert's
+    ``c1 .. c4`` (module docstring), and ``eps``."""
     m, d = x.shape
     n_experts, _, f = w_gate.shape
     tm = tile_rows(m)
     if m % tm:
         raise ValueError(f"{m} rows are not whole tiles of {tm}")
+    if activation not in ("swiglu", "poly_norm"):
+        raise ValueError(f"moe_experts: unknown activation {activation!r}")
     tf = block_f(d, f, w_gate.dtype)
     (offsets, group_ids, tile_ids), visits = make_group_metadata(
         group_sizes=group_sizes.astype(jnp.int32), m=m, tm=tm,
@@ -141,6 +267,11 @@ def moe_experts(x, w_gate, w_up, w_down, group_sizes):
 
     def rows(v, fi, offsets, group_ids, tile_ids):
         return tile_ids[v], 0
+
+    if activation == "poly_norm":
+        return _poly_norm_experts(
+            x, w_gate, w_up, w_down, (offsets, group_ids, tile_ids), visits,
+            tm, tf, rows, poly, eps)
 
     def columns(v, fi, offsets, group_ids, tile_ids):
         return group_ids[v], 0, fi

@@ -218,6 +218,9 @@ def moe_apply_dropless(
     w_up: jax.Array,  # (E, dim, f)
     w_down: jax.Array,  # (E, f, dim)
     held: Optional[Tuple[int, int]] = None,
+    activation: str = "swiglu",
+    poly: Optional[jax.Array] = None,  # (E, 4) with "poly_norm"
+    eps: float = 1e-5,
 ) -> jax.Array:
     """``sum_j weights[t, j] * swiglu_{experts[t, j]}(x[t])`` for every
     token, no assignment dropped: the ``tokens x k`` assignments sorted by
@@ -231,7 +234,10 @@ def moe_apply_dropless(
     those experts give. The assignments to them are sorted first and are
     the only rows the grouped matmul visits; an assignment to an expert
     held elsewhere, like the padding, sorts behind them, belongs to no
-    group and adds nothing: the work follows the held assignments."""
+    group and adds nothing: the work follows the held assignments.
+
+    ``activation`` / ``poly`` / ``eps``: the expert's activation as
+    ``ops/moe_experts.moe_experts`` takes it (SwiGLU unless said)."""
     from ..ops.moe_experts import moe_experts, tile_rows
 
     tokens, k = experts.shape
@@ -263,7 +269,10 @@ def moe_apply_dropless(
     source = jnp.minimum(order // k, tokens - 1)
     # (a key past the last expert is out of bounds here, and dropped)
     group_sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
-    y = moe_experts(x[source], w_gate, w_up, w_down, group_sizes)
+    # (SwiGLU's call is the one it always was: no argument it does not take)
+    extra = {} if activation == "swiglu" else dict(
+        activation=activation, poly=poly, eps=eps)
+    y = moe_experts(x[source], w_gate, w_up, w_down, group_sizes, **extra)
     back = jnp.argsort(order)[:n]  # sorted row of each assignment
     y = y[back].reshape(tokens, k, -1)
     if held is not None:

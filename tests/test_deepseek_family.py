@@ -552,7 +552,8 @@ def test_deepseek_serves_through_serve_run(shutdown_only):
             # 64-position cache) long: nothing for the schedule to skip
             "attention_chunks_visited": 8, "attention_chunks_dense": 8,
             # no per-row state without a sequence axis, so prefixes are shared
-            "state_bytes_per_row": 0,
+            # ... and no window layer's ring (models.WINDOW)
+            "state_bytes_per_row": 0, "window_bytes_per_row": 0,
             "prefix_reuse": True, "prefix_reuse_refused": None,
         }
         assert info["kernels"]["latent_decode_attention"] == [True]
