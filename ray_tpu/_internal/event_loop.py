@@ -37,6 +37,8 @@ class LoopThread:
         try:
             return fut.result(timeout)
         except concurrent.futures.TimeoutError:
+            if fut.done():
+                raise  # the coroutine's own TimeoutError, not this wait's
             fut.cancel()
             raise TimeoutError(f"{self.name}: coroutine timed out after {timeout}s")
 

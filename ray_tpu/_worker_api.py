@@ -86,5 +86,7 @@ def run_on_worker_loop(coro, timeout=None):
     try:
         return fut.result(timeout)
     except concurrent.futures.TimeoutError:
+        if fut.done():
+            raise  # the coroutine's own TimeoutError, not this wait's
         fut.cancel()
         raise TimeoutError("operation timed out")

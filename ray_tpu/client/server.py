@@ -32,7 +32,7 @@ class ClientServer:
     ALLOWED_OPS = frozenset({
         "put", "get_objects", "wait", "submit_task", "create_actor",
         "submit_actor_task", "kill_actor", "attach_actor",
-        "next_stream_item", "drop_stream",
+        "next_stream_item", "take_stream_values", "drop_stream",
     })
 
     def __init__(self, gcs_address: Tuple[str, int], config: Optional[Config] = None):

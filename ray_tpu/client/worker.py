@@ -155,6 +155,17 @@ class ClientWorker:
             "worker_op", self._client_id, "next_stream_item", task_id
         )
 
+    async def take_stream_values(
+        self, task_id: TaskID, timeout: Optional[float] = None
+    ):
+        """The value-reading half of the same stream: the server's worker
+        takes the items and they travel here packed, as a get's values do;
+        nothing is pinned, since no ref is made."""
+        return await self._server.call(
+            "worker_op", self._client_id, "take_stream_values", task_id,
+            timeout,
+        )
+
     def drop_stream(self, task_id: TaskID):
         """Sync fire-and-forget like CoreWorker.drop_stream — invoked from
         ObjectRefGenerator.__del__ via call_soon_threadsafe on this loop."""

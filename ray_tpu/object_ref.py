@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+from ._internal import serialization
 from ._internal.ids import ObjectID
 
 
@@ -71,8 +72,6 @@ class ObjectRef:
         # receiver a borrower; the owner address travels with the ref. An
         # active arg-flattening collector records the ref so nested refs get
         # pinned for the task's flight (serialization.collect_refs).
-        from ._internal import serialization
-
         serialization.record_serialized_ref(self)
         return (_deserialize_ref, (self.id, self.owner_address))
 
@@ -92,6 +91,24 @@ def _deserialize_ref(object_id, owner_address):
     return ObjectRef(object_id, owner_address)
 
 
+class UnpackedStreamItem:
+    """A stream item that ``take_values`` hands over already deserialized
+    (it went through plasma), where the others travel packed."""
+
+    __slots__ = ("value",)
+
+    def __init__(self, value):
+        self.value = value
+
+
+def unpack_stream_value(item):
+    """The value of one element of ``ObjectRefGenerator.take_values()``,
+    deserialized on the calling thread."""
+    if isinstance(item, UnpackedStreamItem):
+        return item.value
+    return serialization.unpack(item)
+
+
 class ObjectRefGenerator:
     """Iterator over a streaming-generator task's yielded objects.
 
@@ -101,6 +118,14 @@ class ObjectRefGenerator:
     next yielded item (items stream while the task still runs) and returns
     its ObjectRef; StopIteration at end-of-stream; a mid-stream task error
     raises after the already-yielded items are consumed.
+
+    Two ways to read, one cursor. ``next()`` makes the item an ObjectRef:
+    owned by this process, fetchable any number of times, passable to tasks,
+    freed when the last reference drops. ``take_values()`` makes no ref: the
+    owner hands over the values themselves and forgets them in the same
+    call, so an item read that way cannot be fetched again, by ref or
+    otherwise. The two may be mixed on one stream (by one reader at a time);
+    each item goes to whichever asked for it.
     """
 
     def __init__(self, task_id):
@@ -119,6 +144,20 @@ class ObjectRefGenerator:
         if ref is None:
             raise StopIteration
         return ref
+
+    def take_values(self, timeout: Optional[float] = None) -> Optional[list]:
+        """Every item the stream holds from the cursor on, in yield order,
+        in one hop onto the owner's loop; blocks while it holds none. Each
+        element opens with ``unpack_stream_value``. None at end-of-stream;
+        the task's error once everything yielded before it has been taken.
+        ``timeout`` bounds the wait for the next item (GetTimeoutError; the
+        stream stays readable)."""
+        from . import _worker_api
+
+        worker = _worker_api.get_core_worker()
+        return _worker_api.run_on_worker_loop(
+            worker.take_stream_values(self._task_id, timeout)
+        )
 
     def __repr__(self):
         return f"ObjectRefGenerator({self._task_id.hex()})"

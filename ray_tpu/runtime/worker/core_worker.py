@@ -65,7 +65,7 @@ from ...exceptions import (
     TaskError,
     WorkerCrashedError,
 )
-from ...object_ref import ObjectRef
+from ...object_ref import ObjectRef, UnpackedStreamItem
 from ..gcs import keys as gcs_keys
 from ..gcs.pubsub import SubscriberClient
 from ..object_store.store import StoreClient
@@ -213,6 +213,11 @@ class CoreWorker:
         # streaming generators (owner side): task_id -> stream progress
         # (reference: ObjectRefStream, task_manager.h:67)
         self._streams: Dict[TaskID, _StreamState] = {}
+        # how the streams' items left: "values" taken packed in "takes" hops
+        # (the largest "max_take"), "refs" made ObjectRefs. values / takes
+        # near 1 with refs at 0 is a consumer that keeps up; well over 1 is
+        # one that is catching up
+        self.stream_counts = {"values": 0, "takes": 0, "max_take": 0, "refs": 0}
 
         # lineage (owner side; reference: ObjectRecoveryManager,
         # object_recovery_manager.h:41 + TaskManager lineage pinning): the
@@ -1307,35 +1312,35 @@ class CoreWorker:
             self.memory_store.put_plasma(object_id, size, node_addr)
         self._owned.add(object_id)
         state = self._streams.get(task_id)
-        if state is not None:
+        if state is not None and index >= state.next_read:
             state.reported.add(index)
             state.pulse()
             return True
-        # stream already dropped/terminated (state is created at submit
-        # time, so None means the consumer abandoned it): free the item
-        # we just stored, or a still-producing generator pins every
-        # remaining yield for the process lifetime. _maybe_free respects
-        # live ObjectRefs, so re-reports of already-read items survive.
-        # False tells the executor nobody is listening — it closes the
-        # user generator instead of producing items into the void.
+        # no reader will come for this item: the stream was dropped or has
+        # terminated (state is created at submit time, so None means the
+        # consumer abandoned it), or the index is under the cursor (an actor
+        # restarted mid-stream yields again from 0). Free what we just
+        # stored, or it is pinned for the process lifetime. _maybe_free
+        # respects live ObjectRefs, so re-reports of items read by ref
+        # survive. False tells the executor nobody is listening — it closes
+        # the user generator instead of producing items into the void.
         self._maybe_free(object_id)
-        return False
+        return state is not None
 
-    async def next_stream_item(self, task_id: TaskID) -> Optional[ObjectRef]:
-        """Next ObjectRef of a streaming task, in yield order; None at
-        end-of-stream (reference: TryReadObjectRefStream, core_worker.h:306).
-        Items already yielded remain readable even if the task later fails —
-        the error surfaces when reading PAST the last delivered item."""
-        state = self._streams.get(task_id)
-        if state is None:
-            return None
+    async def _stream_readable(
+        self, task_id: TaskID, state: "_StreamState",
+        timeout: Optional[float] = None,
+    ) -> bool:
+        """Wait until the item at the stream's cursor has been reported:
+        True then, False at end-of-stream. Items already yielded remain
+        readable even if the task later fails — the task's error is raised
+        when reading PAST the last delivered item. Both terminal outcomes
+        pop the stream's state. With a ``timeout``, GetTimeoutError once no
+        item has arrived within it (the stream stays readable)."""
+        deadline = None if timeout is None else self.loop.time() + timeout
         while True:
             if state.next_read in state.reported:
-                i = state.next_read
-                state.next_read += 1
-                return ObjectRef(
-                    ObjectID.for_task_return(task_id, i), self.address
-                )
+                return True
             if state.error is not None:
                 # terminal: drop the stream so an abandoned/failed stream
                 # doesn't pin its state for the process lifetime
@@ -1344,9 +1349,90 @@ class CoreWorker:
                 raise serialization.unpack(state.error)
             if state.total is not None and state.next_read >= state.total:
                 self._streams.pop(task_id, None)
-                return None
+                return False
             state.event.clear()
-            await state.event.wait()
+            if deadline is None:
+                await state.event.wait()
+                continue
+            try:
+                async with asyncio.timeout_at(deadline):
+                    await state.event.wait()
+            except TimeoutError:
+                raise GetTimeoutError(
+                    f"no item of stream {task_id.hex()} within {timeout}s"
+                ) from None
+
+    async def next_stream_item(self, task_id: TaskID) -> Optional[ObjectRef]:
+        """Next ObjectRef of a streaming task, in yield order; None at
+        end-of-stream (reference: TryReadObjectRefStream, core_worker.h:306).
+        Shares the stream's cursor with take_stream_values."""
+        state = self._streams.get(task_id)
+        if state is None or not await self._stream_readable(task_id, state):
+            return None
+        index = state.next_read
+        state.next_read += 1
+        self.stream_counts["refs"] += 1
+        return ObjectRef(ObjectID.for_task_return(task_id, index), self.address)
+
+    async def take_stream_values(
+        self, task_id: TaskID, timeout: Optional[float] = None
+    ) -> Optional[list]:
+        """Every consecutive reported item from the stream's cursor on, as
+        packed values (``object_ref.unpack_stream_value`` opens one, on the
+        caller's thread), advancing the cursor past them; None at
+        end-of-stream. With none reported it waits, ends and fails as
+        next_stream_item does; ``timeout`` bounds that wait.
+
+        An item taken here never becomes an ObjectRef: its entry leaves the
+        memory store and ``_owned`` in this call, so nothing is registered,
+        looked up again or freed by a later hop, and it cannot be fetched a
+        second time. An item that went to plasma is read through
+        ``_read_plasma``, alone, and freed as a dropped ref frees it."""
+        state = self._streams.get(task_id)
+        if state is None or not await self._stream_readable(
+            task_id, state, timeout
+        ):
+            return None
+        values = []
+        index = state.next_read
+        while index in state.reported:
+            object_id = ObjectID.for_task_return(task_id, index)
+            entry = self.memory_store.get_if_exists(object_id)
+            if entry is None or entry.value is None:
+                break  # not inline: read alone, below, once it comes first
+            values.append(entry.value)
+            self.memory_store.delete(object_id)
+            self._owned.discard(object_id)
+            index += 1
+        if values:
+            state.next_read = index
+        else:
+            # the item at the cursor (reported, so the loop met it) is not
+            # inline
+            if entry is None or not entry.in_plasma:
+                raise ObjectLostError(object_id, "stream item has no value")
+            ref = ObjectRef(object_id, self.address, _register=False)
+            values.append(
+                UnpackedStreamItem(await self._read_plasma(ref, entry.size))
+            )
+            # the cursor moves once the value is in hand: a take cancelled
+            # mid-read leaves the item where the next reader finds it (and
+            # never moves back, whatever read the stream meanwhile)
+            state.next_read = max(state.next_read, index + 1)
+            self._maybe_free(object_id)
+        counts = self.stream_counts
+        counts["values"] += len(values)
+        counts["takes"] += 1
+        counts["max_take"] = max(counts["max_take"], len(values))
+        from ...util import tracing
+
+        # a count at the take, not at the item: what an item's region costs
+        # is PERF.md finding 34.3
+        with tracing.annotate_device_trace(
+            "owner.stream_take", items=len(values)
+        ):
+            pass
+        return values
 
     def drop_stream(self, task_id: TaskID):
         """Consumer abandoned the generator: release owner-side stream

@@ -27,6 +27,7 @@ import random
 import threading
 import time
 import zlib
+from collections import deque
 from typing import Any, Dict, FrozenSet, Optional, Set
 
 from .. import api
@@ -39,6 +40,7 @@ from ..exceptions import (
     RpcError,
     WorkerCrashedError,
 )
+from ..object_ref import unpack_stream_value
 from ..util import events as _events
 from ..util import tracing as _tracing
 from .hash_ring import ReplicaRing
@@ -261,6 +263,18 @@ class DeploymentResponseGenerator:
     generator produces, as soon as it is reported — the first item is
     consumable while the replica is still generating.
 
+    Items are read as values, never as refs: one hop onto the owner's loop
+    (``ObjectRefGenerator.take_values``) brings every item the stream holds
+    when the consumer asks, packed; each is deserialized here, on the
+    consuming thread, as it is handed out. A consumer that keeps up takes
+    one item a hop; one that fell behind catches up in one. An item read
+    this way is gone from its owner and cannot be fetched again by ref;
+    ``_to_object_ref_gen()`` is the ref-making reader of the same stream
+    (the same cursor, so it sees only what was not yet taken).
+
+    ``timeout_s`` (or the request's deadline) bounds the wait for the next
+    item; GetTimeoutError when none arrives within it.
+
     Failover is guarded by consumption: once any item has been delivered
     to the caller, a mid-stream failure surfaces instead of retrying (a
     restarted stream would silently replay or skip output)."""
@@ -271,6 +285,8 @@ class DeploymentResponseGenerator:
         self._timeout_s = timeout_s
         self._ctx = ctx
         self._consumed = 0
+        # taken from the owner, not yet handed out (packed)
+        self._taken: deque = deque()
 
     def replica_id(self) -> Optional[str]:
         """See DeploymentResponse.replica_id."""
@@ -306,8 +322,14 @@ class DeploymentResponseGenerator:
     def __next__(self):
         while True:
             try:
-                ref = next(self._ref_gen)  # StopIteration at end of stream
-                return_value = api.get(ref, timeout=self._item_timeout())
+                if not self._taken:
+                    if self._ref_gen is None:
+                        raise StopIteration
+                    taken = self._ref_gen.take_values(self._item_timeout())
+                    if taken is None:
+                        raise StopIteration
+                    self._taken.extend(taken)
+                value = unpack_stream_value(self._taken.popleft())
             except StopIteration:
                 raise
             except BaseException as exc:  # noqa: BLE001
@@ -322,16 +344,17 @@ class DeploymentResponseGenerator:
                         raise to_raise from exc
                 raise
             self._consumed += 1
-            return return_value
+            return value
 
     def close(self):
-        """Stop consuming; closing the underlying ObjectRefGenerator
-        eagerly releases the owner's stream bookkeeping AND signals the
-        producing replica to stop generating (object_ref.py close())."""
-        close = getattr(self._ref_gen, "close", None)
-        if close is not None:
-            close()
-        self._ref_gen = iter(())
+        """Stop consuming: what was taken and not handed out is dropped, and
+        closing the underlying ObjectRefGenerator eagerly releases the
+        owner's stream bookkeeping AND signals the producing replica to
+        stop generating (object_ref.py close())."""
+        self._taken.clear()
+        if self._ref_gen is not None:
+            self._ref_gen.close()
+        self._ref_gen = None
 
     def _to_object_ref_gen(self):
         return self._ref_gen

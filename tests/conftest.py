@@ -58,6 +58,18 @@ def greedy_reference(cfg, params, prompt, n_new):
     return toks[len(prompt):]
 
 
+def wait_for(predicate, timeout_s=60.0):
+    """Poll until ``predicate()`` holds; fail the test if it never does.
+    For what another process or the owner's loop does in its own time: a
+    test asserts the state it reaches, never how long it took."""
+    import time
+
+    deadline = time.time() + timeout_s
+    while not predicate():
+        assert time.time() < deadline, "condition not reached"
+        time.sleep(0.01)
+
+
 @pytest.fixture(scope="module")
 def http_port():
     """A free port for this module's serve proxy. The driver runs the test

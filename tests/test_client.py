@@ -123,3 +123,70 @@ def test_client_server_survives_client_exit(shutdown_only):
         )
         assert proc.returncode == 0, (proc.stdout, proc.stderr)
         assert "OK" in proc.stdout
+
+
+VALUES_SCRIPT = textwrap.dedent(
+    """
+    import sys
+    sys.path.insert(0, %(repo)r)
+    import numpy as np
+    import ray_tpu
+    from ray_tpu.object_ref import unpack_stream_value
+    from ray_tpu.serve.handle import DeploymentResponseGenerator
+
+    ray_tpu.init(address="ray://127.0.0.1:%(port)d")
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen(n):
+        for i in range(n):
+            yield {"i": i}
+
+    # the handle's reader has one path, whatever kind of worker it runs on
+    assert list(DeploymentResponseGenerator(gen.remote(4))) == [
+        {"i": i} for i in range(4)
+    ]
+
+    @ray_tpu.remote
+    class Gen:
+        def mixed(self):
+            yield "small"
+            yield np.full((300_000,), 7, np.float32)  # through plasma
+            raise RuntimeError("stream broke")
+
+    a = Gen.remote()
+    g = a.mixed.options(num_returns="streaming").remote()
+    got = []
+    try:
+        while True:
+            got.extend(unpack_stream_value(v) for v in g.take_values(60.0))
+    except Exception as e:
+        assert "stream broke" in str(e), e
+    assert got[0] == "small" and got[1].shape == (300_000,) and got[1][0] == 7.0
+    assert g.take_values(60.0) is None
+
+    ray_tpu.shutdown()
+    print("CLIENT_OK")
+    """
+)
+
+
+def test_client_mode_streams_values(shutdown_only):
+    """The proxied worker answers the value-reading operation beside
+    next_stream_item: the items travel packed, the server's worker counts
+    them, and nothing is made a ref or pinned for the session."""
+    node = ray_tpu.init(
+        num_cpus=4, _system_config={"client_server_port": 0}
+    )
+    server = node.client_server
+    port = server.address[1]
+    proc = subprocess.run(
+        [sys.executable, "-c", VALUES_SCRIPT % {"repo": REPO, "port": port}],
+        capture_output=True, text=True, timeout=180,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert proc.returncode == 0, (proc.stdout, proc.stderr)
+    assert "CLIENT_OK" in proc.stdout
+    counts = server.worker.stream_counts
+    assert counts["values"] == 6 and counts["refs"] == 0
+    assert 2 <= counts["takes"] <= 6
+    assert not server.worker._streams

@@ -5,6 +5,7 @@ ReportGeneratorItemReturns, core_worker.proto:507)."""
 import time
 
 import pytest
+from conftest import wait_for
 
 import ray_tpu
 from ray_tpu.object_ref import ObjectRefGenerator
@@ -220,3 +221,283 @@ def test_actor_stream_survives_actor_death(shutdown_only):
     time.sleep(0.5)
     g2 = a.gen.options(num_returns="streaming").remote(4)
     assert [ray_tpu.get(r, timeout=120) for r in g2] == [0, 1, 2, 3]
+
+
+# -- reading a stream as values (ObjectRefGenerator.take_values) -------------
+# Counts, never times: how many hops an item cost its owner's loop and how
+# many refs were registered for it (1 and 0), and what is left behind.
+
+
+def _worker():
+    from ray_tpu import _worker_api
+
+    return _worker_api.get_core_worker()
+
+
+def _produced(g, n):
+    """Block until the owner holds the stream's end and all ``n`` items."""
+    streams = _worker()._streams
+
+    def done():
+        state = streams.get(g._task_id)
+        return state is not None and state.total == n and len(state.reported) == n
+
+    wait_for(done)
+
+
+def _entries_of(g):
+    """What the owner still holds for the stream: store entries, owned ids."""
+    w = _worker()
+    mine = lambda oid: oid.task_id() == g._task_id  # noqa: E731
+    return (
+        [oid for oid in list(w.memory_store._objects) if mine(oid)],
+        [oid for oid in list(w._owned) if mine(oid)],
+    )
+
+
+def _drain_values(g):
+    from ray_tpu.object_ref import unpack_stream_value
+
+    out = []
+    while (taken := g.take_values(60.0)) is not None:
+        assert taken, "a take that returns holds at least one item"
+        out.extend(unpack_stream_value(item) for item in taken)
+    return out
+
+
+def _item(i, big):
+    import numpy as np
+
+    # 300_000 float32 is over max_direct_call_object_size: it goes to plasma
+    return np.full((300_000,), i, np.float32) if big else {"i": i, "sq": i * i}
+
+
+def _same(a, b):
+    import numpy as np
+
+    if isinstance(a, np.ndarray):
+        return isinstance(b, np.ndarray) and a.shape == b.shape and bool((a == b).all())
+    return a == b
+
+
+@pytest.mark.parametrize("big_at", [None, 2], ids=["inline", "one_in_plasma"])
+@pytest.mark.parametrize("kind", ["task", "actor"])
+def test_values_match_refs(ray_start_regular, kind, big_at):
+    """The value path returns what the ref path returns, in the same order,
+    for a task's and an actor's generator, inline and through plasma."""
+
+    def items(n, big_at):
+        for i in range(n):
+            yield _item(i, i == big_at)
+
+    if kind == "task":
+        start = ray_tpu.remote(num_returns="streaming")(items).remote
+    else:
+        @ray_tpu.remote
+        class A:
+            def gen(self, n, big_at):
+                yield from items(n, big_at)
+
+        a = A.remote()  # held: a dropped handle kills its actor
+        start = a.gen.options(num_returns="streaming").remote
+    by_ref = [ray_tpu.get(r, timeout=120) for r in start(5, big_at)]
+    before = dict(_worker().stream_counts)
+    g = start(5, big_at)
+    by_value = _drain_values(g)
+    assert len(by_value) == len(by_ref) == 5
+    assert all(_same(a, b) for a, b in zip(by_value, by_ref))
+    after = _worker().stream_counts
+    assert after["values"] - before["values"] == 5
+    assert after["refs"] == before["refs"]  # not one ObjectRef was made
+    assert 1 <= after["takes"] - before["takes"] <= 5
+    # drained: nothing of the stream is left with its owner
+    assert g._task_id not in _worker()._streams
+    wait_for(lambda: _entries_of(g) == ([], []))
+
+
+def test_refs_and_values_share_the_cursor(ray_start_regular):
+    @ray_tpu.remote(num_returns="streaming")
+    def gen(n):
+        for i in range(n):
+            yield i
+
+    from ray_tpu.object_ref import unpack_stream_value
+
+    g = gen.remote(6)
+    _produced(g, 6)
+    first = next(g)  # index 0, as a ref
+    state = _worker()._streams[g._task_id]
+    assert state.next_read == 1
+    # a take would bring 1..5 at once; stop the stream short to mix readers
+    state.reported.discard(3)
+    assert [unpack_stream_value(v) for v in g.take_values(60.0)] == [1, 2]
+    state.reported.add(3)
+    third = next(g)  # index 3, a ref again
+    assert [unpack_stream_value(v) for v in g.take_values(60.0)] == [4, 5]
+    assert g.take_values(60.0) is None
+    with pytest.raises(StopIteration):
+        next(g)
+    # the refs are refs still: fetchable, more than once
+    assert ray_tpu.get([first, third, first], timeout=60) == [0, 3, 0]
+    # the values are gone from the owner; the refs' entries are not
+    stored, owned = _entries_of(g)
+    assert sorted(stored) == sorted(owned) == sorted([first.id, third.id])
+
+
+@pytest.mark.parametrize("kind", ["task", "actor"])
+def test_values_then_the_tasks_error(ray_start_regular, kind):
+    """A generator that raises after k items delivers k values, then its
+    error; the failed stream leaves nothing behind."""
+
+    def items():
+        yield "a"
+        yield "b"
+        yield "c"
+        raise RuntimeError("stream broke")
+
+    if kind == "task":
+        g = ray_tpu.remote(num_returns="streaming", max_retries=0)(items).remote()
+    else:
+        @ray_tpu.remote
+        class A:
+            def gen(self):
+                yield from items()
+
+        a = A.remote()  # held: a dropped handle kills its actor
+        g = a.gen.options(num_returns="streaming").remote()
+    from ray_tpu.object_ref import unpack_stream_value
+
+    got = []
+    with pytest.raises(Exception, match="stream broke"):
+        while True:
+            got.extend(unpack_stream_value(v) for v in g.take_values(60.0))
+    assert got == ["a", "b", "c"]
+    assert g._task_id not in _worker()._streams
+    assert g.take_values(60.0) is None  # a terminated stream reads as ended
+    wait_for(lambda: _entries_of(g) == ([], []))
+
+
+def test_close_mid_stream_frees_unread_and_tells_the_producer(
+    ray_start_regular, tmp_path
+):
+    closed_marker = tmp_path / "generator_closed"
+
+    @ray_tpu.remote(num_returns="streaming")
+    def endless(marker):
+        try:
+            i = 0
+            while True:
+                yield i
+                i += 1
+                time.sleep(0.02)
+        finally:
+            open(marker, "w").close()
+
+    from ray_tpu.object_ref import unpack_stream_value
+
+    g = endless.remote(str(closed_marker))
+    assert unpack_stream_value(g.take_values(60.0)[0]) == 0
+    # let unread items pile up at the owner, then abandon the stream
+    wait_for(lambda: len(_worker()._streams[g._task_id].reported) >= 4)
+    g.close()
+    # the next report learns nobody listens: the user generator is closed
+    wait_for(closed_marker.exists)
+    assert g._task_id not in _worker()._streams
+    wait_for(lambda: _entries_of(g) == ([], []))
+
+
+def test_rereport_under_the_cursor_is_freed(ray_start_regular):
+    """An actor restarted mid-stream yields again from 0: an index already
+    taken as a value has no reader, so it is not stored again; one read as a
+    ref keeps its value while the ref lives."""
+    from ray_tpu import _worker_api
+    from ray_tpu._internal import serialization
+    from ray_tpu._internal.ids import ObjectID
+    from ray_tpu.object_ref import unpack_stream_value
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen():
+        yield "r"
+        yield "v"
+        yield "rest"
+
+    g = gen.remote()
+    _produced(g, 3)
+    w = _worker()
+    held = next(g)  # index 0 read as a ref
+    w._streams[g._task_id].reported.discard(2)
+    assert [unpack_stream_value(v) for v in g.take_values(60.0)] == ["v"]
+    w._streams[g._task_id].reported.add(2)
+
+    def report(index, value):
+        packed = serialization.pack(value)
+        return _worker_api.run_on_worker_loop(
+            w._handle_report_generator_item(
+                g._task_id, index, packed, len(packed)
+            )
+        )
+
+    taken_id = ObjectID.for_task_return(g._task_id, 1)
+    assert report(1, "v") is True  # the consumer is alive, the item unwanted
+    assert w.memory_store.get_if_exists(taken_id) is None
+    assert taken_id not in w._owned
+    assert report(0, "r") is True
+    assert ray_tpu.get(held, timeout=60) == "r"
+    # neither re-report moved the cursor or re-queued an item
+    assert [unpack_stream_value(v) for v in g.take_values(60.0)] == ["rest"]
+    assert g.take_values(60.0) is None
+    # once the stream is gone, a late report is told so and leaves nothing
+    late_id = ObjectID.for_task_return(g._task_id, 7)
+    assert report(7, "late") is False
+    assert w.memory_store.get_if_exists(late_id) is None
+
+
+def test_consumer_that_fell_behind_catches_up_in_one_hop(ray_start_regular):
+    @ray_tpu.remote(num_returns="streaming")
+    def gen(n):
+        for i in range(n):
+            yield {"token": i}
+
+    n = 40
+    g = gen.remote(n)
+    _produced(g, n)  # the consumer "slept" while all n were yielded
+    before = dict(_worker().stream_counts)
+    from ray_tpu.object_ref import unpack_stream_value
+
+    taken = g.take_values(60.0)
+    assert [unpack_stream_value(v) for v in taken] == [{"token": i} for i in range(n)]
+    assert g.take_values(60.0) is None
+    after = _worker().stream_counts
+    assert after["takes"] - before["takes"] == 1  # the end is not a take
+    assert after["values"] - before["values"] == n
+    assert after["refs"] == before["refs"]
+    assert after["max_take"] >= n
+
+
+def test_take_timeout_bounds_the_wait_for_the_next_item(ray_start_regular):
+    from ray_tpu.exceptions import GetTimeoutError
+    from ray_tpu.object_ref import unpack_stream_value
+
+    @ray_tpu.remote(max_concurrency=2)  # open() runs while gen() waits
+    class Gated:
+        def __init__(self):
+            import asyncio
+
+            self.gate = asyncio.Event()
+
+        async def open(self):
+            self.gate.set()
+
+        async def gen(self):
+            yield "before"
+            await self.gate.wait()
+            yield "after"
+
+    a = Gated.remote()
+    g = a.gen.options(num_returns="streaming").remote()
+    assert unpack_stream_value(g.take_values(60.0)[0]) == "before"
+    with pytest.raises(GetTimeoutError):
+        g.take_values(0.2)
+    # a timed-out wait took nothing and the stream is still there
+    ray_tpu.get(a.open.remote(), timeout=60)
+    assert _drain_values(g) == ["after"]
