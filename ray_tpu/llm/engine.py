@@ -132,6 +132,20 @@ def _merge_last(sampled, fresh, first):
 _span = _tracing.annotate_device_trace
 
 
+def _prefill_path(cached: int, shipped: bool = False,
+                  budgeted: bool = False) -> str:
+    """An admission's ``path`` on its ``engine.prefill`` span, the program
+    the prompt went through: "whole" (no cached prefix: one ``_prefill``,
+    which makes the row's cache and attends to the prompt's own keys),
+    "suffix" (chunks through ``_decode`` against every position of a cache
+    that holds earlier keys: behind a prefix hit, and every chunk of a
+    budgeted prefill, ``_advance_prefills``) or "shipped" (zero-prefill:
+    none)."""
+    if shipped:
+        return "shipped"
+    return "suffix" if cached or budgeted else "whole"
+
+
 def _new_expert_counts(model_config, rows: int = 0) -> Optional[dict]:
     """Zeroed device-side counters for a model with routed experts, None
     for one without: ``steps`` decode steps, ``assignments`` (routed
@@ -1584,6 +1598,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             "engine.prefill", tr,
             attrs=self._prefill_attrs(rid, cached, tier_src),
             computed_tokens=plen - cached, cached_tokens=cached,
+            path=_prefill_path(cached, fast),
         ):
             if fast:
                 # zero-prefill: the payload covers every prompt token and
@@ -1722,6 +1737,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     len(tokens) - (st["pos"] or cached), budget
                 ),
                 cached_tokens=cached,
+                path=_prefill_path(cached, budgeted=True),
             ):
                 if st["row"] is None:
                     if cached:
@@ -1776,6 +1792,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     time.time() - st["pf_wall"], category="engine",
                     cached_tokens=cached,
                     computed_tokens=len(tokens) - cached,
+                    path=_prefill_path(cached, budgeted=True),
                     **self._prefill_attrs(st["rid"], cached, st["tier_src"]),
                 )
             self._finish_admission(

@@ -380,7 +380,7 @@ class _LLMReplica:
     def check_prefill_logits(self, token_ids) -> Dict[str, Any]:
         """Parity self-check on this replica's own weights: the last
         position's logits of ``token_ids`` from the engine's prefill
-        program (einsum attention over the cache) against a plain
+        program (einsum attention over the prompt's keys) against a plain
         full-sequence forward through the family's training-mode model
         (the flash kernel). Returns both argmaxes, the largest absolute logit
         difference, and the reference's margin between its best two

@@ -233,6 +233,9 @@ class Attention(nn.Module):
             # layer in a flax "cache" collection). The cache index is
             # PER-ROW (b,): continuous batching interleaves requests at
             # different positions in one decode batch.
+            # No cache came in: this call makes it, so every position but
+            # the s it writes is zero (a whole-prompt prefill)
+            fresh = not self.has_variable("cache", "cached_key")
             cached_k = self.variable(
                 "cache", "cached_key",
                 jnp.zeros, (b, hk, cfg.max_seq_len, d), cfg.dtype,
@@ -264,9 +267,21 @@ class Attention(nn.Module):
                     jnp.minimum(idx + 1, cfg.max_seq_len), self.mesh,
                 )[:, :, None]
             else:
-                # prefill, chunked prefill, speculative verify
-                k_all = jnp.repeat(cached_k.value, h // hk, axis=1)
-                v_all = jnp.repeat(cached_v.value, h // hk, axis=1)
+                # prefill, chunked prefill, speculative verify. A whole
+                # prompt's keys are the s it just wrote into its own
+                # cache: it scores those and not the zeros behind them. A
+                # suffix behind a prefix hit, a chunk and a verify score
+                # all of a cache that holds earlier keys, each row from
+                # its own offset (what a kernel here would have to take:
+                # ROADMAP S4(b)); the arithmetic is one, so a hit answers
+                # as the miss did
+                n_keys = s if fresh else cfg.max_seq_len
+                k_all = jnp.repeat(
+                    cached_k.value[:, :, :n_keys], h // hk, axis=1
+                )
+                v_all = jnp.repeat(
+                    cached_v.value[:, :, :n_keys], h // hk, axis=1
+                )
                 # row r's query i sits at absolute position idx[r]+i; key
                 # j is visible iff j <= idx[r]+i (and thus has been written)
                 scores = jnp.einsum(
@@ -274,8 +289,8 @@ class Attention(nn.Module):
                     k_all.astype(jnp.float32),
                 ) / math.sqrt(d)
                 q_pos = idx[:, None, None] + jnp.arange(s)[None, :, None]
-                k_pos = jnp.arange(cfg.max_seq_len)[None, None, :]
-                mask = k_pos <= q_pos  # (b, s, max_seq)
+                k_pos = jnp.arange(n_keys)[None, None, :]
+                mask = k_pos <= q_pos  # (b, s, n_keys)
                 scores = jnp.where(mask[:, None], scores, -jnp.inf)
                 probs = jax.nn.softmax(scores, axis=-1)
                 out = jnp.einsum(

@@ -440,3 +440,70 @@ def test_kvtier_summary_rollup():
     peer = out["ttft_ms_by_tier"]["peer"]
     assert peer["count"] == 2.0 and peer["mean_ms"] == 6.0
     assert out["ttft_ms_by_tier"]["miss"]["count"] == 1.0
+
+
+# ------------------------------------- the prefill span names its program
+
+
+@pytest.fixture(scope="module")
+def prefill_spans(tiny):
+    """The ``engine.prefill`` request span of one admission of each kind,
+    by the kind's name."""
+    from ray_tpu.util import tracing
+
+    cfg, params = tiny
+    backend = LocalTierBackend()
+    warm, _ = _engine(cfg, params, backend, "span-warm")
+    cold, _ = _engine(cfg, params, backend, "span-cold")
+    kv = KVCacheManager(num_blocks=16, block_size=BLOCK)
+    chunked = ContinuousBatchingEngine(
+        cfg, params, num_slots=2, kv_cache=kv, prefill_chunk_tokens=BLOCK)
+    prompt = list(range(101, 125))  # three full blocks
+    admissions = {
+        "miss": (warm, prompt),
+        # two of its blocks are in warm's own radix tree by now
+        "hit": (warm, prompt[:16] + [7, 8, 9, 10, 11]),
+        # all of it, and its first token, come from warm through the tier
+        "shipped": (cold, prompt),
+        "budgeted_miss": (chunked, prompt),
+    }
+    found = {}
+    with pytest.MonkeyPatch.context() as patch:
+        # a span pusher an earlier cluster test started would trim the ring
+        patch.setattr(tracing, "flush_spans", lambda: None)
+        was = tracing._enabled
+        tracing.enable_tracing()
+        try:
+            for kind, (eng, tokens) in admissions.items():
+                ctx = tracing.new_trace_context()
+                with tracing.request_span("test.request", ctx):
+                    eng.generate_one(_req(tokens, n=2))
+                (found[kind],) = [
+                    s["args"] for s in tracing.get_spans()
+                    if s["trace_id"] == ctx["trace_id"]
+                    and s["name"] == "engine.prefill"
+                ]
+        finally:
+            tracing._enabled = was
+            tracing.clear_spans()
+            for eng in (warm, cold, chunked):
+                eng.close()
+    return found
+
+
+@pytest.mark.parametrize(
+    "kind,path,computed",
+    [("miss", "whole", 24), ("hit", "suffix", 5), ("shipped", "shipped", 0),
+     ("budgeted_miss", "suffix", 24)],
+)
+def test_prefill_span_names_the_program_the_prompt_took(
+    prefill_spans, kind, path, computed
+):
+    """``path`` on ``engine.prefill``: "whole" for a prompt with no cached
+    prefix (one ``_prefill``, over the prompt's own keys), "suffix" for
+    chunks through ``_decode`` against a cache (behind a hit; every chunk of
+    a budgeted prefill), "shipped" for a zero-prefill admission."""
+    span = prefill_spans[kind]
+    assert span["path"] == path
+    assert span["computed_tokens"] == computed
+    assert span["hit"] == (kind in ("hit", "shipped"))

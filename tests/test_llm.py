@@ -7,6 +7,8 @@ round trip, and the batch-inference stage.
 """
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 from conftest import greedy_reference
 
@@ -547,3 +549,161 @@ def test_llm_deployment_with_replica_autoscaling(ray_start_regular):
         assert out["finished_reason"] in ("length", "eos")
     finally:
         serve.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# a whole-prompt prefill attends to its own keys, not to all of the cache
+# ---------------------------------------------------------------------------
+
+# a softmax over s keys against one over max_seq_len of which all but s are
+# masked sums the same terms in another order: float32's rounding, and in
+# bfloat16 a step of the result's at most (0.0039 at these logits, 0.031
+# at a cache row's largest values)
+WIDTH_TOL = {"f32": 1e-5, "bf16": 2e-2}
+PREFILL_VARIANTS = {
+    "gqa_4_to_1": dict(n_heads=4, n_kv_heads=1),
+    "mha": dict(n_heads=4, n_kv_heads=4),
+    "qk_norm": dict(n_heads=4, n_kv_heads=2, qk_norm=True),
+    "attn_gate_no_rope": dict(
+        n_heads=4, n_kv_heads=1, attn_gate=True, rope=False
+    ),
+}
+
+
+def _decode_model(dtype=jnp.bfloat16, max_seq_len=64, **model_kwargs):
+    """(the engine's two functions for a tiny model, its params)."""
+    from ray_tpu.llm.engine import _DecodeModelBase
+
+    cfg = LlamaConfig.tiny(
+        max_seq_len=max_seq_len, dtype=dtype, **model_kwargs
+    )
+    params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
+    return _DecodeModelBase(cfg, params), params
+
+
+def _tokens(n, seed=0):
+    return jnp.asarray(
+        np.random.RandomState(seed).randint(1, 250, (1, n)), jnp.int32
+    )
+
+
+def _f32(x):
+    return np.asarray(x, np.float32)
+
+
+def _assert_rows_close(got, want, tol):
+    """Two cache leaves within ``tol`` of the leaf's largest value (a rotated
+    key is a difference of products, so a small one carries the rounding of
+    large ones); ``tol`` 0 is bit for bit."""
+    got, want = _f32(got), _f32(want)
+    assert np.abs(want).max() > 0.5
+    assert np.abs(got - want).max() <= tol * np.abs(want).max()
+
+
+def _score_widths(fn, *args, queries):
+    """The last extent of every float32 (.., .., queries, n) in ``fn``'s
+    jaxpr: among them (beside a head's width and its halves) the number of
+    keys the prompt's queries were scored against."""
+    import re
+
+    return {
+        int(keys) for keys in re.findall(
+            rf"f32\[\d+,\d+,{queries},(\d+)\]", str(jax.make_jaxpr(fn)(*args))
+        )
+    }
+
+
+@pytest.mark.parametrize(
+    "length", [128, 137, 1100], ids=["128", "odd_137", "long_1100"]
+)
+@pytest.mark.parametrize("variant", list(PREFILL_VARIANTS))
+def test_whole_prompt_prefill_is_the_full_width_prefill(variant, length):
+    """The fresh-cache branch against the einsum over every position of the
+    cache, which is what the same prompt runs into when a zeroed cache is
+    handed in (the program a whole prefill was): the last position's logits
+    and the second layer's row within the reduction's rounding, the first
+    layer's row (written before any attention) bit for bit, and no score
+    of the prompt against more keys than it has."""
+    max_seq_len = 1152
+    model, params = _decode_model(
+        max_seq_len=max_seq_len, **PREFILL_VARIANTS[variant]
+    )
+    tokens = _tokens(length)
+    logits, cache = jax.jit(model._prefill_impl)(params, tokens)
+    empty = jax.tree.map(jnp.zeros_like, cache)
+    want_logits, want_cache = jax.jit(model._decode_impl)(
+        params, empty, tokens
+    )
+    widths = _score_widths(model._prefill_impl, params, tokens, queries=length)
+    assert length in widths and max_seq_len not in widths
+    assert max_seq_len in _score_widths(
+        model._decode_impl, params, empty, tokens, queries=length
+    )
+    tol = WIDTH_TOL["bf16"]
+    assert np.abs(_f32(want_logits)).max() > 10 * tol
+    assert np.abs(_f32(logits) - _f32(want_logits)).max() < tol
+    assert int(jnp.argmax(logits)) == int(jnp.argmax(want_logits))
+    for layer, row_tol in (("layer_0", 0.0), ("layer_1", tol)):
+        got, want = cache[layer]["attn"], want_cache[layer]["attn"]
+        assert int(got["cache_index"][0]) == length
+        for leaf in ("cached_key", "cached_value"):
+            _assert_rows_close(got[leaf], want[leaf], row_tol)
+            assert not _f32(got[leaf])[:, :, length:].any()
+
+
+@pytest.mark.parametrize(
+    "dtype,tol", [(jnp.float32, 1e-4), (jnp.bfloat16, 2e-2)],
+    ids=["f32", "bf16"],
+)
+def test_prefill_then_decode_is_the_prompt_stepped_a_token_at_a_time(
+    dtype, tol
+):
+    """A whole prefill and decode steps behind it, against the same tokens
+    fed one at a time into an empty row (the decode kernel at every
+    position): each step's logits, and in float32 the greedy tokens."""
+    model, params = _decode_model(dtype, n_heads=4, n_kv_heads=2)
+    prefill, decode = jax.jit(model._prefill_impl), jax.jit(model._decode_impl)
+    prompt, n_new = _tokens(21, seed=3), 6
+
+    logits, cache = prefill(params, prompt)
+    fed, stepped_after = [int(t) for t in prompt[0]], []
+    for _ in range(n_new):
+        stepped_after.append(logits[0])
+        fed.append(int(jnp.argmax(logits[0])))
+        logits, cache = decode(params, cache, jnp.asarray([[fed[-1]]]))
+
+    _, row = prefill(params, prompt[:, :1])
+    row = jax.tree.map(jnp.zeros_like, row)
+    one_at_a_time = []
+    for token in fed[:-1]:
+        logits, row = decode(params, row, jnp.asarray([[token]]))
+        one_at_a_time.append(logits[0])
+    for got, want in zip(stepped_after, one_at_a_time[prompt.shape[1] - 1:]):
+        assert np.abs(_f32(got) - _f32(want)).max() < tol
+        if dtype == jnp.float32:
+            assert int(jnp.argmax(got)) == int(jnp.argmax(want))
+
+
+@pytest.mark.parametrize(
+    "dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"],
+)
+def test_suffix_prefill_scores_the_cache_and_equals_a_whole_one(dtype):
+    """A suffix behind cached keys (a prefix hit, a chunk, a verify) is a
+    cache that came in: it scores every position of it, in the arithmetic
+    of the whole prompt's prefill, and ends where that does: the same
+    greedy token, in bfloat16 too, which is what lets a prefix hit answer
+    as the miss before it did (tests/test_step_spans.py replays one)."""
+    model, params = _decode_model(dtype, n_heads=4, n_kv_heads=1)
+    prefill, decode = jax.jit(model._prefill_impl), jax.jit(model._decode_impl)
+    prompt = _tokens(29, seed=5)
+    whole_logits, whole = prefill(params, prompt)
+    _, row = prefill(params, prompt[:, :16])
+    assert 64 in _score_widths(
+        model._decode_impl, params, row, prompt[:, 16:], queries=13
+    )
+    logits, row = decode(params, row, prompt[:, 16:])
+    tol = WIDTH_TOL["f32" if dtype == jnp.float32 else "bf16"]
+    assert np.abs(_f32(logits) - _f32(whole_logits)).max() < tol
+    assert int(jnp.argmax(logits)) == int(jnp.argmax(whole_logits))
+    for got, want in zip(jax.tree.leaves(row), jax.tree.leaves(whole)):
+        _assert_rows_close(got, want, tol)

@@ -276,6 +276,9 @@ def test_every_span_of_the_table_with_its_counts(recorded):
     prefills = _named(spans, "engine.prefill")
     assert [(s["stats"]["computed_tokens"], s["stats"]["cached_tokens"])
             for s in prefills] == [(20, 0)] * 4 + [(4, BLOCK)]
+    # ... and which program took the prompt: the whole one, or chunks
+    # against the cache behind the hit
+    assert [s["stats"]["path"] for s in prefills] == ["whole"] * 4 + ["suffix"]
     assert len(_named(spans, "kv.assemble")) == 1
     # the first committed prompt block of the repeated prompt is cached
     assert [s["stats"]["blocks"] for s in _named(spans, "kv.commit")
