@@ -48,7 +48,7 @@ class AdapterConfig:
 class LLMConfig:
     model_id: str = "llama-tiny"
     # model construction: either a models.llama config name or kwargs
-    model_family: str = "llama"  # "llama" | "moe" | "deepseek" | "falcon_h1" | "solar_open2" | "motif"
+    model_family: str = "llama"  # "llama" | "moe" | "deepseek" | "falcon_h1" | "solar_open2" | "motif" | "nemotron_h"
     model_kwargs: Dict[str, Any] = field(default_factory=dict)
     max_seq_len: int = 512
     max_batch_size: int = 8
@@ -242,6 +242,8 @@ class LLMConfig:
             from ..models.solar_open2 import SolarOpen2Config as config_type
         elif self.model_family == "motif":
             from ..models.motif import MotifConfig as config_type
+        elif self.model_family == "nemotron_h":
+            from ..models.nemotron_h import NemotronHConfig as config_type
         else:
             raise ValueError(f"unknown model family {self.model_family!r}")
         kwargs = dict(self.model_kwargs)

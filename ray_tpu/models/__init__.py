@@ -9,7 +9,9 @@ three layers in four, a gated NoPE GQA layer the fourth, routed experts of
 which one chip's share may be held; serving only), Motif-shaped
 transformer (grouped differential latent attention on window and full
 layers, a four-stream mHC residual, PolyNorm experts of which a share may be
-held; serving only), ViT (vision encoder).
+held; serving only), Nemotron-H-shaped transformer (layers that are a
+Mamba-2 mixer, an attention or a latent expert layer alone; serving only),
+ViT (vision encoder).
 The reference delegates model execution to torch/vLLM; this framework owns
 it.
 
@@ -108,6 +110,18 @@ and the engine's expert counters count over the experts held. ``motif``
 side in one row), shares ``deepseek``'s latent rows as functions
 (``latent_rows`` / ``latent_cache``), and gave ``MoEConfig`` an
 ``expert_activation`` (``ops/moe_experts.py``'s PolyNorm form).
+``nemotron_h`` (PR 54) is the first stack of *unlike single-mixer layers*:
+a layer is a Mamba-2 mixer (``STATE`` leaves), an attention (``SEQUENCE``
+leaves and an ``INDEX``) or an expert layer (no leaf at all), so a row's
+cache tree has no entry for five layers in eleven and ``routed_layers``
+names layers that are nothing but experts. It brought no module of its
+own but the order: ``falcon_h1.Mixer`` came out from under
+``FalconH1Config`` (``MixerConfig``, which both families build),
+``MoEConfig`` gained ``latent_dim`` (the routed experts work between two
+shared projections, narrower than the model) and the ungated
+``expert_activation="relu2"`` (``ops/moe_experts.py``'s two-matrix form);
+the engine and the cache manager changed nowhere: they already walked the
+cache by leaf kind and counted experts over ``routed_layers``.
 """
 
 from __future__ import annotations
@@ -221,6 +235,26 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "for more than one new position a row (ROADMAP R4)"
         ),
     },
+    "nemotron_h": {
+        "adapters": (
+            "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
+            "wq/wk/wv/wo in every layer and has no placement for a stack "
+            "in which one layer in eleven has them, nor for the mixer's "
+            "projections or for expert weights"
+        ),
+        "draft_model": (
+            "a rejected draft run cannot be undone by moving an index "
+            "back: the mixer layers' state has moved on and no snapshot of "
+            "it is kept to return to, and the expert counters count plain "
+            "decode steps (the model's own MTP head: ROADMAP R3)"
+        ),
+        "mesh": (
+            "parallel/plan.py has no partition rule for a per-row state "
+            "leaf (models.STATE), for the mixer's projections or for the "
+            "(expert, ...) weights; a held share of the experts has no ep "
+            "exchange of latent rows yet (ROADMAP R1)"
+        ),
+    },
 }
 
 
@@ -268,8 +302,12 @@ def refusals(family: str) -> Dict[str, str]:
 
 
 def _family(model_config):
-    from . import deepseek, falcon_h1, llama, moe, motif, solar_open2
+    from . import (
+        deepseek, falcon_h1, llama, moe, motif, nemotron_h, solar_open2,
+    )
 
+    if isinstance(model_config, nemotron_h.NemotronHConfig):
+        return nemotron_h
     if isinstance(model_config, motif.MotifConfig):
         return motif
     if isinstance(model_config, solar_open2.SolarOpen2Config):

@@ -91,6 +91,55 @@ ROW_STATE = True
 
 
 @dataclasses.dataclass(frozen=True)
+class MixerConfig:
+    """What ``Mixer`` reads of its family's config: the Mamba-2 mixer's own
+    sizes, which every family that has one builds from its published keys
+    (``FalconH1Config.mixer_config``, ``NemotronHConfig.mixer_config``). A
+    multiplier of 1 is no multiplication in the program."""
+
+    dim: int
+    n_heads: int
+    d_head: int
+    d_state: int
+    n_groups: int
+    d_conv: int = 4
+    chunk_size: int = 128
+    in_multiplier: float = 1.0
+    out_multiplier: float = 1.0
+    # W_in's column groups, in the order z, x, B, C, dt
+    multipliers: Tuple[float, ...] = (1.0,) * 5
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        object.__setattr__(self, "multipliers", tuple(self.multipliers))
+        if len(self.multipliers) != 5:
+            raise ValueError(
+                "MixerConfig: multipliers has five entries (z, x, B, C, dt)")
+        if self.n_heads % self.n_groups:
+            raise ValueError(
+                f"MixerConfig: {self.n_heads} mixer heads in "
+                f"{self.n_groups} groups"
+            )
+
+    @property
+    def d_ssm(self) -> int:
+        return self.n_heads * self.d_head
+
+    @property
+    def conv_channels(self) -> int:
+        """x, B and C: what the convolution runs over."""
+        return self.d_ssm + 2 * self.n_groups * self.d_state
+
+    @property
+    def in_proj_columns(self) -> Tuple[int, ...]:
+        """``W_in``'s column groups, in the order of ``multipliers``."""
+        gn = self.n_groups * self.d_state
+        return (self.d_ssm, self.d_ssm, gn, gn, self.n_heads)
+
+
+@dataclasses.dataclass(frozen=True)
 class FalconH1Config:
     """Falcon-H1-34B-Instruct's published sizes are the defaults."""
 
@@ -133,31 +182,23 @@ class FalconH1Config:
             self, "ssm_multipliers", tuple(self.ssm_multipliers))
         object.__setattr__(
             self, "mlp_multipliers", tuple(self.mlp_multipliers))
-        if len(self.ssm_multipliers) != 5 or len(self.mlp_multipliers) != 2:
+        if len(self.mlp_multipliers) != 2:
             raise ValueError(
-                "FalconH1Config: ssm_multipliers has five entries (z, x, B,"
-                " C, dt) and mlp_multipliers two"
-            )
-        if self.mamba_n_heads % self.mamba_n_groups:
-            raise ValueError(
-                f"FalconH1Config: {self.mamba_n_heads} mixer heads in "
-                f"{self.mamba_n_groups} groups"
-            )
+                "FalconH1Config: mlp_multipliers has two entries")
+        self.mixer_config()  # refuses what the mixer cannot be built from
 
-    @property
-    def d_ssm(self) -> int:
-        return self.mamba_n_heads * self.mamba_d_head
-
-    @property
-    def conv_channels(self) -> int:
-        """x, B and C: what the convolution runs over."""
-        return self.d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
-
-    @property
-    def in_proj_columns(self) -> Tuple[int, ...]:
-        """``W_in``'s column groups, in the order of ``ssm_multipliers``."""
-        gn = self.mamba_n_groups * self.mamba_d_state
-        return (self.d_ssm, self.d_ssm, gn, gn, self.mamba_n_heads)
+    def mixer_config(self) -> MixerConfig:
+        """The mixer of every block as ``Mixer`` takes it."""
+        return MixerConfig(
+            dim=self.dim, n_heads=self.mamba_n_heads,
+            d_head=self.mamba_d_head, d_state=self.mamba_d_state,
+            n_groups=self.mamba_n_groups, d_conv=self.mamba_d_conv,
+            chunk_size=self.mamba_chunk_size,
+            in_multiplier=self.ssm_in_multiplier,
+            out_multiplier=self.ssm_out_multiplier,
+            multipliers=self.ssm_multipliers, norm_eps=self.norm_eps,
+            dtype=self.dtype, param_dtype=self.param_dtype,
+        )
 
     @staticmethod
     def tiny(**kw) -> "FalconH1Config":
@@ -359,26 +400,28 @@ def _uniform(bound: float):
 
 
 class Mixer(nn.Module):
-    """The Mamba-2 mixer and its per-row state (module docstring)."""
+    """The Mamba-2 mixer and its per-row state (module docstring), at the
+    sizes a ``MixerConfig`` names: this family's, and
+    ``models/nemotron_h.py``'s (every multiplier 1)."""
 
-    config: FalconH1Config
+    config: MixerConfig
 
     @nn.compact
     def __call__(self, hidden):
         cfg = self.config
         b, s, _ = hidden.shape
-        h, p, n, g = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
-                      cfg.mamba_n_groups)
-        taps, channels = cfg.mamba_d_conv, cfg.conv_channels
+        h, p, n, g = cfg.n_heads, cfg.d_head, cfg.d_state, cfg.n_groups
+        taps, channels = cfg.d_conv, cfg.conv_channels
         columns = cfg.in_proj_columns
+        scaled = any(m != 1.0 for m in cfg.multipliers)
         # one multiplier a column of W_in, by the column's group
         column_scale = jnp.concatenate([
             jnp.full((width,), m, F32)
-            for width, m in zip(columns, cfg.ssm_multipliers)
+            for width, m in zip(columns, cfg.multipliers)
         ])
 
         def in_proj_init(key, shape, dtype):
-            kernel = _fan_in(1.0 / cfg.ssm_in_multiplier)(key, shape, F32)
+            kernel = _fan_in(1.0 / cfg.in_multiplier)(key, shape, F32)
             return (kernel / column_scale).astype(dtype)
 
         def vector(name, init, shape, axes=(None,)):
@@ -388,11 +431,14 @@ class Mixer(nn.Module):
             ).astype(F32)
 
         with jax.named_scope("ssm.proj"):
+            if cfg.in_multiplier != 1.0:
+                hidden = hidden * cfg.in_multiplier
             u = _dense(
                 cfg, sum(columns), ("embed", "mlp"), "in_proj",
                 kernel_init=in_proj_init,
-            )(hidden * cfg.ssm_in_multiplier)
-            u = u * column_scale.astype(u.dtype)
+            )(hidden)
+            if scaled:
+                u = u * column_scale.astype(u.dtype)
         z = u[..., :columns[0]]
         xbc = u[..., columns[0]:columns[0] + channels]
         dt = u[..., columns[0] + channels:]
@@ -436,8 +482,7 @@ class Mixer(nn.Module):
                 y = y[:, None]
             else:
                 state, y = ssm_chunked(
-                    state, x, dt, a, b_in, c_in, d_skip,
-                    cfg.mamba_chunk_size)
+                    state, x, dt, a, b_in, c_in, d_skip, cfg.chunk_size)
             ssm.value = state.astype(STATE_DTYPE)
 
         with jax.named_scope("ssm.proj"):
@@ -450,10 +495,11 @@ class Mixer(nn.Module):
             norm_w = vector(
                 "norm", nn.initializers.ones_init(), (cfg.d_ssm,), ("mlp",))
             y = (y.reshape(b, s, cfg.d_ssm) * norm_w).astype(cfg.dtype)
-            return _dense(
+            out = _dense(
                 cfg, cfg.dim, ("mlp", "embed"), "out_proj",
-                1.0 / cfg.ssm_out_multiplier,
-            )(y) * cfg.ssm_out_multiplier
+                1.0 / cfg.out_multiplier,
+            )(y)
+            return out * cfg.out_multiplier if cfg.out_multiplier != 1.0 else out
 
 
 class MLP(nn.Module):
@@ -494,7 +540,7 @@ class Block(nn.Module):
         h = norm(x, "in_norm")
         x = (
             x + Attention(cfg, self.mesh, name="attn")(h, cos, sin)
-            + Mixer(cfg, name="mixer")(h)
+            + Mixer(cfg.mixer_config(), name="mixer")(h)
         )
         return x + MLP(cfg, name="mlp")(norm(x, "ff_norm"))
 

@@ -85,20 +85,30 @@ class MoEConfig:
     # "poly_norm" (``P(gate) * up``, ops/moe_experts.py), an expert's four
     # coefficients in a ``poly`` parameter: three weights, and a bias
     # clipped to +-``polynorm_bias_clamp``, all times ``polynorm_scale``
+    # or Nemotron-H's ungated "relu2" (``down(relu(up x)^2)``: an expert
+    # has two matrices, no ``w_gate``)
     expert_activation: str = "swiglu"
     polynorm_scale: float = 0.5
     polynorm_bias_clamp: float = 0.5
+    # the width the routed experts work in where it is not ``dim``
+    # (Nemotron-H's ``moe_latent_size``): one ``dim -> latent_dim``
+    # projection in front of a layer's experts and one back behind their
+    # weighted sum, shared by them (``w_latent_in`` / ``w_latent_out``);
+    # the router stays on the ``dim``-wide input
+    latent_dim: Optional[int] = None
 
     def __post_init__(self):
-        if self.expert_activation not in ("swiglu", "poly_norm"):
+        if self.expert_activation not in ("swiglu", "poly_norm", "relu2"):
             raise ValueError(
                 f"MoEConfig: unknown expert_activation "
                 f"{self.expert_activation!r}"
             )
-        if self.expert_activation != "swiglu" and not self.dropless:
+        if not self.dropless and (
+                self.expert_activation != "swiglu" or self.latent_dim):
             raise ValueError(
-                "MoEConfig: expert_activation 'poly_norm' needs "
-                "dropless=True: the capacity path's experts are SwiGLU"
+                f"MoEConfig: expert_activation {self.expert_activation!r}"
+                f" with latent_dim {self.latent_dim} needs dropless=True: "
+                "the capacity path's experts are SwiGLU on the model's width"
             )
         if self.experts_held is not None:
             first, stop = self.experts_held
@@ -130,6 +140,11 @@ class MoEConfig:
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def expert_dim(self) -> int:
+        """The width a routed expert reads and writes."""
+        return self.latent_dim or self.dim
 
     @property
     def n_experts_held(self) -> int:
@@ -203,9 +218,20 @@ def poly_norm(z, c, eps: float):
     return c[0] * normed(z2 * z) + c[1] * normed(z2) + c[2] * normed(z) + c[3]
 
 
+def _latent(cfg: MoEConfig, features: int, name: str):
+    """One of a latent expert layer's two shared projections."""
+    return nn.DenseGeneral(
+        features=features, use_bias=False, name=name, dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype,
+        kernel_init=nn.with_logical_partitioning(
+            nn.initializers.lecun_normal(), ("embed", "mlp")),
+    )
+
+
 class MoEFFN(nn.Module):
-    """Top-k routed SwiGLU expert FFN. Router aux loss is emitted through
-    the ``losses`` collection (sown) for the trainer to add."""
+    """Top-k routed expert FFN (SwiGLU experts on the model's width unless
+    the config says otherwise). Router aux loss is emitted through the
+    ``losses`` collection (sown) for the trainer to add."""
 
     config: MoEConfig
 
@@ -260,30 +286,20 @@ class MoEFFN(nn.Module):
         # small and a random model's logits do not depend on its experts
         # (measured on the chip at OLMoE's widths, PERF.md finding 25.5)
         per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param(
-            "w_gate",
-            nn.with_logical_partitioning(
-                per_expert, ("expert", "embed", "mlp")
-            ),
-            (cfg.n_experts_held, cfg.dim, cfg.intermediate),
-            cfg.param_dtype,
-        )
-        w_up = self.param(
-            "w_up",
-            nn.with_logical_partitioning(
-                per_expert, ("expert", "embed", "mlp")
-            ),
-            (cfg.n_experts_held, cfg.dim, cfg.intermediate),
-            cfg.param_dtype,
-        )
-        w_down = self.param(
-            "w_down",
-            nn.with_logical_partitioning(
-                per_expert, ("expert", "mlp", "embed")
-            ),
-            (cfg.n_experts_held, cfg.intermediate, cfg.dim),
-            cfg.param_dtype,
-        )
+        width = cfg.expert_dim
+
+        def expert_matrix(name, shape, axes):
+            return self.param(
+                name, nn.with_logical_partitioning(per_expert, axes),
+                (cfg.n_experts_held,) + shape, cfg.param_dtype)
+
+        # (an ungated expert has no gate matrix)
+        w_gate = None if cfg.expert_activation == "relu2" else expert_matrix(
+            "w_gate", (width, cfg.intermediate), ("expert", "embed", "mlp"))
+        w_up = expert_matrix(
+            "w_up", (width, cfg.intermediate), ("expert", "embed", "mlp"))
+        w_down = expert_matrix(
+            "w_down", (cfg.intermediate, width), ("expert", "mlp", "embed"))
 
         def experts(inp):  # (E, C, d) -> (E, C, d)
             gate = jnp.einsum("ecd,edf->ecf", inp, w_gate.astype(inp.dtype))
@@ -305,16 +321,25 @@ class MoEFFN(nn.Module):
                 poly=poly_coefficients(
                     poly, cfg.polynorm_scale, cfg.polynorm_bias_clamp),
             )
+        elif cfg.expert_activation == "relu2":
+            activation = dict(activation="relu2")
 
+        if cfg.latent_dim:
+            with jax.named_scope("moe.latent"):
+                tokens = _latent(cfg, cfg.latent_dim, "w_latent_in")(tokens)
         with jax.named_scope("moe.experts"):
             if cfg.dropless:
                 out = moe_apply_dropless(
-                    tokens, weights, chosen, w_gate.astype(tokens.dtype),
+                    tokens, weights, chosen,
+                    None if w_gate is None else w_gate.astype(tokens.dtype),
                     w_up.astype(tokens.dtype), w_down.astype(tokens.dtype),
                     held=cfg.experts_held, **activation,
                 )
             else:
                 out = moe_apply_gspmd(tokens, dispatch, combine, experts)
+        if cfg.latent_dim:
+            with jax.named_scope("moe.latent"):
+                out = _latent(cfg, cfg.dim, "w_latent_out")(out)
         return out.reshape(b, s, d)
 
 

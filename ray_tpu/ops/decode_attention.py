@@ -259,9 +259,12 @@ def _kernel(
         def _start_row():
             _init(acc_ref, m_ref, l_ref)
 
-        in_scores, in_values = _live(ci, chunk, lengths_ref[row], _GROUP_ROWS)
+        # (a group's padded rows: _GROUP_ROWS up to 8 query heads a KV head,
+        # two tiles at Nemotron-H's 16)
+        in_scores, in_values = _live(
+            ci, chunk, lengths_ref[row], q_ref.shape[2])
         for j in range(kv_heads):
-            q = q_ref[row, j]  # (_GROUP_ROWS, d)
+            q = q_ref[row, j]  # (rows, d)
             k = k_buf[slot, j]  # (chunk, d)
             # positions past the length hold whatever the cache held before:
             # their probabilities are 0, but 0 * NaN would still poison the

@@ -39,6 +39,13 @@ the up columns against the finished activation and accumulates the down
 product. Each matrix is still read once: the gate's block index stands
 still through the second sweep and the other two's through the first, and
 a block whose index does not change is not fetched again.
+
+``"relu2"`` (Nemotron-H: ``y[r] = down_e(relu(up_e x[r])^2)``, no gate) is
+the SwiGLU kernel with two matrices a visit instead of three: one sweep of
+the f blocks, ``relu(.)^2`` of an up block in VMEM, the matching down rows,
+each matrix read once. Two matrices in flight where SwiGLU has three, so a
+block may be half as large again in the same VMEM (``block_f(matrices=2)``).
+``w_gate`` is None.
 """
 
 from __future__ import annotations
@@ -78,16 +85,18 @@ def tile_rows(rows: int) -> int:
     return min(_TILE_ROWS, -(-rows // _ROW_ALIGN) * _ROW_ALIGN)
 
 
-def block_f(dim: int, inner: int, dtype) -> int:
+def block_f(dim: int, inner: int, dtype, matrices: int = 3) -> int:
     """Columns of the expert's inner width in one block: the largest
     divisor of ``inner`` that is a multiple of 128 and keeps a (dim, block)
-    slab within ``_BLOCK_BYTES``; the whole width where there is none.
+    slab within ``_BLOCK_BYTES`` (with ``matrices`` in flight instead of
+    three, within the same VMEM in all); the whole width where there is
+    none.
     Moonlight's 1408 = 11 x 128 has no such divisor but 128 (0.5 MB slabs,
     11 grid steps a matrix), which measured the same as the whole width in
     one 5.8 MB block: 8.295 against 8.299 ms for a decode step's 144
     assignments over 57.8 touched experts a layer, six layers chained, 88%
     of the HBM's peak either way (PERF.md, PR 30). So the rule stands."""
-    fit = _BLOCK_BYTES // (dim * jnp.dtype(dtype).itemsize)
+    fit = _BLOCK_BYTES * 3 // matrices // (dim * jnp.dtype(dtype).itemsize)
     for block in range(min(fit, inner) // 128 * 128, 0, -128):
         if inner % block == 0:
             return block
@@ -118,10 +127,11 @@ def _store_own_rows(offsets_ref, group_ids_ref, tile_ids_ref, acc_ref,
     o_ref[...] = jnp.where(mine, acc_ref[...], o_ref[...])
 
 
-def _kernel(
-    offsets_ref, group_ids_ref, tile_ids_ref,
-    x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *, tm: int,
-):
+def _sweep(offsets_ref, group_ids_ref, tile_ids_ref, o_ref, acc_ref, tm,
+           down_product):
+    """One step of a visit's single sweep of the f blocks: the accumulator
+    zeroed at the first block, this block's ``down_product()`` added, the
+    visit's own rows stored behind the last."""
     visit = pl.program_id(0)
     fi = pl.program_id(1)
 
@@ -129,14 +139,37 @@ def _kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]  # (tm, d)
-    hidden = jax.nn.silu(_dot(x, wg_ref[...])) * _dot(x, wu_ref[...])
-    acc_ref[...] += _dot(hidden.astype(wd_ref.dtype), wd_ref[...])
+    acc_ref[...] += down_product()
 
     @pl.when(fi == pl.num_programs(1) - 1)
     def _store():
         _store_own_rows(
             offsets_ref, group_ids_ref, tile_ids_ref, acc_ref, o_ref, visit, tm)
+
+
+def _kernel(
+    offsets_ref, group_ids_ref, tile_ids_ref,
+    x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *, tm: int,
+):
+    def down_product():
+        x = x_ref[...]  # (tm, d)
+        hidden = jax.nn.silu(_dot(x, wg_ref[...])) * _dot(x, wu_ref[...])
+        return _dot(hidden.astype(wd_ref.dtype), wd_ref[...])
+
+    _sweep(offsets_ref, group_ids_ref, tile_ids_ref, o_ref, acc_ref, tm,
+           down_product)
+
+
+def _relu2_kernel(
+    offsets_ref, group_ids_ref, tile_ids_ref,
+    x_ref, wu_ref, wd_ref, o_ref, acc_ref, *, tm: int,
+):
+    def down_product():
+        hidden = jnp.square(jax.nn.relu(_dot(x_ref[...], wu_ref[...])))
+        return _dot(hidden.astype(wd_ref.dtype), wd_ref[...])
+
+    _sweep(offsets_ref, group_ids_ref, tile_ids_ref, o_ref, acc_ref, tm,
+           down_product)
 
 
 def _poly_kernel(
@@ -250,15 +283,21 @@ def moe_experts(x, w_gate, w_up, w_down, group_sizes,
     whatever the output buffer held (``moe_apply_dropless`` puts the
     assignments to experts held elsewhere there). Returns ``(m, d)``
     f32. ``activation="poly_norm"`` takes ``poly (E, 4)``, an expert's
-    ``c1 .. c4`` (module docstring), and ``eps``."""
+    ``c1 .. c4`` (module docstring), and ``eps``; ``activation="relu2"``
+    takes no gate (``w_gate`` None)."""
     m, d = x.shape
-    n_experts, _, f = w_gate.shape
+    n_experts, _, f = w_up.shape
     tm = tile_rows(m)
     if m % tm:
         raise ValueError(f"{m} rows are not whole tiles of {tm}")
-    if activation not in ("swiglu", "poly_norm"):
+    if activation not in ("swiglu", "poly_norm", "relu2"):
         raise ValueError(f"moe_experts: unknown activation {activation!r}")
-    tf = block_f(d, f, w_gate.dtype)
+    if (w_gate is None) != (activation == "relu2"):
+        raise ValueError(
+            f"moe_experts: activation {activation!r} "
+            f"{'takes no' if w_gate is not None else 'needs a'} gate matrix")
+    ungated = activation == "relu2"
+    tf = block_f(d, f, w_up.dtype, matrices=2 if ungated else 3)
     (offsets, group_ids, tile_ids), visits = make_group_metadata(
         group_sizes=group_sizes.astype(jnp.int32), m=m, tm=tm,
         start_group=jnp.int32(0), num_nonzero_groups=n_experts,
@@ -279,15 +318,16 @@ def moe_experts(x, w_gate, w_up, w_down, group_sizes,
     def down_rows(v, fi, offsets, group_ids, tile_ids):
         return group_ids[v], fi, 0
 
+    # (the gate's matrix and its block are what "relu2" lacks)
+    weights = (w_up, w_down) if ungated else (w_gate, w_up, w_down)
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm),
+        functools.partial(_relu2_kernel if ungated else _kernel, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(visits, f // tf),
             in_specs=[
                 pl.BlockSpec((tm, d), rows),
-                pl.BlockSpec((None, d, tf), columns),
-                pl.BlockSpec((None, d, tf), columns),
+                *[pl.BlockSpec((None, d, tf), columns)] * (len(weights) - 1),
                 pl.BlockSpec((None, tf, d), down_rows),
             ],
             out_specs=pl.BlockSpec((tm, d), rows),
@@ -300,4 +340,4 @@ def moe_experts(x, w_gate, w_up, w_down, group_sizes,
         ),
         name="moe_experts",  # the op's name in a device trace
         interpret=_use_interpret(),
-    )(offsets, group_ids, tile_ids, x, w_gate, w_up, w_down)
+    )(offsets, group_ids, tile_ids, x, *weights)

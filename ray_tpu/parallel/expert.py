@@ -214,7 +214,7 @@ def moe_apply_dropless(
     x: jax.Array,  # (tokens, dim)
     weights: jax.Array,  # (tokens, k) f32
     experts: jax.Array,  # (tokens, k) int32
-    w_gate: jax.Array,  # (E, dim, f)
+    w_gate: Optional[jax.Array],  # (E, dim, f); None with "relu2"
     w_up: jax.Array,  # (E, dim, f)
     w_down: jax.Array,  # (E, f, dim)
     held: Optional[Tuple[int, int]] = None,
@@ -237,11 +237,12 @@ def moe_apply_dropless(
     group and adds nothing: the work follows the held assignments.
 
     ``activation`` / ``poly`` / ``eps``: the expert's activation as
-    ``ops/moe_experts.moe_experts`` takes it (SwiGLU unless said)."""
+    ``ops/moe_experts.moe_experts`` takes it (SwiGLU unless said; an
+    ungated ``"relu2"`` expert has no ``w_gate``)."""
     from ..ops.moe_experts import moe_experts, tile_rows
 
     tokens, k = experts.shape
-    n_experts = w_gate.shape[0]
+    n_experts = w_up.shape[0]
     n = tokens * k
     tm = tile_rows(n)
     padded = -(-n // tm) * tm

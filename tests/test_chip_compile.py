@@ -615,6 +615,55 @@ def test_solar_open2_decode_step_moves_its_state_once(
     assert prefill.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
+def test_nemotron_h_decode_step_at_a_group_of_sixteen_and_two_matrices(
+    v5e_chip, native_kernels, compiled
+):
+    """`nemotron3s-reasoning-backlog`'s programs at its widths, one layer of
+    each kind (`M*E`), 64 slots x 4096, 128 of 512 experts held: a layer
+    keeps the leaves of its kind and the expert layer none, each aliased to
+    its successor; the decode kernel takes 32 query heads over 2 KV heads
+    (two row tiles a group: the described chip refused the kernel's 8-row
+    mask here before any chip call); the expert kernel is handed two
+    matrices of 128 experts in the 1024-wide latent and 1408 assignments;
+    the state update is one fusion, as Falcon-H1's."""
+    from ray_tpu.models.nemotron_h import NemotronHConfig
+
+    cfg = NemotronHConfig(
+        vocab_size=32768, pattern="M*E", experts_held=(0, 128),
+        param_dtype=jnp.bfloat16, max_seq_len=4096)
+    prefill, decode, params, pool = _serving_programs(compiled, v5e_chip, cfg, 64)
+    assert set(pool) == {"layer_0", "layer_1"}  # the expert layer keeps nothing
+    text = decode.as_text()
+    header = text.split("\n", 1)[0]
+    aliases = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliases) == len(jax.tree.leaves(pool)) == 2 + 3
+    entry = text[text.index("\nENTRY "):]
+    state = r"f32\[64,128,64,128\]"
+    assert not re.search(rf"= {state}\S* copy\(", text)
+    assert len(re.findall(
+        rf"= \(f32\[64,128,64\]\S*, {state}\S*\) fusion\(", text)) == 1
+    assert len(re.findall(r"%decode_attention\S* = bf16\[64,2,16,128\]", entry)) == 1
+    calls = re.findall(
+        r"%moe_experts\S* = f32\[1408,1024\]\S* custom-call\(([^)]*)\)", entry)
+    # the grid's extent, the schedule's three arrays, the rows, two matrices
+    operands = calls[0].split(", ")
+    assert len(calls) == 1 and len(operands) == 1 + 3 + 1 + 2
+    assert [o.split("moe____")[-1][:4] for o in operands[-2:]] == ["w_up", "w_do"]
+    assert "bf16[128,1024,2688]" in entry and "bf16[128,2688,1024]" in entry
+    assert "bf16[512,1024,2688]" not in text
+    _assert_rows_written_by_the_kernel(text, 1, {
+        "k": s for s in jax.tree.leaves(pool) if s.shape == (64, 2, 4096, 128)})
+    mem = decode.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert _size(pool) <= mem.alias_size_in_bytes <= _size(pool) + 512 * len(aliases)
+    # 0.54 GB of embedding and head; a mixer layer 0.22, the attention layer
+    # 0.07, the expert layer 128 x 11.0 MB + 0.11 GB
+    assert 2.3e9 < _size(params) < 2.4e9
+    # 64 rows x (4.26 MB of state and tail + 4.19 of K and V)
+    assert 0.53e9 < _size(pool) < 0.55e9
+    assert prefill.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 # ---------------------------------------------------------------------------
 # 2. one process per chip, no fallback
 # ---------------------------------------------------------------------------

@@ -243,3 +243,93 @@ def test_rope_properties():
     pad = jnp.zeros((1, 2, 32, 64), x.dtype)
     full = apply_rope(jnp.concatenate([pad, x], axis=2), cos, sin)[:, :, 32:]
     np.testing.assert_allclose(np.asarray(shifted), np.asarray(full), atol=1e-5)
+
+
+# -- the grouped expert kernel's ungated form (ops/moe_experts.py) ------------
+
+def _relu2_einsum(x, w_up, w_down, group_sizes):
+    """``down_e(relu(up_e x[r])^2)`` for the expert that owns row ``r``."""
+    owner = jnp.repeat(jnp.arange(len(group_sizes)), group_sizes,
+                       total_repeat_length=x.shape[0])
+    hidden = jnp.square(jax.nn.relu(jnp.einsum("md,mdf->mf", x, w_up[owner])))
+    return jnp.einsum("mf,mfd->md", hidden, w_down[owner])
+
+
+def _relu2_operands(rows, inner, experts=5, d=64):
+    keys = jax.random.split(jax.random.PRNGKey(rows + inner), 4)
+    x = jax.random.normal(keys[0], (rows, d))
+    w_up = jax.random.normal(keys[1], (experts, d, inner)) / 8
+    w_down = jax.random.normal(keys[2], (experts, inner, d)) / 8
+    return x, w_up, w_down, keys[3]
+
+
+@pytest.mark.parametrize("rows,inner", [(16, 32), (48, 256), (256, 384)])
+def test_the_relu2_kernel_is_the_einsum(rows, inner, monkeypatch):
+    """The two-matrix form against the einsum it stands for, with an inner
+    width of one block and of several, tiles that experts share, and an
+    expert nobody chose."""
+    from ray_tpu.ops import moe_experts as kernel
+
+    monkeypatch.setattr(kernel, "_BLOCK_BYTES", 64 * 128 * 4 * 2 // 3 + 1)
+    x, w_up, w_down, key = _relu2_operands(rows, inner)
+    cut = jnp.sort(jax.random.randint(key, (3,), 0, rows + 1))
+    sizes = jnp.diff(jnp.concatenate(
+        [jnp.zeros(1, jnp.int32), cut, jnp.asarray([rows])]))
+    sizes = jnp.concatenate([sizes[:2], jnp.zeros(1, jnp.int32), sizes[2:]])
+    got = kernel.moe_experts(x, None, w_up, w_down, sizes, activation="relu2")
+    want = _relu2_einsum(x, w_up, w_down, sizes)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * float(
+        jnp.max(jnp.abs(want)))
+    # two matrices in flight: a block half as large again as SwiGLU's three
+    assert kernel.block_f(64, 384, jnp.float32, matrices=2) == 128
+    monkeypatch.undo()
+    assert kernel.block_f(1024, 2688, jnp.bfloat16, matrices=2) == 2688
+    assert kernel.block_f(1024, 2688, jnp.bfloat16) == 896
+
+
+def test_the_relu2_kernel_leaves_rows_behind_the_last_group_alone():
+    """Groups that sum to fewer rows than handed (the assignments to
+    experts held elsewhere sort behind the held ones): the rows of the
+    groups are the einsum's, and no tile behind them is visited."""
+    from ray_tpu.ops.moe_experts import moe_experts
+
+    x, w_up, w_down, _ = _relu2_operands(256, 128)
+    sizes = jnp.asarray([40, 0, 30, 7, 3], jnp.int32)  # 80 of 256 rows
+    got = moe_experts(x, None, w_up, w_down, sizes, activation="relu2")
+    want = _relu2_einsum(x[:80], w_up, w_down, sizes)
+    assert float(jnp.max(jnp.abs(got[:80] - want))) < 2e-5 * float(
+        jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("held", [None, (4, 12)], ids=["all", "held"])
+def test_dropless_relu2_is_the_weighted_sum_over_the_experts_held(held):
+    """``moe_apply_dropless`` in the ungated form, with and without a held
+    share: every token's weighted sum over its chosen experts that are
+    held, no assignment dropped."""
+    from ray_tpu.parallel.expert import moe_apply_dropless
+
+    tokens, k, experts, d, inner = 37, 4, 16, 32, 48
+    keys = jax.random.split(jax.random.PRNGKey(7), 5)
+    x = jax.random.normal(keys[0], (tokens, d))
+    w_up = jax.random.normal(keys[1], (experts, d, inner)) / 6
+    w_down = jax.random.normal(keys[2], (experts, inner, d)) / 6
+    weights = jax.random.uniform(keys[3], (tokens, k))
+    chosen = jnp.argsort(
+        jax.random.uniform(keys[4], (tokens, experts)), axis=-1)[:, :k]
+    first, stop = held or (0, experts)
+    got = moe_apply_dropless(
+        x, weights, chosen.astype(jnp.int32), None, w_up[first:stop],
+        w_down[first:stop], held=held, activation="relu2")
+    every = jnp.einsum(
+        "tef,efd->ted",
+        jnp.square(jax.nn.relu(jnp.einsum("td,edf->tef", x, w_up))), w_down)
+    kept = jnp.where((chosen >= first) & (chosen < stop), weights, 0.0)
+    want = jnp.einsum(
+        "tk,tkd->td", kept,
+        jnp.take_along_axis(every, chosen[..., None], axis=1))
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5 * float(
+        jnp.max(jnp.abs(want)))
+    with pytest.raises(ValueError, match="gate"):
+        moe_apply_dropless(
+            x, weights, chosen.astype(jnp.int32), w_up, w_up, w_down,
+            activation="relu2")
