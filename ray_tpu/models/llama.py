@@ -26,7 +26,9 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from flax import traverse_util
 from jax import shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.decode_attention import decode_attention
@@ -35,7 +37,9 @@ from ..ops.kv_row_write import write_rows
 from ..ops.rmsnorm import rmsnorm
 from ..ops.rope import apply_rope, rope_table
 from ..parallel.ring_attention import ring_attention
-from ..parallel.sharding import logical_to_spec
+from ..parallel.sharding import logical_to_spec, matrix_shards
+from ..util import tracing
+from . import remat_plan
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,6 +55,14 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    # Wrap each layer in nn.remat (train path only): the backward pass gets
+    # a layer's input and recomputes what does not fit. What fits is worked
+    # out while the step is traced (``Llama._remat_policy``,
+    # models/remat_plan.py) from the batch's tokens a device, the widths
+    # and depth, the mesh, and the device kind's memory limit less the
+    # parameters, the step's working set and a margin: the same choice on
+    # every run of a job. A device that names no limit (the CPU) keeps
+    # nothing.
     remat: bool = True
     # Stack the layers and run them with nn.scan (train path only). One
     # layer's buffers are live at a time — the python loop form lets XLA's
@@ -224,8 +236,14 @@ class Attention(nn.Module):
             return y.reshape(b, s, n, d).transpose(0, 2, 1, 3)
 
         q = heads(normed(run(proj(h * d, "wq"), "wq"), "q_norm"), h)
-        k = heads(normed(run(proj(hk * d, "wk"), "wk"), "k_norm"), hk)
-        v = heads(run(proj(hk * d, "wv"), "wv"), hk)
+        # k and v are tagged for nn.remat's policy (the identity anywhere
+        # else) here, at the width of the KV heads, before anything repeats
+        # them to the query heads'; q, attention's output and its
+        # log-sum-exp where the kernels' backward rules take them
+        k = heads(normed(checkpoint_name(
+            run(proj(hk * d, "wk"), "wk"), remat_plan.ATTN_K), "k_norm"), hk)
+        v = heads(checkpoint_name(
+            run(proj(hk * d, "wv"), "wv"), remat_plan.ATTN_V), hk)
 
         if self.decode:
             # KV-cache incremental path (serving; reference role: vLLM's
@@ -341,12 +359,12 @@ class MLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        gate = _dense(
+        gate = checkpoint_name(_dense(
             cfg.intermediate, ("embed", "mlp"), "w_gate", cfg.param_dtype, cfg.dtype
-        )(x)
-        up = _dense(
+        )(x), remat_plan.MLP_GATE)
+        up = checkpoint_name(_dense(
             cfg.intermediate, ("embed", "mlp"), "w_up", cfg.param_dtype, cfg.dtype
-        )(x)
+        )(x), remat_plan.MLP_UP)
         fused = nn.silu(gate) * up
         return _dense(
             cfg.dim, ("mlp", "embed"), "w_down", cfg.param_dtype, cfg.dtype
@@ -367,11 +385,14 @@ class Block(nn.Module):
             (cfg.dim,),
             cfg.param_dtype,
         )
-        h = x + Attention(cfg, self.mesh, self.decode, name="attn")(
+        # the residual and not ``wo``'s output: the sum is written anyway,
+        # and a kept value between the matmul and its add splits their
+        # fusion (a step 1.8% longer for keeping it: PERF.md, PR 56)
+        h = checkpoint_name(x + Attention(cfg, self.mesh, self.decode, name="attn")(
             rmsnorm(x, attn_norm_w.astype(x.dtype), cfg.norm_eps, self.mesh),
             cos, sin,
             (adapters or {}).get("attn"), adapter_slots,
-        )
+        ), remat_plan.ATTN_RESID)
         mlp_norm_w = self.param(
             "mlp_norm",
             nn.with_logical_partitioning(nn.initializers.ones_init(), ("embed",)),
@@ -402,6 +423,31 @@ class Llama(nn.Module):
     mesh: Optional[Mesh] = None
     decode: bool = False
 
+    def _remat_policy(self, tokens):
+        """Keep, of what a layer's second forward would recompute, what the
+        device's memory holds beside this job (``remat_plan.for_step``).
+        Decided as the step is traced, from the traced shapes, the mesh and
+        the device kind's limit, and written into the profiler's trace as
+        ``train.remat_plan``. A decode model runs no backward pass and an
+        ``init`` has no parameters to count: both keep nothing."""
+        kept = ()
+        if not self.decode and not self.is_initializing():
+            flat = traverse_util.flatten_dict(
+                nn.meta.unbox(self.variables["params"]))
+            plan = remat_plan.for_step(
+                self.config,
+                dict(self.mesh.shape) if self.mesh is not None else {},
+                matrix_shards(self.mesh),
+                {path: x.size * x.dtype.itemsize for path, x in flat.items()},
+                *tokens.shape, remat_plan.device_bytes_limit())
+            tracing.program_fact(
+                "train.remat_plan", kept="+".join(plan.kept),
+                kept_bytes_per_device=plan.kept_bytes,
+                budget_bytes=plan.budget_bytes,
+                tokens_per_device=plan.tokens_per_device)
+            kept = plan.tags
+        return jax.checkpoint_policies.save_only_these_names(*kept)
+
     @nn.compact
     def __call__(self, tokens, adapters=None, adapter_slots=None):
         # tokens: (batch, seq) int32; adapters: nested AdapterStore bank
@@ -425,7 +471,7 @@ class Llama(nn.Module):
             if cfg.remat:
                 step = nn.remat(
                     BlockStep,
-                    policy=jax.checkpoint_policies.save_only_these_names(),
+                    policy=self._remat_policy(tokens),
                     prevent_cse=False,
                 )
             x, _ = nn.scan(
@@ -441,7 +487,7 @@ class Llama(nn.Module):
             if cfg.remat:
                 block = nn.remat(
                     Block,
-                    policy=jax.checkpoint_policies.save_only_these_names(),
+                    policy=self._remat_policy(tokens),
                     prevent_cse=False,
                 )
             for i in range(cfg.n_layers):

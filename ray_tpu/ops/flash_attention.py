@@ -37,6 +37,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -562,8 +563,24 @@ def _flash_core(q, k, v, sm_scale, causal, block_q, block_k):
     return o, lse
 
 
+# ``checkpoint_name`` tags of what a backward rule keeps of its forward
+# (models/remat_plan.py says which of them a rematerialised layer saves)
+ATTN_Q, ATTN_OUT, ATTN_LSE = "attn_q", "attn_out", "attn_lse"
+
+
+def tag_residuals(q, o, lse):
+    """Tag q, the output and its log-sum-exp where a backward rule's
+    residuals are made (the identity outside ``jax.checkpoint``): a remat
+    policy that keeps ``o`` and ``lse`` runs no forward kernel a second
+    time, where a tag on the caller's output would keep a copy and still
+    run it; one that keeps ``q`` too skips its projection, RoPE and layout."""
+    return (checkpoint_name(q, ATTN_Q), checkpoint_name(o, ATTN_OUT),
+            checkpoint_name(lse, ATTN_LSE))
+
+
 def _flash_core_fwd(q, k, v, sm_scale, causal, block_q, block_k):
     o, lse = _flash_forward(q, k, v, sm_scale, causal, block_q, block_k)
+    q, o, lse = tag_residuals(q, o, lse)
     return (o, lse), (q, k, v, o, lse)
 
 

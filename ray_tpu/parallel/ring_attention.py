@@ -38,6 +38,7 @@ from ..ops.flash_attention import (
     flash_attention_with_lse,
     flash_bwd_dkv,
     flash_bwd_dq,
+    tag_residuals,
 )
 
 
@@ -100,6 +101,7 @@ def _ring_forward(q, k, v, axis_name, sm_scale):
 
 def _ring_fwd(q, k, v, axis_name, sm_scale):
     o, lse = _ring_forward(q, k, v, axis_name, sm_scale)
+    q, o, lse = tag_residuals(q, o, lse)
     return o, (q, k, v, o, lse)
 
 

@@ -8,6 +8,7 @@ the reference's delegation of TP/FSDP to torch/vLLM (SURVEY §2c).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -38,6 +39,19 @@ def logical_to_spec(
 ) -> P:
     table = dict(rules or DEFAULT_RULES)
     return P(*[table.get(name) if name else None for name in logical_axes])
+
+
+def matrix_shards(mesh: Optional[Mesh], rules=None) -> int:
+    """Over how many devices the rules cut a weight matrix ("embed" by "mlp",
+    "heads" or "vocab"): what divides the parameters' bytes a device."""
+    if mesh is None:
+        return 1
+    table = dict(rules or DEFAULT_RULES)
+    axes = set()
+    for name in ("embed", "mlp"):
+        over = table.get(name) or ()
+        axes.update((over,) if isinstance(over, str) else over)
+    return math.prod(mesh.shape.get(a, 1) for a in axes)
 
 
 def tree_shardings(

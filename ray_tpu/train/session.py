@@ -120,6 +120,11 @@ class TrainContext:
             self._step_breakdown = StepBreakdown(role="train")
         self._step_breakdown.mark()
         self._record_step_series()
+        from ..util import tracing as _tracing
+
+        # a profiler session opened since the step program was traced gets
+        # what that trace decided (train.remat_plan)
+        _tracing.replay_program_facts()
         persisted: Optional[Checkpoint] = None
         if checkpoint is not None:
             dest = os.path.join(self.run_dir, f"checkpoint_{index:06d}")

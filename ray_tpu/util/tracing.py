@@ -20,7 +20,7 @@ import threading
 import time
 import uuid
 from contextlib import contextmanager, nullcontext
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 _lock = threading.Lock()
 _spans: List[dict] = []
@@ -479,6 +479,33 @@ def annotate_device_trace(name: str, **counts):
 
         _TraceAnnotation = TraceAnnotation
     return _TraceAnnotation(name, **counts)
+
+
+_program_facts: Dict[Tuple[str, tuple], dict] = {}
+
+
+def program_fact(name: str, **counts) -> None:
+    """What a jitted program fixed while it was traced (``train.remat_plan``:
+    which values its backward pass keeps), as an instant region with
+    ``counts`` as its stats. A profiler session is as a rule opened long
+    after the trace that made the program, so the fact is also kept, once a
+    distinct set of counts (the newest sixteen of a process that keeps
+    tracing new shapes), for ``replay_program_facts``."""
+    _program_facts[name, tuple(sorted(counts.items()))] = counts
+    while len(_program_facts) > 16:
+        del _program_facts[next(iter(_program_facts))]
+    with annotate_device_trace(name, **counts):
+        pass
+
+
+def replay_program_facts() -> None:
+    """Write every kept ``program_fact`` into the profiler's trace again, at
+    a step boundary (``train.report``): a session that opened after the
+    programs were traced then carries what they decided. Microseconds with
+    no session running."""
+    for (name, _), counts in _program_facts.items():
+        with annotate_device_trace(name, **counts):
+            pass
 
 
 class step_span:
