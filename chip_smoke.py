@@ -322,6 +322,8 @@ def _compile_facts(facts: dict) -> dict:
         programs=c["programs"], cache_requests=c["cache_requests"],
         cache_hits=c["cache_hits"],
         compile_cache_dir=facts["compile_cache_dir"],
+        # worker.startup: the chip worker's start by phase (util/tracing.py)
+        startup=facts["startup"],
     )
 
 
@@ -334,6 +336,7 @@ def _train_loop(config: dict):
     from ray_tpu._internal import compile_cache
     from ray_tpu._internal.platform import traced_kernel_modes
     from ray_tpu.train.examples.llama_lora import train_loop_per_worker
+    from ray_tpu.util import tracing
 
     train_loop_per_worker(config)
     devices = jax.local_devices()
@@ -348,6 +351,7 @@ def _train_loop(config: dict):
         ],
         "compile_cache_dir": jax.config.jax_compilation_cache_dir,
         "compile": compile_cache.stats(),
+        "startup": tracing.startup_record(),
         "kernels": traced_kernel_modes(),
     }})
 

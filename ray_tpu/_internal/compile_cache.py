@@ -38,6 +38,7 @@ _stats = {
     "cache_requests": 0,
     "cache_hits": 0,
 }
+_compile_s_by_name: Dict[str, float] = {}  # the event's fun_name, summed
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _TRACE_LOWER = (
@@ -48,11 +49,15 @@ _CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 
-def _on_duration(event: str, duration_secs: float, **_kw) -> None:
+def _on_duration(event: str, duration_secs: float, fun_name: str = "",
+                 **_kw) -> None:
     with _lock:
         if event == _BACKEND_COMPILE:
             _stats["compile_s"] += duration_secs
             _stats["programs"] += 1
+            if fun_name:
+                _compile_s_by_name[fun_name] = (
+                    _compile_s_by_name.get(fun_name, 0.0) + duration_secs)
         elif event in _TRACE_LOWER:
             _stats["trace_lower_s"] += duration_secs
 
@@ -87,3 +92,26 @@ def stats() -> Dict[str, float]:
     """What this process compiled since ``configure()``."""
     with _lock:
         return dict(_stats)
+
+
+def totals_us() -> Dict[str, object]:
+    """``stats()`` as ``worker.startup`` carries it (whole microseconds),
+    with the three names that cost the backend compiler most (``slowest``:
+    ``name:seconds`` joined by ``+``, since a comma ends a stat in the
+    profiler's encoding of a region). This JAX passes the jitted function's
+    name with the event, so a prompt length's prefill programs add up under
+    one. Empty before ``configure()``: nothing was counted."""
+    with _lock:
+        if not _counting:
+            return {}
+        worst = sorted(_compile_s_by_name.items(), key=lambda kv: -kv[1])[:3]
+        totals = {
+            "compile_us": round(_stats["compile_s"] * 1e6),
+            "trace_lower_us": round(_stats["trace_lower_s"] * 1e6),
+            "programs": _stats["programs"],
+            "cache_requests": _stats["cache_requests"],
+            "cache_hits": _stats["cache_hits"],
+        }
+        if worst:
+            totals["slowest"] = "+".join(f"{n}:{s:.1f}" for n, s in worst)
+        return totals

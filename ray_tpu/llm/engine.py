@@ -878,6 +878,11 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         that raises fails every waiting request and the thread goes on."""
         behind = _StepLock(self._lock, behind=True)
         while not st.closed and self._has_work():
+            if st.steps & 31 == 0:
+                # every 32nd turn, with no step open and no lock asked for:
+                # a profiler session opened meanwhile gets worker.startup
+                # and the programs' kept facts
+                _tracing.replay_program_facts()
             try:
                 with behind:
                     if self._has_work():  # a caller's own step() drained it

@@ -37,6 +37,8 @@ import time
 from typing import Any, Dict, Optional
 
 from .. import serve
+from .._internal.platform import backend_initialized
+from ..util import tracing
 from ..util.tracing import annotate_device_trace as _span
 from .config import LLMConfig
 from .engine import ContinuousBatchingEngine, GenerationRequest
@@ -89,6 +91,11 @@ class _LLMReplica:
         from ..parallel.plan import PartitionPlan
         from ..parallel.sharding import unbox_params
 
+        if not backend_initialized():
+            # worker.startup: the chip attach gets a phase of its own, here
+            # where the replica is about to touch its devices anyway
+            with tracing.startup_phase("backend") as phase:
+                phase.count(devices=len(jax.devices()))
         self._config = llm_config
         model_config = llm_config.build_model_config()
         tp, sp = llm_config.effective_parallelism()
@@ -113,108 +120,122 @@ class _LLMReplica:
         self._weights_sharding = (
             plan.param_shardings if plan is not None else None
         )
-        if weights_name is not None:
-            # hot-reloadable weights from the weight plane: the replica
-            # subscribes to the named model and serves its head version;
-            # reload_weights()/reconfigure swap in fresh versions in place.
-            # Resolving here — inside __init__ — is what makes cold
-            # scale-up correct: the serve controller's health probe (and so
-            # the STARTING -> RUNNING transition) queues behind __init__,
-            # so a replica never reports RUNNING with unresolved weights.
-            import time as _time
+        # worker.startup's ``weights`` phase, whichever branch has them;
+        # ``_weights_resolve_s`` is the same stopwatch's reading
+        with tracing.startup_phase("weights") as weights:
+            if weights_name is not None:
+                # hot-reloadable weights from the weight plane: the replica
+                # subscribes to the named model and serves its head version;
+                # reload_weights()/reconfigure swap in fresh versions in
+                # place. Resolving here — inside __init__ — is what makes
+                # cold scale-up correct: the serve controller's health probe
+                # (and so the STARTING -> RUNNING transition) queues behind
+                # __init__, so a replica never reports RUNNING with
+                # unresolved weights.
+                from ..weights import WeightSubscriber
 
-            from ..weights import WeightSubscriber
-
-            t0 = _time.perf_counter()
-            self._weights_sub = WeightSubscriber(weights_name)
-            self._weights_version, params = self._weights_sub.get(
-                timeout=60.0, sharding=self._weights_sharding
-            )
-            self._weights_resolve_s = _time.perf_counter() - t0
-        elif params_blob is not None:
-            from .._internal import serialization
-
-            params = serialization.loads(params_blob)
-        else:
-            from .. import models
-
-            params = unbox_params(
-                models.init_params(
-                    model_config, jax.random.PRNGKey(llm_config.seed or 0)
+                weights.count(weights_source="plane")
+                self._weights_sub = WeightSubscriber(weights_name)
+                self._weights_version, params = self._weights_sub.get(
+                    timeout=60.0, sharding=self._weights_sharding
                 )
-            )
+            elif params_blob is not None:
+                from .._internal import serialization
+
+                weights.count(weights_source="blob")
+                params = serialization.loads(params_blob)
+            else:
+                from .. import models
+
+                weights.count(weights_source="init")
+                params = unbox_params(
+                    models.init_params(
+                        model_config, jax.random.PRNGKey(llm_config.seed or 0)
+                    )
+                )
+            # on the device, not merely dispatched: the time is this phase's
+            params = jax.block_until_ready(params)
+            weights.count(weights_bytes=sum(
+                getattr(x, "nbytes", 0)
+                for x in jax.tree_util.tree_leaves(params)))
+        if weights_name is not None:
+            self._weights_resolve_s = weights.us / 1e6
         if role not in (None, "prefill", "decode"):
             raise ValueError(f"unknown replica role {role!r}")
         self._role = role
         self._kv_tier = None
         self._kv_cache = None
-        if llm_config.kv_cache_blocks:
-            # a shared KV block pool under the engine's slots: admission is
-            # memory-gated and prompts sharing cached prefixes prefill only
-            # the suffix. Without one, a slot is a dense row
-            from ..kvcache import KVCacheManager
+        # worker.startup's ``engine`` phase: the block pool's manager and the
+        # engine with what it is built from (tier, draft, adapter store). The
+        # slot rows and the pool are allocated by the first admission, later
+        with tracing.startup_phase("engine"):
+            if llm_config.kv_cache_blocks:
+                # a shared KV block pool under the engine's slots: admission is
+                # memory-gated and prompts sharing cached prefixes prefill only
+                # the suffix. Without one, a slot is a dense row
+                from ..kvcache import KVCacheManager
 
-            self._kv_cache = KVCacheManager(
-                num_blocks=llm_config.kv_cache_blocks,
-                block_size=llm_config.kv_block_size,
-                plan=plan,
-            )
-            if llm_config.kv_tier or role is not None:
-                # cluster KV prefix tier: role replicas need it for the
-                # prefill->decode handoff; fused replicas opt in to share
-                # warm prefixes across the deployment
-                from ..kvtier import GcsTierBackend, KVTierClient
-
-                self._kv_tier = KVTierClient(
-                    model=llm_config.model_id,
-                    backend=(
-                        tier_backend if tier_backend is not None
-                        else GcsTierBackend()
-                    ),
+                self._kv_cache = KVCacheManager(
+                    num_blocks=llm_config.kv_cache_blocks,
                     block_size=llm_config.kv_block_size,
-                    codec=llm_config.kv_ship_codec,
+                    plan=plan,
                 )
-        draft = None
-        if llm_config.draft_model is not None:
-            # speculative draft: initialized per replica (the draft is
-            # tiny — no weight plane, no sharded publish)
-            from .. import models
+                if llm_config.kv_tier or role is not None:
+                    # cluster KV prefix tier: role replicas need it for the
+                    # prefill->decode handoff; fused replicas opt in to share
+                    # warm prefixes across the deployment
+                    from ..kvtier import GcsTierBackend, KVTierClient
 
-            draft_cfg = llm_config.build_draft_model_config()
-            draft_params = unbox_params(
-                models.init_params(draft_cfg, jax.random.PRNGKey(1))
-            )
-            draft = (draft_cfg, draft_params)
-        self._adapter_store = None
-        if llm_config.adapters is not None:
-            # multi-tenant LoRA plane: one paged AdapterStore per
-            # replica; request threads resolve slot leases before
-            # admission so cold weight-plane pulls never block the
-            # engine loop
-            from ..lora import AdapterStore
+                    self._kv_tier = KVTierClient(
+                        model=llm_config.model_id,
+                        backend=(
+                            tier_backend if tier_backend is not None
+                            else GcsTierBackend()
+                        ),
+                        block_size=llm_config.kv_block_size,
+                        codec=llm_config.kv_ship_codec,
+                    )
+            draft = None
+            if llm_config.draft_model is not None:
+                # speculative draft: initialized per replica (the draft is
+                # tiny — no weight plane, no sharded publish)
+                from .. import models
 
-            ac = llm_config.adapters
-            self._adapter_store = AdapterStore(
-                model_config,
-                max_live=ac.max_live,
-                rank=ac.slot_rank,
-                alpha=ac.alpha,
-                source=ac.source,
+                draft_cfg = llm_config.build_draft_model_config()
+                draft_params = unbox_params(
+                    models.init_params(draft_cfg, jax.random.PRNGKey(1))
+                )
+                draft = (draft_cfg, draft_params)
+            self._adapter_store = None
+            if llm_config.adapters is not None:
+                # multi-tenant LoRA plane: one paged AdapterStore per
+                # replica; request threads resolve slot leases before
+                # admission so cold weight-plane pulls never block the
+                # engine loop
+                from ..lora import AdapterStore
+
+                ac = llm_config.adapters
+                self._adapter_store = AdapterStore(
+                    model_config,
+                    max_live=ac.max_live,
+                    rank=ac.slot_rank,
+                    alpha=ac.alpha,
+                    source=ac.source,
+                    plan=plan,
+                    param_dtype=model_config.param_dtype,
+                )
+            self._engine = ContinuousBatchingEngine(
+                model_config, params, mesh,
+                num_slots=llm_config.max_batch_size,
+                kv_cache=self._kv_cache,
+                seed=llm_config.seed,
                 plan=plan,
-                param_dtype=model_config.param_dtype,
+                kv_tier=self._kv_tier,
+                draft=draft,
+                spec_tokens=llm_config.spec_tokens,
+                prefill_chunk_tokens=llm_config.prefill_chunk_tokens,
+                adapter_store=self._adapter_store,
             )
-        self._engine = ContinuousBatchingEngine(
-            model_config, params, mesh,
-            num_slots=llm_config.max_batch_size,
-            kv_cache=self._kv_cache,
-            seed=llm_config.seed,
-            plan=plan,
-            kv_tier=self._kv_tier,
-            draft=draft,
-            spec_tokens=llm_config.spec_tokens,
-            prefill_chunk_tokens=llm_config.prefill_chunk_tokens,
-            adapter_store=self._adapter_store,
-        )
         self._tokenizer = None
         if tokenizer_name:
             from transformers import AutoTokenizer
@@ -222,6 +243,7 @@ class _LLMReplica:
             self._tokenizer = AutoTokenizer.from_pretrained(tokenizer_name)
         # event loop -> its open streams, while it has any
         self._loop_streams: Dict[Any, _LoopStreams] = {}
+        tracing.startup_ready()  # the process's first replica is built
 
     def shutdown(self) -> None:
         """Replica shutdown hook: stop the engine's stepping thread."""
@@ -345,6 +367,10 @@ class _LLMReplica:
             ],
             "compile_cache_dir": jax.config.jax_compilation_cache_dir,
             "compile": compile_cache.stats(),
+            # worker.startup as a profiler session gets it: where the time
+            # from the process's start to this replica went, by phase, and
+            # the compile totals of this moment (util/tracing.py)
+            "startup": tracing.startup_record(),
             "kernels": traced_kernel_modes(),
             # a routed model's expert counters (engine.expert_stats());
             # None for a dense model

@@ -1834,6 +1834,7 @@ class CoreWorker:
         """Execute a normal task and reply with its returns."""
         from ...util import tracing
 
+        tracing.startup_reached("wait")  # the worker's first task, once
         prev_task = self._current_task_id
         self._current_task_id = spec.task_id
         self.record_task_event(
@@ -2083,6 +2084,10 @@ class CoreWorker:
     # -- actor execution ---------------------------------------------------
 
     async def _handle_create_actor(self, spec: TaskSpec):
+        from ...util import tracing
+
+        # worker.startup: until here the lease and its owner had the time
+        tracing.startup_reached("wait")
         gcs = self.client_pool.get(*self.gcs_address)
         raw = await gcs.call(
             "kv_get", gcs_keys.FUNCTION.key(spec.function.function_hash)

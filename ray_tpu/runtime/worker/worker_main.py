@@ -17,6 +17,10 @@ import sys
 
 
 async def main(args):
+    from ...util import tracing
+
+    # worker.startup: the interpreter and the imports are behind us
+    tracing.startup_reached("main")
     from ..._internal.config import Config
     from ..._internal.rpc import RpcClient
     from .core_worker import CoreWorker, WorkerMode
@@ -72,6 +76,7 @@ async def main(args):
         await materialize(json.loads(runtime_env_json), gcs_client)
 
     await worker.connect_to_raylet()
+    tracing.startup_reached("register")  # leasable from here
 
     # expose this worker for API calls made inside executed tasks
     from ... import _worker_api

@@ -85,6 +85,9 @@ def _jax_worker_setup(
 ):
     # which platform this worker's JAX may initialise was decided by the
     # worker pool before the process started (chip grant, or the CPU)
+    from .. import get_tpu_ids
+    from .._internal.platform import backend_initialized
+
     if coordinator is not None:
         import jax
 
@@ -93,6 +96,18 @@ def _jax_worker_setup(
             num_processes=num_processes,
             process_id=process_id,
         )
+    if get_tpu_ids() and not backend_initialized():
+        # worker.startup: a worker leased chips for this loop attaches them
+        # here, in a phase of its own (else: wherever the loop first touches
+        # a device), and never before the distributed runtime is up. A CPU
+        # worker's loop may never import jax, and is not made to
+        import jax
+
+        from ..util import tracing
+
+        with tracing.startup_phase("backend") as phase:
+            phase.count(devices=len(jax.local_devices()))
+    if coordinator is not None:
         logger.info(
             "jax.distributed up: rank %d/%d coordinator %s devices=%d",
             process_id,

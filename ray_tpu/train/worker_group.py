@@ -109,6 +109,9 @@ class TrainWorker:
             try:
                 import inspect
 
+                from ..util import tracing
+
+                tracing.startup_ready()  # worker.startup: the loop is entered
                 sig = inspect.signature(train_fn)
                 if len(sig.parameters) >= 1:
                     train_fn(config if config is not None else {})

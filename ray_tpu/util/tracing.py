@@ -498,13 +498,115 @@ def program_fact(name: str, **counts) -> None:
         pass
 
 
+# ``worker.startup``: what this process did before its first step. Whole
+# microseconds, each measured once where the work happens: milestones
+# (``startup_reached``) tile the time from the kernel's start of the process,
+# phases (``startup_phase``) are stopwatches after the last milestone, and
+# ``startup_ready`` closes the record with what they leave (``other_us``).
+_startup: Dict[str, Any] = {}
+_startup_clock: Dict[str, int] = {}  # start_ns (perf_counter's), reached_us
+
+
+def _since_process_start_us() -> int:
+    if not _startup_clock:
+        # /proc/self/stat field 22 (ticks since boot) against CLOCK_BOOTTIME:
+        # the stat file's btime has a second's resolution
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age_us = round(1e6 * (time.clock_gettime(time.CLOCK_BOOTTIME)
+                              - ticks / os.sysconf("SC_CLK_TCK")))
+        _startup_clock["start_ns"] = time.perf_counter_ns() - age_us * 1000
+        _startup_clock["reached_us"] = 0
+        _startup["process_start_wall_us"] = time.time_ns() // 1000 - age_us
+    return (time.perf_counter_ns() - _startup_clock["start_ns"]) // 1000
+
+
+def startup_reached(key: str) -> None:
+    """A milestone of this process's start: ``<key>_us`` is the time since
+    the one before it (the first: since the process started). Once a key,
+    and not after ``startup_ready``."""
+    name = key + "_us"
+    if name in _startup or "ready_us" in _startup:
+        return
+    now = _since_process_start_us()
+    _startup[name] = now - _startup_clock["reached_us"]
+    _startup_clock["reached_us"] = now
+
+
+class startup_phase:
+    """A stopwatch around one phase of the start (never nested in another):
+    ``us`` as it closes, and into the record as ``<key>_us`` with what
+    ``count()`` added, unless the process is ready already (a second replica of one process
+    times its own weights and leaves the record alone)."""
+
+    __slots__ = ("_key", "_counts", "_t0", "us")
+
+    def __init__(self, key: str):
+        self._key, self._counts = key, {}
+
+    def count(self, **counts) -> None:
+        self._counts.update(counts)
+
+    def __enter__(self):
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.us = (time.perf_counter_ns() - self._t0) // 1000
+        if exc_type is None and "ready_us" not in _startup:
+            name = self._key + "_us"
+            _startup[name] = _startup.get(name, 0) + self.us
+            _startup.update(self._counts)
+        return False
+
+
+def startup_ready() -> None:
+    """The process can do what it was started for (a replica is built, a
+    training loop is entered): ``ready_us`` since the process started,
+    ``other_us``, what the milestones and phases leave of it, and
+    ``compile_at_ready_us``, what making programs had cost by now (the
+    compile totals a write carries less this came after ``ready_us``)."""
+    if "ready_us" in _startup:
+        return
+    from .._internal import compile_cache
+
+    now = _since_process_start_us()
+    named = sum(v for k, v in _startup.items()
+                if k.endswith("_us") and k != "process_start_wall_us")
+    _startup["other_us"] = now - named
+    _startup["ready_us"] = now
+    totals = compile_cache.totals_us()
+    if totals:
+        _startup["compile_at_ready_us"] = (
+            totals["compile_us"] + totals["trace_lower_us"])
+
+
+def startup_record() -> Optional[Dict[str, Any]]:
+    """``worker.startup`` as it stands: the phases, and the compile totals
+    of this moment (programs compile lazily, so they run on after
+    ``ready_us``; absent where nothing counts them). None before
+    ``startup_ready``."""
+    if "ready_us" not in _startup:
+        return None
+    from .._internal import compile_cache
+
+    return {**_startup, **compile_cache.totals_us()}
+
+
 def replay_program_facts() -> None:
-    """Write every kept ``program_fact`` into the profiler's trace again, at
-    a step boundary (``train.report``): a session that opened after the
-    programs were traced then carries what they decided. Microseconds with
-    no session running."""
-    for (name, _), counts in _program_facts.items():
+    """Write every kept ``program_fact``, and ``worker.startup`` once the
+    process is ready, into the profiler's trace again, at a step boundary
+    (``train.report``, every 32nd turn of the engine's stepping thread): a
+    session that opened after the programs were traced and the process
+    started then carries what they decided and what it cost. Microseconds
+    with no session running."""
+    # a copy: the stepping thread replays while another thread may trace
+    for (name, _), counts in list(_program_facts.items()):
         with annotate_device_trace(name, **counts):
+            pass
+    record = startup_record()
+    if record is not None:
+        with annotate_device_trace("worker.startup", **record):
             pass
 
 
