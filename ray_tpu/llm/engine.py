@@ -123,13 +123,31 @@ def _greedy_sample(logits):
 def _merge_last(sampled, fresh, first):
     """The tokens a pool step is fed, (slots, 1), made where the last
     step's samples already are: a row admitted since takes the token its
-    prefill sampled, every other row what the step before sampled for it.
+    prefill sampled (``first``, pool-shaped and on the device too:
+    ``_put_first``), every other row what the step before sampled for it.
     It runs in every step, fresh rows or none, so a batch of concurrent
     requests reaches no program a lone request did not (jit__merge_last)."""
     return jnp.where(fresh, first, sampled)[:, None]
 
 
+@jax.jit
+def _put_first(firsts, token, si):
+    """``firsts`` (slots,) with row ``si`` set to an admission's first token
+    (``token``: (1,), the sampler's own output, or the host's where it has
+    the token already). One program whatever the slot and however many
+    admissions a step makes: ``si`` is data (jit__put_first)."""
+    return jax.lax.dynamic_update_slice(
+        firsts, token.astype(firsts.dtype), (si,))
+
+
 _span = _tracing.annotate_device_trace
+
+# Admissions dispatched whose first token the host has not read, at most.
+# The parent of this bound held one (it read every token at once); two keep
+# the device's queue full across an admission (while the host waits for
+# admission j, j+1 is queued) and cost one solo row, one prompt's logits
+# and one prefill's scratch of device memory more.
+_UNREAD_ADMISSIONS = 2
 
 
 def _prefill_path(cached: int, shipped: bool = False,
@@ -492,10 +510,6 @@ class _DecodeModelBase:
             return _fused_sample(logits, jnp.asarray(temps), key)
         return _greedy_sample(logits)
 
-    def _sample_tokens(self, logits, temps: np.ndarray, key) -> np.ndarray:
-        """``_sample_on_device`` and the one transfer that reads it."""
-        return host_sync(self._sample_on_device(logits, temps, key))
-
 
 @dataclasses.dataclass
 class _Slot:
@@ -510,6 +524,22 @@ class _Slot:
     # blocks eagerly (accepted runs cross block boundaries mid-flight)
     committed_blocks: int = 0
     last_emit_ts: float = 0.0  # monotonic stamp of the last emitted token
+
+
+@dataclasses.dataclass
+class _Admission:
+    """An admission whose first token the host has not read: the prefill
+    and its sampler are dispatched, ``token`` (1,) is on the device (and,
+    for a row that decodes on, in the pool's ``_firsts`` at ``si``).
+    ``slot`` is the row's ``_Slot``, its ``generated`` still empty, or None
+    for a request that wants one token at most and was never inserted."""
+    si: int
+    rid: int
+    request: GenerationRequest
+    token: Any
+    slot: Optional[_Slot]
+    lease: Any
+    ttft: Optional[tuple]  # (enqueue stamp, cached tokens, tier source)
 
 
 @dataclasses.dataclass
@@ -540,7 +570,12 @@ class ContinuousBatchingEngine(_DecodeModelBase):
     N while step N+1 runs. So a token reaches its caller one host read
     after it was computed, and a row leaves the batch when the host has
     *seen* its last token: it may ride one step more, whose token for it
-    nobody reads.
+    nobody reads. An admission is read the same way: its prefill and the
+    sampler of its first token are dispatched, the token feeds the next
+    step on the device (``_put_first``), and the host reads it behind that
+    step's dispatch (``_read_firsts``); at most ``_UNREAD_ADMISSIONS`` are
+    ever dispatched and unread (``_hold_to_bound``). Only what needs the
+    token on the host at once reads it at once (``_reads_first_at_once``).
 
     One thread steps. ``generate``, ``generate_one``, ``generate_stream``
     and ``stream_to`` enqueue, wake the engine's stepping thread and wait
@@ -592,6 +627,13 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # sampled ids, on the device: what the next step is fed
         self._inflight: Optional[_Step] = None
         self._sampled = jnp.zeros((num_slots,), jnp.int32)
+        # the first token of every row's admission, where ``_merge_last``
+        # reads a fresh row's, and the admissions whose token the host has
+        # not read yet, oldest first (empty between steps)
+        self._firsts = jnp.zeros((num_slots,), jnp.int32)
+        if self._replicated is not None:
+            self._firsts = jax.device_put(self._firsts, self._replicated)
+        self._unread: List[_Admission] = []
         # running expert counts of a routed model (None for a dense one),
         # device-side; expert_stats() reads them
         self._expert_counts = _new_expert_counts(model_config, num_slots)
@@ -908,6 +950,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         """Empty the engine; returns the sinks that were waiting."""
         held = [s.lease for s in self._slots.values()]
         held += [st["lease"] for st in self._prefilling.values()]
+        held += [a.lease for a in self._unread if a.slot is None]
         if self._kv is not None:
             for lease in held:
                 try:
@@ -921,6 +964,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         self._slots.clear()
         self._prefilling.clear()
         self._pending.clear()
+        self._unread.clear()
         self._inflight = None
         self._enqueue_ts.clear()
         self._req_trace.clear()
@@ -954,12 +998,22 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         retirements, the hand-over to the next caller) runs with a step
         queued on the device. With none in flight (the engine was idle) two
         are dispatched and the first is read, so a call still yields a
-        token. Whatever ``_admit`` queued above (prefill, row insert) runs
-        behind the step in flight and before the one dispatched here."""
+        token. Whatever ``_admit`` queued above (prefill, first token, row
+        insert) runs behind the step in flight and before the one
+        dispatched here, and is read here too, behind that dispatch."""
         step = self._inflight or self._dispatch_decode(None)
+        if step is not None:
+            self._inflight = self._dispatch_decode(step)
+        self._read_firsts(finished)
         if step is None:
             return
-        self._inflight = self._dispatch_decode(step)
+        if not self._slots:
+            # an idle engine's admissions all ended with their first token
+            # (eos): nobody reads ``step`` or the one behind it, so neither
+            # happened, and the next step takes the first one's number
+            self._step_count -= 1 + (self._inflight is not None)
+            self._inflight = None
+            return
         with _span("engine.sample_sync"):  # the host waits for the device
             tokens = host_sync(step.tokens)
         with _span("engine.emit"):
@@ -1006,7 +1060,6 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         block at most, which no commit takes."""
         riding = unread.rows if unread is not None else {}
         fresh = np.zeros(self._num_slots, bool)
-        first = np.zeros(self._num_slots, np.int32)
         active = np.zeros(self._num_slots, bool)
         temps = np.zeros(self._num_slots, np.float32)
         # keys a row holds for the step's attention: a free row restarts
@@ -1017,10 +1070,10 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             active[si] = True
             temps[si] = max(slot.request.temperature, 0.0)
             in_flight = riding.get(si) is slot
-            if not in_flight:  # admitted since: the host knows its token
-                fresh[si] = True
-                first[si] = slot.last_token
-            have = len(slot.generated) + in_flight
+            # admitted since: its first token is in ``_firsts``
+            fresh[si] = not in_flight
+            # (an admission the host has not read has made one token)
+            have = max(len(slot.generated), 1) + in_flight
             keys[si] = len(slot.request.token_ids) + have
             if have < slot.request.max_new_tokens:
                 batch += 1
@@ -1039,7 +1092,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             )
             logits, self._cache, *counts = self._decode(
                 self._params, self._cache,
-                _merge_last(self._sampled, fresh, first),
+                _merge_last(self._sampled, fresh, self._firsts),
                 *self._adapter_args(self._row_adapter_slots()),
                 active=active, **counted,
             )
@@ -1599,11 +1652,16 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             plen if fast
             else lease.num_cached_tokens if lease is not None else 0
         )
+        export = not fast and self._exports(req, lease)
+        at_once = fast or self._reads_first_at_once(export)
+        if not fast:
+            self._hold_to_bound(finished)
         with _tracing.step_span(
             "engine.prefill", tr,
             attrs=self._prefill_attrs(rid, cached, tier_src),
             computed_tokens=plen - cached, cached_tokens=cached,
             path=_prefill_path(cached, fast),
+            first="read" if at_once else "deferred",
         ):
             if fast:
                 # zero-prefill: the payload covers every prompt token and
@@ -1615,11 +1673,12 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     req, lease, trace=tr
                 )
                 self.last_step_prefill_tokens += plen - cached
-                # the host waits for the prefill here
                 first = self._sample_first(logits, req, rid)
+                if at_once:  # the host waits for the prefill here
+                    first = self._read_first(first)
         return self._finish_admission(
             si, rid, req, lease, solo_cache, first, fast, tier_src,
-            tr, finished,
+            tr, finished, export,
         )
 
     def _prefill_attrs(self, rid, cached: int, tier_src) -> dict:
@@ -1628,34 +1687,132 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             "tier": tier_src or "local", "mesh": self._mesh_tag,
         }
 
-    def _sample_first(self, logits, req: GenerationRequest, rid: int) -> int:
-        return int(
-            self._sample_tokens(
-                logits,
-                np.array([max(req.temperature, 0.0)], np.float32),
-                jax.random.fold_in(self._rng, rid),
-            )[0]
+    def _sample_first(self, logits, req: GenerationRequest, rid: int):
+        """An admission's first token, (1,), sampled on the device and
+        left there."""
+        return self._sample_on_device(
+            logits,
+            np.array([max(req.temperature, 0.0)], np.float32),
+            jax.random.fold_in(self._rng, rid),
         )
 
+    @staticmethod
+    def _read_first(token) -> int:
+        """The transfer that reads ``_sample_first``: the host waits for
+        the prefill and for whatever was queued in front of it."""
+        return int(host_sync(token)[0])
+
+    def _exports(self, req: GenerationRequest, lease) -> bool:
+        """Whether this admission publishes its prompt's blocks to the
+        tier: the first computation of the prefix here."""
+        return (
+            self._tier is not None
+            and self._kv is not None
+            and lease.cacheable
+            and req.adapter_id is None
+            and self._tier.should_export(
+                req.token_ids, len(req.token_ids) // self._kv.block_size
+            )
+        )
+
+    def _reads_first_at_once(self, export: bool) -> bool:
+        """Who needs an admission's first token on the host before the
+        next step's read: the speculative step, which feeds the draft
+        ``last_token`` from the host, and a tier export, whose shipment
+        carries the token (and whose payload is host arrays: reading those
+        waits for the prefill anyway)."""
+        return export or bool(self._spec_k and self._draft is not None)
+
+    def _hold_to_bound(self, finished: List[tuple]) -> None:
+        """Before another admission's prefill is dispatched: read the
+        oldest unread first token while ``_UNREAD_ADMISSIONS`` are out. A
+        program's outputs and temporaries are allocated when it is
+        dispatched, so every unread admission holds a solo row, its logits
+        and its prefill's scratch; the read returns when that admission
+        has run, with the next one still queued on the device."""
+        while len(self._unread) >= _UNREAD_ADMISSIONS:
+            adm = self._unread.pop(0)
+            with _span("engine.first_sync", rows=1, waited=1):
+                first = self._read_first(adm.token)
+            self._land_first(adm, first, finished)
+
+    def _read_firsts(self, finished: List[tuple]) -> None:
+        """Read every first token still on the device: one transfer of
+        the pool's ``_firsts`` for the rows that decode on (and one of its
+        own for a request that was never inserted). Called behind a decode
+        dispatch, so the device has a step queued while the host waits."""
+        if not self._unread:
+            return
+        unread, self._unread = self._unread, []
+        with _span("engine.first_sync", rows=len(unread), waited=0):
+            pool = (
+                host_sync(self._firsts)
+                if any(a.slot is not None for a in unread) else None
+            )
+            firsts = [
+                int(pool[a.si]) if a.slot is not None
+                else self._read_first(a.token)
+                for a in unread
+            ]
+        for adm, first in zip(unread, firsts):
+            self._land_first(adm, first, finished)
+
+    def _land_first(self, adm: _Admission, first: int,
+                    finished: List[tuple]) -> bool:
+        """The host has an admission's first token: TTFT, then the row's
+        first entry, or the request's end (eos on that token, or one token
+        was all it wanted). False when it ended here."""
+        req, slot = adm.request, adm.slot
+        if adm.ttft is not None:
+            ts, cached, tier_src = adm.ttft
+            _record_ttft(
+                max(time.time() - ts, 0.0), hit=cached > 0,
+                mesh=self._mesh_tag,
+                tier=tier_src or ("local" if cached > 0 else "miss"),
+            )
+        req_eos = req.eos_token_id is not None and first == req.eos_token_id
+        if slot is None:
+            result = GenerationResult(
+                token_ids=[first][: req.max_new_tokens],
+                num_prompt_tokens=len(req.token_ids),
+                finished_reason="eos" if req_eos else "length",
+            )
+            finished.append((adm.rid, result))
+            if self._kv is not None:
+                self._kv.release(adm.lease)
+            return False
+        slot.generated.append(first)
+        slot.last_token = first
+        slot.last_emit_ts = time.monotonic()
+        if req_eos:
+            # found one read late: the row may ride a step already
+            # dispatched, as a row that ended in a step does
+            self._finish_slot(adm.si, slot, "eos", finished)
+            return False
+        return True
+
     def _finish_admission(self, si, rid, req, lease, solo_cache, first,
-                          fast, tier_src, tr, finished) -> bool:
+                          fast, tier_src, tr, finished,
+                          export: bool = False) -> bool:
         """The admission tail every prefill path funnels through (inline,
-        chunked, zero-prefill): TTFT + prefill metrics, prompt-block
-        commit + tier export, pool row insert, slot creation. Returns
-        False when the request finished AT admission (eos on the first
-        token / max_new_tokens <= 1) — the caller returns the slot."""
+        chunked, zero-prefill): prefill metrics, prompt-block commit + tier
+        export, pool row insert, slot creation. ``first`` is the host's int
+        where it has the token (a shipment's; ``_reads_first_at_once``),
+        else the sampler's device scalar: nothing here needs it, so
+        everything is dispatched behind the prefill and the token is landed
+        (``_land_first``: TTFT, the row's first entry, an eos) when
+        ``_read_firsts`` has it. Returns False when the slot is free again:
+        the request wants one token at most and is not inserted, or ended
+        with a token the host already has."""
         plen = len(req.token_ids)
         ts = self._enqueue_ts.pop(rid, None)
+        known = isinstance(first, int)
+        ttft = None
         if self._kv is not None:
             cached = plen if fast else lease.num_cached_tokens
             self._kv.record_prefill(cached, plen - cached)
             if ts is not None:
-                _record_ttft(
-                    max(time.time() - ts, 0.0), hit=cached > 0,
-                    mesh=self._mesh_tag,
-                    tier=tier_src
-                    or ("local" if cached > 0 else "miss"),
-                )
+                ttft = (ts, cached, tier_src)
             if not fast:
                 # commit the prompt's full blocks while the prefilled
                 # row is at hand; reserved blocks are consumed here
@@ -1668,14 +1825,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     self._kv.commit(
                         lease, self._kv_key_tokens(req), solo_cache
                     )
-                if (
-                    self._tier is not None
-                    and lease.cacheable
-                    and req.adapter_id is None
-                    and self._tier.should_export(
-                        req.token_ids, plen // self._kv.block_size
-                    )
-                ):
+                if export:
                     # first computation of this prefix here: publish
                     # it so every other replica (and fresh scale-ups)
                     # can peer-pull instead of recomputing
@@ -1687,38 +1837,40 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                         plen // self._kv.block_size,
                         first_token=first,
                     )
-        with _span("kv.insert_row"):
-            if self._cache is None:
-                self._cache = self._empty_cache(solo_cache)
-            # insert the prefilled K/V row + its write position into slot si
-            self._cache = self._insert_row(
-                self._cache, solo_cache, jnp.asarray(si, jnp.int32)
+        slot = None
+        if req.max_new_tokens > 1 and not (
+            known and first == req.eos_token_id
+        ):
+            with _span("kv.insert_row"):
+                if self._cache is None:
+                    self._cache = self._empty_cache(solo_cache)
+                # insert the prefilled K/V row + its write position into
+                # slot si, and its first token where the next step reads it
+                self._cache = self._insert_row(
+                    self._cache, solo_cache, np.int32(si)
+                )
+                self._firsts = _put_first(
+                    self._firsts,
+                    np.array([first], np.int32) if known else first,
+                    np.int32(si),
+                )
+            if self._draft is not None:
+                self._admit_draft_row(req, si)
+            slot = self._slots[si] = _Slot(
+                request_id=rid, request=req, generated=[], last_token=-1,
+                lease=lease,
+                committed_blocks=(
+                    plen // self._kv.block_size if self._kv is not None else 0
+                ),
+                trace=(
+                    {"ctx": tr["ctx"], "wall": time.time()} if tr else None
+                ),
             )
-        req_eos = req.eos_token_id is not None and first == req.eos_token_id
-        if req_eos or req.max_new_tokens <= 1:
-            result = GenerationResult(
-                token_ids=[first][: req.max_new_tokens],
-                num_prompt_tokens=len(req.token_ids),
-                finished_reason="eos" if req_eos else "length",
-            )
-            finished.append((rid, result))
-            if self._kv is not None:
-                self._kv.release(lease)
-            return False
-        if self._draft is not None:
-            self._admit_draft_row(req, si)
-        self._slots[si] = _Slot(
-            request_id=rid, request=req, generated=[first],
-            last_token=first, lease=lease,
-            committed_blocks=(
-                plen // self._kv.block_size if self._kv is not None else 0
-            ),
-            last_emit_ts=time.monotonic(),
-            trace=(
-                {"ctx": tr["ctx"], "wall": time.time()} if tr else None
-            ),
-        )
-        return True
+        adm = _Admission(si, rid, req, first, slot, lease, ttft)
+        if known:
+            return self._land_first(adm, first, finished)
+        self._unread.append(adm)
+        return slot is not None
 
     def _advance_prefills(self, finished: List[tuple]) -> None:
         """Advance in-progress chunked prefills, spending at most
@@ -1736,14 +1888,16 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             req, lease, tr = st["req"], st["lease"], st["tr"]
             tokens = req.token_ids
             cached = lease.num_cached_tokens if lease is not None else 0
-            with _span(
+            self._hold_to_bound(finished)
+            region = _span(
                 "engine.prefill",
                 computed_tokens=min(
                     len(tokens) - (st["pos"] or cached), budget
                 ),
                 cached_tokens=cached,
                 path=_prefill_path(cached, budgeted=True),
-            ):
+            )
+            with region:
                 if st["row"] is None:
                     if cached:
                         with _tracing.step_span(
@@ -1788,7 +1942,12 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                         st["committed"] = pos // bs
                     continue
                 del self._prefilling[si]
+                export = self._exports(req, lease)
+                at_once = self._reads_first_at_once(export)
+                region.set_metadata(first="read" if at_once else "deferred")
                 first = self._sample_first(st["logits"], req, st["rid"])
+                if at_once:
+                    first = self._read_first(first)
             if tr:
                 # the request's prefill span runs from its parking to here,
                 # across steps: no one block brackets it
@@ -1802,7 +1961,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 )
             self._finish_admission(
                 si, st["rid"], req, lease, st["row"], first, False,
-                st["tier_src"], tr, finished,
+                st["tier_src"], tr, finished, export,
             )
 
     def _empty_row(self):
@@ -2016,7 +2175,9 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 self._next_id += 1
             try:
                 logits, solo_cache = self._prefill_leased(request, lease)
-                first = self._sample_first(logits, request, rid)
+                # shipped with the payload: read at once
+                first = self._read_first(
+                    self._sample_first(logits, request, rid))
                 cached = lease.num_cached_tokens
                 self._kv.record_prefill(cached, plen - cached)
                 self._kv.commit(lease, request.token_ids, solo_cache)

@@ -15,6 +15,7 @@ a recorder notes where each request was admitted and the number of every
 pool step; the reference is given those, nothing else of the engine's.
 """
 
+import itertools
 import os
 import subprocess
 import sys
@@ -328,7 +329,7 @@ def test_a_second_decode_is_dispatched_before_the_first_is_read(tiny, plain, mon
     step's ids, and from then on dispatch and read alternate."""
     eng, _ = _engine(tiny, paged=False, chunk=0)
     warm = GenerationRequest(token_ids=_prompt(6, 7), max_new_tokens=3)
-    eng.generate([warm])  # the only sync left inside a step is the step's
+    eng.generate([warm])
     order = []
     sync, decode = engine_module.host_sync, eng._decode
 
@@ -354,8 +355,8 @@ def test_a_second_decode_is_dispatched_before_the_first_is_read(tiny, plain, mon
     r = GenerationRequest(token_ids=_prompt(6, 7), max_new_tokens=6)
     rid = eng.add_request(r)
     assert eng.step() == [] and len(eng._slots[0].generated) == 2
-    # admission's own sync (the first token), then two steps, then a read
-    assert order == ["sync", "decode", "decode", "sync"]
+    # two steps, then the admission's first token, then the first step
+    assert order == ["decode", "decode", "sync", "sync"]
     assert [c["ahead"] for c in spans] == [0, 1]
     assert eng._inflight is not None and eng._step_count - 2 == 2
     order.clear()
@@ -396,8 +397,10 @@ def test_a_concurrent_batch_reaches_no_program_a_lone_request_did_not():
     """The benchmark's ``correct``: after a warm-up of single requests sent
     one at a time (``check_and_warm``), nothing may compile, nor be read
     from the compile cache. So every program of a step runs in every step:
-    with or without fresh rows, riding rows, a step in flight. Counted by
-    ``compile_cache.stats()`` in a process of its own, as a replica does."""
+    with or without fresh rows, riding rows, a step in flight, and a
+    step's admissions, one or six, go through programs whose shapes know
+    nothing of their number. Counted by ``compile_cache.stats()`` in a
+    process of its own, as a replica does."""
     script = r"""
 import threading
 import jax
@@ -414,7 +417,7 @@ BS = 8
 cfg = LlamaConfig.tiny(max_seq_len=128)
 params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
 eng = ContinuousBatchingEngine(
-    cfg, params, num_slots=4, seed=0,
+    cfg, params, num_slots=6, seed=0,
     kv_cache=KVCacheManager(num_blocks=64, block_size=BS))
 
 def request(seed, n, new):
@@ -440,8 +443,12 @@ for t in threads:
     t.start()
 for t in threads:
     t.join()
+# six admissions in one step: the bound's early reads, the pool's read of two
 rids = [eng.add_request(request(30 + i, lengths[i % 3], 9 + i)) for i in range(6)]
 assert len(eng.run_until_complete()) == 6
+# and one token a request, which is never inserted
+rids = [eng.add_request(request(40 + i, lengths[i % 3], 1)) for i in range(5)]
+assert len(eng.run_until_complete()) == 5
 after = compile_cache.stats()
 same = {k: (before[k], after[k]) for k in ("programs", "cache_requests")}
 assert all(a == b for a, b in same.values()), same
@@ -456,3 +463,317 @@ print("steps", eng._step_count, same)
                              capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
     assert out.stdout.startswith("steps ")
+
+
+# -- an admission's first token stays on the device (ROADMAP S14) ------------
+#
+# ``_admit_one`` dispatches the prefill and its sampler and goes on; the host
+# reads the token behind the next step's dispatch (``_read_firsts``), and at
+# most two admissions are ever dispatched and unread (``_hold_to_bound``).
+# The reference is the same engine made to read every first token at once,
+# which is what the engine did before: ``_reads_first_at_once`` -> True.
+
+WIDE = 8  # slots of the engines below: five admissions fit beside a live row
+_prompt_seeds = itertools.count(10_000)
+
+
+def _new_prompt(n):
+    """A prompt no case before it used: no prefix of it is in any pool."""
+    return _prompt(next(_prompt_seeds), n)
+
+
+class Watch:
+    """What an engine did, in order: "prefill" (a whole-prompt prefill
+    dispatched, with the admissions unread at that moment), "decode" (a pool
+    step dispatched), "sync" (a read by the host), and its
+    ``engine.first_sync`` / ``engine.prefill`` spans' counts."""
+
+    def __init__(self, eng, monkeypatch):
+        self.order, self.unread, self.first_syncs, self.prefills = [], [], [], []
+        prefill, decode = eng._prefill, eng._decode
+        sync, span = engine_module.host_sync, engine_module._span
+        step_span = engine_module._tracing.step_span
+
+        def spy_prefill(*args, **kwargs):
+            self.order.append("prefill")
+            self.unread.append(len(eng._unread))
+            return prefill(*args, **kwargs)
+
+        def spy_decode(*args, **kwargs):
+            if "active" in kwargs:
+                self.order.append("decode")
+            return decode(*args, **kwargs)
+
+        def spy_sync(x):
+            self.order.append("sync")
+            return sync(x)
+
+        def spy_span(name, **counts):
+            if name == "engine.first_sync":
+                self.first_syncs.append(counts)
+            return span(name, **counts)
+
+        def spy_step_span(name, *args, **kwargs):
+            if name == "engine.prefill":
+                self.prefills.append(kwargs["first"])
+            return step_span(name, *args, **kwargs)
+
+        eng._prefill, eng._decode = spy_prefill, spy_decode
+        monkeypatch.setattr(engine_module, "host_sync", spy_sync)
+        monkeypatch.setattr(engine_module, "_span", spy_span)
+        monkeypatch.setattr(engine_module._tracing, "step_span", spy_step_span)
+        self._eng, self._own = eng, (prefill, decode)
+
+    def close(self):
+        self._eng._prefill, self._eng._decode = self._own
+
+
+def _wide(tiny, at_once):
+    cfg, params = tiny
+    eng = ContinuousBatchingEngine(
+        cfg, params, num_slots=WIDE, seed=SEED,
+        kv_cache=KVCacheManager(num_blocks=96, block_size=BS))
+    if at_once:
+        eng._reads_first_at_once = lambda export: True
+    return eng
+
+
+@pytest.fixture(scope="module")
+def builds(tiny):
+    """(the engine, the same engine reading every first token at once): a
+    case drives both through the same requests, so their request ids, slots
+    and step numbers stay side by side from case to case."""
+    pair = _wide(tiny, False), _wide(tiny, True)
+
+    def get():
+        now, then = pair
+        assert (now._next_id, now._step_count) == (then._next_id, then._step_count)
+        return pair
+
+    return get
+
+
+def _idle(eng):
+    return (not eng._slots and eng._inflight is None and not eng._unread
+            and not eng._pending and not eng._has_work())
+
+
+def _drive(eng, reqs, busy=None):
+    """``reqs`` admitted in one step, beside a row that has been decoding
+    ``busy`` (a prompt) for three steps, if given; returns every request's
+    (tokens, reason), the live row's first."""
+    rids, done = [], {}
+    if busy:
+        bg = GenerationRequest(token_ids=busy, max_new_tokens=14,
+                               temperature=reqs[0].temperature)
+        rids.append(eng.add_request(bg))
+        for _ in range(3):
+            done.update(eng.step())
+    rids += [eng.add_request(r) for r in reqs]
+    free = WIDE - len(eng._slots)
+    done.update(eng.step())
+    assert free >= len(reqs) and not eng._pending  # one step took them all
+    done.update(eng.run_until_complete())
+    assert sorted(done) == sorted(rids) and _idle(eng)
+    return [(done[r].token_ids, done[r].finished_reason) for r in rids]
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.8], ids=["greedy", "temp0.8"])
+@pytest.mark.parametrize("busy", [False, True], ids=["idle", "beside_a_live_row"])
+@pytest.mark.parametrize("n", [1, 2, 3, 5])
+def test_deferred_first_tokens_are_those_read_at_once(
+        builds, plain, monkeypatch, n, busy, temp):
+    """``n`` admissions in one step: every stream is bit for bit what the
+    build that reads each first token at once returns; the host reads
+    nothing before the step's dispatch but what the bound makes it read,
+    and never has more than two admissions dispatched and unread."""
+    now, then = builds()
+    busy = _new_prompt(10) if busy else None
+    reqs = [
+        GenerationRequest(token_ids=_new_prompt(9 + 3 * i),
+                          max_new_tokens=5 + 2 * i, temperature=temp)
+        for i in range(n)
+    ]
+    want = _drive(then, reqs, busy)
+    watch = Watch(now, monkeypatch)
+    try:
+        got = _drive(now, reqs, busy)
+    finally:
+        watch.close()
+    assert got == want
+    assert [len(t) for t, _ in got[bool(busy):]] == [r.max_new_tokens for r in reqs]
+    if not temp:
+        assert [t for t, _ in got[bool(busy):]] == [plain(r)[0] for r in reqs]
+    # the admitting step: prefills, early reads only where two are out,
+    # then the step's dispatch, then the read of what is left
+    assert watch.prefills[-n:] == ["deferred"] * n
+    assert max(watch.unread) <= 1 and watch.unread[-n:] == [0, 1, 1, 1, 1][:n]
+    at = len(watch.order) - 1 - watch.order[::-1].index("prefill")  # last prefill
+    first_decode = watch.order.index("decode", at)
+    head = watch.order[watch.order.index("prefill", 3 * bool(busy)):first_decode]
+    assert head.count("prefill") == n and head.count("sync") == max(n - 2, 0)
+    assert head[:2] == ["prefill", "prefill"][:n]
+    waited = [c for c in watch.first_syncs if c["waited"]]
+    behind = [c for c in watch.first_syncs if not c["waited"]]
+    assert waited == [{"rows": 1, "waited": 1}] * max(n - 2, 0)
+    assert behind[bool(busy):] == [{"rows": min(n, 2), "waited": 0}]
+
+
+def test_the_build_that_reads_at_once_reads_before_it_goes_on(builds, monkeypatch):
+    """The reference of the cases above is the behaviour it stands for:
+    one read an admission, before the next prefill and before any step."""
+    now, then = builds()
+    reqs = [GenerationRequest(token_ids=_new_prompt(9 + i), max_new_tokens=4)
+            for i in range(3)]
+    got = _drive(now, reqs)
+    watch = Watch(then, monkeypatch)
+    try:
+        assert _drive(then, reqs) == got
+    finally:
+        watch.close()
+    assert watch.order[:7] == ["prefill", "sync"] * 3 + ["decode"]
+    assert watch.prefills == ["read"] * 3 and not watch.first_syncs
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.8], ids=["greedy", "temp0.8"])
+@pytest.mark.parametrize("busy", [False, True], ids=["idle", "beside_a_live_row"])
+@pytest.mark.parametrize("new", [1, 2])
+def test_a_request_of_one_or_two_tokens(builds, plain, busy, new, temp):
+    """``max_new_tokens`` 1: known without the token, so the request is
+    never inserted, its token is read with the others and its slot is free
+    at once (the next request of the same step takes it). 2: one step."""
+    now, then = builds()
+    busy = _new_prompt(10) if busy else None
+    reqs = [
+        GenerationRequest(token_ids=_new_prompt(12), max_new_tokens=new,
+                          temperature=temp),
+        GenerationRequest(token_ids=_new_prompt(10), max_new_tokens=6,
+                          temperature=temp),
+    ]
+    inserted = []
+    insert = now._insert_row
+    now._insert_row = lambda *a: inserted.append(int(a[2])) or insert(*a)
+    try:
+        got = _drive(now, reqs, busy)
+    finally:
+        now._insert_row = insert
+    assert got == _drive(then, reqs, busy)
+    short = got[bool(busy)]
+    assert len(short[0]) == new and short[1] == "length"
+    if not temp:
+        assert short[0] == plain(reqs[0])[0]
+    # beside a live row in slot 0 the two take slot 1: the first left it
+    first_free = int(bool(busy))
+    assert inserted[-2 + (new == 1):] == (
+        [first_free] if new == 1 else [first_free, first_free + 1])
+
+
+@pytest.mark.parametrize("busy", [False, True], ids=["idle", "beside_a_live_row"])
+def test_eos_as_the_first_token_is_found_one_read_late(builds, plain, busy):
+    """The request ends with ``generated == [first]``; the steps an idle
+    engine dispatched for it alone never happened, so a request sampled at
+    a temperature afterwards draws the keys it draws in the build that
+    finds the eos at admission."""
+    now, then = builds()
+    busy = _new_prompt(10) if busy else None
+    a = GenerationRequest(token_ids=_new_prompt(11), max_new_tokens=9)
+    a.eos_token_id = plain(a)[0][0]
+    after = GenerationRequest(token_ids=_new_prompt(13), max_new_tokens=7,
+                              temperature=0.8)
+    got, want = [], []
+    for eng, out in ((now, got), (then, want)):
+        steps = eng._step_count
+        out += _drive(eng, [a], busy)
+        out.append(eng._step_count - steps)
+        out += _drive(eng, [after])
+    assert got == want
+    assert got[bool(busy)] == ([a.eos_token_id], "eos")
+    assert got[-2] == 0 if not busy else got[-2] > 0  # steps that happened
+    assert len(got[-1][0]) == 7
+
+
+def test_a_request_cancelled_between_its_prefill_and_its_read(builds, plain):
+    """``drop_sink`` after the prefill was dispatched and before the host
+    read its token: nothing is delivered for it, its row runs to its end
+    and leaves, and the stream beside it is untouched."""
+    now, then = builds()
+    kept = GenerationRequest(token_ids=_new_prompt(12), max_new_tokens=6)
+    gone = GenerationRequest(token_ids=_new_prompt(9), max_new_tokens=5)
+    posted = []
+    rid_kept = now.stream_to(kept, posted.extend)
+    rid_gone = now.stream_to(gone, posted.extend)
+    dispatch = now._dispatch_decode
+
+    def cancel_then_dispatch(unread):
+        if any(a.rid == rid_gone for a in now._unread):
+            now.drop_sink(rid_gone)
+        return dispatch(unread)
+
+    now._dispatch_decode = cancel_then_dispatch
+    try:
+        deadline = 600
+        while not any(end is not None for rid, _, end in posted if rid == rid_kept):
+            deadline -= 1
+            assert deadline, posted
+            threading.Event().wait(0.05)
+        while now._has_work():
+            threading.Event().wait(0.05)
+    finally:
+        now._dispatch_decode = dispatch
+    assert {rid for rid, _, _ in posted} == {rid_kept}
+    tokens = [t for _, new, _ in posted for t in new]
+    assert tokens == plain(kept)[0]
+    with now._lock:
+        assert _idle(now)
+    then.generate([kept, gone])  # the pair stays side by side
+
+
+def test_the_speculative_engine_and_prefill_only_read_at_once(tiny, monkeypatch):
+    """Told apart by what the engine is, not by a switch: the speculative
+    step feeds the draft ``last_token`` from the host, and a disaggregated
+    prefill ships the token (as does the first export of a prefix to the
+    tier). Each reads behind its own prefill; nothing is ever unread."""
+    from ray_tpu.kvtier import KVTierClient, LocalTierBackend
+
+    cfg, params = tiny
+    dcfg = LlamaConfig.tiny(max_seq_len=64, n_layers=1)
+    dparams = unbox_params(init_params(dcfg, jax.random.PRNGKey(1)))
+    spec = ContinuousBatchingEngine(
+        cfg, params, num_slots=4, seed=SEED, draft=(dcfg, dparams), spec_tokens=3,
+        kv_cache=KVCacheManager(num_blocks=48, block_size=BS))
+    watch = Watch(spec, monkeypatch)
+    reqs = [GenerationRequest(token_ids=_new_prompt(9 + i), max_new_tokens=6)
+            for i in range(3)]
+    got = spec.generate(reqs)
+    watch.close()
+    assert [len(r.token_ids) for r in got] == [6, 6, 6]
+    assert watch.prefills == ["read"] * 3 and not watch.first_syncs
+    assert watch.order[:6] == ["prefill", "sync"] * 3 and watch.unread == [0, 0, 0]
+    assert _idle(spec)
+    spec.close()
+
+    tier = KVTierClient(model="LlamaConfig", backend=LocalTierBackend(),
+                        block_size=BS, codec="raw", holder_id="prefill")
+    pre = ContinuousBatchingEngine(
+        cfg, params, num_slots=4, seed=SEED, kv_tier=tier,
+        kv_cache=KVCacheManager(num_blocks=48, block_size=BS))
+    watch = Watch(pre, monkeypatch)
+    prompt = _new_prompt(2 * BS + 3)
+    shipment = pre.prefill_only(
+        GenerationRequest(token_ids=prompt, max_new_tokens=4))
+    assert watch.order[:2] == ["prefill", "sync"] and not pre._unread
+    # the first computation of another prefix here is exported with its token
+    other = GenerationRequest(token_ids=_new_prompt(2 * BS + 1), max_new_tokens=4)
+    (res,) = pre.generate([other])
+    watch.close()
+    assert watch.prefills == ["read"] and not watch.first_syncs
+    assert shipment.first_token is not None and len(res.token_ids) == 4
+    # the same prompt again: nothing to export, so nothing to read at once
+    watch = Watch(pre, monkeypatch)
+    again = GenerationRequest(token_ids=_new_prompt(BS - 1), max_new_tokens=4)
+    pre.generate([again])
+    watch.close()
+    assert watch.prefills == ["deferred"]
+    assert watch.first_syncs == [{"rows": 1, "waited": 0}]
+    assert _idle(pre)
+    pre.close()
