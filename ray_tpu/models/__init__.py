@@ -11,6 +11,9 @@ transformer (grouped differential latent attention on window and full
 layers, a four-stream mHC residual, PolyNorm experts of which a share may be
 held; serving only), Nemotron-H-shaped transformer (layers that are a
 Mamba-2 mixer, an attention or a latent expert layer alone; serving only),
+Cohere2-MoE-shaped transformer (a parallel block on one LayerNorm, plain K/V
+heads in a ring three layers in four, experts as wide as the model of which
+a share may be held, shared experts averaged, a tied head; serving only),
 ViT (vision encoder).
 The reference delegates model execution to torch/vLLM; this framework owns
 it.
@@ -60,12 +63,12 @@ type of the model config. What the engine asks of a family:
     heads, d_k, d_v)`` float32 and a ``state_conv`` in each KDA layer, and
     ``llama``'s three in each GQA layer
   - ``WINDOW``: a ring of a window layer's last ``ring`` positions,
-    ``(batch, 1, ring, width)``, beside the layer's ``INDEX``; a name that
-    starts with ``window_``. Position ``p`` lives at slot ``p % ring``: a
-    step writes its token there and attends ``min(index + 1, ring)`` slots
-    (a cached latent row carries no position and a cached rotary row is
-    already rotated, and a softmax over a set does not depend on its
-    order, so the ring read up to that length *is* the window); a
+    ``(batch, heads, ring, width)``, beside the layer's ``INDEX``; a name
+    that starts with ``window_``. Position ``p`` lives at slot ``p % ring``:
+    a step writes its token there and attends ``min(index + 1, ring)`` slots
+    (a cached latent row or value carries no position and a cached rotary
+    row or key is already rotated, and a softmax over a set does not depend
+    on its order, so the ring read up to that length *is* the window); a
     whole-prompt prefill leaves the prompt's last ``min(len, ring)``
     positions; a free row needs no zeroing (index 0 is an empty ring). A
     slot row is a slice of axis 0 as for the others; like ``STATE`` it is
@@ -74,7 +77,11 @@ type of the model config. What the engine asks of a family:
     prefix has no ring-aware form (``_NO_RULES``: ``prefill_chunk``).
     ``models/motif.py`` keeps ``window_latent`` ``(batch, 1, 128, 512)``
     and ``window_rope`` ``(batch, 1, 128, 64)`` in three layers of four,
-    ``deepseek``'s two ``SEQUENCE`` leaves in the fourth
+    ``deepseek``'s two ``SEQUENCE`` leaves in the fourth;
+    ``models/cohere2_moe.py`` ``llama.Attention``'s ``window_key`` /
+    ``window_value`` ``(batch, kv_heads, 4096, head_dim)`` in three layers
+    of four (``LlamaConfig.window``), its ``cached_key`` / ``cached_value``
+    in the fourth
 - a step of one token a row (``seq == 1`` against a cache) attends each
   row up to its own position; a longer ``seq`` against a cache is a chunk
   behind a cached prefix, row ``r``'s token ``i`` at ``index[r] + i``, and
@@ -122,6 +129,16 @@ shared projections, narrower than the model) and the ungated
 ``expert_activation="relu2"`` (``ops/moe_experts.py``'s two-matrix form);
 the engine and the cache manager changed nowhere: they already walked the
 cache by leaf kind and counted experts over ``routed_layers``.
+``cohere2_moe`` (PR 59) is the first whose ring holds plain K/V heads (50 MB
+of a 92 MB row, read by ``ops/decode_attention.decode_attention`` at 16
+query heads a K/V head) and the first to prefill through
+``ops/flash_attention.py``: ``llama.Attention`` gained ``window`` (the ring,
+and the band in the flash kernel's forward), ``rope_interleaved``
+(``ops/rope.py``'s GPT-J pairs) and the rule that says which whole prompts
+take the kernel (``llama.prefills_through_kernel``). Its own are a
+LayerNorm, the parallel block and a tied head (one ``lm_head`` of ``(vocab,
+dim)``); the engine, the cache manager and the expert kernel changed
+nowhere.
 """
 
 from __future__ import annotations
@@ -235,6 +252,30 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "for more than one new position a row (ROADMAP R4)"
         ),
     },
+    "cohere2_moe": {
+        "adapters": (
+            "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
+            "wq/wk/wv/wo at dim = n_heads x head_dim (here 4096 against "
+            "128 x 128) and has no placement for expert weights"
+        ),
+        "draft_model": (
+            "a rejected draft run cannot be undone by moving an index "
+            "back: a window layer's ring has overwritten the positions the "
+            "run would return to, and the expert counters count plain "
+            "decode steps"
+        ),
+        "mesh": (
+            "parallel/plan.py has no partition rule for a ring leaf "
+            "(models.WINDOW) or for the (expert, ...) weights; a held "
+            "share of the experts has no ep exchange of model-wide rows "
+            "yet (ROADMAP R1)"
+        ),
+        "prefill_chunk": (
+            "a chunk behind a cached prefix would have to read a window "
+            "layer's ring while it overwrites it: the ring has no form "
+            "for more than one new position a row (ROADMAP R4)"
+        ),
+    },
     "nemotron_h": {
         "adapters": (
             "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
@@ -303,9 +344,12 @@ def refusals(family: str) -> Dict[str, str]:
 
 def _family(model_config):
     from . import (
-        deepseek, falcon_h1, llama, moe, motif, nemotron_h, solar_open2,
+        cohere2_moe, deepseek, falcon_h1, llama, moe, motif, nemotron_h,
+        solar_open2,
     )
 
+    if isinstance(model_config, cohere2_moe.Cohere2MoEConfig):
+        return cohere2_moe
     if isinstance(model_config, nemotron_h.NemotronHConfig):
         return nemotron_h
     if isinstance(model_config, motif.MotifConfig):

@@ -83,6 +83,14 @@ class LlamaConfig:
     attn_gate: bool = False
     # a head's width where it is not ``dim / n_heads``
     attn_head_dim: Optional[int] = None
+    # rotary pairs are a head's neighbours ``(x[2i], x[2i + 1])`` (GPT-J's
+    # form, ``rope_gptj``), not its halves (ops/rope.py)
+    rope_interleaved: bool = False
+    # a window layer (serving only): a query sees its last ``window``
+    # positions, itself included, and a row keeps those alone, in a ring
+    # (``models.WINDOW``: ``window_key`` / ``window_value`` in the place of
+    # ``cached_key`` / ``cached_value``)
+    window: Optional[int] = None
 
     @property
     def head_dim(self) -> int:
@@ -114,6 +122,39 @@ class LlamaConfig:
         )
         defaults.update(kw)
         return LlamaConfig(**defaults)
+
+
+# the float32 scores (batch x heads x seq x seq) a whole-prompt prefill may
+# hold as one einsum's temporary (``prefills_through_kernel``)
+_EINSUM_SCORE_BYTES = 1 << 30
+
+
+def prefills_through_kernel(cfg: LlamaConfig, batch: int, seq: int) -> bool:
+    """Which of its two forms a fresh whole prompt's attention takes, from
+    the layer's configuration and the call's shapes alone: the float32
+    einsum over the prompt's own keys, or ``ops/flash_attention.py``'s
+    forward kernel. The kernel where the einsum has no form (a window
+    layer: the band is the kernel's) or cannot hold its scores (more than
+    ``_EINSUM_SCORE_BYTES`` of them: 128 heads at 2048 tokens are 2.1 GB,
+    at 8192 34 GB); the einsum everywhere else, which is every prompt the
+    benchmark's other cells send (32 heads x 2048 x 2048 are 0.5 GB) until
+    ROADMAP S4(b)(i) moves them with a measured pair of its own. A suffix
+    behind a cached prefix, a chunk and a verify never come here."""
+    return (cfg.window is not None
+            or 4 * batch * cfg.n_heads * seq * seq > _EINSUM_SCORE_BYTES)
+
+
+def ring_of(rows, ring: int):
+    """The ring a whole-prompt prefill leaves: ``rows (b, heads, s, width)``,
+    position ``p`` to slot ``p % ring``, the last ``min(s, ring)`` of
+    them; slots no position reached are zero (and never read: ``lengths``
+    stops short of them)."""
+    s = rows.shape[2]
+    if s <= ring:
+        return jnp.pad(rows, ((0, 0), (0, 0), (0, ring - s), (0, 0)))
+    last = rows[:, :, s - ring:]  # entry k is position s - ring + k
+    turn = ring - (s - ring) % ring  # ... and belongs at (k - turn) % ring
+    return jnp.concatenate([last[:, :, turn:], last[:, :, :turn]], axis=2)
 
 
 def _dense(features, logical_axes, name, param_dtype, dtype, use_bias=False):
@@ -251,39 +292,69 @@ class Attention(nn.Module):
             # layer in a flax "cache" collection). The cache index is
             # PER-ROW (b,): continuous batching interleaves requests at
             # different positions in one decode batch.
+            # A window layer keeps a ring of its last ``window`` positions
+            # under names of its own (models.WINDOW), position p at slot
+            # p % window
+            ring = cfg.window
+            positions = ring or cfg.max_seq_len
+            kept = "window" if ring else "cached"
             # No cache came in: this call makes it, so every position but
             # the s it writes is zero (a whole-prompt prefill)
-            fresh = not self.has_variable("cache", "cached_key")
+            fresh = not self.has_variable("cache", f"{kept}_key")
             cached_k = self.variable(
-                "cache", "cached_key",
-                jnp.zeros, (b, hk, cfg.max_seq_len, d), cfg.dtype,
+                "cache", f"{kept}_key",
+                jnp.zeros, (b, hk, positions, d), cfg.dtype,
             )
             cached_v = self.variable(
-                "cache", "cached_value",
-                jnp.zeros, (b, hk, cfg.max_seq_len, d), cfg.dtype,
+                "cache", f"{kept}_value",
+                jnp.zeros, (b, hk, positions, d), cfg.dtype,
             )
             idx_var = self.variable(
                 "cache", "cache_index", lambda: jnp.zeros((b,), jnp.int32)
             )
             idx = idx_var.value  # (b,)
             if cfg.rope:
-                q = apply_rope(q, cos, sin, offset=idx)
-                k = apply_rope(k, cos, sin, offset=idx)
+                q = apply_rope(q, cos, sin, offset=idx,
+                               interleaved=cfg.rope_interleaved)
+                k = apply_rope(k, cos, sin, offset=idx,
+                               interleaved=cfg.rope_interleaved)
+            new = (k.astype(cfg.dtype), v.astype(cfg.dtype))
 
-            # each row's new keys and values at its own position
-            cached_k.value, cached_v.value = write_rows(
-                (cached_k.value, cached_v.value),
-                (k.astype(cfg.dtype), v.astype(cfg.dtype)), idx, self.mesh,
-            )
+            if ring and s > 1:
+                if not fresh:
+                    raise NotImplementedError(
+                        "a window layer has no form for more than one new "
+                        "position a row against its ring (models.refusals: "
+                        "prefill_chunk)"
+                    )
+                # a fresh row is at position 0: the prompt's last
+                # min(s, ring) positions, in ring order
+                cached_k.value, cached_v.value = (
+                    ring_of(leaf, ring) for leaf in new)
+            else:
+                # each row's new keys and values at its own position (its
+                # position's slot in a ring)
+                cached_k.value, cached_v.value = write_rows(
+                    (cached_k.value, cached_v.value), new,
+                    idx % ring if ring else idx, self.mesh,
+                )
             idx_var.value = idx + s
             if s == 1:
                 # a decode step: the kernel reads each row's keys and
                 # values once, as stored, up to the row's length (an index
-                # that ran past the cache still names at most all of it)
+                # that ran past the cache still names at most all of it;
+                # the keys are cached rotated and a softmax over a set
+                # does not depend on its order, so a ring read up to its
+                # live slots is the window)
                 out = decode_attention(
                     q[:, :, 0], cached_k.value, cached_v.value,
-                    jnp.minimum(idx + 1, cfg.max_seq_len), self.mesh,
+                    jnp.minimum(idx + 1, positions), self.mesh,
                 )[:, :, None]
+            elif fresh and prefills_through_kernel(cfg, b, s):
+                # a whole prompt on its own keys, K/V heads as they are,
+                # under the band in a window layer
+                out = flash_attention(
+                    q, *new, causal=True, window=ring, forward_only=True)
             else:
                 # prefill, chunked prefill, speculative verify. A whole
                 # prompt's keys are the s it just wrote into its own

@@ -102,7 +102,7 @@ from ..ops.rmsnorm import rmsnorm
 from ..ops.rope import apply_rope, rope_table
 from . import ROUTING  # noqa: F401  (MoEFFN sows into it)
 from .deepseek import latent_cache, latent_rows
-from .llama import _dense
+from .llama import _dense, ring_of
 from .moe import MoEConfig, MoEFFN, poly_coefficients, poly_init, poly_norm
 
 F32 = jnp.float32
@@ -301,19 +301,6 @@ def mix_out(streams, y, post, res, clamp: float):
             ).astype(streams[0].dtype)
             for i, p in enumerate(post)
         )
-
-
-def ring_of(rows, ring: int):
-    """The ring a whole-prompt prefill leaves: ``rows (b, 1, s, width)``,
-    position ``p`` to slot ``p % ring``, the last ``min(s, ring)`` of
-    them; slots no position reached are zero (and never read: ``lengths``
-    stops short of them)."""
-    s = rows.shape[2]
-    if s <= ring:
-        return jnp.pad(rows, ((0, 0), (0, 0), (0, ring - s), (0, 0)))
-    last = rows[:, :, s - ring:]  # entry k is position s - ring + k
-    turn = ring - (s - ring) % ring  # ... and belongs at (k - turn) % ring
-    return jnp.concatenate([last[:, :, turn:], last[:, :, :turn]], axis=2)
 
 
 def _banded_attention(q, k, v, scale: float, window: Optional[int]):

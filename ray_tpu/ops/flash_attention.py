@@ -8,7 +8,10 @@ it per ring step and merges with the returned log-sum-exp.
 Design (flash-attention-2 schedule):
 - forward: grid (batch*heads, num_q_blocks, num_k_blocks), k innermost so the
   f32 accumulator/(m,l) scratch carries across k steps in VMEM; online
-  softmax; causal blocks beyond the diagonal are predicated off
+  softmax; causal blocks beyond the diagonal are predicated off, and with
+  a ``window`` (a query sees its last ``window`` positions, itself
+  included: forward only) so are the blocks wholly beneath the band, while
+  the blocks the band's lower edge crosses take the masked body
 - backward: recompute P per block from the saved LSE (no S×S residuals);
   one kernel for dq (grid over q blocks) and one for dk/dv (grid over k
   blocks, scores computed transposed so no tile is ever transposed)
@@ -75,7 +78,8 @@ def _use_interpret() -> bool:
 
 
 def default_blocks(
-    seq_q: int, seq_k: int, head_dim: int, dtype, causal: bool = True
+    seq_q: int, seq_k: int, head_dim: int, dtype, causal: bool = True,
+    window: Optional[int] = None,
 ) -> Tuple[int, int]:
     """(block_q, block_k) for a call nobody gave tiles: chosen from the shape
     alone, never by timing (set-up time is a judged metric).
@@ -84,8 +88,10 @@ def default_blocks(
     (rescale the accumulator, write it back, the pipeline's bookkeeping)
     is paid a step, so tiles are as large as VMEM allows and the work
     inside one is done ``_CHUNK`` columns at a time: 2048 x 2048 for a
-    causal call, 1024 x 1024 for a call without a diagonal to skip above
-    (its tiles keep every row in every chunk), both for a head row of at
+    causal call, 1024 x 1024 for a call without a diagonal to skip above or
+    with a ``window`` (their masked tiles keep every row in every chunk: a
+    windowed 2048 x 2048 call asked the described chip for 19.5 MiB), both
+    for a head row of at
     most 256 bytes (128 wide in bf16) and halved for each doubling of it,
     which keeps every call inside the 16 MiB of VMEM a kernel gets.
     A side of at most 1024 is one tile with no padding; a longer one takes
@@ -95,7 +101,7 @@ def default_blocks(
     1024 x 1024 14.4, 2048 x 2048 in chunks 10.8.
     """
     row_bytes = max(head_dim, _LANES) * jnp.dtype(dtype).itemsize
-    most = 2048 if causal and seq_q == seq_k else 1024
+    most = 2048 if causal and seq_q == seq_k and window is None else 1024
     most = max(512, most * 256 // max(256, row_bytes) // 512 * 512)
 
     def side(seq: int) -> int:
@@ -109,9 +115,10 @@ def default_blocks(
     return side(seq_q), side(seq_k)
 
 
-def _resolve_blocks(q, k, causal, block_q, block_k) -> Tuple[int, int]:
+def _resolve_blocks(q, k, causal, block_q, block_k,
+                    window=None) -> Tuple[int, int]:
     auto_q, auto_k = default_blocks(
-        q.shape[1], k.shape[1], q.shape[2], q.dtype, causal
+        q.shape[1], k.shape[1], q.shape[2], q.dtype, causal, window
     )
     return (
         auto_q if block_q is None else min(block_q, q.shape[1]),
@@ -128,31 +135,43 @@ def _lanes(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
-def _mask_scores(s, q_axis: int, causal: bool, q0, k0, k_left=None):
+def _mask_scores(s, q_axis: int, causal: bool, q0, k0, k_left=None,
+                 window: Optional[int] = None):
     """Scores with q positions from ``q0`` along ``q_axis`` and k positions
     from ``k0`` along the other axis, -inf where the key comes after the
-    query (``causal``) or lies past the sequence's end (``k_left``: the k
-    positions of this tile that exist; None when none is padding). A
-    subtract and a compare against a scalar, not two position tiles."""
+    query (``causal``), ``window`` or more positions before it, or lies
+    past the sequence's end (``k_left``: the k positions of this tile that
+    exist; None when none is padding). A subtract and a compare against a
+    scalar, not two position tiles."""
     k_idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     valid = None
     if causal:  # q_pos >= k_pos
         q_idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
-        valid = k_idx - q_idx <= q0 - k0
+        ahead = k_idx - q_idx
+        valid = ahead <= q0 - k0
+        if window is not None:  # k_pos > q_pos - window
+            valid &= ahead > q0 - k0 - window
     if k_left is not None:
         valid = k_idx < k_left if valid is None else valid & (k_idx < k_left)
     return s if valid is None else jnp.where(valid, s, _NEG_INF)
 
 
-def _tile_kinds(causal: bool, q_idx, k_idx, block_q: int, block_k: int, edge):
+def _tile_kinds(causal: bool, q_idx, k_idx, block_q: int, block_k: int, edge,
+                window: Optional[int] = None):
     """(plain, masked) predicates of grid tile (q_idx, k_idx): a tile runs
-    the masked body iff the causal diagonal crosses it or it is an ``edge``
-    tile holding padding (``edge`` is None when there is no padding); a
-    causal tile wholly above the diagonal runs nothing. ``None`` for a
-    predicate that is statically true/false."""
+    the masked body iff the causal diagonal or a ``window``'s lower edge
+    crosses it or it is an ``edge`` tile holding padding (``edge`` is None
+    when there is no padding); a causal tile wholly above the diagonal, or
+    wholly beneath the band, runs nothing. ``None`` for a predicate that is
+    statically true/false."""
     if causal:
         runs = q_idx * block_q + block_q - 1 >= k_idx * block_k
         below = q_idx * block_q >= k_idx * block_k + block_k - 1
+        if window is not None:
+            # its last key is out of its first query's window: nothing to see
+            runs &= k_idx * block_k + block_k - 1 > q_idx * block_q - window
+            # its first key is in its last query's window: nothing to mask
+            below &= k_idx * block_k > q_idx * block_q + block_q - 1 - window
         if edge is None:
             return below, runs & ~below
         return below & ~edge, runs & (~below | edge)
@@ -178,7 +197,7 @@ def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref,
     acc_ref, m_ref, l_ref,
     *, sm_scale: float, causal: bool, block_q: int, block_k: int,
-    seq_q: int, seq_k: int,
+    seq_q: int, seq_k: int, window: Optional[int] = None,
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -193,7 +212,10 @@ def _fwd_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def _body(masked: bool):
-        tri = _skips_above(masked, causal, pad_k, block_q, block_k)
+        # (a masked tile of a window call may lie on the band's lower
+        # edge, off the diagonal, where every chunk needs every row)
+        tri = window is None and _skips_above(
+            masked, causal, pad_k, block_q, block_k)
         for c, w in _chunks(block_k):
             r0 = c if tri else 0
             rows = slice(r0, block_q)
@@ -205,7 +227,8 @@ def _fwd_kernel(
             if masked:
                 k_left = seq_k - ki * block_k - c if pad_k else None
                 s = _mask_scores(
-                    s, 0, causal, qi * block_q + r0, ki * block_k + c, k_left
+                    s, 0, causal, qi * block_q + r0, ki * block_k + c, k_left,
+                    window,
                 )
                 if pad_k:
                     # the pad rows of v are uninitialized, and 0 * NaN would
@@ -226,7 +249,8 @@ def _fwd_kernel(
             m_ref[rows, :] = m_new
 
     edge = (ki == nk - 1) if pad_k else None
-    _run_tiles(_body, *_tile_kinds(causal, qi, ki, block_q, block_k, edge))
+    _run_tiles(
+        _body, *_tile_kinds(causal, qi, ki, block_q, block_k, edge, window))
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -251,10 +275,26 @@ def _causal_kv_index(block_q: int, block_k: int):
     return index_map
 
 
-def _kv_index(causal: bool, sq: int, sk: int, block_q: int, block_k: int):
-    if causal and sq == sk:
-        return _causal_kv_index(block_q, block_k)
-    return lambda b, i, j: (b, j, 0)
+def _kv_index(causal: bool, sq: int, sk: int, block_q: int, block_k: int,
+              window: Optional[int] = None, group: int = 1):
+    """Where grid step (b, i, j) finds its K/V block. ``group`` query heads
+    read one K/V head (``b // group``: the K/V heads as they are, not
+    repeated), and with a ``window`` the blocks beneath the band are clamped
+    to the band's first as those above the diagonal are to its last."""
+    if window is None and group == 1:
+        if causal and sq == sk:
+            return _causal_kv_index(block_q, block_k)
+        return lambda b, i, j: (b, j, 0)
+
+    def index_map(b, i, j):
+        if causal and sq == sk:
+            last = (i * block_q + block_q - 1) // block_k
+            first = 0 if window is None else jnp.maximum(
+                i * block_q - window + 1, 0) // block_k
+            j = jnp.clip(j, first, last)
+        return (b // group, j, 0)
+
+    return index_map
 
 
 # no vmem_limit_bytes: default_blocks keeps a call inside the compiler's 16
@@ -269,16 +309,22 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
 def _flash_forward(
     q, k, v, sm_scale: float, causal: bool,
     block_q: Optional[int], block_k: Optional[int],
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
+    """``k`` and ``v`` may hold fewer rows than ``q``: row ``r`` of them is
+    then read by q's rows ``r * group ..`` (grouped-query attention on the
+    K/V heads as they are)."""
     bh, sq, d = q.shape
-    _, sk, _ = k.shape
-    block_q, block_k = _resolve_blocks(q, k, causal, block_q, block_k)
-    kv_index = _kv_index(causal, sq, sk, block_q, block_k)
+    bhk, sk, _ = k.shape
+    block_q, block_k = _resolve_blocks(
+        q, k, causal, block_q, block_k, window)
+    kv_index = _kv_index(
+        causal, sq, sk, block_q, block_k, window, bh // bhk)
     o, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel,
             sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
-            seq_q=sq, seq_k=sk,
+            seq_q=sq, seq_k=sk, window=window,
         ),
         grid=(bh, pl.cdiv(sq, block_q), pl.cdiv(sk, block_k)),
         in_specs=[
@@ -600,17 +646,33 @@ def flash_attention_with_lse(
     sm_scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    window: Optional[int] = None,
+    forward_only: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Attention over (batch, heads, seq, head_dim); also returns per-row
     log-sum-exp (batch, heads, seq) for ring-step merging. Tiles nobody
-    names are ``default_blocks`` of the shape."""
+    names are ``default_blocks`` of the shape.
+
+    ``forward_only``: the forward kernel alone, with no backward rule, on
+    the K/V heads as they come (a group of query heads reads its K/V head
+    where it lies; nothing is repeated): a serving prefill. ``window``
+    (causal calls; implies ``forward_only``, the backward kernels have no
+    band): query ``t`` sees the keys ``t - window < j <= t``."""
     b, h, sq, d = q.shape
     _, hk, sk, _ = k.shape
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    if window is not None or forward_only:
+        if window is not None and not causal:
+            raise ValueError("flash_attention: a window is a causal band")
+        o, lse = _flash_forward(
+            q.reshape(b * h, sq, d), k.reshape(b * hk, sk, d),
+            v.reshape(b * hk, sk, d), sm_scale, causal, block_q, block_k,
+            window)
+        return o.reshape(b, h, sq, d), lse.reshape(b, h, sq)
     if h != hk:  # grouped-query attention: repeat kv heads
         k = jnp.repeat(k, h // hk, axis=1)
         v = jnp.repeat(v, h // hk, axis=1)
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
     qf = q.reshape(b * h, sq, d)
     kf = k.reshape(b * h, sk, d)
     vf = v.reshape(b * h, sk, d)
@@ -622,7 +684,8 @@ def flash_attention(q, k, v, **kwargs) -> jax.Array:
     return flash_attention_with_lse(q, k, v, **kwargs)[0]
 
 
-def reference_attention(q, k, v, *, causal: bool = True, sm_scale=None):
+def reference_attention(q, k, v, *, causal: bool = True, sm_scale=None,
+                        window: Optional[int] = None):
     """Plain XLA attention for correctness checks."""
     b, h, sq, d = q.shape
     _, hk, sk, _ = k.shape
@@ -634,6 +697,8 @@ def reference_attention(q, k, v, *, causal: bool = True, sm_scale=None):
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if causal:
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq - window)
         s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)

@@ -179,6 +179,28 @@ def test_flash_attention_forward_and_backward_compile(
         assert name in text, name
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["band", "causal"])
+def test_flash_attention_serving_forward_compiles_under_a_window(
+    v5e_chip, native_kernels, window
+):
+    """`commandaplus-rag-backlog`'s longest prefill a layer: 128 query heads
+    on 8 K/V heads as they are, 8192 tokens, under a 4096 band in a window
+    layer (1024 x 1024 tiles: at 2048 x 2048 the described chip refused the
+    banded body's 19.5 MiB of VMEM) and causal in a full one; no repeated
+    K/V reaches the kernel."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 128, 8192, 128), jnp.bfloat16, sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16, sharding=v5e_chip)
+    text = _compile(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, forward_only=True),
+        q, kv, kv)
+    assert "tpu_custom_call" in text and "flash_fwd" in text
+    assert "bf16[128,8192,128]" in text and "bf16[8,8192,128]" in text
+    assert not re.search(r"= bf16\[1,128,8192,128\]\S* (broadcast|copy)\(", text)
+
+
 def test_rmsnorm_compiles(v5e_chip, native_kernels):
     from ray_tpu.ops.rmsnorm import rmsnorm
 
@@ -191,10 +213,15 @@ def test_rmsnorm_compiles(v5e_chip, native_kernels):
 # over 8 KV heads of 128, 16 slots x 4096) and chip_smoke.py's (Llama-2-7B)
 MISTRAL_POOL = (16, 32, 8, 4096)
 LLAMA2_POOL = (8, 32, 32, 2048)
+# `commandaplus-rag-backlog`'s (Command A+: 128 query heads over 8 KV heads,
+# 24 slots): a window layer's ring of 4096 and a full layer's row of 10240
+C2MOE_RING = (24, 128, 8, 4096)
+C2MOE_ROW = (24, 128, 8, 10240)
 
 
-@pytest.mark.parametrize("pool", [MISTRAL_POOL, LLAMA2_POOL],
-                         ids=["mistral", "llama2"])
+@pytest.mark.parametrize(
+    "pool", [MISTRAL_POOL, LLAMA2_POOL, C2MOE_RING, C2MOE_ROW],
+    ids=["mistral", "llama2", "c2moe_ring", "c2moe_row"])
 def test_decode_attention_compiles(v5e_chip, native_kernels, pool):
     from ray_tpu.ops.decode_attention import decode_attention
 
@@ -662,6 +689,63 @@ def test_nemotron_h_decode_step_at_a_group_of_sixteen_and_two_matrices(
     # 64 rows x (4.26 MB of state and tail + 4.19 of K and V)
     assert 0.53e9 < _size(pool) < 0.55e9
     assert prefill.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_cohere2_moe_decode_step_reads_rings_and_model_wide_experts(
+    v5e_chip, native_kernels, compiled
+):
+    """`commandaplus-rag-backlog`'s programs at its widths, one layer of
+    each kind (``layer_switch`` 2), 24 slots x 10240, 16 of 128 experts
+    held: a window layer keeps two rings of 4096 and a full layer two rows
+    of 10240, each aliased to its successor and written by the row kernel;
+    the decode kernel takes 128 query heads over 8 KV heads on both; the
+    expert kernel is handed three 4096 x 4096 matrices of 16 experts (its
+    blocking of a 4096-wide inner had never met the chip's compiler); a
+    2048-token prefill goes through the flash kernel in both layers, K/V
+    heads as they are."""
+    from ray_tpu.models.cohere2_moe import Cohere2MoEConfig
+
+    cfg = Cohere2MoEConfig(
+        vocab_size=32768, n_layers=2, layer_switch=2, experts_held=(0, 16),
+        param_dtype=jnp.bfloat16, max_seq_len=10240)
+    model, params, pool, args, kwargs = _decode_step(v5e_chip, cfg, 24)
+    decode = model._decode.lower(*args, **kwargs).compile()
+    assert set(pool["layer_0"]["attn"]) == {
+        "window_key", "window_value", "cache_index"}
+    assert pool["layer_0"]["attn"]["window_key"].shape == (24, 8, 4096, 128)
+    assert pool["layer_1"]["attn"]["cached_key"].shape == (24, 8, 10240, 128)
+    text = decode.as_text()
+    header = text.split("\n", 1)[0]
+    aliases = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliases) == len(jax.tree.leaves(pool)) == 2 * 3
+    entry = text[text.index("\nENTRY "):]
+    assert len(re.findall(r"%decode_attention\S* = bf16\[24,8,16,128\]", entry)) == 2
+    assert len(re.findall(r"%kv_row_write\S* = \(bf16\[24,8,4096,128\]", entry)) == 1
+    assert len(re.findall(r"%kv_row_write\S* = \(bf16\[24,8,10240,128\]", entry)) == 1
+    calls = re.findall(
+        r"%moe_experts\S* = f32\[\d+,4096\]\S* custom-call\(([^)]*)\)", entry)
+    operands = calls[0].split(", ")
+    assert len(calls) == 2
+    assert [o.split("moe____")[-1][:6] for o in operands[-3:]] == [
+        "w_gate", "w_up__", "w_down"]
+    assert entry.count("bf16[16,4096,4096]") >= 6
+    assert "bf16[128,4096,4096]" not in text
+    for ring in ("24,8,4096,128", "24,8,10240,128"):
+        assert not re.search(rf"= bf16\[{ring}\]\S* copy\(", text)
+    mem = decode.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert _size(pool) <= mem.alias_size_in_bytes <= _size(pool) + 512 * len(aliases)
+    # 0.27 GB of tied embedding; a layer 0.689 GB + 16 x 100.7 MB
+    assert 4.85e9 < _size(params) < 4.9e9
+    # 24 rows x (2 x 8.4 MB of rings + 2 x 21.0 of K and V)
+    assert 1.4e9 < _size(pool) < 1.42e9
+    prompt = jax.ShapeDtypeStruct((1, 2048), jnp.int32)
+    prefill = jax.jit(model._prefill_impl).lower(
+        args[0], _on(v5e_chip, prompt)).compile()
+    text = prefill.as_text()
+    assert len(re.findall(r"%flash_fwd\S* = \(bf16\[128,2048,128\]", text)) == 2
+    assert "f32[1,128,2048,2048]" not in text
+    assert prefill.memory_analysis().temp_size_in_bytes < 0.8e9
 
 
 # ---------------------------------------------------------------------------

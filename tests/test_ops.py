@@ -209,6 +209,66 @@ def test_flash_default_tiles():
     assert call.params["grid_mapping"].grid == (1, 2, 4)
 
 
+def _masked_softmax_attention(q, k, v, window):
+    """The band written out: query t sees keys t - window < j <= t, K/V
+    head u // group for query head u, one softmax over a masked row."""
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    seq = q.shape[2]
+    t, j = jnp.arange(seq)[:, None], jnp.arange(seq)[None, :]
+    seen = (j <= t) & (j > t - window)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+@pytest.mark.parametrize("seq,window,tile", [
+    (512, 200, 128),   # seq > window: tiles beneath the band run nothing
+    (512, 130, 256),   # ... and the band's lower edge crosses a q tile twice
+    (384, 384, 128),   # seq == window: the band is the causal triangle
+    (256, 1000, 128),  # seq < window
+    (300, 77, 128),    # a padded last tile under the band
+    (96, 40, None),    # one tile, both edges in it
+], ids=["over", "edge", "equal", "under", "padded", "one_tile"])
+def test_flash_window_is_the_banded_softmax(seq, window, tile):
+    """The forward kernel under a window, 4 query heads on 2 K/V heads as
+    they are, against a masked softmax."""
+    keys = jax.random.split(jax.random.PRNGKey(seq + window), 3)
+    q = jax.random.normal(keys[0], (2, 4, seq, 32))
+    k, v = (jax.random.normal(key, (2, 2, seq, 32)) for key in keys[1:])
+    got = flash_attention(
+        q, k, v, causal=True, window=window, block_q=tile, block_k=tile)
+    assert float(jnp.abs(
+        got - _masked_softmax_attention(q, k, v, window)).max()) < 1e-5
+    # the plain reference knows the band too
+    assert float(jnp.abs(got - reference_attention(
+        q, k, v, causal=True, window=window)).max()) < 1e-5
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=window)
+
+
+@DTYPES
+def test_flash_without_a_window_is_the_kernel_it_was(qkv, dtype):
+    """``window=None`` changes nothing: the forward-only entry on K/V heads
+    as they are is bit-equal to the path with a backward rule on repeated
+    heads, and a window that covers the sequence is the causal call."""
+    q, k, v = (x.astype(dtype) for x in qkv)
+    q4 = jnp.concatenate([q, q[:, ::-1]], axis=1)  # 4 query heads on 2
+    trained = flash_attention(q4, k, v, causal=True, block_q=128, block_k=128)
+    served = flash_attention(
+        q4, k, v, causal=True, block_q=128, block_k=128, forward_only=True)
+    assert bool(jnp.all(trained == served))
+    covered = flash_attention(
+        q4, k, v, causal=True, block_q=128, block_k=128, window=256)
+    assert float(jnp.abs(_f32(covered)[0] - _f32(trained)[0]).max()) < TOL
+    # a window has no backward kernel: it says so, it does not mis-train
+    with pytest.raises(Exception):
+        jax.grad(lambda x: flash_attention(
+            x, k, v, window=64).astype(jnp.float32).sum())(q4)
+    assert default_blocks(8192, 8192, 128, jnp.bfloat16, window=4096) == (
+        1024, 1024)
+
+
 def test_rmsnorm_matches_reference():
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 96, 128))
     w = jax.random.normal(jax.random.PRNGKey(1), (128,)) * 0.1 + 1.0
@@ -243,6 +303,35 @@ def test_rope_properties():
     pad = jnp.zeros((1, 2, 32, 64), x.dtype)
     full = apply_rope(jnp.concatenate([pad, x], axis=2), cos, sin)[:, :, 32:]
     np.testing.assert_allclose(np.asarray(shifted), np.asarray(full), atol=1e-5)
+
+
+@pytest.mark.parametrize("offset", [0, 37, "rows"])
+def test_interleaved_rope_is_the_complex_rotation(offset):
+    """``interleaved=True`` turns the neighbours ``(x[2i], x[2i + 1])``:
+    the complex number ``x[2i] + 1j x[2i + 1]`` times ``exp(1j p theta_i)``
+    (GPT-J's form), where the default turns a head's halves; one is the
+    other under a fixed permutation of the columns."""
+    d, theta = 16, 50000.0
+    cos, sin = rope_table(128, d, theta)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 3, 5, d))
+    at = jnp.asarray([3, 40]) if offset == "rows" else offset
+    got = np.asarray(apply_rope(x, cos, sin, at, interleaved=True))
+    freqs = 1.0 / theta ** (np.arange(0, d, 2) / d)
+    pairs = np.asarray(x).reshape(2, 3, 5, d // 2, 2)
+    for row in range(2):
+        first = int(at[row]) if offset == "rows" else offset
+        angles = (first + np.arange(5))[:, None] * freqs[None, :]
+        turned = (pairs[row, ..., 0] + 1j * pairs[row, ..., 1]) * np.exp(
+            1j * angles)[None]
+        want = np.stack([turned.real, turned.imag], axis=-1).reshape(3, 5, d)
+        np.testing.assert_allclose(got[row], want, atol=2e-6)
+    # the two forms are one rotation on permuted columns, not one function
+    halves = np.asarray(apply_rope(x, cos, sin, at))
+    assert np.abs(halves - got).max() > 0.1
+    order = np.concatenate([np.arange(0, d, 2), np.arange(1, d, 2)])
+    np.testing.assert_allclose(
+        np.asarray(apply_rope(x[..., order], cos, sin, at)), got[..., order],
+        atol=2e-6)
 
 
 # -- the grouped expert kernel's ungated form (ops/moe_experts.py) ------------
