@@ -144,6 +144,27 @@ def prefills_through_kernel(cfg: LlamaConfig, batch: int, seq: int) -> bool:
             or 4 * batch * cfg.n_heads * seq * seq > _EINSUM_SCORE_BYTES)
 
 
+# the smallest projection weight a decode step's program was seen to leave out
+# of VMEM (``holds_projection``): half of the v5e's 128 MiB
+_UNSTAGED_WEIGHT_BYTES = 64 << 20
+
+
+def holds_projection(weight_bytes: int) -> bool:
+    """Whether a decode step keeps its query projection's output as the
+    matmul made it, ``(b, h * d)``, from the weight's bytes alone. The
+    decode kernel wants the query as ``(b, kv_heads, group, d)``, and the
+    TPU compiler gives the projection's output that order by re-laying the
+    *weight*. That is free where it stages the weight in VMEM anyway (the
+    copy is then the weight's one read: Mistral's and Nemotron's 32 MiB)
+    and an HBM round trip of the whole weight every step where it does not
+    (Solar-Open2's 64 MiB, 0.31 ms a step; Command A+'s 128 MiB, 0.41 ms a
+    layer: PERF 6, 59.3 and PR 60). Held, the weight is read once as stored
+    and the order is made on the activation, under a megabyte. Below the
+    limit nothing is held: there the hold takes staged K/V reads out of
+    VMEM (ROADMAP S11(e))."""
+    return weight_bytes >= _UNSTAGED_WEIGHT_BYTES
+
+
 def ring_of(rows, ring: int):
     """The ring a whole-prompt prefill leaves: ``rows (b, heads, s, width)``,
     position ``p`` to slot ``p % ring``, the last ``min(s, ring)`` of
@@ -276,7 +297,13 @@ class Attention(nn.Module):
         def heads(y, n):
             return y.reshape(b, s, n, d).transpose(0, 2, 1, 3)
 
-        q = heads(normed(run(proj(h * d, "wq"), "wq"), "q_norm"), h)
+        q = run(proj(h * d, "wq"), "wq")
+        if self.decode and s == 1 and holds_projection(
+                x.shape[-1] * h * d * jnp.dtype(cfg.param_dtype).itemsize):
+            # an identity the compiler does not look through: the heads'
+            # layout below is made on q, and W_q is read as stored
+            q = jax.lax.optimization_barrier(q)
+        q = heads(normed(q, "q_norm"), h)
         # k and v are tagged for nn.remat's policy (the identity anywhere
         # else) here, at the width of the KV heads, before anything repeats
         # them to the query heads'; q, attention's output and its

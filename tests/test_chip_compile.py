@@ -10,6 +10,8 @@
    nothing that is not a chip run prints a result.
 """
 
+import collections
+import math
 import os
 import re
 import subprocess
@@ -146,6 +148,64 @@ def _assert_rows_written_by_the_kernel(text, layers, pool):
         leaf = re.escape("[" + ",".join(map(str, shape)) + "]")
         assert not re.search(rf"{leaf}[^\n]* while\(", text)
         assert not re.search(rf"= \w+{leaf}\S* dynamic-update-slice\(", text)
+
+
+def _assert_no_weight_relaid_in_hbm(text, params):
+    """The compiled step re-lays no whole weight HBM to HBM (ROADMAP S11(a)'s
+    recipe; PERF 6, 59.2, 59.3 and PR 60): no ``copy(`` whose result has the
+    element count of a two-dimensional parameter of 16 MB or more and no
+    ``S(1)`` in its layout. (A weight-shaped copy *with* ``S(1)`` lands in
+    VMEM and is the weight's one read.)"""
+    weights = {
+        s.size for s in jax.tree.leaves(params)
+        if s.ndim == 2 and s.size * s.dtype.itemsize >= 16e6
+    }
+    relaid = [
+        f"[{shape}]{layout}"
+        for shape, layout in re.findall(r"= \w+\[([\d,]+)\](\S*) copy\(", text)
+        if math.prod(map(int, shape.split(","))) in weights
+        and "S(1)" not in layout
+    ]
+    assert not relaid, relaid
+
+
+def _wq_readers(text):
+    """What reads each layer's ``W_q`` in the compiled step. A fusion that
+    takes the parameter as stored, or through its prefetch into VMEM in the
+    stored order (a ``copy-start`` / ``copy-done`` pair of the parameter's
+    own shape), is given as (result, ``dim_labels``) of the convolution
+    inside it; any other reader by its opcode alone (``("bitcast",)``: the
+    transposed view in front of a re-laying ``copy``)."""
+    entry = text[text.index("\nENTRY "):]
+    found = []
+    for name, stored in re.findall(
+        r"%(params__layer_\d+____attn____wq____base____kernel__\S*) = "
+        r"(bf16\[\d+,\d+\]\{1,0)\S* parameter\(", entry
+    ):
+        sources, stored = [name], re.escape(stored)
+        for start in re.findall(
+            rf"%(\S+) = \({stored}\S*S\(1\)\}}[^\n]* copy-start\(%{name}\)", entry
+        ):
+            sources += re.findall(
+                rf"%(\S+) = {stored}\S*S\(1\)\}} copy-done\(%{re.escape(start)}\)",
+                entry)
+        for source in sources:
+            for op, operands in re.findall(
+                rf"= \S+ ([\w\-]+)\(([^\n]*%{re.escape(source)}[,)][^\n]*)", entry
+            ):
+                if op == "copy-start":
+                    continue
+                if op != "fusion":
+                    found.append((op,))
+                    continue
+                called = re.search(r"calls=%([\w.\-]+)", operands).group(1)
+                body = re.search(
+                    rf"^%{re.escape(called)} \([^\n]*\{{\n(.*?)^\}}", text,
+                    re.M | re.S).group(1)
+                found += re.findall(
+                    r"= (bf16\[[\d,]+\])\S* convolution\([^\n]*"
+                    r"dim_labels=([\w>\-]+)", body)
+    return found
 
 
 # Llama-2-7B's heads at chip_smoke.py's length, and what one chip of the
@@ -370,6 +430,17 @@ def _decode_step(chip, cfg, slots):
     return model, params, pool, args, dict(active=_on(chip, active), **counted)
 
 
+def _compiled_decode(compiled, chip, cfg, slots):
+    """``_decode_step``'s program on a described chip, compiled once:
+    (model, params, pool, args, decode)."""
+    key = "decode", repr((cfg, slots))
+    if key not in compiled:
+        model, params, pool, args, kwargs = _decode_step(chip, cfg, slots)
+        decode = model._decode.lower(*args, **kwargs).compile()
+        compiled[key] = model, params, pool, args, decode
+    return compiled[key]
+
+
 def _serving_programs(compiled, chip, cfg, slots):
     """The serving engine's two programs for ``cfg`` on a described chip,
     compiled once: the prefill function over a 512-token prompt, and the
@@ -378,12 +449,12 @@ def _serving_programs(compiled, chip, cfg, slots):
     key = repr((cfg, slots))
     if key in compiled:
         return compiled[key]
-    model, params, pool, args, kwargs = _decode_step(chip, cfg, slots)
+    model, params, pool, args, decode = _compiled_decode(
+        compiled, chip, cfg, slots)
     prompt = jax.ShapeDtypeStruct((1, 512), jnp.int32)
     prefill = jax.jit(model._prefill_impl).lower(
         args[0], _on(chip, prompt)
     ).compile()
-    decode = model._decode.lower(*args, **kwargs).compile()
     compiled[key] = prefill, decode, params, pool
     return compiled[key]
 
@@ -427,6 +498,18 @@ def test_decode_model_prefill_and_decode_compile(
     )
 
 
+def _mistral_chat_step():
+    """The chat cells' decode step: (12 layers at Mistral's widths, 16 slots)."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    slots, h, hk, max_seq_len = MISTRAL_POOL
+    return LlamaConfig(
+        dim=4096, n_layers=12, n_heads=h, n_kv_heads=hk,
+        max_seq_len=max_seq_len, param_dtype=jnp.bfloat16,
+        vocab_size=32768, intermediate=14336, rope_theta=1e6,
+    ), slots
+
+
 def test_a_twelve_layer_decode_step_lowers_the_kernel_once(
     v5e_chip, native_kernels
 ):
@@ -434,14 +517,7 @@ def test_a_twelve_layer_decode_step_lowers_the_kernel_once(
     own, so the chat cells' program (12 layers, one shape) traces and
     lowers it once and calls that twelve times; as an op of the layer it
     was lowered twelve times over (+19 s of set-up, ROADMAP S11(c))."""
-    from ray_tpu.models.llama import LlamaConfig
-
-    slots, h, hk, max_seq_len = MISTRAL_POOL
-    cfg = LlamaConfig(
-        dim=4096, n_layers=12, n_heads=h, n_kv_heads=hk,
-        max_seq_len=max_seq_len, param_dtype=jnp.bfloat16,
-        vocab_size=32768, intermediate=14336, rope_theta=1e6,
-    )
+    cfg, slots = _mistral_chat_step()
     model, _, _, args, kwargs = _decode_step(v5e_chip, cfg, slots)
     text = model._decode.lower(*args, **kwargs).as_text()
     assert len(re.findall(r"func\.func private @_attend\b", text)) == 1
@@ -594,6 +670,16 @@ def test_falcon_h1_decode_step_carries_its_state_in_place(
     assert prefill.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
+def _solar_open2_step():
+    """`solar2-longgen-backlog`'s step a GQA and a KDA layer deep: (config,
+    32 slots)."""
+    from ray_tpu.models.solar_open2 import SolarOpen2Config
+
+    return SolarOpen2Config(
+        vocab_size=24576, n_layers=2, gqa_layers=(0,), experts_held=(0, 40),
+        param_dtype=jnp.bfloat16, max_seq_len=2048), 32
+
+
 def test_solar_open2_decode_step_moves_its_state_once(
     v5e_chip, native_kernels, compiled
 ):
@@ -605,12 +691,8 @@ def test_solar_open2_decode_step_moves_its_state_once(
     fresh row's state as zero inside the kernel), and no other instruction
     produces or copies a state; the expert kernel is handed 40 experts'
     weights."""
-    from ray_tpu.models.solar_open2 import SolarOpen2Config
-
-    cfg = SolarOpen2Config(
-        vocab_size=24576, n_layers=2, gqa_layers=(0,), experts_held=(0, 40),
-        param_dtype=jnp.bfloat16, max_seq_len=2048)
-    prefill, decode, params, pool = _serving_programs(compiled, v5e_chip, cfg, 32)
+    prefill, decode, params, pool = _serving_programs(
+        compiled, v5e_chip, *_solar_open2_step())
     text = decode.as_text()
     header = text.split("\n", 1)[0]
     aliases = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
@@ -624,6 +706,7 @@ def test_solar_open2_decode_step_moves_its_state_once(
     assert len(calls) == 1
     assert "state_kda" in calls[0].split(", ")[1]  # the parameter itself
     assert not re.search(rf"= {state}\S* copy\(", text)
+    _assert_no_weight_relaid_in_hbm(text, params)
     produced = re.findall(rf"^\s*%\S+ = {state}\S* (\w[\w\-]*)\(", entry, re.M)
     assert set(produced) <= {"get-tuple-element", "parameter", "bitcast"}, produced
     assert not re.search(rf"= \([^=]*{state}[^=]*\) fusion\(", entry)
@@ -691,6 +774,16 @@ def test_nemotron_h_decode_step_at_a_group_of_sixteen_and_two_matrices(
     assert prefill.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
+def _cohere2_moe_step():
+    """`commandaplus-rag-backlog`'s step one layer of each kind deep
+    (``layer_switch`` 2): (config, 24 slots)."""
+    from ray_tpu.models.cohere2_moe import Cohere2MoEConfig
+
+    return Cohere2MoEConfig(
+        vocab_size=32768, n_layers=2, layer_switch=2, experts_held=(0, 16),
+        param_dtype=jnp.bfloat16, max_seq_len=10240), 24
+
+
 def test_cohere2_moe_decode_step_reads_rings_and_model_wide_experts(
     v5e_chip, native_kernels, compiled
 ):
@@ -703,13 +796,8 @@ def test_cohere2_moe_decode_step_reads_rings_and_model_wide_experts(
     blocking of a 4096-wide inner had never met the chip's compiler); a
     2048-token prefill goes through the flash kernel in both layers, K/V
     heads as they are."""
-    from ray_tpu.models.cohere2_moe import Cohere2MoEConfig
-
-    cfg = Cohere2MoEConfig(
-        vocab_size=32768, n_layers=2, layer_switch=2, experts_held=(0, 16),
-        param_dtype=jnp.bfloat16, max_seq_len=10240)
-    model, params, pool, args, kwargs = _decode_step(v5e_chip, cfg, 24)
-    decode = model._decode.lower(*args, **kwargs).compile()
+    model, params, pool, args, decode = _compiled_decode(
+        compiled, v5e_chip, *_cohere2_moe_step())
     assert set(pool["layer_0"]["attn"]) == {
         "window_key", "window_value", "cache_index"}
     assert pool["layer_0"]["attn"]["window_key"].shape == (24, 8, 4096, 128)
@@ -732,6 +820,7 @@ def test_cohere2_moe_decode_step_reads_rings_and_model_wide_experts(
     assert "bf16[128,4096,4096]" not in text
     for ring in ("24,8,4096,128", "24,8,10240,128"):
         assert not re.search(rf"= bf16\[{ring}\]\S* copy\(", text)
+    _assert_no_weight_relaid_in_hbm(text, params)
     mem = decode.memory_analysis()
     assert mem.temp_size_in_bytes < 0.3e9
     assert _size(pool) <= mem.alias_size_in_bytes <= _size(pool) + 512 * len(aliases)
@@ -746,6 +835,55 @@ def test_cohere2_moe_decode_step_reads_rings_and_model_wide_experts(
     assert len(re.findall(r"%flash_fwd\S* = \(bf16\[128,2048,128\]", text)) == 2
     assert "f32[1,128,2048,2048]" not in text
     assert prefill.memory_analysis().temp_size_in_bytes < 0.8e9
+
+
+@pytest.mark.parametrize(
+    "step,held",
+    [(_cohere2_moe_step, True), (_solar_open2_step, True),
+     (_mistral_chat_step, False)],
+    ids=["commandaplus_128MiB", "solar2_64MiB", "mistral_32MiB"],
+)
+def test_a_query_projection_too_wide_to_stage_reads_its_weight_as_stored(
+    v5e_chip, native_kernels, compiled, step, held
+):
+    """Each side of ``llama.holds_projection`` as a compiled plan. The
+    decode kernel takes the query by K/V head and group, and the compiler
+    makes that order by re-laying ``W_q``. Mistral's 32 MiB it stages in
+    VMEM for the matmul anyway: those copies are the weights' one read
+    (``S(1)``; layer 0's have no step before them to hide behind), nothing
+    is held, and the plan is the one the chat cells have run since PR 31.
+    Solar-Open2's 64 MiB and Command A+'s 128 it does not stage: the copy
+    was an HBM round trip of the whole weight every step (0.31 and 1.63 ms,
+    PERF 6, 59.3), so there the projection's output is held as the matmul
+    made it: ``W_q`` is read once, as stored, by a plain convolution, and
+    the heads' order is made on the activation."""
+    cfg, slots = step()
+    _, params, _, _, decode = _compiled_decode(compiled, v5e_chip, cfg, slots)
+    text = decode.as_text()
+    h, hk, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    readers = _wq_readers(text)
+    copies = collections.Counter(
+        (shape, "S(1)" in layout) for shape, layout in re.findall(
+            r"= bf16\[(\d+,4096)\](\S*) copy\(", text)
+    )
+    if held:
+        _assert_no_weight_relaid_in_hbm(text, params)
+        assert readers and set(readers) == {
+            (f"bf16[{slots},{h * d}]", "bf_io->bf")}
+        assert not re.search(
+            rf"= bf16\[{slots},{hk},{h // hk},{d}\]\S* convolution\(", text)
+        # k and v are left alone: read into VMEM, once
+        assert copies == {("1024,4096", True): 2 * len(readers)}
+    else:
+        assert copies == {
+            ("4096,4096", True): 11, ("4096,4096", False): 1,
+            ("1024,4096", True): 22, ("1024,4096", False): 2,
+        }
+        # the projection's result comes out by head: the weight is re-laid
+        assert len(re.findall(
+            rf"= bf16\[{slots},{h},{d}\]\S* convolution\(", text)) == cfg.n_layers
+        assert readers == [("bitcast",)] * cfg.n_layers
+        assert text.count(" slice-start(") == 88  # its prefetch plan (S11(e))
 
 
 # ---------------------------------------------------------------------------

@@ -685,6 +685,56 @@ def test_prefill_then_decode_is_the_prompt_stepped_a_token_at_a_time(
 
 
 @pytest.mark.parametrize(
+    "heads,held",
+    [(dict(n_heads=16, n_kv_heads=1, attn_head_dim=32), True),
+     (dict(n_heads=4, n_kv_heads=1), False)],
+    ids=["query_four_times_dim_held", "query_of_dim_left_alone"],
+)
+def test_a_decode_step_that_holds_its_query_projection_is_the_step_that_does_not(
+    heads, held, monkeypatch
+):
+    """``llama.holds_projection`` decides from ``W_q``'s bytes whether a
+    decode step keeps the projection's output as the matmul made it (what
+    keeps the chip's compiler from re-laying the weight every step: PERF 6,
+    PR 60). With the limit put between two toys' weights (float32, dim 128:
+    256 KiB at 16 heads of 32 over one KV head, 64 KiB at 4), the wide one's
+    step carries the hold in every layer and the narrow one's none; either
+    way the step's logits and rows are bit for bit those of the same module
+    with the rule off, and the prompt's last position as a whole prefill
+    has it."""
+    from ray_tpu.models import llama
+
+    def step():  # a function of its own: traced anew under each patch
+        return lambda *a: model._decode_impl(*a)
+
+    def holds(*args):
+        return str(jax.make_jaxpr(step())(*args)).count("optimization_barrier")
+
+    monkeypatch.setattr(llama, "_UNSTAGED_WEIGHT_BYTES", 128 * 1024)
+    model, params = _decode_model(**heads)
+    prompt = _tokens(22, seed=7)
+    prefill = jax.jit(model._prefill_impl)
+    _, cache = prefill(params, prompt[:, :-1])
+    args = (params, cache, prompt[:, -1:])
+    assert holds(*args) == (2 if held else 0)  # a layer each
+    # a prefill is no decode step: its projection is never held
+    assert "optimization_barrier" not in str(
+        jax.make_jaxpr(model._prefill_impl)(params, prompt))
+    logits, row = jax.jit(step())(*args)
+
+    monkeypatch.setattr(llama, "holds_projection", lambda weight_bytes: False)
+    assert holds(*args) == 0
+    free_logits, free_row = jax.jit(step())(*args)
+    np.testing.assert_array_equal(_f32(logits), _f32(free_logits))
+    for got, want in zip(jax.tree.leaves(row), jax.tree.leaves(free_row)):
+        np.testing.assert_array_equal(_f32(got), _f32(want))
+
+    whole_logits, _ = prefill(params, prompt)
+    assert np.abs(_f32(whole_logits)).max() > 10 * WIDTH_TOL["bf16"]
+    assert np.abs(_f32(logits) - _f32(whole_logits)).max() < WIDTH_TOL["bf16"]
+
+
+@pytest.mark.parametrize(
     "dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"],
 )
 def test_suffix_prefill_scores_the_cache_and_equals_a_whole_one(dtype):
