@@ -48,7 +48,7 @@ class AdapterConfig:
 class LLMConfig:
     model_id: str = "llama-tiny"
     # model construction: either a models.llama config name or kwargs
-    model_family: str = "llama"  # "llama" | "moe" | "deepseek" | "falcon_h1" | "solar_open2" | "motif" | "nemotron_h" | "cohere2_moe"
+    model_family: str = "llama"  # "llama" | "moe" | "deepseek" | "falcon_h1" | "solar_open2" | "motif" | "nemotron_h" | "cohere2_moe" | "smallthinker"
     model_kwargs: Dict[str, Any] = field(default_factory=dict)
     max_seq_len: int = 512
     max_batch_size: int = 8
@@ -246,6 +246,8 @@ class LLMConfig:
             from ..models.nemotron_h import NemotronHConfig as config_type
         elif self.model_family == "cohere2_moe":
             from ..models.cohere2_moe import Cohere2MoEConfig as config_type
+        elif self.model_family == "smallthinker":
+            from ..models.smallthinker import SmallThinkerConfig as config_type
         else:
             raise ValueError(f"unknown model family {self.model_family!r}")
         kwargs = dict(self.model_kwargs)

@@ -14,6 +14,9 @@ Mamba-2 mixer, an attention or a latent expert layer alone; serving only),
 Cohere2-MoE-shaped transformer (a parallel block on one LayerNorm, plain K/V
 heads in a ring three layers in four, experts as wide as the model of which
 a share may be held, shared experts averaged, a tied head; serving only),
+SmallThinker-shaped transformer (a router that reads the attention's input,
+ReGLU experts every layer, a full layer without positions then three rotary
+window layers a period; serving only),
 ViT (vision encoder).
 The reference delegates model execution to torch/vLLM; this framework owns
 it.
@@ -81,7 +84,8 @@ type of the model config. What the engine asks of a family:
     ``models/cohere2_moe.py`` ``llama.Attention``'s ``window_key`` /
     ``window_value`` ``(batch, kv_heads, 4096, head_dim)`` in three layers
     of four (``LlamaConfig.window``), its ``cached_key`` / ``cached_value``
-    in the fourth
+    in the fourth; ``models/smallthinker.py`` the same two pairs of names
+    at 4 K/V heads, the full layer *first* in each four
 - a step of one token a row (``seq == 1`` against a cache) attends each
   row up to its own position; a longer ``seq`` against a cache is a chunk
   behind a cached prefix, row ``r``'s token ``i`` at ``index[r] + i``, and
@@ -139,6 +143,17 @@ take the kernel (``llama.prefills_through_kernel``). Its own are a
 LayerNorm, the parallel block and a tied head (one ``lm_head`` of ``(vocab,
 dim)``); the engine, the cache manager and the expert kernel changed
 nowhere.
+``smallthinker`` (PR 61) is the first whose routing does not depend on its
+layer's attention: ``MoEFFN`` became two steps (``route`` on the
+attention's input, the experts on the post-attention norm's output; every
+other family's one call is the two in a row), ``MoEConfig`` gained the
+``expert_activation`` ``"reglu"`` (``ops/moe_experts.py``'s SwiGLU kernel
+under ``relu``), and the sort that follows from the routing alone has a
+scope of its own (``moe.sort``, ``parallel/expert.py``). It holds every
+expert, and says so as the range ``(0, n_experts)`` where a check wants each
+row's last choice kept. Window layers as ``cohere2_moe``'s (rotate-half
+pairs), at 7 query heads a K/V head; the engine and the cache manager
+changed nowhere.
 """
 
 from __future__ import annotations
@@ -276,6 +291,30 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "for more than one new position a row (ROADMAP R4)"
         ),
     },
+    "smallthinker": {
+        "adapters": (
+            "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
+            "wq/wk/wv/wo at dim = n_heads x head_dim (here 2560 against "
+            "28 x 128) and has no placement for expert weights"
+        ),
+        "draft_model": (
+            "a rejected draft run cannot be undone by moving an index "
+            "back: a window layer's ring has overwritten the positions the "
+            "run would return to, and the expert counters count plain "
+            "decode steps"
+        ),
+        "mesh": (
+            "parallel/plan.py has no partition rule for a ring leaf "
+            "(models.WINDOW) or for the (expert, ...) weights, and the "
+            "grouped expert kernel has no shard_map form yet (ROADMAP R1: "
+            "ep rules)"
+        ),
+        "prefill_chunk": (
+            "a chunk behind a cached prefix would have to read a window "
+            "layer's ring while it overwrites it: the ring has no form "
+            "for more than one new position a row (ROADMAP R4)"
+        ),
+    },
     "nemotron_h": {
         "adapters": (
             "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
@@ -345,9 +384,11 @@ def refusals(family: str) -> Dict[str, str]:
 def _family(model_config):
     from . import (
         cohere2_moe, deepseek, falcon_h1, llama, moe, motif, nemotron_h,
-        solar_open2,
+        smallthinker, solar_open2,
     )
 
+    if isinstance(model_config, smallthinker.SmallThinkerConfig):
+        return smallthinker
     if isinstance(model_config, cohere2_moe.Cohere2MoEConfig):
         return cohere2_moe
     if isinstance(model_config, nemotron_h.NemotronHConfig):

@@ -86,7 +86,8 @@ class MoEConfig:
     # coefficients in a ``poly`` parameter: three weights, and a bias
     # clipped to +-``polynorm_bias_clamp``, all times ``polynorm_scale``
     # or Nemotron-H's ungated "relu2" (``down(relu(up x)^2)``: an expert
-    # has two matrices, no ``w_gate``)
+    # has two matrices, no ``w_gate``), or SmallThinker's "reglu"
+    # (``relu(gate) * up``: SwiGLU's three matrices under another gate)
     expert_activation: str = "swiglu"
     polynorm_scale: float = 0.5
     polynorm_bias_clamp: float = 0.5
@@ -98,17 +99,20 @@ class MoEConfig:
     latent_dim: Optional[int] = None
 
     def __post_init__(self):
-        if self.expert_activation not in ("swiglu", "poly_norm", "relu2"):
+        if self.expert_activation not in (
+                "swiglu", "poly_norm", "relu2", "reglu"):
             raise ValueError(
                 f"MoEConfig: unknown expert_activation "
                 f"{self.expert_activation!r}"
             )
         if not self.dropless and (
-                self.expert_activation != "swiglu" or self.latent_dim):
+                self.expert_activation not in ("swiglu", "reglu")
+                or self.latent_dim):
             raise ValueError(
                 f"MoEConfig: expert_activation {self.expert_activation!r}"
                 f" with latent_dim {self.latent_dim} needs dropless=True: "
-                "the capacity path's experts are SwiGLU on the model's width"
+                "the capacity path's experts are gated (SwiGLU or ReGLU) on "
+                "the model's width"
             )
         if self.experts_held is not None:
             first, stop = self.experts_held
@@ -218,10 +222,10 @@ def poly_norm(z, c, eps: float):
     return c[0] * normed(z2 * z) + c[1] * normed(z2) + c[2] * normed(z) + c[3]
 
 
-def _latent(cfg: MoEConfig, features: int, name: str):
+def _latent(cfg: MoEConfig, features: int):
     """One of a latent expert layer's two shared projections."""
     return nn.DenseGeneral(
-        features=features, use_bias=False, name=name, dtype=cfg.dtype,
+        features=features, use_bias=False, dtype=cfg.dtype,
         param_dtype=cfg.param_dtype,
         kernel_init=nn.with_logical_partitioning(
             nn.initializers.lecun_normal(), ("embed", "mlp")),
@@ -231,17 +235,21 @@ def _latent(cfg: MoEConfig, features: int, name: str):
 class MoEFFN(nn.Module):
     """Top-k routed expert FFN (SwiGLU experts on the model's width unless
     the config says otherwise). Router aux loss is emitted through the
-    ``losses`` collection (sown) for the trainer to add."""
+    ``losses`` collection (sown) for the trainer to add.
+
+    Two steps, ``route`` and the experts. ``__call__(x)`` takes both on
+    ``x``. A family whose router reads another tensor than its experts
+    (SmallThinker's reads the *attention's* input) calls ``route`` on that
+    tensor itself, where in its layer it likes, and hands the result on:
+    ``moe(x, moe.route(other))``."""
 
     config: MoEConfig
 
-    @nn.compact
-    def __call__(self, x):  # (b, s, d)
+    def setup(self):
         cfg = self.config
-        b, s, d = x.shape
-        tokens = x.reshape(b * s, d)
-
-        router_w = self.param(
+        # (in the order they were always made: a parameter's key counts
+        # the parameters made before it)
+        self.router = self.param(
             "router",
             nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(), ("embed", "expert")
@@ -249,37 +257,14 @@ class MoEFFN(nn.Module):
             (cfg.dim, cfg.n_experts),
             cfg.param_dtype,
         )
-        with jax.named_scope("moe.route"):
-            # full f32 products: on a TPU a default-precision f32 matmul
-            # rounds its operands to bf16, which is enough to swap the
-            # k-th and (k+1)-th expert; the product is tiny
-            logits = jnp.dot(
-                tokens.astype(jnp.float32), router_w.astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST,
-            )
-            if cfg.dropless:
-                bias = self.param(
-                    "router_bias",
-                    nn.with_logical_partitioning(
-                        nn.initializers.zeros_init(), ("expert",)
-                    ),
-                    (cfg.n_experts,),
-                    jnp.float32,
-                ) if cfg.router_bias else None
-                weights, chosen, aux = top_k_routing(
-                    logits, cfg.experts_per_token, cfg.norm_topk_prob,
-                    cfg.router_scoring, bias, cfg.routed_scale,
-                )
-                self.sow(ROUTING, "experts", chosen)
-            else:
-                capacity = expert_capacity(
-                    b * s, cfg.n_experts, cfg.capacity_factor,
-                    cfg.experts_per_token,
-                )
-                dispatch, combine, aux = top_k_gating(
-                    logits, capacity, k=cfg.experts_per_token
-                )
-        self.sow("losses", "router_aux", cfg.router_aux_weight * aux)
+        self.router_bias = self.param(
+            "router_bias",
+            nn.with_logical_partitioning(
+                nn.initializers.zeros_init(), ("expert",)
+            ),
+            (cfg.n_experts,),
+            jnp.float32,
+        ) if cfg.dropless and cfg.router_bias else None
 
         # fan-in of one expert's matrix, not of all of them together: with
         # the expert axis counted in, every matrix is sqrt(n_experts) too
@@ -294,41 +279,89 @@ class MoEFFN(nn.Module):
                 (cfg.n_experts_held,) + shape, cfg.param_dtype)
 
         # (an ungated expert has no gate matrix)
-        w_gate = None if cfg.expert_activation == "relu2" else expert_matrix(
-            "w_gate", (width, cfg.intermediate), ("expert", "embed", "mlp"))
-        w_up = expert_matrix(
+        self.w_gate = None if cfg.expert_activation == "relu2" else (
+            expert_matrix(
+                "w_gate", (width, cfg.intermediate), ("expert", "embed", "mlp")))
+        self.w_up = expert_matrix(
             "w_up", (width, cfg.intermediate), ("expert", "embed", "mlp"))
-        w_down = expert_matrix(
+        self.w_down = expert_matrix(
             "w_down", (cfg.intermediate, width), ("expert", "mlp", "embed"))
+        self.poly = self.param(
+            "poly",
+            nn.with_logical_partitioning(poly_init, ("expert", None)),
+            (cfg.n_experts_held, 4),
+            jnp.float32,
+        ) if cfg.expert_activation == "poly_norm" else None
+        if cfg.latent_dim:
+            self.w_latent_in = _latent(cfg, cfg.latent_dim)
+            self.w_latent_out = _latent(cfg, cfg.dim)
+
+    def route(self, x):  # (b, s, d)
+        """Each token's experts and their weights, from ``x``: dropless
+        ``(weights (b*s, k) f32, chosen (b*s, k) int32)``, sown into
+        ``ROUTING``; with a capacity ``(dispatch, combine)``."""
+        cfg = self.config
+        b, s, d = x.shape
+        with jax.named_scope("moe.route"):
+            # full f32 products: on a TPU a default-precision f32 matmul
+            # rounds its operands to bf16, which is enough to swap the
+            # k-th and (k+1)-th expert; the product is tiny
+            logits = jnp.dot(
+                x.reshape(b * s, d).astype(jnp.float32),
+                self.router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            if cfg.dropless:
+                weights, chosen, aux = top_k_routing(
+                    logits, cfg.experts_per_token, cfg.norm_topk_prob,
+                    cfg.router_scoring, self.router_bias, cfg.routed_scale,
+                )
+                self.sow(ROUTING, "experts", chosen)
+                routing = weights, chosen
+            else:
+                capacity = expert_capacity(
+                    b * s, cfg.n_experts, cfg.capacity_factor,
+                    cfg.experts_per_token,
+                )
+                dispatch, combine, aux = top_k_gating(
+                    logits, capacity, k=cfg.experts_per_token
+                )
+                routing = dispatch, combine
+        self.sow("losses", "router_aux", cfg.router_aux_weight * aux)
+        return routing
+
+    def __call__(self, x, routing=None):  # (b, s, d)
+        cfg = self.config
+        b, s, d = x.shape
+        if routing is None:
+            routing = self.route(x)
+        tokens = x.reshape(b * s, d)
+        w_gate, w_up, w_down = self.w_gate, self.w_up, self.w_down
 
         def experts(inp):  # (E, C, d) -> (E, C, d)
             gate = jnp.einsum("ecd,edf->ecf", inp, w_gate.astype(inp.dtype))
             up = jnp.einsum("ecd,edf->ecf", inp, w_up.astype(inp.dtype))
+            gated = nn.relu if cfg.expert_activation == "reglu" else nn.silu
             return jnp.einsum(
-                "ecf,efd->ecd", nn.silu(gate) * up, w_down.astype(inp.dtype)
+                "ecf,efd->ecd", gated(gate) * up, w_down.astype(inp.dtype)
             )
 
         activation = {}
         if cfg.expert_activation == "poly_norm":
-            poly = self.param(
-                "poly",
-                nn.with_logical_partitioning(poly_init, ("expert", None)),
-                (cfg.n_experts_held, 4),
-                jnp.float32,
-            )
             activation = dict(
                 activation="poly_norm", eps=cfg.norm_eps,
                 poly=poly_coefficients(
-                    poly, cfg.polynorm_scale, cfg.polynorm_bias_clamp),
+                    self.poly, cfg.polynorm_scale, cfg.polynorm_bias_clamp),
             )
-        elif cfg.expert_activation == "relu2":
-            activation = dict(activation="relu2")
+        elif cfg.expert_activation != "swiglu":
+            activation = dict(activation=cfg.expert_activation)
 
         if cfg.latent_dim:
             with jax.named_scope("moe.latent"):
-                tokens = _latent(cfg, cfg.latent_dim, "w_latent_in")(tokens)
+                tokens = self.w_latent_in(tokens)
         with jax.named_scope("moe.experts"):
             if cfg.dropless:
+                weights, chosen = routing
                 out = moe_apply_dropless(
                     tokens, weights, chosen,
                     None if w_gate is None else w_gate.astype(tokens.dtype),
@@ -336,10 +369,10 @@ class MoEFFN(nn.Module):
                     held=cfg.experts_held, **activation,
                 )
             else:
-                out = moe_apply_gspmd(tokens, dispatch, combine, experts)
+                out = moe_apply_gspmd(tokens, *routing, experts)
         if cfg.latent_dim:
             with jax.named_scope("moe.latent"):
-                out = _latent(cfg, cfg.dim, "w_latent_out")(out)
+                out = self.w_latent_out(out)
         return out.reshape(b, s, d)
 
 

@@ -46,6 +46,19 @@ the f blocks, ``relu(.)^2`` of an up block in VMEM, the matching down rows,
 each matrix read once. Two matrices in flight where SwiGLU has three, so a
 block may be half as large again in the same VMEM (``block_f(matrices=2)``).
 ``w_gate`` is None.
+
+``"reglu"`` (SmallThinker: ``y[r] = down_e(relu(gate_e x[r]) * up_e
+x[r])``) is the SwiGLU kernel under another gate function and nothing else:
+three matrices a visit, one sweep, the same blocks.
+
+**Past one row tile** a touched expert is no longer read once. The visits
+are (row tile, expert) pairs, so an expert whose rows straddle the edge of
+a tile has a visit in each and its three matrices are fetched for each: at
+64 rows of a 6-of-64 router a step sorts 384 assignments into three tiles
+of 128 and, with ~64 groups of ~6 rows, two experts a layer lie on an edge
+and are read twice (~3% more bytes than the touched experts'). A roofline
+that counts each touched expert once counts the least work, not this
+kernel's: its share falls by what the second reads cost.
 """
 
 from __future__ import annotations
@@ -66,11 +79,16 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
 # HBM's peak) and 10.7 / 10.2 / 9.8 ms for a 128-token prefill's 1024. At
 # 4 MB an OLMoE expert's matrix is one block.
 _BLOCK_BYTES = 4 * 1024 * 1024
-# rows of a tile: a decode step's 64 assignments are one tile, so each
-# touched expert is visited (and read) exactly once; a prefill's thousands
-# of rows take tiles of 128, the MXU's height (256 measured the same)
+# rows of a tile: a decode step of up to 128 assignments (OLMoE's 64) is
+# one tile, so each touched expert is visited (and read) exactly once;
+# more rows take tiles of 128, the MXU's height (256 measured the same),
+# and an expert whose rows lie on a tile's edge is visited, and read, once
+# a tile: a prefill's thousands of rows, and a decode step of 64 rows x 6
+# experts (384 assignments, three tiles: module docstring)
 _TILE_ROWS = 128
 _ROW_ALIGN = 16  # bf16 sublane packing
+# the gate function of a three-matrix expert, by ``activation``
+_GATES = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
@@ -149,11 +167,11 @@ def _sweep(offsets_ref, group_ids_ref, tile_ids_ref, o_ref, acc_ref, tm,
 
 def _kernel(
     offsets_ref, group_ids_ref, tile_ids_ref,
-    x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *, tm: int,
+    x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *, tm: int, gate,
 ):
     def down_product():
         x = x_ref[...]  # (tm, d)
-        hidden = jax.nn.silu(_dot(x, wg_ref[...])) * _dot(x, wu_ref[...])
+        hidden = gate(_dot(x, wg_ref[...])) * _dot(x, wu_ref[...])
         return _dot(hidden.astype(wd_ref.dtype), wd_ref[...])
 
     _sweep(offsets_ref, group_ids_ref, tile_ids_ref, o_ref, acc_ref, tm,
@@ -284,13 +302,14 @@ def moe_experts(x, w_gate, w_up, w_down, group_sizes,
     assignments to experts held elsewhere there). Returns ``(m, d)``
     f32. ``activation="poly_norm"`` takes ``poly (E, 4)``, an expert's
     ``c1 .. c4`` (module docstring), and ``eps``; ``activation="relu2"``
-    takes no gate (``w_gate`` None)."""
+    takes no gate (``w_gate`` None); ``activation="reglu"`` is SwiGLU's
+    call under ``relu``."""
     m, d = x.shape
     n_experts, _, f = w_up.shape
     tm = tile_rows(m)
     if m % tm:
         raise ValueError(f"{m} rows are not whole tiles of {tm}")
-    if activation not in ("swiglu", "poly_norm", "relu2"):
+    if activation not in ("swiglu", "poly_norm", "relu2", "reglu"):
         raise ValueError(f"moe_experts: unknown activation {activation!r}")
     if (w_gate is None) != (activation == "relu2"):
         raise ValueError(
@@ -320,8 +339,10 @@ def moe_experts(x, w_gate, w_up, w_down, group_sizes,
 
     # (the gate's matrix and its block are what "relu2" lacks)
     weights = (w_up, w_down) if ungated else (w_gate, w_up, w_down)
+    kernel = functools.partial(_relu2_kernel, tm=tm) if ungated else (
+        functools.partial(_kernel, tm=tm, gate=_GATES[activation]))
     return pl.pallas_call(
-        functools.partial(_relu2_kernel if ungated else _kernel, tm=tm),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(visits, f // tf),

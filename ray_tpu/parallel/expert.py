@@ -238,7 +238,8 @@ def moe_apply_dropless(
 
     ``activation`` / ``poly`` / ``eps``: the expert's activation as
     ``ops/moe_experts.moe_experts`` takes it (SwiGLU unless said; an
-    ungated ``"relu2"`` expert has no ``w_gate``)."""
+    ungated ``"relu2"`` expert has no ``w_gate``; ``"reglu"`` is SwiGLU's
+    three matrices under ``relu``)."""
     from ..ops.moe_experts import moe_experts, tile_rows
 
     tokens, k = experts.shape
@@ -246,35 +247,40 @@ def moe_apply_dropless(
     n = tokens * k
     tm = tile_rows(n)
     padded = -(-n // tm) * tm
-    flat = experts.reshape(n)
-    if held is not None:
-        first, stop = held
-        if stop - first != n_experts:
-            raise ValueError(
-                f"moe_apply_dropless: {n_experts} experts' weights for the "
-                f"held range {held}"
-            )
-        here = (flat >= first) & (flat < stop)
-        # a key past the last held expert: behind every held assignment
-        flat = jnp.where(here, flat - first, n_experts)
-        outside = n_experts
-    else:
-        outside = n_experts - 1
-    if padded != n:
-        flat = jnp.concatenate(
-            [flat, jnp.full((padded - n,), outside, jnp.int32)]
+    if held is not None and held[1] - held[0] != n_experts:
+        raise ValueError(
+            f"moe_apply_dropless: {n_experts} experts' weights for the "
+            f"held range {held}"
         )
-    order = jnp.argsort(flat, stable=True)
-    # an assignment's token: row i of ``flat`` belongs to token i // k
-    # (padding rows read the last token; nothing reads their result)
-    source = jnp.minimum(order // k, tokens - 1)
-    # (a key past the last expert is out of bounds here, and dropped)
-    group_sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    # what follows from the routing alone, whatever the rows hold (a
+    # scope of its own inside the caller's: a trace can tell it from the
+    # rows' gather and the kernel)
+    with jax.named_scope("moe.sort"):
+        flat = experts.reshape(n)
+        if held is not None:
+            first, stop = held
+            here = (flat >= first) & (flat < stop)
+            # a key past the last held expert: behind every held assignment
+            flat = jnp.where(here, flat - first, n_experts)
+            outside = n_experts
+        else:
+            outside = n_experts - 1
+        if padded != n:
+            flat = jnp.concatenate(
+                [flat, jnp.full((padded - n,), outside, jnp.int32)]
+            )
+        order = jnp.argsort(flat, stable=True)
+        # an assignment's token: row i of ``flat`` belongs to token i // k
+        # (padding rows read the last token; nothing reads their result)
+        source = jnp.minimum(order // k, tokens - 1)
+        # (a key past the last expert is out of bounds here, and dropped)
+        group_sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
     # (SwiGLU's call is the one it always was: no argument it does not take)
     extra = {} if activation == "swiglu" else dict(
         activation=activation, poly=poly, eps=eps)
     y = moe_experts(x[source], w_gate, w_up, w_down, group_sizes, **extra)
-    back = jnp.argsort(order)[:n]  # sorted row of each assignment
+    with jax.named_scope("moe.sort"):
+        back = jnp.argsort(order)[:n]  # sorted row of each assignment
     y = y[back].reshape(tokens, k, -1)
     if held is not None:
         # a row no group owns was never written: whatever the buffer held

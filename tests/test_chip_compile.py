@@ -261,6 +261,47 @@ def test_flash_attention_serving_forward_compiles_under_a_window(
     assert not re.search(r"= bf16\[1,128,8192,128\]\S* (broadcast|copy)\(", text)
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["band", "causal"])
+def test_flash_forward_compiles_at_a_group_of_seven(
+    v5e_chip, native_kernels, window
+):
+    """`smallthinker-chat-mixed-backlog`'s longest prefill a layer: 28 query
+    heads on 4 K/V heads as they are, 4096 tokens, under the 4096 band in a
+    window layer and causal in a full one."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 28, 4096, 128), jnp.bfloat16, sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 4096, 128), jnp.bfloat16, sharding=v5e_chip)
+    text = _compile(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, forward_only=True),
+        q, kv, kv)
+    assert "tpu_custom_call" in text and "flash_fwd" in text
+    assert "bf16[28,4096,128]" in text and "bf16[4,4096,128]" in text
+    assert not re.search(r"= bf16\[1,28,4096,128\]\S* (broadcast|copy)\(", text)
+
+
+def test_moe_experts_compiles_under_relu_at_three_row_tiles(
+    v5e_chip, native_kernels
+):
+    """`smallthinker-chat-mixed-backlog`'s expert kernel a layer: 64 rows x
+    6 experts = 384 sorted assignments (three row tiles) through 64 ReGLU
+    experts of 2560 x 768, whose matrix (3.9 MB) is one block."""
+    from ray_tpu.ops import moe_experts as kernel
+
+    def on(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    assert kernel.block_f(2560, 768, jnp.bfloat16) == 768
+    assert kernel.tile_rows(384) == 128
+    text = _compile(
+        lambda *a: kernel.moe_experts(*a, activation="reglu"),
+        on((384, 2560)), on((64, 2560, 768)), on((64, 2560, 768)),
+        on((64, 768, 2560)), on((64,), jnp.int32))
+    assert re.search(r"%moe_experts\S* = f32\[384,2560\]", text)
+    assert "tpu_custom_call" in text
+
+
 def test_rmsnorm_compiles(v5e_chip, native_kernels):
     from ray_tpu.ops.rmsnorm import rmsnorm
 
@@ -277,11 +318,17 @@ LLAMA2_POOL = (8, 32, 32, 2048)
 # 24 slots): a window layer's ring of 4096 and a full layer's row of 10240
 C2MOE_RING = (24, 128, 8, 4096)
 C2MOE_ROW = (24, 128, 8, 10240)
+# `smallthinker-chat-mixed-backlog`'s (SmallThinker: 28 query heads over 4 KV
+# heads, a group of 7, 64 slots): a ring of 4096 and a row of 5120
+STMOE_RING = (64, 28, 4, 4096)
+STMOE_ROW = (64, 28, 4, 5120)
 
 
 @pytest.mark.parametrize(
-    "pool", [MISTRAL_POOL, LLAMA2_POOL, C2MOE_RING, C2MOE_ROW],
-    ids=["mistral", "llama2", "c2moe_ring", "c2moe_row"])
+    "pool",
+    [MISTRAL_POOL, LLAMA2_POOL, C2MOE_RING, C2MOE_ROW, STMOE_RING, STMOE_ROW],
+    ids=["mistral", "llama2", "c2moe_ring", "c2moe_row", "stmoe_ring",
+         "stmoe_row"])
 def test_decode_attention_compiles(v5e_chip, native_kernels, pool):
     from ray_tpu.ops.decode_attention import decode_attention
 
@@ -297,8 +344,8 @@ def test_decode_attention_compiles(v5e_chip, native_kernels, pool):
 @pytest.mark.parametrize(
     "leaves",
     [[(16, 8, 4096, 128)] * 2, [(8, 16, 4096, 128)] * 2,
-     [(24, 1, 8192, 512), (24, 1, 8192, 64)]],
-    ids=["mistral", "olmoe", "moonlight"],
+     [(24, 1, 8192, 512), (24, 1, 8192, 64)], [(64, 4, 4096, 128)] * 2],
+    ids=["mistral", "olmoe", "moonlight", "stmoe_ring"],
 )
 def test_kv_row_write_compiles_in_place(v5e_chip, native_kernels, leaves):
     """A layer's leaves at each cell's shapes: one kernel, each leaf
@@ -835,6 +882,73 @@ def test_cohere2_moe_decode_step_reads_rings_and_model_wide_experts(
     assert len(re.findall(r"%flash_fwd\S* = \(bf16\[128,2048,128\]", text)) == 2
     assert "f32[1,128,2048,2048]" not in text
     assert prefill.memory_analysis().temp_size_in_bytes < 0.8e9
+
+
+def _smallthinker_step():
+    """`smallthinker-chat-mixed-backlog`'s step one layer of each kind deep
+    (``layer_period`` 2: the full layer first): (config, 64 slots)."""
+    from ray_tpu.models.smallthinker import SmallThinkerConfig
+
+    return SmallThinkerConfig(
+        n_layers=2, layer_period=2, experts_held=(0, 64),
+        param_dtype=jnp.bfloat16, max_seq_len=5120), 64
+
+
+def test_smallthinker_decode_step_routes_ahead_and_reads_every_expert(
+    v5e_chip, native_kernels, compiled
+):
+    """`smallthinker-chat-mixed-backlog`'s programs at its widths, one layer
+    of each kind, 64 slots x 5120, all 64 experts held, the whole
+    vocabulary: the full layer keeps two rows of 5120 and the window layer
+    two rings of 4096, each aliased to its successor and written by the row
+    kernel; the decode kernel takes 28 query heads as 4 groups of 7 (in 8 sublanes) on both;
+    the expert kernel is handed 384 sorted rows (three tiles) and three
+    matrices of 64 experts of 2560 x 768; the router's float32 product
+    carries the family's scope around ``MoEFFN``'s own; no weight is re-laid
+    in HBM; a 4096-token prefill goes through the flash kernel in both
+    layers, K/V heads as they are."""
+    model, params, pool, args, decode = _compiled_decode(
+        compiled, v5e_chip, *_smallthinker_step())
+    assert set(pool["layer_1"]["attn"]) == {
+        "window_key", "window_value", "cache_index"}
+    assert pool["layer_0"]["attn"]["cached_key"].shape == (64, 4, 5120, 128)
+    assert pool["layer_1"]["attn"]["window_key"].shape == (64, 4, 4096, 128)
+    text = decode.as_text()
+    header = text.split("\n", 1)[0]
+    aliases = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
+    assert len(aliases) == len(jax.tree.leaves(pool)) == 2 * 3
+    entry = text[text.index("\nENTRY "):]
+    # (a group of 7 rides in 8 sublanes)
+    assert len(re.findall(r"%decode_attention\S* = bf16\[64,4,8,128\]", entry)) == 2
+    assert len(re.findall(r"%kv_row_write\S* = \(bf16\[64,4,4096,128\]", entry)) == 1
+    assert len(re.findall(r"%kv_row_write\S* = \(bf16\[64,4,5120,128\]", entry)) == 1
+    calls = re.findall(
+        r"%moe_experts\S* = f32\[384,2560\]\S* custom-call\(([^)]*)\)", entry)
+    assert len(calls) == 2
+    assert [o.split("moe____")[-1][:6] for o in calls[0].split(", ")[-3:]] == [
+        "w_gate", "w_up__", "w_down"]
+    for scope in ("sthink.route/moe.route", "moe.experts/moe.sort",
+                  "sthink.attn_full", "sthink.attn_window", "sthink.norm"):
+        assert scope in text, scope
+    for kept in ("64,4,4096,128", "64,4,5120,128"):
+        assert not re.search(rf"= bf16\[{kept}\]\S* copy\(", text)
+    _assert_no_weight_relaid_in_hbm(text, params)
+    mem = decode.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert _size(pool) <= mem.alias_size_in_bytes <= _size(pool) + 512 * len(aliases)
+    # 2 x 0.778 GB of embedding and head; a layer 42.3 MB + 64 x 11.8 MB
+    assert 3.14e9 < _size(params) < 3.16e9
+    # 64 rows x (2 x 4.19 MB of rings + 2 x 5.24 of K and V)
+    assert 1.2e9 < _size(pool) < 1.22e9
+    prompt = jax.ShapeDtypeStruct((1, 4096), jnp.int32)
+    prefill = jax.jit(model._prefill_impl).lower(
+        args[0], _on(v5e_chip, prompt)).compile()
+    text = prefill.as_text()
+    assert len(re.findall(r"%flash_fwd\S* = \(bf16\[28,4096,128\]", text)) == 2
+    assert "f32[1,28,4096,4096]" not in text
+    # 1.24 GB of them every position's logits (ROADMAP S4: a head over the
+    # last position only)
+    assert 1.2e9 < prefill.memory_analysis().temp_size_in_bytes < 1.6e9
 
 
 @pytest.mark.parametrize(

@@ -63,6 +63,33 @@ def _one_step(want, dtype):
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
+@pytest.mark.parametrize("kept", ["ring", "row"])
+def test_a_group_of_seven_over_a_ring_and_a_full_row(kept, dtype, monkeypatch):
+    """28 query heads on 4 K/V heads (SmallThinker's: a group that is
+    neither a power of two nor a multiple of the 8 sublanes) over a window
+    layer's ring, rows younger than it and rows that have wrapped it (every
+    slot live), and over a full layer's ragged rows."""
+    monkeypatch.setattr(da, "_BLOCK_BYTES", 0)
+    hk, d = 4, 128
+    block = da.block_k(1 << 20, hk, d, dtype)
+    if kept == "ring":
+        ring = 2 * block
+        # min(index + 1, ring): young rows, the edge, and wrapped ones
+        lengths = [min(p + 1, ring) for p in
+                   (0, 6, block - 1, block, ring - 2, ring - 1, ring, 5 * ring)]
+        max_seq_len = ring
+    else:
+        max_seq_len = 3 * block
+        lengths = [1, 7, block + 1, max_seq_len, block // 3, 2 * block + 5]
+    got, want = _case(7, d, dtype, lengths, max_seq_len, hk, seed=7)
+    assert got.shape == (len(lengths), 28, d)
+    assert not np.isnan(got).any()
+    steps = 1 if dtype == jnp.bfloat16 else 8
+    assert np.abs(got - want).max() <= steps * _one_step(want, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
 @pytest.mark.parametrize("d", [32, 128])
 @pytest.mark.parametrize("group", [1, 4, 8])
 def test_matches_the_einsum_on_ragged_rows(group, d, dtype, monkeypatch):

@@ -422,3 +422,113 @@ def test_dropless_relu2_is_the_weighted_sum_over_the_experts_held(held):
         moe_apply_dropless(
             x, weights, chosen.astype(jnp.int32), w_up, w_up, w_down,
             activation="relu2")
+
+
+# -- the grouped expert kernel past one row tile (ops/moe_experts.py) ---------
+
+_GATES = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
+
+
+def _experts_einsum(x, w_gate, w_up, w_down, group_sizes, activation):
+    """Each row through the expert that owns it, by the activation's own
+    formula: the einsum the kernel stands for."""
+    if activation == "relu2":
+        return _relu2_einsum(x, w_up, w_down, group_sizes)
+    owner = jnp.repeat(jnp.arange(len(group_sizes)), group_sizes,
+                       total_repeat_length=x.shape[0])
+    hidden = _GATES[activation](
+        jnp.einsum("md,mdf->mf", x, w_gate[owner])
+    ) * jnp.einsum("md,mdf->mf", x, w_up[owner])
+    return jnp.einsum("mf,mfd->md", hidden, w_down[owner])
+
+
+@pytest.mark.parametrize("tiles", [1, 2, 3])
+@pytest.mark.parametrize("activation", ["swiglu", "reglu", "relu2"])
+def test_an_expert_on_a_tile_edge_is_the_einsum(activation, tiles, monkeypatch):
+    """One, two and three row tiles of 128 (a decode step of 64 rows x 6
+    experts sorts 384 assignments into three), ~6 rows an expert, an expert
+    nobody chose, and past one tile an expert whose rows straddle each
+    tile's edge: it is visited once a tile and each visit stores its own
+    rows alone. The same case for each activation the one-sweep kernel
+    has."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
+
+    from ray_tpu.ops import moe_experts as kernel
+
+    # an inner width of three blocks
+    monkeypatch.setattr(kernel, "_BLOCK_BYTES", 64 * 128 * 4)
+    rows, experts, d, inner = 128 * tiles, 21 * tiles + 1, 64, 384
+    keys = jax.random.split(jax.random.PRNGKey(tiles), 5)
+    x = jax.random.normal(keys[0], (rows, d))
+    w_gate, w_up = (jax.random.normal(k, (experts, d, inner)) / 8
+                    for k in keys[1:3])
+    w_down = jax.random.normal(keys[3], (experts, inner, d)) / 8
+    # groups of 6 rows, one of them empty, whose ends (multiples of 6)
+    # miss 128 and 256; the last takes what is left
+    sizes = [6] * experts
+    sizes[3] = 0
+    sizes[-1] += rows - sum(sizes)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    assert int(sizes.sum()) == rows and int(sizes.min()) == 0
+    ends = np.cumsum(np.asarray(sizes))
+    assert all(128 * t not in ends for t in range(1, tiles))
+    (_, group_ids, _), visits = make_group_metadata(
+        group_sizes=sizes, m=rows, tm=128, start_group=jnp.int32(0),
+        num_nonzero_groups=experts, visit_empty_groups=False)
+    # every touched expert once, and once more for each that straddles
+    assert int(visits) == experts - 1 + (tiles - 1)
+    assert kernel.tile_rows(rows) == 128
+    got = kernel.moe_experts(
+        x, None if activation == "relu2" else w_gate, w_up, w_down, sizes,
+        **({} if activation == "swiglu" else {"activation": activation}))
+    want = _experts_einsum(x, w_gate, w_up, w_down, sizes, activation)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * float(
+        jnp.max(jnp.abs(want)))
+
+
+def test_reglu_is_not_swiglu_and_needs_its_gate():
+    from ray_tpu.ops.moe_experts import moe_experts
+
+    x, w_up, w_down, key = _relu2_operands(32, 128)
+    w_gate = jax.random.normal(key, w_up.shape) / 8
+    sizes = jnp.asarray([10, 0, 12, 7, 3], jnp.int32)
+    reglu = moe_experts(x, w_gate, w_up, w_down, sizes, activation="reglu")
+    swiglu = moe_experts(x, w_gate, w_up, w_down, sizes)
+    assert float(jnp.max(jnp.abs(reglu - swiglu))) > 1e-2
+    with pytest.raises(ValueError, match="gate"):
+        moe_experts(x, None, w_up, w_down, sizes, activation="reglu")
+    with pytest.raises(ValueError, match="unknown activation"):
+        moe_experts(x, w_gate, w_up, w_down, sizes, activation="geglu")
+
+
+@pytest.mark.parametrize("held", [None, (0, 16), (4, 12)],
+                         ids=["all", "whole_range", "held"])
+def test_dropless_reglu_is_the_weighted_sum_over_the_experts_held(held):
+    """``moe_apply_dropless`` under ReGLU: every token's weighted sum over
+    its chosen experts that are held, no assignment dropped; the whole
+    range named as a share is all of them."""
+    from ray_tpu.parallel.expert import moe_apply_dropless
+
+    tokens, k, experts, d, inner = 37, 4, 16, 32, 48
+    keys = jax.random.split(jax.random.PRNGKey(9), 6)
+    x = jax.random.normal(keys[0], (tokens, d))
+    w_gate, w_up = (jax.random.normal(key, (experts, d, inner)) / 6
+                    for key in keys[1:3])
+    w_down = jax.random.normal(keys[3], (experts, inner, d)) / 6
+    weights = jax.random.uniform(keys[4], (tokens, k))
+    chosen = jnp.argsort(
+        jax.random.uniform(keys[5], (tokens, experts)), axis=-1)[:, :k]
+    first, stop = held or (0, experts)
+    got = moe_apply_dropless(
+        x, weights, chosen.astype(jnp.int32), w_gate[first:stop],
+        w_up[first:stop], w_down[first:stop], held=held, activation="reglu")
+    every = jnp.einsum(
+        "tef,efd->ted",
+        jax.nn.relu(jnp.einsum("td,edf->tef", x, w_gate))
+        * jnp.einsum("td,edf->tef", x, w_up), w_down)
+    kept = jnp.where((chosen >= first) & (chosen < stop), weights, 0.0)
+    want = jnp.einsum(
+        "tk,tkd->td", kept,
+        jnp.take_along_axis(every, chosen[..., None], axis=1))
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5 * float(
+        jnp.max(jnp.abs(want)))
