@@ -637,11 +637,12 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # running expert counts of a routed model (None for a dense one),
         # device-side; expert_stats() reads them
         self._expert_counts = _new_expert_counts(model_config, num_slots)
-        # the decode kernel's chunks over the steps dispatched: those it
-        # visited and those a grid of rows x the whole cache would have
-        # (attention_chunks()), one call's worth a step, counted from the
-        # rows' positions as the host knows them
-        self._attention_chunks = [0, 0]
+        # the decode kernel's visits over the steps dispatched: those it
+        # made, those a grid of rows x the whole cache would have, and the
+        # key positions the ones made copied (attention_chunks()), one
+        # call's worth a step, counted from the rows' positions as the host
+        # knows them
+        self._attention_chunks = [0, 0, 0]
         self._attention_grid: Optional[tuple] = None  # (chunk, max_seq_len)
         self._cache = None  # pooled cache, allocated on first prefill
         # paged prefix cache (ray_tpu.kvcache.KVCacheManager) or None for
@@ -1287,19 +1288,25 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             else:
                 return
         chunk, max_seq_len = self._attention_grid
-        self._attention_chunks[0] += int(
-            visits(np.minimum(keys, max_seq_len), chunk))
+        visited = int(visits(np.minimum(keys, max_seq_len), chunk))
+        self._attention_chunks[0] += visited
         self._attention_chunks[1] += self._num_slots * -(-max_seq_len // chunk)
+        self._attention_chunks[2] += visited * chunk  # a visit copies whole
 
     def attention_chunks(self) -> Dict[str, int]:
-        """``attention_chunks_visited``: chunks of keys the decode kernel
-        visited (and copied) over the decode steps dispatched so far, one
-        call a step; ``attention_chunks_dense``: what rows x the whole
-        cache would have been. Their ratio is the share of the dense grid
-        the traffic's lengths leave (1.0: every row full)."""
-        visited, dense = self._attention_chunks
+        """``attention_chunks_visited``: visits the decode kernel made (a
+        visit is the kernel's ``traced_chunk`` key positions of one row,
+        copied whole) over the decode steps dispatched so far, one call a
+        step; ``attention_chunks_dense``: what rows x the whole cache would
+        have been. Their ratio is the share of the dense grid the traffic's
+        lengths leave (1.0: every row full).
+        ``attention_positions_copied``: key positions those visits copied;
+        the steps' live positions over it is what a row's last visit, copied
+        whole, leaves of every copy (1.0: every row ends on a visit's edge)."""
+        visited, dense, copied = self._attention_chunks
         return {"attention_chunks_visited": visited,
-                "attention_chunks_dense": dense}
+                "attention_chunks_dense": dense,
+                "attention_positions_copied": copied}
 
     def _live_tokens(self) -> int:
         """Key positions the coming decode step attends over all live rows

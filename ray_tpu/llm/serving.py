@@ -389,9 +389,10 @@ class _LLMReplica:
                 "state_bytes_per_row": self._engine.state_bytes_per_row(),
                 "window_bytes_per_row": self._engine.window_bytes_per_row(),
                 "row_write": self._engine.row_write(),
-                # chunks of keys the decode kernel visited over the steps
-                # dispatched, and what a dense grid would have
-                # (attention_chunks_visited / attention_chunks_dense)
+                # visits the decode kernel made over the steps dispatched,
+                # what a dense grid would have, and the key positions the
+                # visits copied (attention_chunks_visited /
+                # attention_chunks_dense / attention_positions_copied)
                 **self._engine.attention_chunks(),
                 **(
                     {} if self._kv_cache is None else {

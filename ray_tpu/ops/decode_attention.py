@@ -24,8 +24,13 @@ Design:
   copies of the next ``_SLOTS - 1`` visits are on their way, so only chunks
   that hold a key are ever copied. Queries and outputs of all rows are
   resident in VMEM (a few hundred KB)
+- a visit follows the bytes a position holds (``block_k``): 128 keys where
+  8 K/V heads or more make that 256 KB a cache, 512 KB a cache where fewer
+  heads make a 128-key copy too small to pay for the ~0.3 us a visit costs
+  besides it (4 heads: 512 keys, 2: 1024). The arithmetic goes 128 keys
+  (``_PIECE``) at a time whatever the visit copied
 - the ``h // hk`` query heads of a group are the rows of one small matmul
-  against the group's K/V chunk: nothing is repeated in HBM
+  against a piece of the group's K/V chunk: nothing is repeated in HBM
 - the einsum's arithmetic: exact products accumulated in f32 (stored values
   times stored values for q.k, f32 probabilities for p.v), f32 online
   softmax with running max, sum and accumulator in VMEM scratch
@@ -60,11 +65,10 @@ _NEG_INF = -1e30
 # query heads of a group are padded to one f32 sublane tile, so every
 # in-kernel slice of the group's rows is tile-aligned
 _GROUP_ROWS = 8
-# K (or V) bytes one visit copies: 128 key positions at every serving
-# shape there is (8 / 16 / 4 KV heads of 128, bf16), more where a shard holds
-# one head or two. Measured on a v5e, the kernel alone, a program of the
-# cell's layers, ms (PERF.md, PR 48; before: a grid of rows x cdiv(max_seq_len,
-# block) steps over blocks of 1 MB, 512 / 256 / 1024 / 2048 keys):
+# K (or V) bytes one visit copies. Measured on a v5e, the kernel alone, a
+# program of the cell's layers, ms (PERF.md, PR 48; before: a grid of rows x
+# cdiv(max_seq_len, block) steps over blocks of 1 MB, 512 / 256 / 1024 / 2048
+# keys; a visit's matmuls then contracted over all the keys it copied):
 #   16 rows x 8 heads x 4096, 12 layers   128 keys  256 keys  512 keys  before
 #     15 rows of one key + one of 480       0.261     0.600       -      1.436
 #     128-832 keys a row                    0.766     1.341       -      1.810
@@ -76,12 +80,53 @@ _GROUP_ROWS = 8
 #   32 x 8 heads x 2048, 2 layers, 200-2048 0.479     0.816     0.575    0.752
 #   16 x 2 heads x 4096 (a tp=4 shard)      0.502     0.459     0.370    1.061
 #     every row full                        2.918     2.407     1.483    1.139
-# A visit costs ~0.35 us besides its copy, so a chunk wants bytes (the rows
-# of few heads, the last three shapes); a row's last chunk is copied and
-# multiplied whole, so short rows want it small. 256 keys are slower than
-# either neighbour wherever four heads or more share a chunk (the f32 p.v
-# at a contraction of 256), so the rule stops at 128 for those.
-_BLOCK_BYTES = 128 * 1024
+# A visit costs ~0.25-0.35 us besides its copy (0.58 us a 128-key visit of 4
+# heads, 0.16 of it the copy's bytes at the HBM's peak), so a visit wants
+# bytes; a row's last visit is copied and multiplied whole, so short rows
+# want it small. Since PR 62 the copy's extent and a matmul's contraction
+# are two things: a visit copies ``block_k`` keys and works them ``_PIECE``
+# at a time (``_kernel``). The kernel alone again, the cells' layers and
+# lengths (SmallThinker's and Command A+'s rows mid-flight in their closed
+# loops, 0.5k-5k and 2.5k-9.7k), parent (128 keys; 256 at 2 heads) and keys
+# a visit in pieces of 128, ms; * is what the rule below gives the shape:
+#                                          parent    128     256     512    1024
+#   64 x 28/4 x 4096 ring, 6 layers, cell   3.774   3.772   2.570   2.459*  2.613
+#     every row full                        7.099   7.095   4.665   4.302*  4.311
+#   64 x 28/4 x 5120 row, 2 layers, cell    1.316   1.317   0.909   0.879*  0.969
+#     every row full                        2.967   2.965   1.950   1.804*  1.807
+#   24 x 128/8 x 4096 ring, 3 layers, cell  1.723   1.724*  1.481   1.897   2.287
+#   24 x 128/8 x 10240 row, 1 layer, cell   0.836   0.834*  0.726   0.943   1.181
+#   16 x 32/8 x 4096, 12 layers, 15 + 480   0.246   0.242*  0.343   0.598   1.148
+#     128-832 keys a row                    0.653   0.654*  0.658   0.763   1.145
+#     every row full                        4.599   4.601*  4.314   4.326   4.339
+#   8 x 16/16 x 4096, 8 layers, 128-832     0.330   0.330*  0.381   0.460     -
+#     every row full                        2.882   2.883*  2.887   2.901     -
+#   64 x 20/4 x 1024, 6 layers, 128-832     1.066   1.069   0.788   0.877*  1.113
+#     every row full                        1.844   1.844   1.239   1.111*  1.116
+#   64 x 32/2 x 4096, 1 layer, 0.3k-4k      0.314   0.375   0.245   0.198   0.207*
+#     every row full                        0.826   1.052   0.645   0.450   0.378*
+#   32 x 64/8 x 2048, 2 layers, 200-2048    0.421   0.420*  0.415   0.467   0.541
+# Full rows of 4 heads go from 55% to 91% of the HBM's peak, the two cells'
+# own lengths from 53% to 82 / 79% (10-13% more keys copied than live). The
+# other forms of the arithmetic, same ring, cell's lengths / every row full:
+# one q.k and one softmax update a visit with p.v in pieces, or all of it
+# over the visit's keys (PR 48's form), 4.44 / 8.12 at 256 keys and 2.99 /
+# 5.19 at 512; pieces with a ``pl.when`` around each one past the first,
+# 3.66 / 6.85 at 512 (the branch costs what the visit was extended for). A
+# true group of 8 for the 7 padded to 8: 3.769 -> 2.456 (nothing); 24 rows
+# of 4096 against 64 of 1536, every row full: 2.678 / 2.719 -> 1.637 /
+# 1.644 (nothing). At 8 heads a second piece gains 13-14% on rows of
+# 2.5k-9.7k and costs 40% on the steady cell's one-key rows (its copy is
+# twice the bytes and nothing hides it), at one shape of cache: a piece that
+# is ``_LONE_PIECE_BYTES`` already stays a visit by itself, and those
+# shapes' programs are what they were.
+_VISIT_BYTES = 512 * 1024
+_LONE_PIECE_BYTES = 256 * 1024
+# key positions a matmul of the kernel contracts over (the lane width of the
+# scores), and the most of them a visit's straight-line code holds (measured
+# up to 8: 2 heads x 1024 keys; 8 heads x 4 pieces were slower than x 2)
+_PIECE = 128
+_MAX_PIECES = 8
 # ... and of a latent cache, keys and values in one: 1024 positions of 576
 # values. Its rows are long where it is served (1024-5120 keys of 8192, 24
 # rows, 7 layers): 512 / 1024 / 2048 positions took 1.229 / 1.120 / 1.192 ms
@@ -123,10 +168,15 @@ def _chunk(max_seq_len: int, per_position: int, fit_bytes: int) -> int:
 
 
 def block_k(max_seq_len: int, kv_heads: int, head_dim: int, dtype) -> int:
-    """Key positions in one chunk: about ``_BLOCK_BYTES`` across the
-    chunk's KV heads (``_chunk``'s rule)."""
+    """Key positions in one visit: a piece of ``_PIECE`` keys where that is
+    ``_LONE_PIECE_BYTES`` across the visit's KV heads already, else about
+    ``_VISIT_BYTES`` (``_chunk``'s rule) in ``_MAX_PIECES`` pieces at
+    most."""
     per_position = kv_heads * head_dim * jnp.dtype(dtype).itemsize
-    return _chunk(max_seq_len, per_position, _BLOCK_BYTES)
+    piece_bytes = _PIECE * per_position
+    fit = 0 if piece_bytes >= _LONE_PIECE_BYTES else min(
+        _VISIT_BYTES, _MAX_PIECES * piece_bytes)
+    return _chunk(max_seq_len, per_position, fit)
 
 
 def _dot(a, b, dims):
@@ -245,6 +295,11 @@ def _kernel(
     k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
     *, sm_scale: float, chunk: int, kv_heads: int,
 ):
+    # what a visit copies and what a matmul contracts are two things: the
+    # arithmetic goes ``piece`` keys at a time however many the visit holds
+    piece = min(chunk, _PIECE)
+    pieces = chunk // piece
+
     def copies(row, ci, slot):
         keys = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
         return (
@@ -259,20 +314,27 @@ def _kernel(
         def _start_row():
             _init(acc_ref, m_ref, l_ref)
 
-        # (a group's padded rows: _GROUP_ROWS up to 8 query heads a KV head,
-        # two tiles at Nemotron-H's 16)
-        in_scores, in_values = _live(
-            ci, chunk, lengths_ref[row], q_ref.shape[2])
-        for j in range(kv_heads):
-            q = q_ref[row, j]  # (rows, d)
-            k = k_buf[slot, j]  # (chunk, d)
-            # positions past the length hold whatever the cache held before:
-            # their probabilities are 0, but 0 * NaN would still poison the
-            # matmul
-            v = jnp.where(in_values, v_buf[slot, j], 0)
-            s = _dot(q, k, ((1,), (1,))) * sm_scale
-            s = jnp.where(in_scores, s, _NEG_INF)
-            _accumulate(s, v, acc_ref, m_ref, l_ref, j)
+        # straight-line code, pieces past the row's length included (all
+        # masked): a branch a piece costs the visit what it was extended
+        # for (the table above, "a `pl.when` a piece")
+        for i in range(pieces):
+            at = pl.ds(i * piece, piece)
+            # (a group's padded rows: _GROUP_ROWS up to 8 query heads a KV
+            # head, two tiles at Nemotron-H's 16; one piece a visit: the
+            # program as it was before a visit could hold several)
+            in_scores, in_values = _live(
+                ci * pieces + i if pieces > 1 else ci, piece,
+                lengths_ref[row], q_ref.shape[2])
+            for j in range(kv_heads):
+                q = q_ref[row, j]  # (rows, d)
+                k = k_buf[slot, j, at]  # (piece, d)
+                # positions past the length hold whatever the cache held
+                # before: their probabilities are 0, but 0 * NaN would
+                # still poison the matmul
+                v = jnp.where(in_values, v_buf[slot, j, at], 0)
+                s = _dot(q, k, ((1,), (1,))) * sm_scale
+                s = jnp.where(in_scores, s, _NEG_INF)
+                _accumulate(s, v, acc_ref, m_ref, l_ref, j)
 
         @pl.when(ends)
         def _finish_row():

@@ -322,15 +322,24 @@ C2MOE_ROW = (24, 128, 8, 10240)
 # heads, a group of 7, 64 slots): a ring of 4096 and a row of 5120
 STMOE_RING = (64, 28, 4, 4096)
 STMOE_ROW = (64, 28, 4, 5120)
+# `falconh1-chat-backlog`'s (20 / 4 heads, 64 slots x 1024) and
+# `nemotron3s-reasoning-backlog`'s (32 / 2: a group of 16, two row tiles)
+FALCON_H1_POOL = (64, 20, 4, 1024)
+NEMOTRON_H_POOL = (64, 32, 2, 4096)
 
 
 @pytest.mark.parametrize(
-    "pool",
-    [MISTRAL_POOL, LLAMA2_POOL, C2MOE_RING, C2MOE_ROW, STMOE_RING, STMOE_ROW],
+    "pool, visit",
+    [(MISTRAL_POOL, 128), (LLAMA2_POOL, 128), (C2MOE_RING, 128),
+     (C2MOE_ROW, 128), (STMOE_RING, 512), (STMOE_ROW, 512),
+     (FALCON_H1_POOL, 512), (NEMOTRON_H_POOL, 1024)],
     ids=["mistral", "llama2", "c2moe_ring", "c2moe_row", "stmoe_ring",
-         "stmoe_row"])
-def test_decode_attention_compiles(v5e_chip, native_kernels, pool):
-    from ray_tpu.ops.decode_attention import decode_attention
+         "stmoe_row", "falcon_h1", "nemotron_h"])
+def test_decode_attention_compiles(v5e_chip, native_kernels, pool, visit):
+    """... at the key positions a visit the rule gives the cell's cache:
+    three buffers a cache of 512 KB where 2 or 4 K/V heads share a visit,
+    inside the compiler's default scoped VMEM (no ``vmem_limit_bytes``)."""
+    from ray_tpu.ops.decode_attention import decode_attention, traced_chunk
 
     b, h, hk, max_seq_len = pool
     q = jax.ShapeDtypeStruct((b, h, 128), jnp.bfloat16, sharding=v5e_chip)
@@ -338,7 +347,9 @@ def test_decode_attention_compiles(v5e_chip, native_kernels, pool):
         (b, hk, max_seq_len, 128), jnp.bfloat16, sharding=v5e_chip
     )
     lengths = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e_chip)
-    assert "tpu_custom_call" in _compile(decode_attention, q, kv, kv, lengths)
+    text = _compile(decode_attention, q, kv, kv, lengths)
+    assert "tpu_custom_call" in text and "vmem_limit_bytes" not in text
+    assert traced_chunk(kv.shape) == visit
 
 
 @pytest.mark.parametrize(
@@ -918,8 +929,12 @@ def test_smallthinker_decode_step_routes_ahead_and_reads_every_expert(
     aliases = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
     assert len(aliases) == len(jax.tree.leaves(pool)) == 2 * 3
     entry = text[text.index("\nENTRY "):]
-    # (a group of 7 rides in 8 sublanes)
+    # (a group of 7 rides in 8 sublanes; a visit holds four 128-key pieces
+    # of the ring and of the row: 3 MB of scratch a call)
     assert len(re.findall(r"%decode_attention\S* = bf16\[64,4,8,128\]", entry)) == 2
+    from ray_tpu.ops.decode_attention import traced_chunk
+
+    assert traced_chunk((64, 4, 4096, 128)) == traced_chunk((64, 4, 5120, 128)) == 512
     assert len(re.findall(r"%kv_row_write\S* = \(bf16\[64,4,4096,128\]", entry)) == 1
     assert len(re.findall(r"%kv_row_write\S* = \(bf16\[64,4,5120,128\]", entry)) == 1
     calls = re.findall(

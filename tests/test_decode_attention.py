@@ -50,9 +50,35 @@ def _case(group, d, dtype, lengths, max_seq_len, hk=2, seed=0):
     # given NaN there and must not let one through
     want = _einsum_attention(
         q, jnp.where(live, k, 0), jnp.where(live, v, 0), lengths)
-    got = jax.jit(da.decode_attention)(
+    # (a new function object a call: a second ``jax.jit`` of the same one
+    # would answer from the first's trace, whatever the rule is patched to)
+    got = jax.jit(lambda *a: da.decode_attention(*a))(
         q, jnp.where(live, k, jnp.nan), jnp.where(live, v, jnp.nan), lengths)
     return np.asarray(got, np.float32), np.asarray(want, np.float32)
+
+
+PIECE = 128  # key positions the kernel's arithmetic takes at a time
+# 128-key pieces a visit holds: one (8 K/V heads and more, and every cache
+# until PR 62) and four (a bf16 cache of 4 K/V heads of 128: 512 KB a visit)
+SPANS = [1, 4]
+
+
+def _visits_of(monkeypatch, span):
+    """Visits of ``span`` pieces of 128 keys, whatever a position holds."""
+    monkeypatch.setattr(da, "_VISIT_BYTES", 1 << 40)
+    monkeypatch.setattr(da, "_LONE_PIECE_BYTES", 1 << 40)
+    monkeypatch.setattr(da, "_MAX_PIECES", span)
+    return span * PIECE
+
+
+def _close(got, want, lengths, dtype, steps=1):
+    """Rows that hold a key match the einsum to ``steps`` of ``dtype``; a
+    row of length 0 (the reference's softmax over nothing is NaN) is zeros."""
+    held = np.asarray(lengths) > 0
+    assert not np.isnan(got).any()
+    assert (got[~held] == 0).all()
+    return np.abs(got[held] - want[held]).max() <= steps * _one_step(
+        want[held], dtype)
 
 
 def _one_step(want, dtype):
@@ -64,24 +90,30 @@ def _one_step(want, dtype):
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("kept", ["ring", "row"])
-def test_a_group_of_seven_over_a_ring_and_a_full_row(kept, dtype, monkeypatch):
+def test_a_group_of_seven_over_a_ring_and_a_full_row(kept, dtype):
     """28 query heads on 4 K/V heads (SmallThinker's: a group that is
     neither a power of two nor a multiple of the 8 sublanes) over a window
     layer's ring, rows younger than it and rows that have wrapped it (every
-    slot live), and over a full layer's ragged rows."""
-    monkeypatch.setattr(da, "_BLOCK_BYTES", 0)
+    slot live), and over a full layer's ragged rows; under the rule's own
+    visit (512 keys of the bf16 cache, 128 of the f32 one), ring and row
+    several whole visits long."""
     hk, d = 4, 128
     block = da.block_k(1 << 20, hk, d, dtype)
+    assert block == (4 * PIECE if dtype == jnp.bfloat16 else PIECE)
     if kept == "ring":
         ring = 2 * block
-        # min(index + 1, ring): young rows, the edge, and wrapped ones
+        # min(index + 1, ring): young rows, a piece's and a visit's edge,
+        # and wrapped ones
         lengths = [min(p + 1, ring) for p in
-                   (0, 6, block - 1, block, ring - 2, ring - 1, ring, 5 * ring)]
+                   (0, 6, PIECE - 1, PIECE, block - 1, block, block + PIECE,
+                    ring - 2, ring - 1, ring, 5 * ring)]
         max_seq_len = ring
     else:
         max_seq_len = 3 * block
-        lengths = [1, 7, block + 1, max_seq_len, block // 3, 2 * block + 5]
+        lengths = [1, 7, PIECE + 1, block + 1, max_seq_len, block // 3,
+                   2 * block + 5, 2 * block + PIECE]
     got, want = _case(7, d, dtype, lengths, max_seq_len, hk, seed=7)
+    assert da.traced_chunk((len(lengths), hk, max_seq_len, d)) == block
     assert got.shape == (len(lengths), 28, d)
     assert not np.isnan(got).any()
     steps = 1 if dtype == jnp.bfloat16 else 8
@@ -92,19 +124,22 @@ def test_a_group_of_seven_over_a_ring_and_a_full_row(kept, dtype, monkeypatch):
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("d", [32, 128])
 @pytest.mark.parametrize("group", [1, 4, 8])
-def test_matches_the_einsum_on_ragged_rows(group, d, dtype, monkeypatch):
-    # the smallest block there is (128 keys), so three of them stay small
-    monkeypatch.setattr(da, "_BLOCK_BYTES", 0)
+@pytest.mark.parametrize("span", SPANS)
+def test_matches_the_einsum_on_ragged_rows(span, group, d, dtype, monkeypatch):
+    # three visits of ``span`` pieces of 128 keys (the smallest there is)
+    block = _visits_of(monkeypatch, span)
     hk = 2
-    block = da.block_k(1 << 20, hk, d, dtype)
+    assert da.block_k(1 << 20, hk, d, dtype) == block
     max_seq_len = 3 * block
-    lengths = [1, block, block + 1, max_seq_len, block // 3, 2 * block + 5]
+    # ... rows that end inside a visit's first piece, on a piece's edge
+    # inside a visit and one past it, on a visit's edge and one past it
+    lengths = [1, block, block + 1, max_seq_len, block // 3, 2 * block + 5,
+               block + PIECE, 2 * block - PIECE + 1, 0]
     got, want = _case(group, d, dtype, lengths, max_seq_len, hk)
+    assert da.traced_chunk((len(lengths), hk, max_seq_len, d)) == block
     assert got.shape == (len(lengths), hk * group, d)
-    assert not np.isnan(got).any()
     # f32 sums in another order: a few of f32's steps, one of bf16's
-    steps = 1 if dtype == jnp.bfloat16 else 8
-    assert np.abs(got - want).max() <= steps * _one_step(want, dtype)
+    assert _close(got, want, lengths, dtype, 1 if dtype == jnp.bfloat16 else 8)
 
 
 def test_a_cache_shorter_than_a_block_and_one_that_ends_inside_one(monkeypatch):
@@ -114,24 +149,52 @@ def test_a_cache_shorter_than_a_block_and_one_that_ends_inside_one(monkeypatch):
     assert np.abs(got - want).max() <= _one_step(want, jnp.bfloat16)
     # 320 positions are no whole chunks of 128, and a copy cannot hang over
     # the cache's end as a block could: five chunks of 64
-    monkeypatch.setattr(da, "_BLOCK_BYTES", 0)
+    monkeypatch.setattr(da, "_VISIT_BYTES", 0)
     assert da.block_k(320, 2, 128, jnp.bfloat16) == 64
     got, want = _case(4, 128, jnp.bfloat16, [320, 257, 256, 3], 320)
     assert not np.isnan(got).any()
     assert np.abs(got - want).max() <= _one_step(want, jnp.bfloat16)
 
 
+SERVING_SHAPES = {
+    # cell's cache (positions, K/V heads of 128, bf16) -> keys a visit
+    "mistral": ((4096, 8), 128),
+    "olmoe": ((4096, 16), 128),
+    "solar_open2": ((2048, 8), 128),
+    "command_a_plus_ring": ((4096, 8), 128),
+    "command_a_plus_row": ((10240, 8), 128),
+    "falcon_h1": ((1024, 4), 512),
+    "smallthinker_ring": ((4096, 4), 512),
+    "smallthinker_row": ((5120, 4), 512),
+    "nemotron_h": ((4096, 2), 1024),
+}
+
+
+@pytest.mark.parametrize("cell", SERVING_SHAPES)
+def test_a_visit_follows_the_bytes_a_position_holds(cell):
+    """The seven serving shapes: 128 keys where they are 256 KB a cache or
+    more (8 and 16 K/V heads: the parent's visit, the parent's program),
+    512 KB a cache where they are less (4 heads: 512 keys, 2: 1024), in
+    whole 128-key pieces that divide the cache."""
+    (max_seq_len, kv_heads), keys = SERVING_SHAPES[cell]
+    assert da.block_k(max_seq_len, kv_heads, 128, jnp.bfloat16) == keys
+    assert max_seq_len % keys == 0 and keys % PIECE == 0
+    # (three buffers a cache of that: 3 MB of the default scoped VMEM)
+    assert keys == PIECE or keys * kv_heads * 128 * 2 == 512 * 1024
+
+
 def test_block_size_follows_the_cache_not_an_option():
-    # every serving shape (8, 16, 4 KV heads of 128, bf16): 128 keys a
-    # visit, the lane width of the scores, 256 KB to 1 MB of K and V
-    assert da.block_k(4096, 8, 128, jnp.bfloat16) == 128
-    assert da.block_k(4096, 16, 128, jnp.bfloat16) == 128
-    assert da.block_k(1024, 4, 128, jnp.bfloat16) == 128
     # Llama-2 widths, 32 KV heads: never under the 128 lanes
     assert da.block_k(2048, 32, 128, jnp.bfloat16) == 128
-    # a tp=4 shard of the chat cells' cache holds 2 KV heads: a visit
-    # wants bytes, 128 KB of K
-    assert da.block_k(4096, 2, 128, jnp.bfloat16) == 256
+    # a tp=4 shard of the chat cells' cache holds 2 KV heads, one of
+    # Falcon-H1's 1: a visit wants bytes, 512 KB of K, in 8 pieces at most
+    assert da.block_k(4096, 2, 128, jnp.bfloat16) == 1024
+    assert da.block_k(4096, 1, 128, jnp.bfloat16) == 1024
+    # an f32 cache of 4 heads holds 8 heads' bytes a position
+    assert da.block_k(4096, 4, 128, jnp.float32) == 128
+    # a visit never hangs over the cache's end: 5120 = 10 x 512, 640 = 5 x 128
+    assert da.block_k(5120, 4, 128, jnp.bfloat16) == 512
+    assert da.block_k(640, 4, 128, jnp.bfloat16) == 128
     # a latent row: 1024 positions, keys and values in one
     assert da.latent_block_k(8192, 576, jnp.bfloat16) == 1024
 
@@ -162,8 +225,9 @@ def test_heads_sharded_over_tp_give_the_same_rows():
 # ---------------------------------------------------------------------------
 
 CHUNK = 128
-MAX_SEQ_LEN = 4 * CHUNK
-# lengths a step can hold, in chunks of 128 of a 512-position cache
+MAX_SEQ_LEN = 8 * CHUNK
+# lengths a step can hold, in chunks of 128 of a 1024-position cache: eight
+# visits of one piece, or two of four
 STEPS = {
     "ragged": [1, CHUNK, CHUNK + 1, 3 * CHUNK, CHUNK // 3, 2 * CHUNK + 5],
     # the steady cell's shape: fifteen free rows, one key each, beside one
@@ -172,6 +236,11 @@ STEPS = {
     "every_row_full": [MAX_SEQ_LEN] * 4,
     "whole_chunks": [2 * CHUNK, CHUNK, 3 * CHUNK],
     "at_max_seq_len": [MAX_SEQ_LEN, 1, MAX_SEQ_LEN - 1],
+    # every 128-key boundary inside a visit of four pieces, on it, one short
+    # of it and one past it; a row that holds nothing
+    "every_piece_edge": [0] + [
+        n + by for n in range(CHUNK, MAX_SEQ_LEN + 1, CHUNK) for by in (-1, 0, 1)
+        if n + by <= MAX_SEQ_LEN],
 }
 
 
@@ -215,11 +284,21 @@ def _latent_case(lengths, max_seq_len, heads=4, rank=128, rope=64, seed=0):
     return np.asarray(got, np.float32), np.asarray(want, np.float32)
 
 
+@pytest.fixture(params=SPANS, ids=lambda span: f"span{span}")
+def visit(request, monkeypatch):
+    """Key positions a visit of both kernels holds: one piece of 128 (the
+    smallest there is: a cache of 1024 positions holds eight) or four."""
+    keys = _visits_of(monkeypatch, request.param)
+    monkeypatch.setattr(da, "_LATENT_BLOCK_BYTES", keys * 192 * 2)
+    assert da.block_k(MAX_SEQ_LEN, 2, 128, jnp.bfloat16) == keys
+    assert da.latent_block_k(MAX_SEQ_LEN, 192, jnp.bfloat16) == keys
+    return keys
+
+
 @pytest.fixture
 def chunks_of_128(monkeypatch):
-    """The smallest chunk there is in both kernels, so that a cache of 512
-    positions holds four."""
-    monkeypatch.setattr(da, "_BLOCK_BYTES", 0)
+    """One piece a visit in both kernels."""
+    _visits_of(monkeypatch, 1)
     monkeypatch.setattr(da, "_LATENT_BLOCK_BYTES", 0)
     assert da.block_k(MAX_SEQ_LEN, 2, 128, jnp.bfloat16) == CHUNK
     assert da.latent_block_k(MAX_SEQ_LEN, 192, jnp.bfloat16) == CHUNK
@@ -251,16 +330,23 @@ def _walked(lengths, chunk):
 
 
 @pytest.mark.parametrize("step", STEPS)
-def test_the_walk_visits_a_steps_live_chunks_and_nothing_else(step):
-    """``sum(cdiv(max(length, 1), chunk))`` visits, rows in order, each
-    row's chunks in order and its last one marked; every visit's copy is
-    started once, ahead of it, into a buffer nobody is still using."""
+@pytest.mark.parametrize("span", SPANS)
+def test_the_walk_visits_a_steps_live_chunks_and_nothing_else(span, step):
+    """``sum(cdiv(max(length, 1), chunk))`` visits of ``span`` pieces, rows
+    in order, each row's chunks in order and its last one marked; every
+    visit's copy is started once, ahead of it, into a buffer nobody is
+    still using."""
     lengths = STEPS[step]
-    events = _walked(lengths, CHUNK)
-    chunks = [-(-max(n, 1) // CHUNK) for n in lengths]
+    keys = span * CHUNK
+    events = _walked(lengths, keys)
+    chunks = [-(-max(n, 1) // keys) for n in lengths]
     visited = [e[1:] for e in events if e[0] == "visit"]
     assert len(visited) == sum(chunks) == int(
-        da.visits(np.asarray(lengths), CHUNK))
+        da.visits(np.asarray(lengths), keys))
+    # a row's last visit is copied whole: a piece at most past a row of one
+    # piece a visit, ``span`` pieces less one key at most past any
+    over = [n * keys - max(length, 1) for n, length in zip(chunks, lengths)]
+    assert all(0 <= o < keys for o in over)
     assert [(row, ci, ends) for row, ci, _, ends in visited] == [
         (row, ci, ci == n - 1) for row, n in enumerate(chunks)
         for ci in range(n)]
@@ -281,18 +367,20 @@ def test_the_walk_visits_a_steps_live_chunks_and_nothing_else(step):
 
 @pytest.mark.parametrize("step", STEPS)
 @pytest.mark.parametrize("kernel", ["gqa", "latent"])
-def test_a_steps_rows_match_the_einsum(kernel, step, chunks_of_128):
-    """Each kernel against its model's own einsum over the same step; what
-    lies past a row's length, NaN here, is masked."""
+def test_a_steps_rows_match_the_einsum(kernel, step, visit):
+    """Each kernel against its model's own einsum over the same step, at
+    one piece a visit and at four; what lies past a row's length, NaN here,
+    is masked, in the pieces of a visit that hold no key too."""
     lengths = STEPS[step]
     if kernel == "gqa":
         got, want = _case(4, 128, jnp.bfloat16, lengths, MAX_SEQ_LEN)
+        assert da.traced_chunk((len(lengths), 2, MAX_SEQ_LEN, 128)) == visit
         steps = 1
     else:
         got, want = _latent_case(lengths, MAX_SEQ_LEN)
+        assert da.traced_chunk((len(lengths), 1, MAX_SEQ_LEN, 128)) == visit
         steps = 2  # the model's einsum rounds its probabilities to bf16
-    assert not np.isnan(got).any()
-    assert np.abs(got - want).max() <= steps * _one_step(want, jnp.bfloat16)
+    assert _close(got, want, lengths, jnp.bfloat16, steps)
 
 
 def _grids(fn, *args):
@@ -329,6 +417,48 @@ def test_no_grid_is_a_function_of_max_seq_len(kernel):
                 jnp.zeros((b, 1, s, 128), jnp.bfloat16),
                 jnp.zeros((b, 1, s, 64), jnp.bfloat16), lengths)
         assert grids == [()]
+
+
+def _kernel_dots(jaxpr, found):
+    """Operand shapes of every ``dot_general`` under ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(tuple(v.aval.shape for v in eqn.invars))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_dots(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("kv_heads, keys", [(8, 128), (4, 512), (2, 1024)])
+def test_a_visit_copies_its_bytes_and_multiplies_a_piece_at_a_time(kv_heads, keys):
+    """What a visit copies and what a matmul contracts are two things: the
+    buffers hold the visit's ``keys`` positions of every K/V head, and every
+    q.k and p.v of the kernel is over one 128-key piece of one head (a
+    contraction over 256 keys was slower than either neighbour on the chip:
+    PERF.md, PR 48 and PR 62)."""
+    b, group, d, s = 4, 4, 128, 2048
+    kv = jnp.zeros((b, kv_heads, s, d), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda *a: da.decode_attention(*a))(
+        jnp.zeros((b, kv_heads * group, d), jnp.bfloat16), kv, kv,
+        jnp.ones((b,), jnp.int32)).jaxpr
+    assert da.traced_chunk(kv.shape) == keys
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    (call,) = calls(jaxpr)
+    buffers = [v.aval.shape for v in call.params["jaxpr"].invars
+               if v.aval.shape[:1] == (da._SLOTS,)]
+    assert buffers == [(da._SLOTS, kv_heads, keys, d)] * 2
+    dots = _kernel_dots(call.params["jaxpr"], [])
+    rows = da._GROUP_ROWS
+    assert sorted(dots) == sorted(
+        [((rows, d), (PIECE, d)), ((rows, PIECE), (PIECE, d))]
+        * (kv_heads * keys // PIECE))
 
 
 @pytest.mark.parametrize("kernel", ["gqa", "latent"])
@@ -437,27 +567,36 @@ def test_one_live_row_of_eight_after_the_others_ran_long(gqa):
     assert steps >= 57 and got == want
 
 
-def test_the_engine_counts_the_chunks_its_steps_visit(monkeypatch):
+@pytest.mark.parametrize("span", [1, 2])
+def test_the_engine_counts_the_chunks_its_steps_visit(span, monkeypatch):
     """``attention_chunks()`` from the host's own row positions: a lone
-    request on eight slots of 256 positions in chunks of 128 visits one
-    chunk a row until its row passes 128 keys and nine a step from there,
-    where a grid of rows x the whole cache has sixteen a step."""
+    request on eight slots of 256 positions in visits of 128 keys visits
+    one chunk a row until its row passes 128 keys and nine a step from
+    there, where a grid of rows x the whole cache has sixteen a step; in
+    visits of two pieces every row is one visit, and a step copies all
+    2048 positions of the cache for the 12 to 152 that hold a key."""
     from ray_tpu.llm.engine import ContinuousBatchingEngine, GenerationRequest
     from ray_tpu.models.llama import LlamaConfig, init_params
     from ray_tpu.parallel.sharding import unbox_params
 
-    monkeypatch.setattr(da, "_BLOCK_BYTES", 0)
+    keys = _visits_of(monkeypatch, span)
     cfg = LlamaConfig.tiny(n_layers=1, n_heads=4, n_kv_heads=2, max_seq_len=256)
     params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
     engine = ContinuousBatchingEngine(cfg, params, num_slots=8)
     assert engine.attention_chunks() == {
-        "attention_chunks_visited": 0, "attention_chunks_dense": 0}
+        "attention_chunks_visited": 0, "attention_chunks_dense": 0,
+        "attention_positions_copied": 0}
     engine.add_request(GenerationRequest(
         token_ids=[7, 8, 9, 10, 11], max_new_tokens=140))
     engine.run_until_complete()
     counted = engine.attention_chunks()
-    steps, rest = divmod(counted["attention_chunks_dense"], 8 * 2)
+    steps, rest = divmod(counted["attention_chunks_dense"], 8 * 256 // keys)
     assert rest == 0 and steps == engine._step_count >= 139
     # the step that feeds token n attends 5 + n keys: past 128 from n = 124
     long_steps = counted["attention_chunks_visited"] - 8 * steps
-    assert long_steps == steps - 123
+    assert long_steps == (steps - 123 if span == 1 else 0)
+    # a visit copies whole: 128 (256) positions for a free row's one key
+    assert counted["attention_positions_copied"] == keys * counted[
+        "attention_chunks_visited"]
+    live = sum(7 + 5 + n for n in range(steps))  # seven free rows, one live
+    assert live < counted["attention_positions_copied"] <= steps * 8 * 256

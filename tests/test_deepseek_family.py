@@ -329,9 +329,9 @@ def test_latent_kernel_matches_the_einsum_on_ragged_rows(
 def test_latent_block_is_keys_and_values_together():
     # a position's 576 bf16 values are 1152 B, its keys and its values in
     # one: 1024 positions a chunk (the cell's rows are 1024-5120 keys long),
-    # where K and V of as many bytes a position would take 128 each
+    # where K and V of as many bytes a position would take 256 each
     assert da.latent_block_k(8192, 576, jnp.bfloat16) == 1024
-    assert da.block_k(8192, 1, 576, jnp.bfloat16) == 128
+    assert da.block_k(8192, 1, 576, jnp.bfloat16) == 256
     assert da.latent_block_k(256, 576, jnp.bfloat16) == 256
     with pytest.raises(ValueError, match="latent caches"):
         da.latent_decode_attention(
@@ -551,6 +551,7 @@ def test_deepseek_serves_through_serve_run(shutdown_only):
             # four decode steps of two rows, each one chunk (the whole
             # 64-position cache) long: nothing for the schedule to skip
             "attention_chunks_visited": 8, "attention_chunks_dense": 8,
+            "attention_positions_copied": 8 * 64,
             # no per-row state without a sequence axis, so prefixes are shared
             # ... and no window layer's ring (models.WINDOW)
             "state_bytes_per_row": 0, "window_bytes_per_row": 0,
