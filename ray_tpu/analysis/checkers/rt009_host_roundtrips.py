@@ -6,7 +6,7 @@ through the engine or the KV-cache manager is a synchronous device->host
 transfer that stalls the dispatch pipeline — and under a sharded mesh it is
 worse, because materializing a replicated output gathers from every device.
 The serving plane therefore funnels ALL materialization through the single
-audited ``host_sync`` chokepoint in ``ray_tpu/llm/engine.py`` (one fused
+audited ``host_sync`` chokepoint in ``ray_tpu/_internal/host_sync.py`` (one fused
 sampling program, one transfer per decode step); everything else on the hot
 path must stay on device.
 

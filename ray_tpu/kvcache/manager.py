@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .._internal.host_sync import host_sync
 from ..models import SEQUENCE, STATE, WINDOW, cache_kinds
 from .block_allocator import BlockAllocator
 from .prefix_index import PrefixIndex
@@ -310,24 +311,6 @@ class KVCacheManager:
         lease.pinned = []
         lease.reserved = []
         self._update_gauges()
-
-    def extend(self, lease: KVCacheLease, n_blocks: int) -> int:
-        """Best-effort speculative lease extension: reserve up to
-        ``n_blocks`` more pool blocks for decode-tail commits (an accepted
-        speculative run can cross several block boundaries in one engine
-        step). Returns how many were actually obtained — on pool pressure
-        the tail simply goes uncached; reserved blocks that never get
-        committed are returned by release() like any other."""
-        if lease.closed or lease.cacheable is False:
-            return 0
-        got = 0
-        for _ in range(max(int(n_blocks), 0)):
-            bid = self._allocate_or_evict()
-            if bid is None:
-                break
-            lease.reserved.append(bid)
-            got += 1
-        return got
 
     # -- device state --------------------------------------------------------
 
@@ -597,8 +580,6 @@ class KVCacheManager:
             self._extract_fns[(nblocks, tail_len)] = fn
         kv_row = self._sequence_leaves(cache_row)
         blocks, tail = fn(kv_row)
-        from ..llm.engine import host_sync
-
         return {
             "blocks": [host_sync(b) for b in blocks],
             "tail": [host_sync(t) for t in tail] if tail else None,

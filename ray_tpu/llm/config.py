@@ -48,7 +48,7 @@ class AdapterConfig:
 class LLMConfig:
     model_id: str = "llama-tiny"
     # model construction: either a models.llama config name or kwargs
-    model_family: str = "llama"  # "llama" | "moe" | "deepseek" | "falcon_h1" | "solar_open2" | "motif" | "nemotron_h" | "cohere2_moe" | "smallthinker"
+    model_family: str = "llama"  # a name of ray_tpu.models.FAMILIES
     model_kwargs: Dict[str, Any] = field(default_factory=dict)
     max_seq_len: int = 512
     max_batch_size: int = 8
@@ -123,15 +123,6 @@ class LLMConfig:
     # with a bounded per-block quantization error (same codec as the
     # quantized weight plane)
     kv_ship_codec: str = "raw"
-    # speculative decoding: a small draft model (same config grammar as
-    # model_id/model_kwargs) proposes spec_tokens tokens per engine step;
-    # the target verifies all of them in ONE forward pass and keeps the
-    # longest accepted prefix — lossless at temperature 0, rejection-
-    # sampled (distribution-preserving) otherwise. spec_tokens defaults
-    # to 4 when a draft_model is named without an explicit k.
-    draft_model: Optional[str] = None
-    draft_model_kwargs: Dict[str, Any] = field(default_factory=dict)
-    spec_tokens: int = 0
     # chunked prefill: per-engine-step prefill token budget so a long
     # prompt admission interleaves with in-flight decodes instead of
     # stalling them; 0 = prefill runs to completion at admission.
@@ -147,7 +138,6 @@ class LLMConfig:
         tp, sp = self.effective_parallelism()
         using = {
             "adapters": self.adapters is not None,
-            "draft_model": self.draft_model is not None,
             "mesh": tp > 1 or sp > 1,
             "prefill_chunk": self.prefill_chunk_tokens > 0,
         }
@@ -157,11 +147,10 @@ class LLMConfig:
                     f"LLMConfig: model_family {self.model_family!r} cannot "
                     f"serve with {feature} yet: {reason}"
                 )
-        if self.model_family == "moe" and not self.model_kwargs.get(
-            "dropless", True
-        ):
-            # no knob: with a capacity, a row's answer would depend on the
-            # rows that share its batch, and the decode model will not build
+        if not self.model_kwargs.get("dropless", True):
+            # no knob, whatever the family: with a capacity, a row's answer
+            # would depend on the rows that share its batch, and the decode
+            # model will not build
             raise ValueError(
                 "LLMConfig: model_kwargs['dropless']=False: serving has no "
                 "capacity path for routed experts"
@@ -212,12 +201,6 @@ class LLMConfig:
                 "disaggregated roles / kv_tier ship KV blocks and need a "
                 "block pool: set kv_cache_blocks"
             )
-        if self.draft_model is not None and self.spec_tokens <= 0:
-            self.spec_tokens = 4
-        if self.spec_tokens > 0 and self.draft_model is None:
-            raise ValueError(
-                "spec_tokens needs a draft_model to propose tokens"
-            )
         if self.prefill_chunk_tokens < 0:
             raise ValueError("prefill_chunk_tokens must be >= 0")
         if isinstance(self.adapters, dict):
@@ -230,26 +213,9 @@ class LLMConfig:
         return (self.tensor_parallel_size, self.sequence_parallel_size)
 
     def build_model_config(self):
-        if self.model_family == "llama":
-            from ..models.llama import LlamaConfig as config_type
-        elif self.model_family == "moe":
-            from ..models.moe import MoEConfig as config_type
-        elif self.model_family == "deepseek":
-            from ..models.deepseek import DeepseekConfig as config_type
-        elif self.model_family == "falcon_h1":
-            from ..models.falcon_h1 import FalconH1Config as config_type
-        elif self.model_family == "solar_open2":
-            from ..models.solar_open2 import SolarOpen2Config as config_type
-        elif self.model_family == "motif":
-            from ..models.motif import MotifConfig as config_type
-        elif self.model_family == "nemotron_h":
-            from ..models.nemotron_h import NemotronHConfig as config_type
-        elif self.model_family == "cohere2_moe":
-            from ..models.cohere2_moe import Cohere2MoEConfig as config_type
-        elif self.model_family == "smallthinker":
-            from ..models.smallthinker import SmallThinkerConfig as config_type
-        else:
-            raise ValueError(f"unknown model family {self.model_family!r}")
+        from .. import models
+
+        config_type = models.config_type(self.model_family)
         kwargs = dict(self.model_kwargs)
         kwargs.setdefault("max_seq_len", self.max_seq_len)
         if self.model_family == "moe":
@@ -259,18 +225,3 @@ class LLMConfig:
         return config_type.tiny(**kwargs) if self.model_id.endswith(
             "tiny"
         ) else config_type(**kwargs)
-
-    def build_draft_model_config(self):
-        """Model config for the speculative draft — same name grammar as
-        build_model_config (llama only: the draft shares the target's
-        vocab/tokenizer, and its max_seq_len must cover the target's so
-        both caches hold the same positions)."""
-        if self.draft_model is None:
-            return None
-        from ..models.llama import LlamaConfig
-
-        kwargs = dict(self.draft_model_kwargs)
-        kwargs.setdefault("max_seq_len", self.max_seq_len)
-        return LlamaConfig.tiny(**kwargs) if self.draft_model.endswith(
-            "tiny"
-        ) else LlamaConfig(**kwargs)

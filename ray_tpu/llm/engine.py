@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .._internal.host_sync import host_sync
 from .._internal.platform import decode_step_compiler_options
 from ..models import (
     INDEX, ROUTING as _ROUTING, SEQUENCE, STATE, WINDOW, cache_kinds,
@@ -69,35 +70,14 @@ def _record_ttft(seconds: float, hit: bool, mesh: str = "tp=1",
         pass
 
 
-def _record_itl(seconds: float, n: int = 1, mesh: str = "tp=1") -> None:
-    """Inter-token latency: one observation per emitted token. A
-    speculative step that lands n tokens at once records n observations
-    of gap/n — the per-token cadence a streaming client actually sees."""
+def _record_itl(seconds: float, mesh: str = "tp=1") -> None:
+    """Inter-token latency: one observation per emitted token."""
     try:
         from ..util.metrics import record_serve_itl
 
-        record_serve_itl(seconds, n=n, mesh=mesh)
+        record_serve_itl(seconds, mesh=mesh)
     except Exception:
         pass
-
-
-def _record_spec(proposed: int, accepted: int, mesh: str = "tp=1") -> None:
-    try:
-        from ..util.metrics import record_spec_tokens
-
-        record_spec_tokens(proposed, accepted, mesh=mesh)
-    except Exception:
-        pass
-
-
-def host_sync(x) -> np.ndarray:
-    """The ONE audited device->host materialization point on the serving
-    hot path. Everything the engine moves to the host — sampled token ids,
-    nothing else — funnels through here, so the RT009 lint rule can forbid
-    ad-hoc ``jax.device_get``/``np.asarray(jnp...)``/``float(jnp...)``
-    round-trips everywhere else in engine/kvcache code (each one is a
-    device sync that stalls the decode pipeline)."""
-    return np.asarray(x)
 
 
 def _sample_impl(logits, temps, key):
@@ -369,8 +349,7 @@ class GenerationResult:
 class _DecodeModelBase:
     """Shared jitted prefill/decode programs over the cached model of
     whatever family ``model_config`` belongs to (``ray_tpu.models`` says
-    what a family has to offer; the engine and its speculative draft are
-    each one of these)."""
+    what a family has to offer)."""
 
     def __init__(self, model_config, params, mesh=None, plan=None,
                  adapter_store=None):
@@ -516,13 +495,8 @@ class _Slot:
     request_id: int
     request: GenerationRequest
     generated: List[int]
-    last_token: int
     lease: Any = None  # KVCacheLease when the engine runs paged
     trace: Any = None  # {"ctx", "wall"} when the request is traced
-    # leading full blocks of (prompt + generated[:-1]) already committed
-    # into the radix index — the speculative path commits decode-tail
-    # blocks eagerly (accepted runs cross block boundaries mid-flight)
-    committed_blocks: int = 0
     last_emit_ts: float = 0.0  # monotonic stamp of the last emitted token
 
 
@@ -601,8 +575,6 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         seed: Optional[int] = None,
         plan=None,
         kv_tier=None,
-        draft=None,
-        spec_tokens: int = 0,
         prefill_chunk_tokens: int = 0,
         adapter_store=None,
     ):
@@ -699,10 +671,10 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 lambda p: jax.lax.dynamic_slice_in_dim(p, si, 1, axis=0), pool
             )
         )
-        # donated in-place row insert, like every program that advances a
-        # cache (_decode, _verify, _propose, _set_index): one compiled
-        # program for every slot (si is a traced scalar), no full-pool copy
-        # per admission. The solo row is read, not donated
+        # donated in-place row insert, like _decode, the other program that
+        # advances a cache: one compiled program for every slot (si is a
+        # traced scalar), no full-pool copy per admission. The solo row is
+        # read, not donated
         self._insert_row = jax.jit(
             lambda pool, solo, si: jax.tree.map(
                 lambda p, s: jax.lax.dynamic_update_index_in_dim(
@@ -713,61 +685,6 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             ),
             donate_argnums=(0,),
         )
-        # -- speculative decoding (draft proposes, target verifies) --------
-        # ``draft`` is (draft_model_config, draft_params): a small model
-        # whose proposals the target verifies k-at-a-time in ONE forward
-        # pass. The draft keeps its own dense per-slot cache pool (no
-        # paging — it is tiny) with the SAME position invariant as the
-        # target: K/V for prompt + generated[:-1], last_token not yet fed.
-        self._spec_k = int(spec_tokens) if draft is not None else 0
-        self._draft = None
-        self._draft_cache = None
-        if draft is not None and self._spec_k > 0:
-            draft_cfg, draft_params = draft
-            if draft_cfg.max_seq_len < model_config.max_seq_len:
-                raise ValueError(
-                    "draft max_seq_len must cover the target's "
-                    f"({draft_cfg.max_seq_len} < {model_config.max_seq_len})"
-                )
-            self._draft = _DecodeModelBase(
-                draft_cfg, draft_params, mesh, plan=plan
-            )
-            if self._draft._cache_shardings is not None:
-                self._propose = jax.jit(
-                    self._propose_impl, donate_argnums=(1,),
-                    out_shardings=(
-                        self._draft._replicated, self._draft._replicated,
-                        self._draft._replicated,
-                        self._draft._cache_shardings,
-                    ),
-                )
-            else:
-                self._propose = jax.jit(
-                    self._propose_impl, donate_argnums=(1,)
-                )
-            if self._cache_shardings is not None:
-                self._verify = jax.jit(
-                    self._verify_impl, donate_argnums=(1,),
-                    out_shardings=(
-                        self._replicated, self._replicated,
-                        self._cache_shardings, self._replicated,
-                    ),
-                )
-            else:
-                self._verify = jax.jit(
-                    self._verify_impl, donate_argnums=(1,)
-                )
-            # rollback-as-index-reset for the draft pool: K/V past the
-            # accepted prefix is garbage the causal mask never reads and
-            # the next write overwrites — only the position moves back
-            self._set_index = jax.jit(
-                lambda cache, idx: jax.tree.map(
-                    lambda leaf, kind: idx.astype(leaf.dtype)
-                    if kind == INDEX else leaf,
-                    cache, cache_kinds(cache),
-                ),
-                donate_argnums=(0,),
-            )
         # -- chunked prefill ----------------------------------------------
         # per-STEP token budget across all in-progress prefills; 0 = run
         # each admission prefill to completion (the historical behavior).
@@ -797,18 +714,6 @@ class ContinuousBatchingEngine(_DecodeModelBase):
     def _check(self, request: GenerationRequest) -> None:
         if len(request.token_ids) + request.max_new_tokens > self._cfg.max_seq_len:
             raise ValueError("prompt + max_new_tokens exceeds max_seq_len")
-        if self._spec_k and (
-            len(request.token_ids) + request.max_new_tokens + self._spec_k
-            > self._cfg.max_seq_len
-        ):
-            # the verify pass writes k+1 provisional positions past the
-            # current index; dynamic_update_slice CLAMPS out-of-range
-            # starts, which would silently corrupt earlier cache entries
-            # near max_seq_len — refuse up front instead
-            raise ValueError(
-                "prompt + max_new_tokens + spec_tokens exceeds max_seq_len "
-                "(speculative verification needs headroom)"
-            )
 
     def _submit(self, request: GenerationRequest, shipment,
                 sink: Optional[_Sink]) -> int:
@@ -854,12 +759,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             finished: List[tuple] = self._admit()
             if self._prefilling:
                 self._advance_prefills(finished)
-            if self._spec_k and self._draft is not None:
-                # the accept counts place the next proposal: read every step
-                if self._slots:
-                    self._spec_step(finished)
-            else:
-                self._dense_step(finished)
+            self._dense_step(finished)
             self._deliver(finished)
             return finished
 
@@ -971,10 +871,8 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         self._req_trace.clear()
         self._blocked_rids.clear()
         # a donated cache whose step failed may be gone with it
-        for name in ("_cache", "_draft_cache"):
-            if any(leaf.is_deleted()
-                   for leaf in jax.tree.leaves(getattr(self, name))):
-                setattr(self, name, None)
+        if any(leaf.is_deleted() for leaf in jax.tree.leaves(self._cache)):
+            self._cache = None
         failed, self._sinks = self._sinks, {}
         return failed
 
@@ -1026,7 +924,6 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     continue
                 tok = int(tokens[si])
                 slot.generated.append(tok)
-                slot.last_token = tok
                 if slot.last_emit_ts:
                     _record_itl(now - slot.last_emit_ts, mesh=self._mesh_tag)
                 slot.last_emit_ts = now
@@ -1103,85 +1000,6 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             self._step_count += 1
             self._sampled = self._sample_rows(logits, temps)
         return _Step(self._sampled, dict(self._slots))
-
-    def _spec_step(self, finished: List[tuple]) -> None:
-        """One speculative iteration for the whole pool: the draft model
-        proposes k tokens per row (ONE fused scan program), the target
-        verifies all k in ONE (num_slots, k+1) forward pass that also
-        computes the accepted-prefix length, the bonus / correction token,
-        and the rolled-back cache index — two compiled programs and one
-        host transfer of (tokens, counts) per step."""
-        S, k = self._num_slots, self._spec_k
-        with _span(
-            "engine.decode_dispatch", batch=len(self._slots),
-            live_tokens=self._live_tokens(),
-        ):
-            last = np.zeros((S, 1), np.int32)
-            temps = np.zeros(S, np.float32)
-            start = np.zeros(S, np.int32)
-            for si, slot in self._slots.items():
-                last[si, 0] = slot.last_token
-                temps[si] = max(slot.request.temperature, 0.0)
-                # cache invariant: K/V covers prompt + generated[:-1]
-                start[si] = (
-                    len(slot.request.token_ids) + len(slot.generated) - 1
-                )
-            key = jax.random.fold_in(self._rng, 10_000 + self._step_count)
-            self._step_count += 1
-            temps_d = jnp.asarray(temps)
-            # proposal: the whole k-step draft loop is one fused program
-            chunk, draft_tok, draft_logits, self._draft_cache = self._propose(
-                self._draft._params, self._draft_cache, jnp.asarray(last),
-                temps_d, key,
-            )
-            # adapters apply to the TARGET verify pass only: the draft
-            # proposes base-model tokens (it has no per-tenant fine-tune),
-            # which costs acceptance rate on adapter-heavy rows but never
-            # correctness — verification is against the adapter-applied
-            # target distribution
-            emitted, counts, self._cache, new_idx = self._verify(
-                self._params, self._cache, chunk, draft_tok, draft_logits,
-                temps_d, jax.random.fold_in(key, 0), jnp.asarray(start),
-                *self._adapter_args(self._row_adapter_slots()),
-            )
-            # the draft pool rolls back to the same corrected position
-            self._draft_cache = self._set_index(self._draft_cache, new_idx)
-        with _span("engine.sample_sync"):
-            em = host_sync(emitted)
-            cnt = host_sync(counts)
-        with _span("engine.emit"):
-            now = time.monotonic()
-            proposed = accepted = 0
-            for si in list(self._slots):
-                slot = self._slots[si]
-                req = slot.request
-                n = int(cnt[si])
-                proposed += k
-                accepted += n - 1  # the last emitted token is bonus/correction
-                done_reason = None
-                for j in range(n):
-                    tok = int(em[si, j])
-                    slot.generated.append(tok)
-                    slot.last_token = tok
-                    if req.eos_token_id is not None and tok == req.eos_token_id:
-                        done_reason = "eos"
-                        break
-                    if len(slot.generated) >= req.max_new_tokens:
-                        done_reason = "length"
-                        break
-                if slot.last_emit_ts:
-                    # n tokens landed in one step: each saw gap/n of latency
-                    _record_itl(
-                        (now - slot.last_emit_ts) / max(n, 1), n=n,
-                        mesh=self._mesh_tag,
-                    )
-                slot.last_emit_ts = now
-                if done_reason is not None:
-                    self._finish_slot(si, slot, done_reason, finished)
-                else:
-                    self._commit_decode_tail(si, slot)
-            if proposed:
-                _record_spec(proposed, accepted, mesh=self._mesh_tag)
 
     def expert_stats(self) -> Optional[dict]:
         """The routed model's running counts as plain numbers (this read
@@ -1308,15 +1126,6 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 "attention_chunks_dense": dense,
                 "attention_positions_copied": copied}
 
-    def _live_tokens(self) -> int:
-        """Key positions the coming decode step attends over all live rows
-        (prompt plus generated, the token being fed included): what a
-        length-aware attention reads, of num_slots x max_seq_len."""
-        return sum(
-            len(s.request.token_ids) + len(s.generated)
-            for s in self._slots.values()
-        )
-
     def _row_adapter_slots(self) -> np.ndarray:
         """Per-row adapter slot indices for the pooled decode batch; free
         rows read -1 (base path — their garbage compute stays adapter-free
@@ -1361,32 +1170,10 @@ class ContinuousBatchingEngine(_DecodeModelBase):
             )
         self._retire_slot(si)
 
-    def _commit_decode_tail(self, si: int, slot: _Slot) -> None:
-        """Speculative mode commits decode-tail blocks eagerly: an
-        accepted run can cross several block boundaries in one step, and
-        waiting for retire would keep long-lived sequences' tails
-        invisible to concurrent shared-prefix requests. Best-effort — the
-        lease is extended for the new blocks first; on pool pressure the
-        tail simply is not cached (never an error)."""
-        if self._kv is None or slot.lease is None or slot.lease.cacheable is False:
-            return
-        bs = self._kv.block_size
-        tokens = list(slot.request.token_ids) + slot.generated[:-1]
-        avail = len(tokens) // bs
-        if avail <= slot.committed_blocks:
-            return
-        self._kv.extend(slot.lease, avail - slot.committed_blocks)
-        self._commit_row_tail(
-            si, slot, self._kv_key_tokens(slot.request, tokens[: avail * bs]),
-            avail - slot.committed_blocks, trace=None,
-        )
-        slot.committed_blocks = avail
-
     def _commit_row_tail(self, si: int, slot: _Slot, key_tokens: List[int],
                          blocks: int, trace) -> None:
         """Read slot ``si``'s row back and commit its new full blocks: the
-        decode-tail commit both the retire path and the speculative path
-        make."""
+        decode-tail commit of the retire path."""
         with self._kv_commit_span(
             trace,
             {"request_id": slot.request_id, "tokens": len(key_tokens),
@@ -1431,10 +1218,7 @@ class ContinuousBatchingEngine(_DecodeModelBase):
         # K/V exists for prompt + generated[:-1]: the final sampled token
         # was never fed back through the model
         tokens = list(req.token_ids) + slot.generated[:-1]
-        already = max(
-            slot.committed_blocks,
-            len(req.token_ids) // self._kv.block_size,
-        )
+        already = len(req.token_ids) // self._kv.block_size
         full = len(tokens) // self._kv.block_size
         if full > already:
             self._commit_row_tail(
@@ -1724,11 +1508,10 @@ class ContinuousBatchingEngine(_DecodeModelBase):
 
     def _reads_first_at_once(self, export: bool) -> bool:
         """Who needs an admission's first token on the host before the
-        next step's read: the speculative step, which feeds the draft
-        ``last_token`` from the host, and a tier export, whose shipment
-        carries the token (and whose payload is host arrays: reading those
-        waits for the prefill anyway)."""
-        return export or bool(self._spec_k and self._draft is not None)
+        next step's read: a tier export, whose shipment carries the token
+        (and whose payload is host arrays: reading those waits for the
+        prefill anyway)."""
+        return export
 
     def _hold_to_bound(self, finished: List[tuple]) -> None:
         """Before another admission's prefill is dispatched: read the
@@ -1789,7 +1572,6 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 self._kv.release(adm.lease)
             return False
         slot.generated.append(first)
-        slot.last_token = first
         slot.last_emit_ts = time.monotonic()
         if req_eos:
             # found one read late: the row may ride a step already
@@ -1861,14 +1643,8 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                     np.array([first], np.int32) if known else first,
                     np.int32(si),
                 )
-            if self._draft is not None:
-                self._admit_draft_row(req, si)
             slot = self._slots[si] = _Slot(
-                request_id=rid, request=req, generated=[], last_token=-1,
-                lease=lease,
-                committed_blocks=(
-                    plen // self._kv.block_size if self._kv is not None else 0
-                ),
+                request_id=rid, request=req, generated=[], lease=lease,
                 trace=(
                     {"ctx": tr["ctx"], "wall": time.time()} if tr else None
                 ),
@@ -1991,119 +1767,6 @@ class ContinuousBatchingEngine(_DecodeModelBase):
                 jax.device_put, row, self._plan.cache_shardings(row)
             )
         return row
-
-    def _admit_draft_row(self, req: GenerationRequest, si: int) -> None:
-        """Full-prompt draft prefill into the draft pool. The draft never
-        pages or prefix-caches — it is small enough that recomputing its
-        prompt K/V is the cheap part of the speculative trade — but it
-        keeps the target's exact position invariant so both caches roll
-        back with the same corrected index."""
-        _, dsolo = self._draft._prefill(
-            self._draft._params, jnp.asarray([req.token_ids], jnp.int32)
-        )
-        if self._draft_cache is None:
-            self._draft_cache = self._empty_cache(dsolo)
-        self._draft_cache = self._insert_row(
-            self._draft_cache, dsolo, jnp.asarray(si, jnp.int32)
-        )
-
-    def _propose_impl(self, dparams, dcache, last, temps, key):
-        """The whole k-step draft proposal as ONE compiled program: a
-        ``lax.scan`` decodes and samples d_1..d_k with the draft cache as
-        carry, then one extra feed writes d_k's K/V so the rollback index
-        ``start + counts`` is valid for EVERY acceptance count. Fusing the
-        loop matters on both ends of the scale: on TPU it removes 2k-1
-        dispatch round-trips per step; on the 1-core CPU bench it is the
-        difference between speculation winning and losing to its own
-        Python overhead. Returns (chunk (S,k+1), draft_tok (S,k),
-        draft_logits (S,k,V), new_cache)."""
-        def one(carry, j):
-            cache, tok = carry
-            lg, cache = self._draft._decode_impl(dparams, cache, tok)
-            nxt = _sample_impl(lg, temps, jax.random.fold_in(key, j + 1))
-            return (cache, nxt[:, None].astype(jnp.int32)), (tok[:, 0], lg)
-
-        (cache, tok), (fed, dlogits) = jax.lax.scan(
-            one, (dcache, last), jnp.arange(self._spec_k)
-        )
-        _, cache = self._draft._decode_impl(dparams, cache, tok)
-        chunk = jnp.concatenate([fed.T, tok], axis=1)  # [last, d_1..d_k]
-        return chunk, chunk[:, 1:], jnp.swapaxes(dlogits, 0, 1), cache
-
-    def _verify_impl(self, params, cache, chunk, draft_tok, draft_logits,
-                     temps, key, start_idx, adapters=None,
-                     adapter_slots=None):
-        """The fused speculative verify: ONE forward pass over the
-        (num_slots, k+1) chunk [last_token, d_1..d_k] scores every
-        proposal (position j's logits predict the token after input j),
-        acceptance + bonus/correction sampling + cache-index rollback all
-        happen in the same program — the host sees only (emitted tokens,
-        counts).
-
-        Lossless by construction: at temperature 0 a proposal is accepted
-        iff it equals the target argmax, so the emitted prefix is exactly
-        the greedy trajectory; at temperature > 0 standard rejection
-        sampling (accept d_j w.p. min(1, p_t/p_d), resample the first
-        rejection from the normalized residual max(p_t - p_d, 0)) keeps
-        the output distribution identical to ancestral sampling from the
-        target."""
-        k = draft_tok.shape[1]
-        logits, vars_out = self._model.apply(
-            {"params": params, "cache": cache}, chunk, adapters,
-            adapter_slots, mutable=["cache"],
-        )  # (S, k+1, V)
-        new_cache = vars_out["cache"]
-        ka, kb = jax.random.split(key)
-        tscale = jnp.maximum(temps, 1e-6)
-        greedy_ok = jnp.argmax(logits[:, :k, :], axis=-1) == draft_tok
-        pt = jax.nn.softmax(
-            logits[:, :k, :] / tscale[:, None, None], axis=-1
-        )
-        pd = jax.nn.softmax(draft_logits / tscale[:, None, None], axis=-1)
-        pt_d = jnp.take_along_axis(pt, draft_tok[..., None], axis=-1)[..., 0]
-        pd_d = jnp.take_along_axis(pd, draft_tok[..., None], axis=-1)[..., 0]
-        u = jax.random.uniform(ka, draft_tok.shape)
-        stoch_ok = u * jnp.maximum(pd_d, 1e-20) < pt_d
-        ok = jnp.where((temps == 0.0)[:, None], greedy_ok, stoch_ok)
-        # longest accepted prefix: cumprod flips to 0 at the 1st rejection
-        a = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=-1), axis=-1)
-        pos_logits = jnp.take_along_axis(
-            logits, a[:, None, None], axis=1
-        )[:, 0, :]  # (S, V): the target's logits right after the prefix
-        greedy_bonus = jnp.argmax(pos_logits, axis=-1)
-        pt_a = jax.nn.softmax(pos_logits / tscale[:, None], axis=-1)
-        pd_a = jnp.take_along_axis(
-            pd, jnp.minimum(a, k - 1)[:, None, None], axis=1
-        )[:, 0, :]
-        resid = jnp.where(
-            (a < k)[:, None], jnp.maximum(pt_a - pd_a, 0.0), pt_a
-        )
-        resid_sum = jnp.sum(resid, axis=-1, keepdims=True)
-        resid = jnp.where(resid_sum > 1e-20, resid, pt_a)
-        stoch_bonus = jax.random.categorical(
-            kb, jnp.log(jnp.maximum(resid, 1e-20)), axis=-1
-        )
-        bonus = jnp.where(temps == 0.0, greedy_bonus, stoch_bonus)
-        counts = a + 1  # accepted prefix + the bonus/correction token
-        jpos = jnp.arange(k + 1)[None, :]
-        padded = jnp.pad(draft_tok, ((0, 0), (0, 1)))
-        emitted = jnp.where(
-            jpos < a[:, None], padded,
-            jnp.where(
-                jpos == a[:, None], bonus[:, None].astype(jnp.int32), 0
-            ),
-        )
-        new_idx = start_idx + counts
-        # rollback-as-index-reset: the only non-KV cache leaves are the
-        # (num_slots,) per-row write positions; K/V past new_idx is
-        # garbage the causal mask never reads and the next verify
-        # overwrites before attending
-        new_cache = jax.tree.map(
-            lambda leaf, kind: new_idx.astype(leaf.dtype)
-            if kind == INDEX else leaf,
-            new_cache, cache_kinds(new_cache),
-        )
-        return emitted, counts, new_cache, new_idx
 
     def _ensure_kv_ready(self) -> None:
         """Shape the manager's block pools before the first adopt/build.

@@ -166,7 +166,7 @@ class _LLMReplica:
         self._kv_tier = None
         self._kv_cache = None
         # worker.startup's ``engine`` phase: the block pool's manager and the
-        # engine with what it is built from (tier, draft, adapter store). The
+        # engine with what it is built from (tier, adapter store). The
         # slot rows and the pool are allocated by the first admission, later
         with tracing.startup_phase("engine"):
             if llm_config.kv_cache_blocks:
@@ -195,17 +195,6 @@ class _LLMReplica:
                         block_size=llm_config.kv_block_size,
                         codec=llm_config.kv_ship_codec,
                     )
-            draft = None
-            if llm_config.draft_model is not None:
-                # speculative draft: initialized per replica (the draft is
-                # tiny — no weight plane, no sharded publish)
-                from .. import models
-
-                draft_cfg = llm_config.build_draft_model_config()
-                draft_params = unbox_params(
-                    models.init_params(draft_cfg, jax.random.PRNGKey(1))
-                )
-                draft = (draft_cfg, draft_params)
             self._adapter_store = None
             if llm_config.adapters is not None:
                 # multi-tenant LoRA plane: one paged AdapterStore per
@@ -231,8 +220,6 @@ class _LLMReplica:
                 seed=llm_config.seed,
                 plan=plan,
                 kv_tier=self._kv_tier,
-                draft=draft,
-                spec_tokens=llm_config.spec_tokens,
                 prefill_chunk_tokens=llm_config.prefill_chunk_tokens,
                 adapter_store=self._adapter_store,
             )

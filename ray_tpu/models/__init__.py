@@ -22,8 +22,10 @@ The reference delegates model execution to torch/vLLM; this framework owns
 it.
 
 This module is also the one place that chooses a family for the serving
-stack (``llm/engine.py``, ``llm/serving.py``, ``llm/batch.py``), by the
-type of the model config. What the engine asks of a family:
+stack (``llm/config.py``, ``llm/engine.py``, ``llm/serving.py``,
+``llm/batch.py``): ``FAMILIES`` is the one table of them, read by name
+(``config_type``, ``refusals``) and by the type of the model config
+(``build``, ``init_params``). What the engine asks of a family:
 
 - ``build(config, mesh, decode)`` returns a flax module whose
   ``apply({"params": p[, "cache": c]}, tokens, adapters, adapter_slots)``
@@ -77,7 +79,7 @@ type of the model config. What the engine asks of a family:
     slot row is a slice of axis 0 as for the others; like ``STATE`` it is
     nothing a pool block holds, so a family with such a leaf gets no prefix
     reuse either (``carries_row_state``), and a chunk behind a cached
-    prefix has no ring-aware form (``_NO_RULES``: ``prefill_chunk``).
+    prefix has no ring-aware form (``FAMILIES``: ``prefill_chunk``).
     ``models/motif.py`` keeps ``window_latent`` ``(batch, 1, 128, 512)``
     and ``window_rope`` ``(batch, 1, 128, 64)`` in three layers of four,
     ``deepseek``'s two ``SEQUENCE`` leaves in the fourth;
@@ -100,65 +102,20 @@ type of the model config. What the engine asks of a family:
   are dense). A config whose ``experts_held`` is a ``(first, stop)`` range
   holds that share of each layer's experts (``MoEConfig.experts_held``):
   the choices sown are still over all ``n_experts``
-- a feature the family has no rules for (adapter bank, speculative draft,
-  a ``tp``/``sp`` mesh) is listed in ``_NO_RULES`` with the reason, and
-  ``LLMConfig`` refuses it at construction: no silent fallback
+- a feature the family has no rules for (adapter bank, a ``tp``/``sp``
+  mesh, a budgeted prefill) is listed in its entry of ``FAMILIES`` with the
+  reason, and ``LLMConfig`` refuses it at construction: no silent fallback
 
-A family is added by a module with those two functions, a branch in
-``_family`` and ``LLMConfig.build_model_config``, and its line here.
-``deepseek`` was added so (PR 30): ``models/deepseek.py`` with its own
-attention and cache leaves, the routed part ``moe.MoEFFN``'s under three new
-``MoEConfig`` fields, a latent form of the decode kernel
-(``ops/decode_attention.latent_decode_attention``); the engine changed
-only where it counted one expert row a layer. ``falcon_h1`` (PR 32) forced
-the leaf kinds above: its mixer's state is the first cached leaf without a
-sequence axis. ``solar_open2`` (PR 36) is the first with ``STATE`` leaves
-*and* routed layers, the first whose layers differ by index (``gqa_layers``),
-and the first to hold a share of its experts: ``llama.Attention`` gained
-``rope`` / ``attn_gate`` / ``attn_head_dim``, ``MoEConfig`` ``experts_held``,
-and the engine's expert counters count over the experts held. ``motif``
-(PR 50) forced the fourth leaf kind (two kinds of attention cache side by
-side in one row), shares ``deepseek``'s latent rows as functions
-(``latent_rows`` / ``latent_cache``), and gave ``MoEConfig`` an
-``expert_activation`` (``ops/moe_experts.py``'s PolyNorm form).
-``nemotron_h`` (PR 54) is the first stack of *unlike single-mixer layers*:
-a layer is a Mamba-2 mixer (``STATE`` leaves), an attention (``SEQUENCE``
-leaves and an ``INDEX``) or an expert layer (no leaf at all), so a row's
-cache tree has no entry for five layers in eleven and ``routed_layers``
-names layers that are nothing but experts. It brought no module of its
-own but the order: ``falcon_h1.Mixer`` came out from under
-``FalconH1Config`` (``MixerConfig``, which both families build),
-``MoEConfig`` gained ``latent_dim`` (the routed experts work between two
-shared projections, narrower than the model) and the ungated
-``expert_activation="relu2"`` (``ops/moe_experts.py``'s two-matrix form);
-the engine and the cache manager changed nowhere: they already walked the
-cache by leaf kind and counted experts over ``routed_layers``.
-``cohere2_moe`` (PR 59) is the first whose ring holds plain K/V heads (50 MB
-of a 92 MB row, read by ``ops/decode_attention.decode_attention`` at 16
-query heads a K/V head) and the first to prefill through
-``ops/flash_attention.py``: ``llama.Attention`` gained ``window`` (the ring,
-and the band in the flash kernel's forward), ``rope_interleaved``
-(``ops/rope.py``'s GPT-J pairs) and the rule that says which whole prompts
-take the kernel (``llama.prefills_through_kernel``). Its own are a
-LayerNorm, the parallel block and a tied head (one ``lm_head`` of ``(vocab,
-dim)``); the engine, the cache manager and the expert kernel changed
-nowhere.
-``smallthinker`` (PR 61) is the first whose routing does not depend on its
-layer's attention: ``MoEFFN`` became two steps (``route`` on the
-attention's input, the experts on the post-attention norm's output; every
-other family's one call is the two in a row), ``MoEConfig`` gained the
-``expert_activation`` ``"reglu"`` (``ops/moe_experts.py``'s SwiGLU kernel
-under ``relu``), and the sort that follows from the routing alone has a
-scope of its own (``moe.sort``, ``parallel/expert.py``). It holds every
-expert, and says so as the range ``(0, n_experts)`` where a check wants each
-row's last choice kept. Window layers as ``cohere2_moe``'s (rotate-half
-pairs), at 7 query heads a K/V head; the engine and the cache manager
-changed nowhere.
+A family is added by its module, with those two functions, and its entry
+in ``FAMILIES``: no line of ``llm/config.py``, ``llm/engine.py`` or
+``kvcache/`` names a family (what each family forced of the shared code is
+in ``CHANGES.md``, under the PR that added it).
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict
+import importlib
+from typing import Any, Dict, NamedTuple
 
 # the kinds of a cache leaf (module docstring)
 SEQUENCE, INDEX, STATE, WINDOW = "sequence", "index", "state", "window"
@@ -170,71 +127,55 @@ _WINDOW_PREFIX = "window_"
 # expert choices into, for the engine's expert counters (llm/engine.py)
 ROUTING = "moe_routing"
 
-# family -> feature -> why LLMConfig refuses it
-_NO_RULES: Dict[str, Dict[str, str]] = {
-    "llama": {},
-    "moe": {
+
+class _Family(NamedTuple):
+    config: str  # the config class's name in the family's module
+    refuses: Dict[str, str]  # serving feature -> why LLMConfig refuses it
+
+
+# the one table of families, by ``LLMConfig.model_family``, which is also the
+# name of the family's module under ``ray_tpu.models``
+FAMILIES: Dict[str, _Family] = {
+    "llama": _Family("LlamaConfig", {}),
+    "moe": _Family("MoEConfig", {
         "adapters": (
             "lora.AdapterStore sizes its slot bank from a LlamaConfig and "
             "has no placement for routed expert weights"
-        ),
-        "draft_model": (
-            "speculative verify has not been checked against routed "
-            "experts (a draft's accepted run changes which rows share an "
-            "expert), and the expert counters count plain decode steps"
         ),
         "mesh": (
             "parallel/plan.py has no partition rule for the (expert, ...) "
             "weights and the grouped expert kernel has no shard_map form "
             "yet (ROADMAP R1: ep rules)"
         ),
-    },
-    "deepseek": {
+    }),
+    "deepseek": _Family("DeepseekConfig", {
         "adapters": (
             "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
             "wq/wk/wv/wo and has no placement for the latent projections "
             "(wkv_a, wkv_b) or for expert weights"
-        ),
-        "draft_model": (
-            "speculative verify feeds several tokens a row against the "
-            "cache, which here is the absorbed-form einsum over all of "
-            "max_seq_len: unchecked against the published form at a "
-            "draft's shapes, and the expert counters count plain decode "
-            "steps"
         ),
         "mesh": (
             "the latent cache row has no head axis for parallel/plan.py's "
             "KV_SPEC to shard and the latent decode kernel no shard_map "
             "form; the expert weights have no ep rule (ROADMAP R1)"
         ),
-    },
-    "falcon_h1": {
+    }),
+    "falcon_h1": _Family("FalconH1Config", {
         "adapters": (
             "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
             "wq/wk/wv/wo at dim = n_heads x head_dim and has no placement "
             "for the mixer's projections"
         ),
-        "draft_model": (
-            "a rejected draft run cannot be undone by moving an index "
-            "back: the mixer's state has moved on, and no snapshot of it "
-            "is kept to return to"
-        ),
         "mesh": (
             "parallel/plan.py has no partition rule for a per-row state "
             "leaf (models.STATE) or for the mixer's projections"
         ),
-    },
-    "solar_open2": {
+    }),
+    "solar_open2": _Family("SolarOpen2Config", {
         "adapters": (
             "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
             "wq/wk/wv/wo at dim = n_heads x head_dim and has no placement "
             "for the KDA mixer's projections or for expert weights"
-        ),
-        "draft_model": (
-            "a rejected draft run cannot be undone by moving an index "
-            "back: the delta rule's state has moved on and no snapshot of "
-            "it is kept to return to, and the expert counters count plain "
-            "decode steps"
         ),
         "mesh": (
             "parallel/plan.py has no partition rule for a per-row state "
@@ -242,18 +183,12 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "the (expert, ...) weights; a held share of the experts has no "
             "ep exchange yet (ROADMAP R1)"
         ),
-    },
-    "motif": {
+    }),
+    "motif": _Family("MotifConfig", {
         "adapters": (
             "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
             "wq/wk/wv/wo and has no placement for the query and latent "
             "low-rank projections or for expert weights"
-        ),
-        "draft_model": (
-            "a rejected draft run cannot be undone by moving an index "
-            "back: a window layer's ring has overwritten the positions the "
-            "run would return to, and the expert counters count plain "
-            "decode steps (the model's own MTP head: ROADMAP R3)"
         ),
         "mesh": (
             "the latent row and the ring have no head axis for "
@@ -266,18 +201,12 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "layer's ring while it overwrites it: the ring has no form "
             "for more than one new position a row (ROADMAP R4)"
         ),
-    },
-    "cohere2_moe": {
+    }),
+    "cohere2_moe": _Family("Cohere2MoEConfig", {
         "adapters": (
             "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
             "wq/wk/wv/wo at dim = n_heads x head_dim (here 4096 against "
             "128 x 128) and has no placement for expert weights"
-        ),
-        "draft_model": (
-            "a rejected draft run cannot be undone by moving an index "
-            "back: a window layer's ring has overwritten the positions the "
-            "run would return to, and the expert counters count plain "
-            "decode steps"
         ),
         "mesh": (
             "parallel/plan.py has no partition rule for a ring leaf "
@@ -290,18 +219,12 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "layer's ring while it overwrites it: the ring has no form "
             "for more than one new position a row (ROADMAP R4)"
         ),
-    },
-    "smallthinker": {
+    }),
+    "smallthinker": _Family("SmallThinkerConfig", {
         "adapters": (
             "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
             "wq/wk/wv/wo at dim = n_heads x head_dim (here 2560 against "
             "28 x 128) and has no placement for expert weights"
-        ),
-        "draft_model": (
-            "a rejected draft run cannot be undone by moving an index "
-            "back: a window layer's ring has overwritten the positions the "
-            "run would return to, and the expert counters count plain "
-            "decode steps"
         ),
         "mesh": (
             "parallel/plan.py has no partition rule for a ring leaf "
@@ -314,19 +237,13 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "layer's ring while it overwrites it: the ring has no form "
             "for more than one new position a row (ROADMAP R4)"
         ),
-    },
-    "nemotron_h": {
+    }),
+    "nemotron_h": _Family("NemotronHConfig", {
         "adapters": (
             "lora.AdapterStore sizes its slot bank from a LlamaConfig's "
             "wq/wk/wv/wo in every layer and has no placement for a stack "
             "in which one layer in eleven has them, nor for the mixer's "
             "projections or for expert weights"
-        ),
-        "draft_model": (
-            "a rejected draft run cannot be undone by moving an index "
-            "back: the mixer layers' state has moved on and no snapshot of "
-            "it is kept to return to, and the expert counters count plain "
-            "decode steps (the model's own MTP head: ROADMAP R3)"
         ),
         "mesh": (
             "parallel/plan.py has no partition rule for a per-row state "
@@ -334,7 +251,7 @@ _NO_RULES: Dict[str, Dict[str, str]] = {
             "(expert, ...) weights; a held share of the experts has no ep "
             "exchange of latent rows yet (ROADMAP R1)"
         ),
-    },
+    }),
 }
 
 
@@ -374,37 +291,36 @@ def restarts_own_state(model_config) -> bool:
     return getattr(_family(model_config), "RESTARTS_OWN_STATE", False)
 
 
+def _entry(family: str) -> _Family:
+    if family not in FAMILIES:
+        raise ValueError(
+            f"unknown model family {family!r}: the families are "
+            f"{', '.join(FAMILIES)}"
+        )
+    return FAMILIES[family]
+
+
+def _module(family: str):
+    return importlib.import_module(f".{family}", __name__)
+
+
 def refusals(family: str) -> Dict[str, str]:
     """Serving features ``family`` has no rules for yet, with the reason."""
-    if family not in _NO_RULES:
-        raise ValueError(f"unknown model family {family!r}")
-    return _NO_RULES[family]
+    return _entry(family).refuses
+
+
+def config_type(family: str):
+    """The config class of ``family`` (its module is imported here)."""
+    entry = _entry(family)
+    return getattr(_module(family), entry.config)
 
 
 def _family(model_config):
-    from . import (
-        cohere2_moe, deepseek, falcon_h1, llama, moe, motif, nemotron_h,
-        smallthinker, solar_open2,
-    )
-
-    if isinstance(model_config, smallthinker.SmallThinkerConfig):
-        return smallthinker
-    if isinstance(model_config, cohere2_moe.Cohere2MoEConfig):
-        return cohere2_moe
-    if isinstance(model_config, nemotron_h.NemotronHConfig):
-        return nemotron_h
-    if isinstance(model_config, motif.MotifConfig):
-        return motif
-    if isinstance(model_config, solar_open2.SolarOpen2Config):
-        return solar_open2
-    if isinstance(model_config, falcon_h1.FalconH1Config):
-        return falcon_h1
-    if isinstance(model_config, deepseek.DeepseekConfig):
-        return deepseek
-    if isinstance(model_config, moe.MoEConfig):
-        return moe
-    if isinstance(model_config, llama.LlamaConfig):
-        return llama
+    """The module of the family ``model_config`` belongs to, by its exact
+    class: no family's config class subclasses another's."""
+    for family in FAMILIES:
+        if type(model_config) is config_type(family):
+            return _module(family)
     raise TypeError(
         f"no model family for a {type(model_config).__name__}"
     )

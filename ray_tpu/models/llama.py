@@ -139,7 +139,7 @@ def prefills_through_kernel(cfg: LlamaConfig, batch: int, seq: int) -> bool:
     at 8192 34 GB); the einsum everywhere else, which is every prompt the
     benchmark's other cells send (32 heads x 2048 x 2048 are 0.5 GB) until
     ROADMAP S4(b)(i) moves them with a measured pair of its own. A suffix
-    behind a cached prefix, a chunk and a verify never come here."""
+    behind a cached prefix and a budgeted prefill's chunk never come here."""
     return (cfg.window is not None
             or 4 * batch * cfg.n_heads * seq * seq > _EINSUM_SCORE_BYTES)
 
@@ -383,11 +383,11 @@ class Attention(nn.Module):
                 out = flash_attention(
                     q, *new, causal=True, window=ring, forward_only=True)
             else:
-                # prefill, chunked prefill, speculative verify. A whole
+                # prefill, whole or a chunk behind earlier keys. A whole
                 # prompt's keys are the s it just wrote into its own
                 # cache: it scores those and not the zeros behind them. A
-                # suffix behind a prefix hit, a chunk and a verify score
-                # all of a cache that holds earlier keys, each row from
+                # suffix behind a prefix hit and a budgeted prefill's chunk
+                # score all of a cache that holds earlier keys, each row from
                 # its own offset (what a kernel here would have to take:
                 # ROADMAP S4(b)); the arithmetic is one, so a hit answers
                 # as the miss did

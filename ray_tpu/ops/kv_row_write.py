@@ -25,8 +25,8 @@ sequence-minor on the TPU (PERF.md, finding 30.1), so it is written in
 that view, ``(rows, heads, width, positions)``, which is its own bytes: a
 tile of 128 positions in the lanes, selected on a lane iota.
 
-More than one new position a row (prefill, chunked prefill, speculative
-verify) is one ``dynamic_update_slice`` a row already and stays that.
+More than one new position a row (a prefill, a suffix behind a prefix hit, a
+budgeted chunk) is one ``dynamic_update_slice`` a row already and stays that.
 Positions clamp as ``dynamic_update_slice`` clamps them: the bytes a step
 leaves in the cache are the bytes the vmapped form left.
 """

@@ -49,7 +49,7 @@ from .mesh import make_mesh
 # Ordered (path-regex, PartitionSpec) table for the Llama family (the MoE
 # transformer reuses the same Attention module, so attention paths match;
 # expert FFN weights fall through to the replicate catch-all). First match
-# wins — mirror of SNIPPETS' match_partition_rules.
+# wins.
 DEFAULT_LLM_RULES: List[Tuple[str, P]] = [
     (r"attn/(wq|wk|wv)/base/kernel$", P(None, "tp")),
     (r"attn/wo/base/kernel$", P("tp", None)),

@@ -1788,12 +1788,10 @@ def _scaled_quantile(m: dict, q: float, scale: float) -> Optional[float]:
 
 # ---------------------------------------------------------------------------
 # LLM decode plane: inter-token latency (the per-token cadence a streaming
-# client sees — TTFT's sibling for everything after the first token) and
-# the speculative-decoding ledger (proposed vs accepted draft tokens; the
-# acceptance rate decides whether speculation is paying for itself on this
-# workload). Engines record through ray_tpu.llm.engine's _record_itl /
-# _record_spec; llm_summary() is the one rollup shared by
-# state.metrics_summary()["llm"] and the dashboard's /api/serve.
+# client sees — TTFT's sibling for everything after the first token).
+# Engines record through ray_tpu.llm.engine's _record_itl; llm_summary() is
+# the one rollup shared by state.metrics_summary()["llm"] and the
+# dashboard's /api/serve.
 # ---------------------------------------------------------------------------
 
 _SERVE_ITL_BOUNDARIES_S = [
@@ -1814,93 +1812,21 @@ def _ensure_llm_metrics() -> dict:
                     "itl": Histogram(
                         "serve_itl_seconds",
                         "Inter-token latency: gap between consecutive "
-                        "emitted tokens of one request (a speculative "
-                        "step landing n tokens records n observations "
-                        "of gap/n)",
+                        "emitted tokens of one request",
                         boundaries=_SERVE_ITL_BOUNDARIES_S,
-                        tag_keys=("mesh",),
-                    ),
-                    "proposed": Counter(
-                        "spec_proposed_tokens_total",
-                        "Draft tokens proposed to the verify pass",
-                        tag_keys=("mesh",),
-                    ),
-                    "accepted": Counter(
-                        "spec_accepted_tokens_total",
-                        "Draft tokens the target accepted (excludes the "
-                        "per-step bonus/correction token)",
-                        tag_keys=("mesh",),
-                    ),
-                    "acceptance": Gauge(
-                        "spec_acceptance_rate",
-                        "Lifetime accepted/proposed ratio of this "
-                        "process's speculative engines",
                         tag_keys=("mesh",),
                     ),
                 }
     return _llm_metrics
 
 
-def record_serve_itl(seconds: float, mesh: str = "tp=1", n: int = 1):
-    m = _ensure_llm_metrics()
-    for _ in range(max(int(n), 1)):
-        m["itl"].observe(seconds, {"mesh": mesh})
-
-
-def record_spec_tokens(proposed: int, accepted: int, mesh: str = "tp=1"):
-    m = _ensure_llm_metrics()
-    m["proposed"].inc(float(proposed), {"mesh": mesh})
-    m["accepted"].inc(float(accepted), {"mesh": mesh})
-    with m["proposed"]._lock:
-        total_p = float(sum(m["proposed"]._values.values()))
-    with m["accepted"]._lock:
-        total_a = float(sum(m["accepted"]._values.values()))
-    if total_p > 0:
-        m["acceptance"].set(total_a / total_p, {"mesh": mesh})
-
-
-def llm_counters() -> Dict[str, float]:
-    """Process-local readback (tests + bench; no cluster needed)."""
-    m = _ensure_llm_metrics()
-
-    def _total(metric) -> float:
-        with metric._lock:
-            return float(sum(metric._values.values()))
-
-    def _count(hist) -> float:
-        with hist._lock:
-            return float(
-                sum(sum(c) for c in hist._counts.values())
-            )
-
-    return {
-        "spec_proposed_tokens": _total(m["proposed"]),
-        "spec_accepted_tokens": _total(m["accepted"]),
-        "itl_observations": _count(m["itl"]),
-    }
+def record_serve_itl(seconds: float, mesh: str = "tp=1"):
+    _ensure_llm_metrics()["itl"].observe(seconds, {"mesh": mesh})
 
 
 def llm_summary(payloads: List[dict]) -> Dict[str, object]:
-    """Cluster rollup: speculative acceptance + ITL percentiles (ms)."""
-    out: Dict[str, object] = {
-        "spec_proposed_tokens": 0.0,
-        "spec_accepted_tokens": 0.0,
-        "spec_acceptance_rate": None,
-        "itl_ms": None,
-    }
-    simple = {
-        "spec_proposed_tokens_total": "spec_proposed_tokens",
-        "spec_accepted_tokens_total": "spec_accepted_tokens",
-    }
-    for payload in payloads:
-        for snap in payload.get("metrics", []):
-            name = snap.get("name")
-            if name in simple:
-                out[simple[name]] += float(sum(snap["values"].values()))
-    if out["spec_proposed_tokens"]:
-        out["spec_acceptance_rate"] = (
-            out["spec_accepted_tokens"] / out["spec_proposed_tokens"]
-        )
+    """Cluster rollup: ITL percentiles (ms)."""
+    out: Dict[str, object] = {"itl_ms": None}
     m = merged_histogram(payloads, "serve_itl_seconds")
     if m and m["count"]:
         out["itl_ms"] = {

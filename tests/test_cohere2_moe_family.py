@@ -411,13 +411,12 @@ def test_bf16_weights_are_drawn_in_float32():
 
 @pytest.mark.parametrize("feature,kwargs", [
     ("adapters", {"adapters": {"max_live": 2}}),
-    ("draft_model", {"draft_model": "llama-tiny"}),
     ("mesh", {"mesh": {"tp": 2}}),
     ("prefill_chunk", {"prefill_chunk_tokens": 16}),
 ])
 def test_refusals(feature, kwargs):
     reasons = models.refusals("cohere2_moe")
-    assert set(reasons) == {"adapters", "draft_model", "mesh", "prefill_chunk"}
+    assert set(reasons) == {"adapters", "mesh", "prefill_chunk"}
     with pytest.raises(ValueError) as refused:
         LLMConfig(model_id="c2moe-tiny", model_family="cohere2_moe",
                   kv_cache_blocks=4, **kwargs)

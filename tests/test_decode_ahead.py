@@ -728,30 +728,14 @@ def test_a_request_cancelled_between_its_prefill_and_its_read(builds, plain):
     then.generate([kept, gone])  # the pair stays side by side
 
 
-def test_the_speculative_engine_and_prefill_only_read_at_once(tiny, monkeypatch):
-    """Told apart by what the engine is, not by a switch: the speculative
-    step feeds the draft ``last_token`` from the host, and a disaggregated
-    prefill ships the token (as does the first export of a prefix to the
-    tier). Each reads behind its own prefill; nothing is ever unread."""
+def test_prefill_only_and_a_tier_export_read_at_once(tiny, monkeypatch):
+    """Told apart by what the engine is doing, not by a switch: a
+    disaggregated prefill ships the token, as does the first export of a
+    prefix to the tier. Each reads behind its own prefill; nothing is ever
+    unread."""
     from ray_tpu.kvtier import KVTierClient, LocalTierBackend
 
     cfg, params = tiny
-    dcfg = LlamaConfig.tiny(max_seq_len=64, n_layers=1)
-    dparams = unbox_params(init_params(dcfg, jax.random.PRNGKey(1)))
-    spec = ContinuousBatchingEngine(
-        cfg, params, num_slots=4, seed=SEED, draft=(dcfg, dparams), spec_tokens=3,
-        kv_cache=KVCacheManager(num_blocks=48, block_size=BS))
-    watch = Watch(spec, monkeypatch)
-    reqs = [GenerationRequest(token_ids=_new_prompt(9 + i), max_new_tokens=6)
-            for i in range(3)]
-    got = spec.generate(reqs)
-    watch.close()
-    assert [len(r.token_ids) for r in got] == [6, 6, 6]
-    assert watch.prefills == ["read"] * 3 and not watch.first_syncs
-    assert watch.order[:6] == ["prefill", "sync"] * 3 and watch.unread == [0, 0, 0]
-    assert _idle(spec)
-    spec.close()
-
     tier = KVTierClient(model="LlamaConfig", backend=LocalTierBackend(),
                         block_size=BS, codec="raw", holder_id="prefill")
     pre = ContinuousBatchingEngine(

@@ -1,9 +1,9 @@
 """The decode programs donate the cache they advance (ROADMAP S1).
 
-``_decode``, ``_verify`` and ``_propose`` take a cache and return its
-successor in the same buffers. A use of a donated buffer raises ("Array
-has been deleted"), so every path that hands a row or a pool to one of
-them must hold no second reference that outlives the call. These cases
+``_decode`` takes a cache and returns its successor in the same buffers
+(``_insert_row`` the pool's). A use of a donated buffer raises ("Array
+has been deleted"), so every path that hands a row or a pool to it
+must hold no second reference that outlives the call. These cases
 walk each such path on the CPU (which donates like the chip does) and
 hold its tokens to a greedy loop over *undonating* programs built here:
 they fail if a donated buffer is ever reused, and if a result changes.
@@ -27,17 +27,14 @@ N_NEW = 6
 @pytest.fixture(scope="module")
 def tiny():
     cfg = LlamaConfig.tiny(max_seq_len=64)
-    params = unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
-    dcfg = LlamaConfig.tiny(max_seq_len=64, n_layers=1)
-    dparams = unbox_params(init_params(dcfg, jax.random.PRNGKey(1)))
-    return cfg, params, (dcfg, dparams)
+    return cfg, unbox_params(init_params(cfg, jax.random.PRNGKey(0)))
 
 
 @pytest.fixture(scope="module")
 def reference(tiny):
     """Greedy tokens from the engine's own two functions under plain
     ``jax.jit``: no donation, one request at a time."""
-    cfg, params, _ = tiny
+    cfg, params = tiny
     model = _DecodeModelBase(cfg, params)
     prefill = jax.jit(model._prefill_impl)
     decode = jax.jit(model._decode_impl)
@@ -67,13 +64,12 @@ def _prompt(seed, n):
     return [int(t) for t in np.random.RandomState(seed).randint(1, 250, n)]
 
 
-def _engine(tiny, *, paged=True, chunk=0, spec=0, num_slots=2):
-    cfg, params, draft = tiny
+def _engine(tiny, *, paged=True, chunk=0, num_slots=2):
+    cfg, params = tiny
     kv = KVCacheManager(num_blocks=48, block_size=BS) if paged else None
     eng = ContinuousBatchingEngine(
         cfg, params, num_slots=num_slots, kv_cache=kv, seed=0,
         prefill_chunk_tokens=chunk,
-        draft=draft if spec else None, spec_tokens=spec,
     )
     return eng, kv
 
@@ -145,26 +141,6 @@ def _generate_and_stream_match_the_undonated_loop(tiny, reference, paged):
     assert batch == streamed == final.token_ids == reference(a)
 
 
-def _speculative_equals_plain(tiny, reference, paged):
-    # _propose donates the draft pool, _verify the target's, _set_index
-    # the draft's again; a random draft makes every step roll back
-    eng, _ = _engine(tiny, paged=paged, spec=3)
-    a, b = _prompt(10, 13), _prompt(11, 9)
-    out = eng.generate([
-        GenerationRequest(token_ids=p, max_new_tokens=2 * N_NEW)
-        for p in (a, b)
-    ])
-    assert [r.token_ids for r in out] == [
-        reference(a, 2 * N_NEW), reference(b, 2 * N_NEW)
-    ]
-
-
-def _speculative_over_chunked_prefill(tiny, reference, paged):
-    eng, _ = _engine(tiny, paged=paged, spec=3, chunk=BS)
-    a = _prompt(12, 3 * BS + 2)
-    assert _run(eng, a, 2 * N_NEW) == reference(a, 2 * N_NEW)
-
-
 @pytest.mark.parametrize(
     "scenario,paged",
     [
@@ -175,10 +151,6 @@ def _speculative_over_chunked_prefill(tiny, reference, paged):
         (_generate_and_stream_match_the_undonated_loop, None),
         (_generate_and_stream_match_the_undonated_loop, False),
         (_generate_and_stream_match_the_undonated_loop, True),
-        (_speculative_equals_plain, False),
-        (_speculative_equals_plain, True),
-        (_speculative_over_chunked_prefill, False),
-        (_speculative_over_chunked_prefill, True),
     ],
     ids=lambda v: v.__name__.strip("_") if callable(v)
     else {None: "one_slot", False: "dense", True: "paged"}[v],
@@ -187,12 +159,11 @@ def test_no_donated_buffer_is_reused(tiny, reference, scenario, paged):
     scenario(tiny, reference, paged)
 
 
-@pytest.mark.parametrize("spec", [0, 3], ids=["dense_step", "spec_step"])
-def test_a_step_consumes_the_cache_it_was_given(tiny, spec):
+def test_a_step_consumes_the_cache_it_was_given(tiny):
     """Donation happened: what was ``engine._cache`` before a step is
-    deleted after it, and ``engine._cache`` is a new, live tree (so are
-    the draft's pool and a chunked prefill's row)."""
-    eng, _ = _engine(tiny, spec=spec, chunk=BS)
+    deleted after it, and ``engine._cache`` is a new, live tree (so is a
+    chunked prefill's row)."""
+    eng, _ = _engine(tiny, chunk=BS)
     eng.add_request(GenerationRequest(
         token_ids=_prompt(13, BS + 2), max_new_tokens=4
     ))
@@ -207,15 +178,13 @@ def test_a_step_consumes_the_cache_it_was_given(tiny, spec):
         leaf.is_deleted() for r in rows for leaf in jax.tree.leaves(r)
     )
     assert eng._slots and eng._prefilling
-    holders = ["_cache"] + (["_draft_cache"] if spec else [])
-    before = {h: getattr(eng, h) for h in holders}
+    before = eng._cache
     eng.step()
-    for h in holders:
-        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(before[h]))
-        after = jax.tree.leaves(getattr(eng, h))
-        assert not any(leaf.is_deleted() for leaf in after)
-        assert all(np.isfinite(np.asarray(leaf, np.float32)).all()
-                   for leaf in after)
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(before))
+    after = jax.tree.leaves(eng._cache)
+    assert not any(leaf.is_deleted() for leaf in after)
+    assert all(np.isfinite(np.asarray(leaf, np.float32)).all()
+               for leaf in after)
     assert all(len(r.token_ids) == 4
                for r in eng.run_until_complete().values())
 

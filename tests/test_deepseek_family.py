@@ -489,11 +489,9 @@ def test_llm_config_builds_the_family_and_refuses_what_has_no_rules():
     tiny_cfg = LLMConfig(
         model_id="deepseek-tiny", model_family="deepseek").build_model_config()
     assert tiny_cfg.max_seq_len == 512 and tiny_cfg.n_layers == 3
-    assert sorted(models.refusals("deepseek")) == ["adapters", "draft_model", "mesh"]
+    assert sorted(models.refusals("deepseek")) == ["adapters", "mesh"]
     with pytest.raises(ValueError, match="adapters"):
         LLMConfig(adapters={"max_live": 2}, **kwargs)
-    with pytest.raises(ValueError, match="draft_model"):
-        LLMConfig(draft_model="llama-tiny", **kwargs)
     with pytest.raises(ValueError, match="mesh"):
         LLMConfig(mesh={"tp": 2}, **kwargs)
     with pytest.raises(ValueError, match="first_dense_layers"):

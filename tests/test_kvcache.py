@@ -419,11 +419,10 @@ def _partly_shared(pin):
 
 
 def _reservation_runs_out(kv, row):
-    """A decoded tail past the reservation: one block extended for, the
-    rest allocated as the walk meets them."""
+    """A decoded tail past the reservation: its blocks are allocated as
+    the walk meets them."""
     lease = kv.m.acquire(_toks(0, 2))
     got = [kv.commit(lease, _toks(0, 2), row(0))]
-    assert kv.m.extend(lease, 1) == 1
     got.append(kv.commit(lease, _toks(0, 6), row(1), pin=False))
     return got, [lease]
 

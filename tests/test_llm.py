@@ -362,16 +362,15 @@ class TestAdmission:
     "kwargs,refused",
     [
         (dict(prefill_chunk_tokens=4), False),
-        (dict(draft_model="llama-tiny", spec_tokens=3), False),
         (dict(adapters={"max_live": 2}), False),
         (dict(roles={"prefill": 1, "decode": 1}), True),
         (dict(kv_tier=True), True),
     ],
-    ids=["chunked_prefill", "draft_model", "adapters", "roles", "kv_tier"],
+    ids=["chunked_prefill", "adapters", "roles", "kv_tier"],
 )
 def test_what_a_config_without_kv_cache_blocks_may_ask(kwargs, refused):
     """``kv_cache_blocks`` says how many blocks, not which engine: chunked
-    prefill, a draft and adapters serve over dense rows, from a replica
+    prefill and adapters serve over dense rows, from a replica
     whose greedy tokens are the whole-forward ones; what ships KV blocks is
     refused, and the message names the pool."""
     from ray_tpu.llm.serving import _LLMReplica

@@ -310,17 +310,14 @@ def test_llm_config_builds_the_family_and_refuses_what_has_no_rules():
         LLMConfig(**dict(kwargs, model_kwargs=dict(dropless=False)))
     with pytest.raises(ValueError, match="adapters"):
         LLMConfig(adapters={"max_live": 2}, **kwargs)
-    with pytest.raises(ValueError, match="draft_model"):
-        LLMConfig(draft_model="llama-tiny", **kwargs)
     with pytest.raises(ValueError, match="mesh"):
         LLMConfig(mesh={"tp": 2}, **kwargs)
     with pytest.raises(ValueError, match="mesh"):
         LLMConfig(tensor_parallel_size=2, **kwargs)
     with pytest.raises(ValueError, match="unknown model family"):
         LLMConfig(model_family="mamba")
-    # the dense family still takes all three
-    LLMConfig(kv_cache_blocks=8, adapters={"max_live": 2}, mesh={"tp": 2},
-              draft_model="llama-tiny")
+    # the dense family still takes both
+    LLMConfig(kv_cache_blocks=8, adapters={"max_live": 2}, mesh={"tp": 2})
 
 
 def test_the_llama_engine_is_what_it_was():

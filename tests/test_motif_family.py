@@ -438,13 +438,12 @@ def test_cache_leaves_classify_by_name():
 
 @pytest.mark.parametrize("feature,kwargs", [
     ("adapters", {"adapters": {"max_adapters": 2}}),
-    ("draft_model", {"draft_model": "llama-tiny"}),
     ("mesh", {"mesh": {"tp": 2}}),
     ("prefill_chunk", {"prefill_chunk_tokens": 64}),
 ])
 def test_refusals(feature, kwargs):
     assert set(models.refusals("motif")) == {
-        "adapters", "draft_model", "mesh", "prefill_chunk"}
+        "adapters", "mesh", "prefill_chunk"}
     with pytest.raises(ValueError, match=feature):
         LLMConfig(model_id="motif-tiny", model_family="motif",
                   kv_cache_blocks=4, **kwargs)

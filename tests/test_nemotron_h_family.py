@@ -333,12 +333,11 @@ def test_bf16_weights_are_drawn_in_float32():
 
 @pytest.mark.parametrize("feature,kwargs", [
     ("adapters", {"adapters": {"max_live": 2}}),
-    ("draft_model", {"draft_model": "llama-tiny"}),
     ("mesh", {"mesh": {"tp": 2}}),
 ])
 def test_refusals(feature, kwargs):
     reasons = models.refusals("nemotron_h")
-    assert set(reasons) == {"adapters", "draft_model", "mesh"}
+    assert set(reasons) == {"adapters", "mesh"}
     with pytest.raises(ValueError) as refused:
         LLMConfig(model_id="nemotron-tiny", model_family="nemotron_h",
                   kv_cache_blocks=4, **kwargs)

@@ -475,11 +475,10 @@ def test_cache_leaves_classify_by_name():
 
 @pytest.mark.parametrize("feature,kwargs", [
     ("adapters", {"adapters": {"max_live": 2}}),
-    ("draft_model", {"draft_model": "llama-tiny"}),
     ("mesh", {"mesh": {"tp": 2}}),
 ])
 def test_refusals(feature, kwargs):
-    assert set(models.refusals("solar_open2")) == {"adapters", "draft_model", "mesh"}
+    assert set(models.refusals("solar_open2")) == {"adapters", "mesh"}
     with pytest.raises(ValueError, match=feature):
         LLMConfig(model_id="solar-tiny", model_family="solar_open2",
                   kv_cache_blocks=4, **kwargs)
